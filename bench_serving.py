@@ -50,9 +50,12 @@ rows are owned by this mode; the default sweep never writes them.
 
     python bench_serving.py --cpu --fleet 2 --telemetry fleet.jsonl
 
-Exit codes: 0 ok; 4 when --check-vs-single is given and the measured
-ratio falls below it (the bench_gate findings code), or when the fleet
-leg's affinity/identity invariants fail.
+Exit codes: 0 ok; 2 when `--cpu` (the hermetic tiny-shape smoke) was
+not given and JAX reports no TPU; 4 when --check-vs-single is given and
+the measured ratio falls below it (the bench_gate findings code), or
+when the fleet leg's affinity/identity invariants fail. A phase that
+raises ends the run with a traceback. Every JSON line names platform,
+device_kind and device count.
 """
 import argparse
 import json
@@ -262,6 +265,7 @@ def fleet_phase(args, n_replicas):
     fleet.* SERVING_BENCH_METRICS rows."""
     import jax
     import paddle_tpu as paddle
+    from bench import device_stamp
     from paddle_tpu import telemetry
     from paddle_tpu.fleet import FleetRouter, InProcessReplica
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
@@ -398,6 +402,7 @@ def fleet_phase(args, n_replicas):
         "metric": "fleet.rated_throughput_tokens_per_sec",
         "value": round(fleet_tps, 1),
         "unit": "tokens/sec",
+        **device_stamp(),
         "fleet.rated_throughput_tokens_per_sec": round(fleet_tps, 1),
         "fleet.scaling_efficiency": round(efficiency, 4),
         "fleet.replicas": n_replicas,
@@ -453,9 +458,8 @@ def main(argv=None):
                     ">= R x the single-request predictor")
     args = ap.parse_args(argv)
 
-    if args.cpu:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from bench import device_stamp, start
+    on_tpu = start(args.cpu)
     if args.fleet:
         if args.fleet < 1:
             ap.error("--fleet needs N >= 1")
@@ -466,11 +470,10 @@ def main(argv=None):
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
     from paddle_tpu.serving import EngineConfig, ServingEngine
 
-    on_tpu = jax.default_backend() == "tpu"
     dev = jax.devices()[0]
     paddle.seed(0)
     if on_tpu:
-        # the BENCH_r05 wo8 decode recipe, engine-served: GPT-125M
+        # bench.py's wo8 decode recipe, engine-served: GPT-125M
         # W8A16 at serving batch sizes (decode is weight-bandwidth
         # bound, so slot count ~multiplies the weight-sweep yield)
         mcfg = GPTConfig.gpt3_125m(max_seq_len=1024, dropout=0.0)
@@ -481,9 +484,9 @@ def main(argv=None):
         slo_ttft = args.slo_ttft_ms or 2000.0
         slo_tpot = args.slo_tpot_ms or 20.0
     else:
-        # CPU smoke: big enough that the model step dominates the
+        # --cpu smoke: big enough that the model step dominates the
         # per-step host work (h=128 toys measure engine overhead, not
-        # batching — see ROUND notes), small enough for the CI budget
+        # batching), small enough for the CI budget
         mcfg = GPTConfig(vocab_size=2048, hidden_size=256, num_layers=4,
                          num_heads=8, max_seq_len=128, dropout=0.0,
                          use_flash_attention=False)
@@ -559,6 +562,7 @@ def main(argv=None):
         "metric": "serving.throughput_tokens_per_sec",
         "value": best["tokens_per_sec"],
         "unit": "tokens/sec",
+        **device_stamp(),
         "slo_ttft_ms": slo_ttft,
         "slo_tpot_ms": slo_tpot,
         "slo_met": bool(within),

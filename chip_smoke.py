@@ -1,0 +1,623 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+One process, started cold, drives the two main paths through the entry
+points a user calls, at the full published width of GPT-3 125M
+(12 layers, d=768, 12 heads of 64, vocab 50304), and checks what comes
+out by the repo's own means:
+
+  train     paddle.jit.TrainStep (bench.py's trainer) at 24 x 1024:
+            finite falling loss, the flash kernels in the compiled
+            step, loss parity with the composed attention path
+  serve     serving.ServingEngine (bench_serving.py's configuration)
+            answers seeded requests: token counts, a quiesced pool,
+            paged_decode / flash_prefill_chunk in the compiled steps,
+            logit parity with the gather+dense path
+  kernels   every registered Pallas kernel compiled by Mosaic against
+            its declared fallback, then the production tile shapes the
+            two legs above do not reach
+  four_chip dist.ShardedTrainStep on a dp=2 x mp=2 mesh when there are
+            four devices; reported as not run otherwise
+
+    python chip_smoke.py
+
+It refuses any platform but `tpu`, exits non-zero the moment a leg
+fails, and on a pass prints as its last line
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
+Times it prints along the way are not results.
+
+To debug the script itself where there is no chip, set
+CHIP_SMOKE_DEBUG=tiny: toy sizes, kernels in the Pallas interpreter,
+the compiled-text checks skipped. Such a run cannot pass — it ends
+with `NOT A CHIP RUN` and exit code 3 and prints no result line.
+"""
+import collections
+import functools
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+import warnings
+
+import numpy as np
+
+TINY = os.environ.get("CHIP_SMOKE_DEBUG") == "tiny"
+
+BF16_TOL = 3e-2     # the repo's bf16 tolerance (.claude/skills/verify)
+
+Sizes = collections.namedtuple(
+    "Sizes", "model batch seq parity_batch train_steps engine prompt_lens "
+             "tail_lens flash_seq decode_len rows")
+
+
+def sizes():
+    """The full GPT-3 125M sizes, or the toy ones of the debug mode."""
+    from paddle_tpu.models.gpt import GPTConfig
+    if not TINY:
+        return Sizes(
+            model=GPTConfig.gpt3_125m(max_seq_len=1024, dropout=0.0),
+            batch=24, seq=1024,
+            # the composed attention path needs 19.3 GB at 24 x 1024
+            # (XLA's own figure), so the parity run takes 8 rows
+            parity_batch=8, train_steps=4,
+            engine=dict(max_slots=16, block_size=16, prefill_chunk=128,
+                        max_model_len=512, weights="wo8"),
+            prompt_lens=(37, 150, 260, 16), tail_lens=(30, 20, 90),
+            flash_seq=2048, decode_len=1024, rows=4096)
+    return Sizes(
+        model=GPTConfig(vocab_size=512, hidden_size=128, num_layers=1,
+                        num_heads=2, max_seq_len=256, dropout=0.0),
+        batch=4, seq=256, parity_batch=2, train_steps=3,
+        engine=dict(max_slots=4, block_size=16, prefill_chunk=32,
+                    max_model_len=128, weights="wo8"),
+        prompt_lens=(21, 40, 70, 16), tail_lens=(10, 8, 30),
+        flash_seq=256, decode_len=32, rows=256)
+
+
+def check(ok, what):
+    """A leg's assertion: says what was checked, raises when it failed."""
+    print(f"  [{'ok' if ok else 'FAIL'}] {what}", flush=True)
+    if not ok:
+        raise AssertionError(what)
+
+
+def max_err(got, want):
+    """Largest absolute difference, relative to the reference's scale
+    (at least 1), over matching pytrees."""
+    import jax
+    worst = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        g = np.asarray(g, np.float64)
+        w = np.asarray(w, np.float64)
+        if g.shape != w.shape:
+            raise AssertionError(f"shape {g.shape} vs reference {w.shape}")
+        worst = max(worst, float(np.max(np.abs(g - w)))
+                    / max(1.0, float(np.max(np.abs(w)))))
+    return worst
+
+
+def kernels_in(obs, family):
+    """Counter of the Mosaic kernels in the programs the compile
+    observatory built for `family` (by pallas_call name), plus the
+    distinct operand shapes seen per kernel."""
+    from paddle_tpu.ops.kernel_registry import mosaic_custom_calls
+    names, shapes = collections.Counter(), {}
+    programs = [c for f, c in obs.compiled_programs() if f.startswith(family)]
+    if not programs:
+        raise AssertionError(f"no compiled program of family {family!r}")
+    for compiled in programs:
+        for name, operands in mosaic_custom_calls(compiled.as_text()):
+            names[name] += 1
+            shapes.setdefault(name, set()).add(operands)
+    return names, shapes
+
+
+# ---------------------------------------------------------------------------
+# train leg
+# ---------------------------------------------------------------------------
+
+def leg_train(sz):
+    from bench import build_gpt_train_step, seeded_token_batch
+    from paddle_tpu import telemetry
+    from paddle_tpu.flags import get_flag, set_flags
+    from paddle_tpu.telemetry.mfu import flops_drift, model_flops_per_token
+
+    cfg = sz.model
+    with telemetry.CompileObservatory(action="record") as obs:
+        model, step = build_gpt_train_step(cfg)
+        ids, lbl = seeded_token_batch(cfg.vocab_size, sz.batch, sz.seq)
+        losses = [float(step(ids, lbl).item())
+                  for _ in range(sz.train_steps)]
+    print(f"  losses at {sz.batch} x {sz.seq}: {losses}")
+    check(all(np.isfinite(losses)), "every loss is finite")
+    check(losses[-1] < losses[0], "loss falls over the steps")
+    n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
+    rec = [r for r in obs.records if r.get("cost")][-1]
+    analytic = model_flops_per_token(
+        n_params, cfg.num_layers, cfg.hidden_size, sz.seq) \
+        * sz.batch * sz.seq
+    print(f"  cost analysis of the compiled step: {rec['cost']}; drift "
+          f"from the analytic 6N+12LHS count: "
+          f"{flops_drift(rec['cost']['flops'], analytic):+.3f}")
+    if not TINY:
+        names, shapes = kernels_in(obs, "TrainStep")
+        print(f"  Mosaic kernels in the compiled step: {dict(names)} "
+              f"on {shapes}")
+        fwd = sum(n for k, n in names.items() if k.startswith("flash_fwd"))
+        bwd = sum(n for k, n in names.items() if k.startswith("flash_bwd"))
+        check(fwd == cfg.num_layers and bwd >= cfg.num_layers,
+              f"a flash forward and backward kernel per layer "
+              f"({fwd} fwd, {bwd} bwd, {cfg.num_layers} layers) — the "
+              "step did not give way to _composed_attention")
+    del model, step
+    gc.collect()
+
+    # the first steps against the composed attention path: the second
+    # and third see the kernels' gradients
+    ids, lbl = seeded_token_batch(cfg.vocab_size, sz.parity_batch, sz.seq)
+    pallas_was = get_flag("use_pallas_attention")
+    runs = {}
+    try:
+        for use_pallas in (True, False):
+            set_flags({"use_pallas_attention": use_pallas})
+            model, step = build_gpt_train_step(cfg)
+            runs[use_pallas] = [float(step(ids, lbl).item())
+                                for _ in range(3)]
+            del model, step
+            gc.collect()
+    finally:
+        set_flags({"use_pallas_attention": pallas_was})
+    diff = max(abs(a - b) for a, b in zip(runs[True], runs[False]))
+    print(f"  {sz.parity_batch} x {sz.seq}: flash {runs[True]} "
+          f"composed {runs[False]}")
+    check(diff <= BF16_TOL,
+          f"first three losses within {BF16_TOL} of the composed "
+          f"attention path (max diff {diff:.2e})")
+    return {"losses": losses, "first_loss": losses[0]}
+
+
+# ---------------------------------------------------------------------------
+# serve leg
+# ---------------------------------------------------------------------------
+
+def leg_serve(sz):
+    import jax
+    import paddle_tpu as paddle
+    from paddle_tpu import telemetry
+    from paddle_tpu.models.gpt import GPTForPretraining
+    from paddle_tpu.ops.pallas_decode import (flash_prefill_supported,
+                                              paged_decode_supported)
+    from paddle_tpu.serving import (EngineConfig, SamplingParams,
+                                    ServingEngine)
+
+    cfg = sz.model
+    paddle.seed(0)
+    model = GPTForPretraining(cfg)
+    ecfg = EngineConfig(**sz.engine)
+    check(paged_decode_supported(ecfg.block_size, cfg.hidden_size,
+                                 cfg.num_heads)
+          and flash_prefill_supported(ecfg.block_size, ecfg.prefill_chunk,
+                                      cfg.hidden_size, cfg.num_heads),
+          "the paged decode and flash prefill gates admit this engine")
+    rs = np.random.RandomState(0)
+
+    def tokens(n):
+        return rs.randint(0, cfg.vocab_size, (n,)).tolist()
+
+    shared = tokens(3 * ecfg.block_size)        # a 3-block common prefix
+    tails = [shared + tokens(n) for n in sz.tail_lens]
+    first_wave = [tokens(n) for n in sz.prompt_lens] + tails[:1]
+    second_wave = tails[1:]
+    with warnings.catch_warnings(record=True) as caught, \
+            telemetry.CompileObservatory(action="record") as obs:
+        warnings.simplefilter("always")
+        engine = ServingEngine(model, config=ecfg)
+        handles = []
+        for wave in (first_wave, second_wave):
+            for i, prompt in enumerate(wave):
+                want = 5 + 3 * i
+                handles.append((want, engine.submit(
+                    prompt, SamplingParams(max_new_tokens=want))))
+            engine.run_until_idle()
+    got = [(want, len(h.output_tokens), h.status) for want, h in handles]
+    print(f"  (requested, produced, status) per request: {got}")
+    check(all(w == n and s == "finished" for w, n, s in got),
+          "every request finished with the requested token count")
+    engine.pool.assert_quiesced()
+    check(True, "the block pool quiesced")
+    stats = engine.prefix_stats()
+    check(stats["hits"] >= 1,
+          f"the shared prefix was served from the cache ({stats})")
+    donated = [str(w.message) for w in caught if "onat" in str(w.message)]
+    check(not donated, f"no donation warning ({donated[:1]})")
+    if not TINY:
+        L = cfg.num_layers
+        for family, kernel in (("serving_prefill", "flash_prefill_chunk"),
+                               ("serving_decode", "paged_decode")):
+            names, shapes = kernels_in(obs, family)
+            print(f"  Mosaic kernels in {family}: {dict(names)} on "
+                  f"{shapes}")
+            # weights="wo8" quantizes the linears only, so the tied head
+            # stays a bf16 matmul; int8_matvec is held against its
+            # fallback at this model's head shape in the kernel leg
+            check(names[kernel] == L,
+                  f"{family} runs {kernel} as a kernel in every layer")
+
+    # one engine step with the kernels against the same step through
+    # gather+dense, on the engine's own arenas: two prefill chunks of a
+    # fresh prompt, then a decode batch of unequal context lengths
+    S, C = ecfg.max_slots, ecfg.prefill_chunk
+    mb = engine.max_blocks_per_seq
+    params = engine._param_vals()
+    k, v = engine.cache.k, engine.cache.v
+    table = np.arange(1, mb + 1, dtype=np.int32)
+    step_err = {}
+    for p0 in (0, C):
+        ids = rs.randint(0, cfg.vocab_size, (1, C)).astype(np.int32)
+        outs = {uk: jax.jit(functools.partial(
+            engine._prefill_logits, use_kernel=uk))(
+                params, k, v, ids, np.int32(p0), np.int32(C), table)
+            for uk in (True, False)}
+        step_err[f"prefill@{p0}"] = max_err(outs[True][0], outs[False][0])
+        _, k, v = outs[True]
+    ctx = (2 * C - 1 - (C // S) * np.arange(S)).astype(np.int32)
+    toks = rs.randint(0, cfg.vocab_size, (S,)).astype(np.int32)
+    tables = np.tile(table, (S, 1))
+    outs = {uk: jax.jit(functools.partial(
+        engine._decode_logits, use_kernel=uk))(
+            params, k, v, toks, ctx, tables) for uk in (True, False)}
+    step_err["decode"] = max_err(outs[True][0], outs[False][0])
+    check(all(np.isfinite(np.asarray(o[0], np.float32)).all()
+              for o in outs.values()), "decode logits are finite")
+    check(max(step_err.values()) <= BF16_TOL,
+          f"last-position logits with the kernels within {BF16_TOL} of "
+          f"gather+dense on the same step ({step_err})")
+    return {"requests": len(handles), "prefix_hits": stats["hits"]}
+
+
+# ---------------------------------------------------------------------------
+# kernel leg
+# ---------------------------------------------------------------------------
+
+def _production_cases(sz):
+    """(kernel names it must contain, label, fn, reference, args): the
+    production tile shapes the train and serve legs do not reach, in
+    bf16, each against the kernel's declared fallback (the flash
+    kernels against _composed_attention and its jax.vjp)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.moe import kernels as moe
+    from paddle_tpu.ops import pallas_attention as pa
+    from paddle_tpu.ops import pallas_decode as pd
+    from paddle_tpu.ops import pallas_layernorm as pln
+    from paddle_tpu.ops.attention import _composed_attention
+
+    rs = np.random.RandomState(0)
+    bf16 = jnp.bfloat16
+
+    def rand(shape, dtype=bf16, scale=0.3):
+        return jnp.asarray(rs.randn(*shape) * scale, dtype)
+
+    cases = []
+    s = sz.flash_seq
+    bq = min(1024, s // 2)      # two q tiles: the triangle grids engage
+
+    def attn_with_grads(attn):
+        def run(q, k, v, w):
+            out, vjp = jax.vjp(attn, q, k, v)
+            return out, vjp(w)
+        return run
+
+    for d, causal, kernels in (
+            (128, True, ("flash_fwd_tri", "flash_bwd_merged_tri")),
+            (128, False, ("flash_fwd_rect", "flash_bwd_merged_rect")),
+            (64, True, ("flash_fwd_tri", "flash_bwd_merged_tri"))):
+        q, k, v, w = (rand((2, s, 4, d)) for _ in range(4))
+        cases.append((
+            kernels, f"flash fwd+bwd s={s} D={d} causal={causal}",
+            attn_with_grads(lambda q, k, v, c=causal: pa.flash_attention_fwd(
+                q, k, v, c, None, bq, bq)),
+            attn_with_grads(lambda q, k, v, c=causal: _composed_attention(
+                q, k, v, causal=c)),
+            (q, k, v, w)))
+
+    # the split backward is what the vjp picks above the merged
+    # kernel's dq-scratch cap; here it is called directly at s, D=128
+    q, k, v, w = (rand((2, s, 4, 128)) for _ in range(4))
+    scale = 1.0 / np.sqrt(128.0)
+
+    def split_bwd(q, k, v, w):
+        out, lse = pa._flash_fwd(q, k, v, True, scale, bq, bq)
+        return pa._flash_bwd(q, k, v, out, lse, w, True, scale, bq, bq)
+
+    def split_ref(q, k, v, w):
+        _, vjp = jax.vjp(lambda a, b, c: _composed_attention(
+            a, b, c, causal=True), q, k, v)
+        return vjp(w)
+
+    cases.append((("flash_bwd_split_dkv", "flash_bwd_split_dq"),
+                  f"flash_bwd_split s={s} D=128", split_bwd, split_ref,
+                  (q, k, v, w)))
+
+    cfg = sz.model
+    nh, n = cfg.hidden_size, cfg.num_heads
+    L = sz.decode_len
+    q = rand((8, 1, nh))
+    kb, vb = rand((8, L, nh)), rand((8, L, nh))
+    off = np.int32(L - L // 4 - 1)
+    cases.append((("decode_fused",), f"decode_fused B=8 L={L} hidden={nh}",
+                  lambda q, k, v: pd.decode_attention(q, k, v, off, n),
+                  lambda q, k, v: pd._decode_fallback(q, k, v, off, n),
+                  (q, kb, vb)))
+
+    from paddle_tpu.ops import pallas_int8 as p8
+    vocab = -(-cfg.vocab_size // p8._BLOCK_V) * p8._BLOCK_V   # row-padded
+    hq = rand((16, nh), scale=1.0)
+    wq = jnp.asarray(rs.randint(-127, 128, (vocab, nh)), jnp.int8)
+    ws = jnp.asarray(0.01 * (0.01 + rs.rand(vocab)), jnp.float32)
+    cases.append((("int8_matvec",), f"int8_matvec 16x{nh} over V={vocab}",
+                  p8.int8_matvec, p8._matvec_fallback, (hq, wq, ws)))
+
+    x, res = rand((sz.rows, nh), scale=1.0), rand((sz.rows, nh), scale=1.0)
+    wt, bs = rand((nh,), jnp.float32, 1.0), rand((nh,), jnp.float32, 1.0)
+    cases.append((("layernorm_fused",), f"layernorm_fused {sz.rows}x{nh}",
+                  lambda *a: pln.fused_add_layer_norm(*a, 1e-5),
+                  lambda *a: pln._ln_primal_fallback(*a, 1e-5),
+                  (x, res, wt, bs)))
+    cases.append((("layernorm_fwd_saved",),
+                  f"layernorm_fwd_saved {sz.rows}x{nh}",
+                  lambda *a: pln._fwd(*a, 1e-5),
+                  lambda *a: pln._ln_fwd_fallback(*a, 1e-5),
+                  (x, res, wt, bs)))
+
+    # the largest source the MoE gate admits at this width, in bf16
+    # (bisected over multiples of 8; the debug mode stops at 64 rows)
+    lo, hi = 8, 64 if TINY else 1 << 20
+    while hi - lo > 8:
+        mid = (lo + hi) // 16 * 8
+        if moe.moe_kernel_supported(nh, bf16, n_src=mid):
+            lo = mid
+        else:
+            hi = mid
+    n_src = lo
+    src = rand((n_src, nh), scale=1.0)
+    idx = jnp.asarray(rs.randint(0, n_src + 1, (2 * n_src,)), jnp.int32)
+    cases.append((("moe_gather",), f"moe_gather bf16 d={nh} n_src={n_src}",
+                  moe._gather_pallas, moe.gather_fallback, (src, idx)))
+    idx2 = jnp.asarray(rs.randint(0, n_src + 1, (1024, 2)), jnp.int32)
+    w2 = jnp.asarray(rs.rand(1024, 2), jnp.float32)
+    cases.append((("moe_combine",), f"moe_combine bf16 d={nh} n_src={n_src}",
+                  moe._combine_pallas, moe.combine_fallback,
+                  (src, idx2, w2)))
+    return cases
+
+
+def leg_kernels(sz):
+    import jax
+    from paddle_tpu.analysis.kernel_lint import check_fallback_parity
+    from paddle_tpu.ops.kernel_registry import (mosaic_custom_calls,
+                                                registered_kernels)
+
+    registry = registered_kernels()
+    if not TINY:
+        interpreted = sorted({k.module for k in registry
+                              if sys.modules[k.module]._interpret()})
+        check(not interpreted,
+              f"no kernel module is in interpret mode ({interpreted})")
+    failed = []
+
+    # every registered kernel at its registered example against its
+    # declared fallback at its own tolerance (the debug mode takes the
+    # first three: tier-1's kernel doctor already sweeps the registry
+    # in the interpreter)
+    for kern in list(registry)[:3] if TINY else registry:
+        errs = []
+        findings = check_fallback_parity(
+            kern, seeds=(0,) if TINY else (0, 1), errors=errs)
+        print(f"  [{'FAIL' if findings else 'ok'}] {kern.name} vs its "
+              f"fallback at rtol/atol {kern.tol}: max abs error "
+              f"{max(errs, default=float('nan')):.2e}"
+              + "".join(f"\n      {f.message}" for f in findings),
+              flush=True)
+        if findings:
+            failed.append(kern.name)
+
+    seen = set()
+    for kernels, label, fn, ref, args in _production_cases(sz):
+        compiled = jax.jit(fn).lower(*args).compile()
+        got = compiled(*args)
+        if not TINY:
+            names = {n for n, _ in mosaic_custom_calls(compiled.as_text())}
+            seen |= names
+            if not set(kernels) <= names:
+                print(f"  [FAIL] {label}: compiled {sorted(names)}, "
+                      f"expected {kernels}")
+                failed.append(label)
+                continue
+        err = max_err(got, jax.jit(ref)(*args))
+        ok = err <= BF16_TOL
+        print(f"  [{'ok' if ok else 'FAIL'}] {label}: max error "
+              f"{err:.2e} vs {BF16_TOL}", flush=True)
+        if not ok:
+            failed.append(label)
+    check(not failed, f"every kernel agrees with its fallback ({failed})")
+    return {"registered": len(registry), "production_kernels": sorted(seen)}
+
+
+# ---------------------------------------------------------------------------
+# four-chip leg
+# ---------------------------------------------------------------------------
+
+def leg_four_chip(sz):
+    import re
+    import jax
+    import paddle_tpu as paddle
+    from bench import seeded_token_batch
+    from paddle_tpu import amp, optimizer, telemetry
+    from paddle_tpu import distributed as dist
+    from paddle_tpu.distributed import env
+    from paddle_tpu.models.gpt import GPTForPretraining
+
+    n_dev = jax.device_count()
+    if n_dev < 4:
+        return f"not run: {n_dev} device(s), needs 4"
+
+    cfg = sz.model
+    batch = 2 * sz.batch
+    ids, lbl = seeded_token_batch(cfg.vocab_size, batch, sz.seq)
+
+    # one-chip reference for the first-step loss: forward only, in
+    # halves (equal token counts, so the mean of the halves' mean
+    # losses is the batch's), before any mesh exists
+    paddle.seed(0)
+    ref_model = GPTForPretraining(cfg)
+    ref_loss_fn = paddle.jit.to_static(ref_model.loss)
+    halves = []
+    with amp.auto_cast(enable=True, dtype="bfloat16"):
+        for part in (slice(0, sz.batch), slice(sz.batch, batch)):
+            halves.append(float(ref_loss_fn(
+                paddle.to_tensor(np.asarray(ids._value)[part]),
+                paddle.to_tensor(np.asarray(lbl._value)[part])).item()))
+    ref_loss = float(np.mean(halves))
+    del ref_model, ref_loss_fn
+    gc.collect()
+
+    mesh = dist.build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+    try:
+        paddle.seed(0)
+        model = GPTForPretraining(cfg)
+        opt = optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
+                              parameters=model.parameters())
+        dist.shard_model(model, mesh)
+
+        def loss_fn(i, l):
+            with amp.auto_cast(enable=True, dtype="bfloat16"):
+                return model.loss(i, l)
+
+        with telemetry.CompileObservatory(action="record") as obs:
+            step = dist.ShardedTrainStep(model, loss_fn, opt, zero_stage=1)
+            losses = [float(step(ids, lbl).item()) for _ in range(3)]
+        print(f"  losses at {batch} x {sz.seq} on dp=2 x mp=2: {losses}; "
+              f"one-chip first-step reference {ref_loss} ({halves})")
+        check(all(np.isfinite(losses)) and losses[-1] < losses[0],
+              "finite, falling loss")
+        check(abs(losses[0] - ref_loss) <= BF16_TOL,
+              f"first-step loss within {BF16_TOL} of the one-chip "
+              f"forward on the same seed and batch "
+              f"(diff {abs(losses[0] - ref_loss):.2e})")
+
+        # layout: every parameter and optimizer state lies over the
+        # devices its spec says, in shards of the spec's shape
+        wrong = []
+        split = collections.Counter()
+        for name, p in zip(step.param_names, step.params):
+            want = step._param_sharding(p)
+            placed = [("param", p._value, want)] + [
+                (slot, val, step._state_sharding(p))
+                for slot, val in opt._states[id(p)].items()
+                if np.shape(val) == tuple(p._value.shape)]
+            for what, val, sh in placed:
+                shard_shape = sh.shard_shape(tuple(val.shape))
+                ok = (val.sharding.is_equivalent_to(sh, val.ndim)
+                      and len(val.sharding.device_set) == 4
+                      and all(s.data.shape == shard_shape
+                              for s in val.addressable_shards))
+                split[what, shard_shape != tuple(val.shape)] += 1
+                if not ok:
+                    wrong.append((name, what, str(val.sharding)))
+        print(f"  (array, is split) counts: {dict(split)}")
+        check(not wrong, f"parameters and optimizer states are laid out "
+                         f"as their specs say ({wrong[:3]})")
+        check(split["param", True] > 0 and split["moment1", True]
+              > split["param", True],
+              "tagged parameters are split over mp, and ZeRO-1 splits "
+              "more of the optimizer state (over dp) than of the "
+              "parameters")
+        stats = [d.memory_stats() for d in jax.devices()[:4]]
+        if all(stats):
+            used = [s["bytes_in_use"] for s in stats]
+            print(f"  bytes_in_use per device: {used}")
+            check(max(used) <= 2 * min(used),
+                  "bytes_in_use is of the same order on all four devices")
+        else:
+            check(TINY, "the backend reports memory_stats()")
+
+        if not TINY:
+            names, shapes = kernels_in(obs, "ShardedTrainStep")
+            print(f"  Mosaic kernels in the sharded step: {dict(names)} "
+                  f"on {shapes}")
+            n, h = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+            per_device = f"bf16[{batch // 2 * n // 2},{sz.seq},{h}]"
+            flash = [o for k, ops in shapes.items() for o in ops
+                     if k.startswith("flash")]
+            check(flash and all(o[0] == per_device for o in flash),
+                  f"the attention kernels work on the per-device shard "
+                  f"{per_device} (batch/dp x heads/mp), not the global "
+                  f"bf16[{batch * n},{sz.seq},{h}]")
+            text = "".join(c.as_text() for f, c in obs.compiled_programs()
+                           if f.startswith("ShardedTrainStep"))
+            n_ar = len(re.findall(r" all-reduce(-start)?\(", text))
+            check(n_ar > 0, f"the compiled step all-reduces ({n_ar} "
+                            "all-reduce ops)")
+    finally:
+        env.clear_mesh()
+    return {"losses": losses, "ref_loss": ref_loss}
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    import jax
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    print(f"jax {jax.__version__}  default_backend "
+          f"{jax.default_backend()}  device {device}", flush=True)
+    if TINY:
+        print("CHIP_SMOKE_DEBUG=tiny: toy sizes, interpreted kernels — "
+              "this run cannot pass")
+    else:
+        if dev.platform != "tpu" or jax.default_backend() != "tpu":
+            print(f"chip_smoke: platform is {dev.platform!r}, not 'tpu' — "
+                  "nothing was run", file=sys.stderr)
+            return 2
+        from paddle_tpu.telemetry.mfu import device_peak_flops
+        device_peak_flops()     # raises for a kind with no peak row
+
+    from paddle_tpu import compile_cache
+    cache_dir = compile_cache.enable()
+    cache = compile_cache.CacheCounter()
+    held = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    print(f"compile cache: {cache_dir} ({held} entries from earlier runs)")
+
+    sz = sizes()
+    for name, leg in (("train", leg_train), ("serve", leg_serve),
+                      ("kernels", leg_kernels),
+                      ("four_chip", leg_four_chip)):
+        print(f"LEG {name}", flush=True)
+        t0 = time.perf_counter()
+        try:
+            out = leg(sz)
+        except Exception:
+            traceback.print_exc()
+            print(f"LEG {name}: FAIL after "
+                  f"{time.perf_counter() - t0:.0f}s", flush=True)
+            return 1
+        if isinstance(out, dict):
+            out = (f"PASS ({time.perf_counter() - t0:.0f}s, not a result) "
+                   f"{out}")
+        print(f"LEG {name}: {out}", flush=True)
+    print(f"compile cache: {cache.hits} hits, {cache.misses} misses in "
+          f"{cache_dir}" + (" — an earlier run's programs were reused"
+                            if held and cache.hits else ""))
+    if TINY:
+        print("NOT A CHIP RUN")
+        return 3
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,59 +9,6 @@ analog) or traces under `paddle_tpu.jit.to_static` into one fused XLA program
 """
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map only under jax.experimental and without
-    # the `axis_names` kwarg (manual-axis subset). This codebase targets
-    # the stable `jax.shard_map` surface; adapt the old API in place:
-    # axis_names=M maps to auto = mesh.axis_names - M, and check_rep is
-    # forced off (partial-manual regions reject it on 0.4.x).
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          axis_names=None, check_rep=False, **kwargs):
-        from jax.experimental.shard_map import shard_map as _sm
-        full = _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, **kwargs)
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names) \
-            if axis_names is not None else frozenset()
-        if not auto:
-            return full
-
-        part = _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, auto=auto, **kwargs)
-
-        def call(*args):
-            if not _shard_map_compat._partial_auto_broken:
-                try:
-                    under_trace = not _jax.core.trace_state_clean()
-                except Exception:
-                    under_trace = False
-                if under_trace:
-                    # under an outer jit trace a partial-auto failure
-                    # only surfaces at the OUTER compile, far from this
-                    # try/except — go straight to fully-manual there
-                    return full(*args)
-                try:
-                    return part(*args)
-                except NotImplementedError:
-                    # 0.4.x partial-auto is unimplemented for many
-                    # prims; fully-manual is equivalent whenever the
-                    # auto axes are unused inside the region (specs
-                    # never mention them). Memoized process-wide: the
-                    # failed attempt costs a full trace, so pay it once.
-                    _shard_map_compat._partial_auto_broken = True
-            return full(*args)
-        return call
-
-    _shard_map_compat._partial_auto_broken = False
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "pcast"):
-    # old jax has no varying/invariant replication tracking (we run its
-    # shard_map with check_rep=False, where everything is varying), so
-    # the new API's explicit pcast is semantically an identity here
-    _jax.lax.pcast = lambda x, *args, **kwargs: x
-
 from .core.dtype import (  # noqa: F401
     bool, uint8, int8, int16, int32, int64, float16, bfloat16, float32,
     float64, complex64, complex128, set_default_dtype, get_default_dtype,

@@ -34,6 +34,10 @@ __all__ = ["check_commbench_wire_bytes", "collective_wire_bytes",
 _FULL = ("ppermute",)
 _SHARD = ("all_to_all", "all_gather", "reduce_scatter")
 _ALLREDUCE = ("psum",)   # pmean lowers to psum + divide
+# under shard_map's varying-axes tracking (check_vma=True) a psum /
+# all_gather whose result is replicated traces as the *_invariant
+# primitive; the wire cost is the plain collective's
+_CANON = {"psum_invariant": "psum", "all_gather_invariant": "all_gather"}
 
 
 def _axis_size(eqn, axis_sizes):
@@ -76,7 +80,7 @@ def _wire_bytes(name, payload, n):
 
 def _walk(jaxpr, mult, axis_sizes, out):
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
+        name = _CANON.get(eqn.primitive.name, eqn.primitive.name)
         if name in _FULL + _SHARD + _ALLREDUCE:
             entry = out.setdefault(name, {"calls": 0, "bytes": 0.0})
             entry["calls"] += mult
@@ -96,12 +100,10 @@ def _sub_jaxprs(eqn):
 
 
 def _jaxprs_in(v):
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", None)
-    jax_t = getattr(jcore, "Jaxpr", None)
-    if closed is not None and isinstance(v, closed):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif jax_t is not None and isinstance(v, jax_t):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for x in v:

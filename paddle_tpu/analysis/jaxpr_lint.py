@@ -39,9 +39,12 @@ from . import Finding, SEV_ERROR, SEV_INFO, SEV_WARNING
 
 # primitives that indicate a host round-trip inside the step
 _CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback",
-                   "callback")
+                   "debug_print", "callback")
 
-_COLLECTIVE_PRIMS = ("psum", "pmax", "pmin", "all_gather", "all_to_all",
+# psum_invariant / all_gather_invariant: what psum / all_gather trace to
+# under shard_map's varying-axes tracking when the result is replicated
+_COLLECTIVE_PRIMS = ("psum", "psum_invariant", "pmax", "pmin",
+                     "all_gather", "all_gather_invariant", "all_to_all",
                      "ppermute", "psum_scatter", "reduce_scatter")
 
 # JX103 floor: below this many elements an upcast is noise, not a
@@ -212,7 +215,8 @@ def lint_jaxpr(closed, *, donated=(), mesh_axis_sizes=None, fn_name="step",
                         suggestion="drop the collective or gate it on "
                                    "the mesh axis size"))
                 # JX106: reduce-scatter immediately re-gathered
-                if prim == "all_gather" and eqn.invars:
+                if prim in ("all_gather", "all_gather_invariant") \
+                        and eqn.invars:
                     src_info = prev_prim.get(id(eqn.invars[0]))
                     if src_info is not None:
                         sprim, saxes = src_info

@@ -174,17 +174,12 @@ def _eval_index_map(index_map, idx, prefetch_values, rank):
 
 
 def _dim_semantics(kwargs):
-    """dimension_semantics from a pallas_call's compiler_params, in
-    either the dict form ({'mosaic': {'dimension_semantics': ...}}) or
-    an object with the attribute."""
+    """dimension_semantics from a pallas_call's compiler_params: a
+    `pltpu.CompilerParams`, alone or keyed by backend
+    ({'mosaic_tpu': CompilerParams(...)})."""
     cp = kwargs.get("compiler_params")
-    if cp is None:
-        return None
     if isinstance(cp, dict):
-        mosaic = cp.get("mosaic", cp)
-        if isinstance(mosaic, dict):
-            return mosaic.get("dimension_semantics")
-        cp = mosaic
+        cp = cp.get("mosaic_tpu")
     return getattr(cp, "dimension_semantics", None)
 
 
@@ -590,12 +585,16 @@ def check_cost(capture, kernel_jaxpr):
 # KN504: fallback-parity fuzzing
 # ---------------------------------------------------------------------------
 
-def check_fallback_parity(reg, seeds=(0, 1, 2)):
+def check_fallback_parity(reg, seeds=(0, 1, 2), errors=None):
     """Seeded differential harness: run the registered kernel and its
     declared exact fallback on randomized in-support inputs, compare
     within the registration's tolerance. Deterministic per seed (the
     example derives shapes AND values from the rng), so a failure
-    reproduces bit-for-bit."""
+    reproduces bit-for-bit. The fallback is the reference, so its
+    matmuls run at full precision (on a TPU, XLA's default is one bf16
+    pass; the CPU is unaffected); the kernel runs as it does in
+    production. `errors`, when given a list, collects each seed's
+    largest absolute error for the caller's report."""
     if reg.fallback is None:
         return []
     import jax
@@ -606,7 +605,8 @@ def check_fallback_parity(reg, seeds=(0, 1, 2)):
         rng = np.random.default_rng(seed)
         args, kwargs = reg.example(rng)
         got = reg.fn(*args, **kwargs)
-        want = reg.fallback(*args, **kwargs)
+        with jax.default_matmul_precision("highest"):
+            want = reg.fallback(*args, **kwargs)
         got_leaves = jax.tree_util.tree_leaves(got)
         want_leaves = jax.tree_util.tree_leaves(want)
         if len(got_leaves) != len(want_leaves):
@@ -615,6 +615,7 @@ def check_fallback_parity(reg, seeds=(0, 1, 2)):
                 f"seed {seed}: kernel returned {len(got_leaves)} "
                 f"arrays, fallback {len(want_leaves)}"))
             continue
+        worst = 0.0
         for li, (g, w) in enumerate(zip(got_leaves, want_leaves)):
             g = np.asarray(g, dtype=np.float64)
             w = np.asarray(w, dtype=np.float64)
@@ -624,9 +625,10 @@ def check_fallback_parity(reg, seeds=(0, 1, 2)):
                     f"seed {seed}: output {li} shape {g.shape} vs "
                     f"fallback {w.shape}"))
                 continue
+            err = float(np.max(np.abs(g - w))) if g.size else 0.0
+            worst = max(worst, err)
             if not np.allclose(g, w, rtol=rtol, atol=atol,
                                equal_nan=True):
-                err = float(np.max(np.abs(g - w)))
                 findings.append(Finding(
                     "KN504", SEV_ERROR, reg.name,
                     f"seed {seed}: output {li} diverges from the "
@@ -637,6 +639,8 @@ def check_fallback_parity(reg, seeds=(0, 1, 2)):
                                f"with np.random.default_rng({seed}) to "
                                "reproduce"))
                 break
+        if errors is not None:
+            errors.append(worst)
     return findings
 
 

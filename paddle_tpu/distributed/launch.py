@@ -12,6 +12,7 @@ protocol kept from the reference: ELASTIC_EXIT_CODE=101,
 `fleet/elastic/manager.py:26`).
 """
 import argparse
+import glob
 import os
 import runpy
 import signal
@@ -57,8 +58,9 @@ def _parse_args(argv=None):
     p.add_argument("--master", type=str,
                    default=os.environ.get("PADDLE_MASTER", ""))
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="spawn N local processes (multi-host emulation / "
-                        "CPU tests; one process per host is the TPU norm)")
+                   help="spawn N local processes (multi-host emulation on "
+                        "the CPU; refused on a TPU host, where one "
+                        "process drives all local chips)")
     p.add_argument("--devices", "--gpus", "--xpus", type=str, default="",
                    help="accepted for CLI parity; chip selection is "
                         "topology-driven on TPU")
@@ -85,10 +87,31 @@ def _free_port():
         return s.getsockname()[1]
 
 
+def _tpu_host(env):
+    """True when children started with `env` would each claim this
+    host's TPU chips. Judged from the environment and the device nodes,
+    never by initialising a backend here: a launcher that has touched
+    JAX holds the chips, and its children then fail or hang."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def start_local_trainers(nproc, script, script_args, master=None,
                          base_env=None):
     """Spawn one training process per local rank (reference
-    `launch_utils.py:464` start_local_trainers)."""
+    `launch_utils.py:464` start_local_trainers). Every child gets the
+    same environment, which on a TPU host would make each of them claim
+    all local chips — so more than one local process is refused there
+    (`distributed.spawn` takes the same line)."""
+    if nproc > 1 and _tpu_host(os.environ if base_env is None
+                               else base_env):
+        raise RuntimeError(
+            f"--nproc_per_node {nproc} on a TPU host: one process "
+            "drives all local chips (a chip belongs to one process at "
+            "a time, and every local child would claim all of them). "
+            "Run one process per host, or set JAX_PLATFORMS=cpu for "
+            "multi-process emulation on the CPU.")
     master = master or f"127.0.0.1:{_free_port()}"
     procs = []
     for rank in range(nproc):

@@ -70,8 +70,7 @@ _register(
     " for q_len==1 KV-cache attention when shapes qualify (TPU, cache "
     "len %8==0, n_heads*head_dim %128==0). One kernel per layer instead "
     "of the einsum+mask+softmax+einsum chain; measured 91 vs 117 us per "
-    "call at B=64/L=256 and end-to-end decode tok/s recorded in "
-    "ROUND4_NOTES.")
+    "call at B=64/L=256 on an earlier revision (jax 0.4.37).")
 _register(
     "use_fused_ce", False, bool,
     "Use the chunked fused projection+cross-entropy for LM losses "

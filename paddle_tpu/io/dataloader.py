@@ -274,7 +274,7 @@ class DataLoader:
 
     DEPRECATED (PR 6): the old fork-context worker pool is gone —
     ``os.fork()`` under multithreaded JAX is a deadlock hazard
-    (BENCH_r04/r05 RuntimeWarning) — and ``worker_mode="fork"`` raises.
+    (CPython's RuntimeWarning) — and ``worker_mode="fork"`` raises.
     The constructor surface is otherwise unchanged;
     ``use_shared_memory`` now gates the preallocated shared-memory slot
     transport of process workers (ignored for threads).

@@ -1,8 +1,8 @@
 """Asynchronous prefetch-to-device input pipeline (the DataLoader engine).
 
 Rebuilt from the fork-based worker pool (PR 6): `os.fork()` under a
-multithreaded JAX runtime is a real deadlock hazard (the BENCH_r04/r05
-RuntimeWarning), so no code path here ever forks the parent. Three
+multithreaded JAX runtime is a real deadlock hazard (CPython's own
+RuntimeWarning says so), so no code path here ever forks the parent. Three
 worker transports, chosen per loader:
 
 - **thread** (default): N worker threads fetch + collate batches. The
@@ -607,8 +607,8 @@ def make_pool(loader):
     if mode == "fork":
         raise ValueError(
             "worker_mode='fork' is not supported: os.fork() under a "
-            "multithreaded JAX runtime deadlocks (BENCH_r04/r05 "
-            "RuntimeWarning). Use 'process' (forkserver/spawn), "
+            "multithreaded JAX runtime deadlocks (CPython warns about "
+            "it). Use 'process' (forkserver/spawn), "
             "'thread', or 'auto'.")
     if mode in ("process", "spawn", "forkserver"):
         try:     # pickle ONCE; the bytes ship to the workers as-is

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.kernel_registry import fits_vmem, register_kernel
+from ..ops.kernel_registry import (fits_vmem, out_struct,
+                                   register_kernel)
 
 __all__ = ["moe_gather", "moe_combine", "gather_fallback",
            "combine_fallback", "moe_kernel_supported"]
@@ -86,10 +87,40 @@ def moe_kernel_supported(d, dtype=jnp.float32, n_src=None):
                                            jnp.dtype(jnp.bfloat16)):
         return False
     if n_src is not None:
-        if not fits_vmem(moving=[((_BLOCK_ROWS, d), dtype)],
-                         resident=[((n_src, d), dtype)]):
+        # projected at 4 bytes/element whatever the dtype: a bf16 source
+        # runs through the kernels widened to f32 (_widened)
+        if not fits_vmem(moving=[((_BLOCK_ROWS, d), 4)],
+                         resident=[((n_src, d), 4)]):
             return False
     return True
+
+
+def _widened(kernel, src, *rest):
+    """Run `kernel` on an f32 copy of a bf16 source and narrow the
+    result. The kernels fetch ONE source row per step at a dynamic
+    sublane index; bf16 packs two rows per sublane and Mosaic refuses
+    the load ("cannot statically prove that index in dimension 0 is a
+    multiple of 8", vector.load of vector<1xdxbf16>, jax 0.9.0 / libtpu
+    0.0.34). bf16 -> f32 -> bf16 is exact for the gather, and the
+    combine already accumulates in f32 and rounds once at the end."""
+    return kernel(src.astype(jnp.float32), *rest).astype(src.dtype)
+
+
+def _auto_use_kernel(src):
+    """The auto gate (use_kernel=None): a TPU, a supported shape, and a
+    single-device program. Under a multi-device mesh the kernels are
+    gated OFF: the MoE layer's expert-parallel region is manual over
+    `ep` only and leaves dp/mp to GSPMD, and jax 0.9.0 refuses to lower
+    a Mosaic call anywhere but a region manual over EVERY mesh axis —
+    "NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map." The exact jnp
+    forms partition fine; a fully-manual MoE region is the repair."""
+    from ..distributed import env
+    mesh = env.current_mesh()
+    return (jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1)
+            and moe_kernel_supported(src.shape[-1], src.dtype,
+                                     n_src=src.shape[0]))
 
 
 def _pad_to(x, mult, fill):
@@ -100,6 +131,21 @@ def _pad_to(x, mult, fill):
     return x
 
 
+def _for_each_row(rows, body):
+    """Run `body(i)` for i in [0, rows) inside a kernel. A while_loop,
+    not a fori_loop: a static-bound fori_loop traces to `scan`, and
+    when the kernel sits under a shard_map the Pallas interpreter
+    (jax 0.9.0) re-evaluates that scan with varying operands against a
+    body typed without them ("Scan carry input and output got
+    mismatched varying manual axes"); `while` carries no such check
+    and Mosaic lowers the counted form to the same scf.for."""
+    def step(i):
+        body(i)
+        return i + 1
+
+    jax.lax.while_loop(lambda i: i < rows, step, jnp.int32(0))
+
+
 # ---------------------------------------------------------------------------
 # dispatch: row gather with sentinel zero-fill
 # ---------------------------------------------------------------------------
@@ -107,15 +153,14 @@ def _pad_to(x, mult, fill):
 def _gather_kernel(idx_ref, src_ref, out_ref, *, rows, n_src):
     base = pl.program_id(0) * rows
 
-    def body(i, _):
+    def body(i):
         t = idx_ref[base + i]
         valid = (t < n_src).astype(src_ref.dtype)
         safe = jnp.where(t < n_src, t, 0)
         row = src_ref[pl.ds(safe, 1), :]
         out_ref[pl.ds(i, 1), :] = row * valid
-        return 0
 
-    jax.lax.fori_loop(0, rows, body, 0)
+    _for_each_row(rows, body)
 
 
 def _gather_example(rng):
@@ -136,6 +181,8 @@ def _gather_example(rng):
     notes="dispatch row-gather with sentinel zero-fill; slot map rides "
           "the scalar-prefetch channel")
 def _gather_pallas(src, idx):
+    if src.dtype != jnp.float32:
+        return _widened(_gather_pallas, src, idx)
     n_src, d = src.shape
     n_out = idx.shape[0]
     rows = _resolve_rows("moe_gather", d, src.dtype, n_src)
@@ -144,6 +191,7 @@ def _gather_pallas(src, idx):
     grid = (n_pad // rows,)
     out = pl.pallas_call(
         functools.partial(_gather_kernel, rows=rows, n_src=n_src),
+        name="moe_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -151,7 +199,7 @@ def _gather_pallas(src, idx):
             out_specs=pl.BlockSpec((rows, d),
                                    lambda b, *_: (b, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), src.dtype),
+        out_shape=out_struct((n_pad, d), src.dtype, src, idx),
         # the per-row VMEM loop reads each src row at most once per
         # output row; cost == one src stream + one out stream
         cost_estimate=pl.CostEstimate(
@@ -170,9 +218,7 @@ def gather_fallback(src, idx):
 
 def _gather_impl(use_kernel, src, idx):
     if use_kernel is None:
-        use_kernel = (jax.default_backend() == "tpu"
-                      and moe_kernel_supported(src.shape[-1], src.dtype,
-                                               n_src=src.shape[0]))
+        use_kernel = _auto_use_kernel(src)
     if use_kernel:
         return _gather_pallas(src, idx)
     return gather_fallback(src, idx)
@@ -210,7 +256,7 @@ moe_gather.defvjp(_gather_fwd, _gather_bwd)
 def _combine_kernel(idx_ref, w_ref, src_ref, out_ref, *, rows, k, n_src):
     base = pl.program_id(0) * rows
 
-    def body(i, _):
+    def body(i):
         acc = jnp.zeros((1, out_ref.shape[-1]), jnp.float32)
         for s in range(k):          # k is static and small (1/2)
             t = idx_ref[(base + i) * k + s]
@@ -220,9 +266,8 @@ def _combine_kernel(idx_ref, w_ref, src_ref, out_ref, *, rows, k, n_src):
             row = src_ref[pl.ds(safe, 1), :].astype(jnp.float32)
             acc = acc + (w * valid) * row
         out_ref[pl.ds(i, 1), :] = acc.astype(out_ref.dtype)
-        return 0
 
-    jax.lax.fori_loop(0, rows, body, 0)
+    _for_each_row(rows, body)
 
 
 def _combine_example(rng):
@@ -242,6 +287,8 @@ def _combine_example(rng):
     tol=(1e-5, 1e-5),
     notes="k-way weighted gather, f32 accumulation in slot order")
 def _combine_pallas(src, idx, w):
+    if src.dtype != jnp.float32:
+        return _widened(_combine_pallas, src, idx, w)
     n_src, d = src.shape
     n, k = idx.shape
     rows = _resolve_rows("moe_combine", d, src.dtype, n_src)
@@ -253,6 +300,7 @@ def _combine_pallas(src, idx, w):
     out = pl.pallas_call(
         functools.partial(_combine_kernel, rows=rows, k=k,
                           n_src=n_src),
+        name="moe_combine",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -260,7 +308,7 @@ def _combine_pallas(src, idx, w):
             out_specs=pl.BlockSpec((rows, d),
                                    lambda b, *_: (b, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), src.dtype),
+        out_shape=out_struct((n_pad, d), src.dtype, src, idx, w),
         cost_estimate=pl.CostEstimate(
             flops=2 * n_pad * k * d, transcendentals=0,
             bytes_accessed=(n_src + (k + 1) * n_pad) * d
@@ -281,9 +329,7 @@ def combine_fallback(src, idx, w):
 
 def _combine_impl(use_kernel, src, idx, w):
     if use_kernel is None:
-        use_kernel = (jax.default_backend() == "tpu"
-                      and moe_kernel_supported(src.shape[-1], src.dtype,
-                                               n_src=src.shape[0]))
+        use_kernel = _auto_use_kernel(src)
     if use_kernel:
         return _combine_pallas(src, idx, w)
     return combine_fallback(src, idx, w)

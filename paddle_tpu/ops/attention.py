@@ -64,6 +64,39 @@ def _use_pallas(q, force=None, k=None):
     return shapes_ok and s >= get_flag("pallas_attention_min_seq")
 
 
+def _flash(q, k, v, causal):
+    """The Pallas flash kernel, per device shard when a mesh is active.
+
+    A Mosaic custom call has no partitioning rule: inside a
+    GSPMD-partitioned step jax 0.9.0 refuses to lower it ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map") unless EVERY mesh axis is manual. Attention is
+    independent over batch and heads, so under a multi-device mesh the
+    kernel runs in a fully-manual shard_map that splits the batch over
+    `dp` and the heads over `mp` — the axes the GPT sharding plan
+    already splits them over — and sees every other axis replicated.
+    An axis whose size does not divide the dimension is not split (the
+    kernel then computes that dimension whole on each device)."""
+    from ..distributed import env as dist_env
+    from .pallas_attention import flash_attention_fwd
+    mesh = dist_env.current_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention_fwd(q, k, v, causal=causal)
+
+    def axis_for(name, dim):
+        if mesh.shape[name] > 1 and dim % mesh.shape[name] == 0:
+            return name
+        return None
+
+    from jax.sharding import PartitionSpec as P
+    spec = P(axis_for("dp", q.shape[0]), None,
+             axis_for("mp", q.shape[2]), None)
+    shard = jax.shard_map(
+        lambda a, b, c: flash_attention_fwd(a, b, c, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    return shard(q, k, v)
+
+
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None,
                     training=True, use_pallas=None, name=None):
@@ -80,8 +113,7 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
     def fn(q, k, v):
         if _use_pallas(q, use_pallas, k=k) and dropout == 0.0:
-            from .pallas_attention import flash_attention_fwd
-            return flash_attention_fwd(q, k, v, causal=causal)
+            return _flash(q, k, v, causal)
         return _composed_attention(q, k, v, causal=causal,
                                    dropout_p=dropout if training else 0.0,
                                    dropout_key=dropout_key)
@@ -104,8 +136,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if attn_mask is None:
         def fn(q, k, v):
             if _use_pallas(q, k=k) and dropout_p == 0.0:
-                from .pallas_attention import flash_attention_fwd
-                return flash_attention_fwd(q, k, v, causal=is_causal)
+                return _flash(q, k, v, is_causal)
             return _composed_attention(
                 q, k, v, causal=is_causal,
                 dropout_p=dropout_p if training else 0.0,

@@ -30,11 +30,14 @@ on v5e) the decode and MoE gates have always used, leaving headroom for
 the compiler's own temporaries.
 """
 import functools
+import re
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
     "VMEM_BUDGET", "block_bytes", "vmem_footprint", "fits_vmem",
+    "out_struct", "mosaic_custom_calls",
     "KernelRegistry", "PallasKernel", "register_kernel",
     "registered_kernels", "get_kernel", "KERNELS",
 ]
@@ -79,6 +82,38 @@ def fits_vmem(moving=(), resident=(), scratch=(), temp_bytes=0,
               budget=VMEM_BUDGET):
     """True when the projected footprint fits the per-core budget."""
     return vmem_footprint(moving, resident, scratch, temp_bytes) <= budget
+
+
+def out_struct(shape, dtype, *operands):
+    """One `pallas_call` out_shape entry, varying over the manual mesh
+    axes its `operands` vary over. Inside a `jax.shard_map` (the MoE ep
+    region, the mesh-aware flash attention) the varying-axes check
+    refuses an output whose `vma` is unset; outside one the set is
+    empty and this is a plain ShapeDtypeStruct."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def mosaic_custom_calls(hlo_text):
+    """The Mosaic kernels a compiled program (`compiled.as_text()`)
+    really contains: one (pallas_call name, operand shapes) pair per
+    `tpu_custom_call`, in program order. Every in-tree pallas_call is
+    named after its registry entry, so this is how chip_smoke.py tells
+    that a kernel ran as a kernel (the platform gates can only say it
+    was asked for) and on which per-device shapes."""
+    calls = []
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        # ".../paged_decode/pallas_call", or wrapped by the transforms
+        # it was traced under: ".../transpose(jvp(flash_bwd_split_dq))/..."
+        name = re.search(r'op_name="[^"]*?([\w.]+)\)*/pallas_call', line)
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, \w+=", line)
+        shapes = re.findall(r"\w+\[[\d,]*\]", operands.group(1)) \
+            if operands else []
+        calls.append((name.group(1) if name else "?", tuple(shapes)))
+    return calls
 
 
 class PallasKernel:

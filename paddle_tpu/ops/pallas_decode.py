@@ -1,8 +1,8 @@
 """Pallas fused decode-attention (q_len == 1) over the KV cache.
 
 The profiled decode bottleneck at serving batch sizes is kernel COUNT,
-not bandwidth (ROUND4_NOTES: ~100 skinny fused kernels per token at
-B=64 — per-layer QK einsum, mask, softmax, AV einsum over the cache).
+not bandwidth (~100 skinny fused kernels per token at B=64 — per-layer
+QK einsum, mask, softmax, AV einsum over the cache).
 This kernel computes the whole masked attention for ALL heads of one
 batch row in ONE program: the cache streams through VMEM once and the
 logits/probs never visit HBM.
@@ -17,7 +17,7 @@ E [128, NH] expanding a head column back over its lanes. All tiles are
 (multiple-of-8, multiple-of-128); the padded columns N..127 are never
 read back.
 
-The cache length is TILED (r5, VERDICT r4 task 2): the grid is (B, nl)
+The cache length is TILED: the grid is (B, nl)
 and the softmax accumulates online across L-tiles (running per-head
 max/denominator in VMEM scratch, the weighted-value accumulator rescaled
 by exp(m_prev - m_new) per tile), so arbitrary cache lengths and
@@ -367,6 +367,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, nl=mb),
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, nh), jnp.float32),
         interpret=_interpret(),
@@ -375,20 +376,39 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     return out.astype(q.dtype)
 
 
+def _head_group(n_heads, head_dim):
+    """Heads per prefill program. A block's lane width must be a
+    multiple of 128 (or the whole array), so heads narrower than 128
+    lanes are processed in groups that fill one 128-lane tile; 0 when
+    the head width neither divides nor is a multiple of 128, or the
+    heads do not split into whole groups."""
+    if head_dim % _COLS == 0:
+        return 1
+    if _COLS % head_dim or n_heads % (_COLS // head_dim):
+        return 0
+    return _COLS // head_dim
+
+
 def _prefill_kernel(tab_ref, p0_ref, q_ref, k_ref, v_ref, out_ref,
-                    m_sc, l_sc, acc_sc, *, scale, bs, nl, C):
+                    m_sc, l_sc, acc_sc, *, scale, bs, nl, C, G, H):
     """Flash chunked-prefill attention over the paged arena: grid
-    (head, logical block). The chunk's C queries attend to every cached
-    block reachable through the scalar-prefetched block table with
-    ONLINE softmax (running per-row max/denominator in VMEM scratch),
-    causal within the chunk via logical positions — the full
+    (head group, logical block). The chunk's C queries attend to every
+    cached block reachable through the scalar-prefetched block table
+    with ONLINE softmax (running per-row max/denominator in VMEM
+    scratch), causal within the chunk via logical positions — the full
     [chunk, ctx] score matrix never exists. Blocks wholly past the
     chunk's last query are skipped: every row of their score tile would
     be masked, and a fully-masked tile at running max -1e30 would turn
     exp(s - m) into ones and corrupt the denominator (block 0 is never
-    fully masked — key position 0 is <= every query position)."""
+    fully masked — key position 0 is <= every query position).
+
+    One program holds G heads side by side in its G*H lanes. Head g's
+    scores come from a full-width contraction with the other heads'
+    query lanes zeroed (no sub-128 lane slicing), and its p@v product
+    is kept on its own lanes only."""
     li = pl.program_id(1)
     p0 = p0_ref[0]
+    W = G * H
 
     @pl.when(li == 0)
     def _init():
@@ -396,58 +416,75 @@ def _prefill_kernel(tab_ref, p0_ref, q_ref, k_ref, v_ref, out_ref,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
+    def lanes_of(g):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+        return jnp.logical_and(lane >= g * H, lane < (g + 1) * H)
+
     @pl.when(li * bs <= p0 + C - 1)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                # [C, H]
-        k = k_ref[0].astype(jnp.float32)                # [bs, H]
-        v = v_ref[0].astype(jnp.float32)                # [bs, H]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [C, bs]
+        q = q_ref[0].astype(jnp.float32)                # [C, W]
+        k = k_ref[0].astype(jnp.float32)                # [bs, W]
+        v = v_ref[0].astype(jnp.float32)                # [bs, W]
         kpos = li * bs + jax.lax.broadcasted_iota(
             jnp.int32, (C, bs), 1)
         qpos = p0 + jax.lax.broadcasted_iota(jnp.int32, (C, bs), 0)
-        s = jnp.where(kpos <= qpos, s, -1e30)
-        m_prev = m_sc[:, :1]                            # [C, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)                 # [C, 1]
-        p = jnp.exp(s - m_new)                          # [C, bs]
-        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [C, H]
-        acc_sc[:] = acc_sc[:] * alpha + pv
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+        causal = kpos <= qpos
+        alpha_w = jnp.zeros((C, W), jnp.float32)
+        pv_w = jnp.zeros((C, W), jnp.float32)
+        for g in range(G):
+            own = lanes_of(g)
+            qg = jnp.where(own, q, 0.0) if G > 1 else q
+            s = jax.lax.dot_general(
+                qg, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [C, bs]
+            s = jnp.where(causal, s, -1e30)
+            m_prev = m_sc[g][:, :1]                         # [C, 1]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)                 # [C, 1]
+            p = jnp.exp(s - m_new)                          # [C, bs]
+            l_new = alpha * l_sc[g][:, :1] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [C, W]
+            alpha_w = jnp.where(own, alpha, alpha_w)
+            pv_w = jnp.where(own, pv, pv_w)
+            m_sc[g] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+            l_sc[g] = jnp.broadcast_to(l_new, l_sc.shape[1:])
+        acc_sc[:] = acc_sc[:] * alpha_w + pv_w
 
     @pl.when(li == nl - 1)
     def _finalize():
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0] = acc_sc[:] / l_safe
+        l_w = jnp.ones((C, W), jnp.float32)
+        for g in range(G):
+            l = l_sc[g][:, :1]
+            l_w = jnp.where(lanes_of(g), jnp.where(l == 0.0, 1.0, l), l_w)
+        out_ref[0] = acc_sc[:] / l_w
 
 
 def flash_prefill_supported(block_size, chunk, hidden, n_heads,
                             itemsize=2):
     """Gate for the fused flash prefill-chunk kernel: TPU tiling
-    constraints on the per-head tiles plus the KN502 VMEM projection
-    via the shared kernel_registry model (q/k/v/out blocks moving,
-    online-softmax scratch resident, f32 casts + the [C, bs] score
-    tile as temps)."""
+    constraints on the per-group tiles (whole 128-lane head groups,
+    see _head_group) plus the KN502 VMEM projection via the shared
+    kernel_registry model (q/k/v/out blocks moving, online-softmax
+    scratch resident, f32 casts + the [C, bs] score tile as temps)."""
     if hidden % n_heads:
         return False
     H = hidden // n_heads
-    if block_size % 8 or chunk % 8 or H % 8:
+    G = _head_group(n_heads, H)
+    if block_size % 8 or chunk % 8 or not G:
         return False
+    W = G * H
     return vmem_footprint(
-        moving=[((1, chunk, H), itemsize),
-                ((1, block_size, H), itemsize),
-                ((1, block_size, H), itemsize),
-                ((1, chunk, H), 4)],
-        scratch=[((chunk, _COLS), 4), ((chunk, _COLS), 4),
-                 ((chunk, H), 4)],
-        temp_bytes=(chunk * H + 2 * block_size * H
+        moving=[((1, chunk, W), itemsize),
+                ((1, block_size, W), itemsize),
+                ((1, block_size, W), itemsize),
+                ((1, chunk, W), 4)],
+        scratch=[((G, chunk, _COLS), 4), ((G, chunk, _COLS), 4),
+                 ((chunk, W), 4)],
+        temp_bytes=(4 * chunk * W + 2 * block_size * W
                     + 2 * chunk * block_size) * 4) <= _VMEM_BUDGET
 
 
@@ -537,28 +574,35 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
         out = jnp.einsum("bnqk,bknh->bqnh", probs, v4.astype(q.dtype))
         return out.reshape(1, C, nh)
 
+    G = _head_group(N, H)
+    if not G:
+        raise ValueError(
+            f"flash_prefill_chunk kernel: {N} heads of {H} lanes do not "
+            "form whole 128-lane groups (see flash_prefill_supported)")
+    W = G * H
     p0_arr = jnp.asarray(p0, jnp.int32).reshape((1,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(N, mb),
+        grid=(N // G, mb),
         in_specs=[
-            pl.BlockSpec((1, C, H), lambda n, i, tab, p0r: (0, 0, n)),
-            pl.BlockSpec((1, bs, H),
+            pl.BlockSpec((1, C, W), lambda n, i, tab, p0r: (0, 0, n)),
+            pl.BlockSpec((1, bs, W),
                          lambda n, i, tab, p0r: (tab[i], 0, n)),
-            pl.BlockSpec((1, bs, H),
+            pl.BlockSpec((1, bs, W),
                          lambda n, i, tab, p0r: (tab[i], 0, n)),
         ],
-        out_specs=pl.BlockSpec((1, C, H),
+        out_specs=pl.BlockSpec((1, C, W),
                                lambda n, i, tab, p0r: (0, 0, n)),
         scratch_shapes=[
-            pltpu.VMEM((C, _COLS), jnp.float32),
-            pltpu.VMEM((C, _COLS), jnp.float32),
-            pltpu.VMEM((C, H), jnp.float32),
+            pltpu.VMEM((G, C, _COLS), jnp.float32),
+            pltpu.VMEM((G, C, _COLS), jnp.float32),
+            pltpu.VMEM((C, W), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=scale, bs=bs, nl=mb,
-                          C=C),
+                          C=C, G=G, H=H),
+        name="flash_prefill_chunk",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, C, nh), jnp.float32),
         interpret=_interpret(),
@@ -629,6 +673,7 @@ def decode_attention(q, k_buf, v_buf, off, n_heads):
 
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, nl=nl),
+        name="decode_fused",
         grid=(B, nl),
         in_specs=[
             pl.BlockSpec((1, 1, nh), lambda b, l: (b, 0, 0)),

@@ -93,6 +93,7 @@ def int8_matvec(h, wq, scale, block_v=_BLOCK_V):
             [h, jnp.zeros((Bp - B, D), h.dtype)], axis=0)
     out = pl.pallas_call(
         _kernel,
+        name="int8_matvec",
         grid=(V // block_v,),
         in_specs=[
             pl.BlockSpec((Bp, D), lambda i: (0, 0)),

@@ -99,6 +99,7 @@ def _fwd(x, residual, weight, bias, eps):
     br = min(_BLOCK_ROWS, rows)
     out, s, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layernorm_fwd_saved",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
@@ -152,6 +153,7 @@ def fused_add_layer_norm(x, residual, weight, bias, eps=1e-5):
     br = min(_BLOCK_ROWS, rows)
     return pl.pallas_call(
         functools.partial(_fwd_only_kernel, eps=eps),
+        name="layernorm_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),

@@ -148,7 +148,7 @@ def _restore_structure(ckptr, path, saved):
     manifest's leaf names (which cannot represent empty subtrees but
     keeps a metadata-less checkpoint restorable)."""
     try:
-        md = ckptr.metadata(path)
+        md = ckptr.metadata(path).item_metadata.tree
 
         def walk(sub, prefix=""):
             out = {}

@@ -373,9 +373,13 @@ class ServingEngine:
             tok_logp = jnp.take_along_axis(logp, tok[:, None], 1)[:, 0]
             return tok, tok_logp
 
-        def decode_fn(param_vals, k_pages, v_pages, tokens, ctx, tables,
-                      keys, counts, temp, top_k, top_p, greedy,
-                      sampling=True):
+        def decode_logits(param_vals, k_pages, v_pages, tokens, ctx,
+                          tables, use_kernel=None):
+            """Last-position logits [S, V] of one decode step plus the
+            updated arenas. use_kernel rides through to
+            paged_decode_attention (None = its platform gate); only
+            chip_smoke.py and the tests pass it, to hold the fused
+            kernel against the gather+dense path on the same step."""
             param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
@@ -400,25 +404,32 @@ class ServingEngine:
                 def attend(qv, kp, vp):
                     return paged_decode_attention(
                         qv.reshape(S, 1, nh), kp, vp, tables, ctx,
-                        n_heads)
+                        n_heads, use_kernel=use_kernel)
 
                 for li, block in enumerate(core.blocks):
                     h, kp, vp = block_step(block, h, attend, write_l(li))
                     new_k.append(kp)
                     new_v.append(vp)
                 last = model.lm_head(core.ln_f(h))._value[:, -1]
-                rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
-                    if sampling else keys
-                tok, logp = select(last, rngs, temp, top_k, top_p,
-                                   greedy, sampling=sampling)
-            return tok, logp, tuple(new_k), tuple(new_v)
+            return last, tuple(new_k), tuple(new_v)
 
-        def prefill_fn(param_vals, k_pages, v_pages, ids, p0, n_real,
-                       table_row, key, count, temp, top_k, top_p, greedy):
-            """One chunk of ONE request: ids [1, C] (tail past n_real is
-            padding -> null-block writes), positions p0..p0+C-1. Also
-            samples the next token from the last REAL position — used
-            only when the host knows this was the final chunk."""
+        def decode_fn(param_vals, k_pages, v_pages, tokens, ctx, tables,
+                      keys, counts, temp, top_k, top_p, greedy,
+                      sampling=True):
+            last, new_k, new_v = decode_logits(
+                param_vals, k_pages, v_pages, tokens, ctx, tables)
+            rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
+                if sampling else keys
+            tok, logp = select(last, rngs, temp, top_k, top_p,
+                               greedy, sampling=sampling)
+            return tok, logp, new_k, new_v
+
+        def prefill_logits(param_vals, k_pages, v_pages, ids, p0, n_real,
+                           table_row, use_kernel=None):
+            """Logits [1, V] at the chunk's last REAL position plus the
+            updated arenas, for one chunk of ONE request: ids [1, C]
+            (tail past n_real is padding -> null-block writes),
+            positions p0..p0+C-1. use_kernel as in decode_logits."""
             param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
@@ -450,7 +461,7 @@ class ServingEngine:
                     # to run_generate
                     return flash_prefill_chunk(
                         qv.reshape(1, C, nh), kp, vp, table_row, p0,
-                        n_heads)
+                        n_heads, use_kernel=use_kernel)
 
                 new_k, new_v = [], []
                 for li, block in enumerate(core.blocks):
@@ -463,10 +474,19 @@ class ServingEngine:
                 h_last = jax.lax.dynamic_slice(
                     hf._value, (0, n_real - 1, 0), (1, 1, hf.shape[-1]))
                 last = model.lm_head(Tensor(h_last))._value[:, -1]
-                rngs = jax.random.fold_in(key, count)[None]
-                tok, logp = select(last, rngs, temp[None], top_k[None],
-                                   top_p[None], greedy[None])
-            return tok[0], logp[0], tuple(new_k), tuple(new_v)
+            return last, tuple(new_k), tuple(new_v)
+
+        def prefill_fn(param_vals, k_pages, v_pages, ids, p0, n_real,
+                       table_row, key, count, temp, top_k, top_p, greedy):
+            """One prefill chunk; also samples the next token from the
+            last REAL position — used only when the host knows this
+            was the final chunk."""
+            last, new_k, new_v = prefill_logits(
+                param_vals, k_pages, v_pages, ids, p0, n_real, table_row)
+            rngs = jax.random.fold_in(key, count)[None]
+            tok, logp = select(last, rngs, temp[None], top_k[None],
+                               top_p[None], greedy[None])
+            return tok[0], logp[0], new_k, new_v
 
         def fork_fn(k_pages, v_pages, src, dst):
             """Copy-on-write fork: duplicate physical block `src` into
@@ -478,6 +498,8 @@ class ServingEngine:
             return new_k, new_v
 
         import functools
+        self._decode_logits = decode_logits
+        self._prefill_logits = prefill_logits
         donate = (1, 2) if jax.default_backend() == "tpu" else ()
         self._decode_jit = jax.jit(
             functools.partial(decode_fn, sampling=True),

@@ -174,12 +174,6 @@ def attribution(op, payload_bytes, axis_size, time_ms, peak_bw=None,
 # the sweep programs
 # ---------------------------------------------------------------------------
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def sweep_program(op, axis, mesh, payload_bytes, dtype=np.float32):
     """Build one swept collective as a global-view callable.
 
@@ -230,7 +224,10 @@ def sweep_program(op, axis, mesh, payload_bytes, dtype=np.float32):
             out_spec = P(axis)
             body = lambda v: jax.lax.all_to_all(             # noqa: E731
                 v, axis, split_axis=0, concat_axis=0, tiled=True)
-    fn = _shard_map(body, mesh, in_spec, out_spec)
+    # check_vma off: a tiled all_gather's result is typed as varying, so
+    # the replicated out_spec of that sweep would be rejected
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                       out_specs=out_spec, check_vma=False)
     sds = jax.ShapeDtypeStruct(global_shape, np.dtype(dtype))
     actual = rows * _SWEEP_COLS * itemsize
     return fn, sds, in_spec, actual

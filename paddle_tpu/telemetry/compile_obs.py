@@ -16,7 +16,7 @@ stack:
   real number — computed on every compile, recorded nowhere, until now.
 - **cost-model drift**: MFU claims divide measured time by an analytic
   FLOPs number (`telemetry/mfu.py`); when the compiled program's own
-  cost analysis (`cost_model._safe_cost_analysis`) disagrees, every MFU
+  cost analysis (`cost_model._cost_analysis`) disagrees, every MFU
   in the run is quietly wrong.
 
 Mechanics — three layers, same pattern as the rest of telemetry
@@ -259,7 +259,7 @@ def diff_signatures(old, new):
 def memory_analysis_dict(compiled):
     """`compiled.memory_analysis()` flattened to plain per-device byte
     counts ({arg,out,temp,code,alias,total}_bytes), None when the
-    backend refuses (the same degrade stance as _safe_cost_analysis).
+    backend refuses.
     total excludes generated code: it is the HBM the program's DATA
     needs, the number SH206 projects."""
     try:
@@ -283,8 +283,8 @@ def memory_analysis_dict(compiled):
 
 
 def _cost_dict(compiled):
-    from ..cost_model import _safe_cost_analysis
-    ca = _safe_cost_analysis(compiled)
+    from ..cost_model import _cost_analysis
+    ca = _cost_analysis(compiled)
     flops = float(ca.get("flops", 0.0) or 0.0)
     byts = float(ca.get("bytes accessed", 0.0) or 0.0)
     if flops <= 0 and byts <= 0:
@@ -567,6 +567,14 @@ class CompileObservatory:
                               stacklevel=4)
             return
         raise HealthError(anomalies)
+
+    def compiled_programs(self):
+        """[(family, compiled executable)] for every program the AOT
+        cache holds — what a caller reads `as_text()` from to check
+        what was really compiled (chip_smoke.py looks for the Mosaic
+        kernels and their per-device operand shapes there)."""
+        return [(key[0], compiled)
+                for key, (_, compiled) in self._aot.items()]
 
     @property
     def anomalies(self):

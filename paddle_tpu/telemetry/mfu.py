@@ -34,23 +34,32 @@ PEAK_HBM_BW_BY_KIND = {
 
 
 def _match_kind(table, kind):
+    """Longest-substring lookup of a device-kind string. kind=None
+    reads the live default device, and there an accelerator with no
+    row is an error, not a default: a utilization computed against a
+    missing peak is silently 0. The CPU (and an explicit name nobody
+    listed, e.g. a planner what-if chip) answers None."""
+    live = None
     if kind is None:
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
-    kind = str(kind).lower()
+        live = jax.devices()[0]
+        kind = live.device_kind
+    low = str(kind).lower()
     for key, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
-        if key in kind:
+        if key in low:
             return val
+    if live is not None and live.platform != "cpu":
+        raise LookupError(
+            f"device_kind {kind!r} (platform {live.platform!r}) has no "
+            "row in the peak table; add one to telemetry/mfu.py with "
+            "its source")
     return None
 
 
 def device_peak_flops(kind=None):
     """Peak bf16 FLOP/s for a device-kind string (longest-substring match,
-    e.g. 'TPU v5 lite' -> 197e12). kind=None reads the default jax device.
-    Returns None when unknown (CPU backends) — callers treat that as
-    'MFU not computable' and report 0.0."""
+    e.g. 'TPU v5 lite' -> 197e12). kind=None reads the default jax
+    device and raises for an accelerator the table does not list.
+    Returns None on CPU backends — 'MFU not computable'."""
     return _match_kind(PEAK_FLOPS_BY_KIND, kind)
 
 
@@ -70,11 +79,14 @@ def model_flops_per_token(n_params, num_layers=0, hidden_size=0, seq_len=0):
 
 def mfu(flops_per_step, step_time_s, peak_flops=None, n_devices=1):
     """Model FLOPs utilization in [0, ~1]: achieved model FLOP/s over the
-    aggregate peak. Returns 0.0 (finite) when the peak is unknown or the
-    window is degenerate, never NaN/inf."""
+    aggregate peak. None when the peak is unknown (the CPU: there is no
+    utilization to report, and 0.0 would read as a measurement); 0.0
+    for a degenerate window, never NaN/inf."""
     if peak_flops is None:
         peak_flops = device_peak_flops()
-    if not peak_flops or not step_time_s or step_time_s <= 0:
+    if not peak_flops:
+        return None
+    if not step_time_s or step_time_s <= 0:
         return 0.0
     return float(flops_per_step) / float(step_time_s) \
         / (float(peak_flops) * max(1, int(n_devices)))

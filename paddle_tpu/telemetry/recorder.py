@@ -303,7 +303,7 @@ class TelemetryRecorder:
     JSONL sink and to `rec.records`. MFU inputs: flops_per_step (exact,
     e.g. mfu.train_step_flops) OR flops_per_token (analytic) combined with
     tokens_per_step; peak_flops defaults from the device kind
-    (mfu.device_peak_flops — None on CPU => MFU 0.0, still finite).
+    (mfu.device_peak_flops — None on CPU => no MFU in the record).
     """
 
     def __init__(self, sink=None, rank=0, tokens_per_step=None,

@@ -520,18 +520,25 @@ def test_specimen_validates_and_detector_sees_all_families():
         assert want in kinds, kinds
 
 
-def test_hapi_flops_compiled_degrades_and_works():
-    """Satellite: flops_compiled rides _safe_cost_analysis — zeros on a
-    refusing backend instead of raising, real numbers on CPU."""
+def test_hapi_flops_compiled_works_and_does_not_swallow():
+    """flops_compiled rides cost_model._cost_analysis: real numbers on
+    CPU, {} for an executable that carries no analysis, and a backend
+    error propagates (swallowed zeros read as "no FLOPs" downstream)."""
     from paddle_tpu import nn
     from paddle_tpu.hapi.flops import flops_compiled
-    from paddle_tpu.cost_model import _safe_cost_analysis
+    from paddle_tpu.cost_model import _cost_analysis
 
     class Refuses:
         def cost_analysis(self):
             raise RuntimeError("backend refuses")
 
-    assert _safe_cost_analysis(Refuses()) == {}
+    class Empty:
+        def cost_analysis(self):
+            return None
+
+    with pytest.raises(RuntimeError, match="backend refuses"):
+        _cost_analysis(Refuses())
+    assert _cost_analysis(Empty()) == {}
     net = nn.Linear(8, 4)
     got = flops_compiled(net, [np.zeros((2, 8), np.float32)])
     assert got["flops"] > 0

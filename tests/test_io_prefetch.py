@@ -100,8 +100,8 @@ def test_process_workers_match_synchronous_stream():
 
 
 def test_worker_mode_fork_rejected():
-    """os.fork() under multithreaded JAX is the BENCH_r04/r05 deadlock
-    hazard the rebuild removed: asking for it is an error, not a warn."""
+    """os.fork() under multithreaded JAX is the deadlock hazard the
+    rebuild removed: asking for it is an error, not a warn."""
     with pytest.raises(ValueError, match="fork"):
         iter(DataLoader(ArangeDataset(8), batch_size=2, num_workers=2,
                         worker_mode="fork"))

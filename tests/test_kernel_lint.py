@@ -15,6 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.analysis import kernel_lint
 from paddle_tpu.analysis.kernel_lint import (
@@ -53,8 +54,8 @@ def _sum_kernel(x_ref, o_ref):
 
 
 def _racy_entry(x, parallel):
-    cp = {"mosaic": {"dimension_semantics": ("parallel", "parallel")}} \
-        if parallel else None
+    cp = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel")) if parallel else None
     kw = {"compiler_params": cp} if cp else {}
     return pl.pallas_call(
         _sum_kernel, grid=(2, 4),

@@ -104,3 +104,23 @@ def test_multiproc_pod_elastic_relaunch(tmp_path):
                  str(script)])
     assert rc == 0
     assert marker.read_text() == "2"
+
+
+def test_multiproc_refused_on_tpu_host(tmp_path, monkeypatch):
+    """On a TPU host every local child would claim all the chips, so
+    more than one local process is refused — judged from the device
+    nodes and the environment, without touching JAX; children pinned to
+    the CPU are still allowed."""
+    launch_mod = sys.modules[start_local_trainers.__module__]
+    monkeypatch.setattr(launch_mod.glob, "glob",
+                        lambda pat: ["/dev/accel0"] if "accel" in pat else [])
+    assert launch_mod._tpu_host({})
+    assert not launch_mod._tpu_host({"JAX_PLATFORMS": "cpu"})
+    script = tmp_path / "train.py"
+    script.write_text("pass\n")
+    with pytest.raises(RuntimeError, match="one process drives all"):
+        start_local_trainers(2, str(script), [], base_env={})
+    procs = start_local_trainers(2, str(script), [],
+                                 base_env={**os.environ,
+                                           "JAX_PLATFORMS": "cpu"})
+    assert watch_local_trainers(procs) == 0

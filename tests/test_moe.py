@@ -18,6 +18,7 @@ Covers, per the PR's acceptance criteria:
 - the >=128k long-context preset: sp=8 layout passes the sharding
   battery, tiny-dims ring training step is finite.
 """
+import functools
 import json
 import math
 import os
@@ -157,10 +158,12 @@ def test_moe_ep2_kernel_vs_fallback_parity():
     wi = jnp.asarray(rs.randn(E, d, f) * 0.1, jnp.float32)
     wo = jnp.asarray(rs.randn(E, f, d) * 0.1, jnp.float32)
 
+    # jitted like every production caller (TrainStep): an eager
+    # shard_map re-lowers the whole region on each call
     def run(use_kernel):
-        out, aux, z, stats = moe_ffn_values(
-            x, wg, wi, wo, num_experts=E, k=2, capacity_factor=2.0,
-            use_kernel=use_kernel, mesh=mesh)
+        out, aux, z, stats = jax.jit(functools.partial(
+            moe_ffn_values, num_experts=E, k=2, capacity_factor=2.0,
+            use_kernel=use_kernel, mesh=mesh))(x, wg, wi, wo)
         return np.asarray(out), float(aux), np.asarray(stats)
 
     o1, a1, s1 = run(False)
@@ -174,8 +177,10 @@ def test_moe_ep2_kernel_vs_fallback_parity():
             *args, num_experts=E, k=2, capacity_factor=2.0,
             use_kernel=use_kernel, mesh=mesh)
         return jnp.sum(out ** 2) + aux
-    g1 = jax.grad(lambda *a: loss(False, *a), (0, 1, 2, 3))(x, wg, wi, wo)
-    g2 = jax.grad(lambda *a: loss(True, *a), (0, 1, 2, 3))(x, wg, wi, wo)
+    g1 = jax.jit(jax.grad(functools.partial(loss, False),
+                          (0, 1, 2, 3)))(x, wg, wi, wo)
+    g2 = jax.jit(jax.grad(functools.partial(loss, True),
+                          (0, 1, 2, 3)))(x, wg, wi, wo)
     for a, b in zip(g1, g2):
         assert np.all(np.isfinite(np.asarray(a)))
         assert np.allclose(np.asarray(a), np.asarray(b), atol=5e-5)

@@ -8,6 +8,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -227,6 +228,21 @@ def test_phase_record_schema():
     json.loads(json.dumps(bad, allow_nan=False))   # strict-JSON clean
 
 
+def test_unlisted_accelerator_kind_is_an_error(monkeypatch):
+    """The live device's peak: None on the CPU, an error (not a silent
+    MFU of 0) for an accelerator the table does not list."""
+    import jax
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v9 mega"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    with pytest.raises(LookupError, match="no row in the peak table"):
+        telemetry.device_peak_flops()
+    Dev.platform, Dev.device_kind = "cpu", "cpu"
+    assert telemetry.device_peak_flops() is None
+
+
 def test_mfu_accounting():
     assert telemetry.device_peak_flops("TPU v5 lite") == 197e12
     assert telemetry.device_peak_flops("TPU v5p") == 459e12
@@ -235,8 +251,9 @@ def test_mfu_accounting():
     assert telemetry.model_flops_per_token(100, 2, 8, 4) == 600 + 12 * 64
     assert telemetry.mfu.mfu(1e12, 0.01, peak_flops=200e12) == \
         1e12 / 0.01 / 200e12
-    # unknown peak / degenerate window stay finite
-    assert telemetry.mfu.mfu(1e12, 0.01, peak_flops=None) == 0.0
+    # unknown peak (the CPU) is "no MFU", never a 0.0 measurement; a
+    # degenerate window stays finite
+    assert telemetry.mfu.mfu(1e12, 0.01, peak_flops=None) is None
     assert telemetry.mfu.mfu(1e12, 0.0, peak_flops=1e12) == 0.0
     # exact compiled per-step flops beat zero and include backward
     import paddle_tpu.nn as nn

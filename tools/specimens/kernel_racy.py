@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.kernel_registry import KernelRegistry, register_kernel
 
@@ -60,7 +61,7 @@ def racy_row_reduce(x):
         in_specs=[pl.BlockSpec((_ROWS, _COLS), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((_ROWS, _COLS), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, _COLS), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=jax.default_backend() != "tpu",
     )(x)
