@@ -35,6 +35,7 @@ histograms at every step and at scrape time, age-stamped by
 with the slowest-K exemplars on `GET /traces`.
 """
 import contextlib
+import functools
 import threading
 import time
 
@@ -71,6 +72,9 @@ _NEG_INF = -1e30
 import itertools as _itertools
 
 _ENGINE_IDS = _itertools.count()
+
+# every span this module writes: `telemetry.span`, cat="serving"
+_span = functools.partial(_telemetry_span, cat="serving")
 
 
 class EngineConfig:
@@ -252,7 +256,6 @@ class ServingEngine:
         # stalled engine can no longer serve percentiles frozen at the
         # last finished request. `_last_latency_obs` age-stamps them.
         self._last_latency_obs = None   # guarded by: _mu
-        self._finished = 0              # guarded by: _mu
         self.tracer = (  # threadlint: type=RequestTracer  # guarded by: none (immutable ref; tracer is self-locked)
             RequestTracer(engine_id=self.engine_id, sink=sink,
                           exemplar_k=cfg.trace_exemplars)
@@ -497,7 +500,6 @@ class ServingEngine:
             new_v = tuple(v.at[dst].set(v[src]) for v in v_pages)
             return new_k, new_v
 
-        import functools
         self._decode_logits = decode_logits
         self._prefill_logits = prefill_logits
         donate = (1, 2) if jax.default_backend() == "tpu" else ()
@@ -577,51 +579,54 @@ class ServingEngine:
             # already on the client's wire — they must not enter this
             # handle's stream queue or stamp first_token_time
             req.out_tokens = replay
-        with self._cv:
-            if self._dead:
-                raise EngineDeadError(
-                    "engine is dead (warm-restart attempts exhausted)")
-            if self._stopping or self._stopped:
-                raise EngineStoppedError("engine is stopped")
-            if self._draining:
-                raise EngineDrainingError(
-                    "engine is draining (admission stopped)",
-                    retry_after_s=5.0)
-            self.sched.validate(req)        # client error, not load
-            try:
-                self._check_mem_headroom()
-                self.admission.admit_or_raise(req, self.sched.waiting)
-            except ShedError as e:
-                self._counts["shed"] += 1
-                monitor.incr("serving.shed")
-                self._record("shed", rid=req.rid,
-                             request_id=req.request_id,
-                             queue_depth=e.queue_depth,
-                             predicted_wait_ms=e.predicted_wait_ms,
-                             retry_after_s=e.retry_after_s,
-                             reason=type(e).reason,
-                             priority=req.priority_class)
+        with _span("serving_submit", rid=req.rid):
+            wait = _span("serving_submit.lock_wait").begin()
+            with self._cv:
+                wait.end()
+                if self._dead:
+                    raise EngineDeadError(
+                        "engine is dead (warm-restart attempts exhausted)")
+                if self._stopping or self._stopped:
+                    raise EngineStoppedError("engine is stopped")
+                if self._draining:
+                    raise EngineDrainingError(
+                        "engine is draining (admission stopped)",
+                        retry_after_s=5.0)
+                self.sched.validate(req)        # client error, not load
+                try:
+                    self._check_mem_headroom()
+                    self.admission.admit_or_raise(req, self.sched.waiting)
+                except ShedError as e:
+                    self._counts["shed"] += 1
+                    monitor.incr("serving.shed")
+                    self._record("shed", rid=req.rid,
+                                 request_id=req.request_id,
+                                 queue_depth=e.queue_depth,
+                                 predicted_wait_ms=e.predicted_wait_ms,
+                                 retry_after_s=e.retry_after_s,
+                                 reason=type(e).reason,
+                                 priority=req.priority_class)
+                    if self.tracer is not None:
+                        # the shed verdict IS this request's trace
+                        self.tracer.record_shed(
+                            req, time.monotonic(),
+                            queue_depth=e.queue_depth,
+                            reason=type(e).reason)
+                    raise
                 if self.tracer is not None:
-                    # the shed verdict IS this request's trace
-                    self.tracer.record_shed(
-                        req, time.monotonic(),
-                        queue_depth=e.queue_depth,
-                        reason=type(e).reason)
-                raise
-            if self.tracer is not None:
-                req.trace = self.tracer.start(req.rid, req.submit_time)
-            self.sched.enqueue(req)     # validated above, by design
-            self._counts["admitted"] += 1
-            monitor.incr("serving.requests")
-            monitor.incr("serving.admitted")
-            self._record("admitted", rid=req.rid,
-                         request_id=req.request_id,
-                         queue_depth=len(self.sched.waiting),
-                         priority=req.priority_class,
-                         queue_deadline_ms=self._queue_deadline_ms(req),
-                         replayed=len(req.out_tokens) or None)
-            self._update_gauges()
-            self._cv.notify_all()
+                    req.trace = self.tracer.start(req.rid, req.submit_time)
+                self.sched.enqueue(req)     # validated above, by design
+                self._counts["admitted"] += 1
+                monitor.incr("serving.requests")
+                monitor.incr("serving.admitted")
+                self._record("admitted", rid=req.rid,
+                             request_id=req.request_id,
+                             queue_depth=len(self.sched.waiting),
+                             priority=req.priority_class,
+                             queue_deadline_ms=self._queue_deadline_ms(req),
+                             replayed=len(req.out_tokens) or None)
+                self._update_gauges()
+                self._cv.notify_all()
         return RequestHandle(req, engine=self)
 
     def cancel(self, req):
@@ -649,10 +654,38 @@ class ServingEngine:
     def step(self):
         """One scheduler iteration: reap (cancellations + deadlines),
         admit, at most one prefill chunk, one decode batch. Returns
-        True when any work was done. The whole iteration runs inside a
-        `serving_step` telemetry span, so engine steps render as a lane
-        next to the per-request trace lanes in the Chrome export."""
-        with self._mu, _telemetry_span("serving_step", cat="serving"):
+        True when any work was done. The whole iteration, the wait for
+        the engine's lock included, is one `serving_step` telemetry span
+        whose children name its phases (`serving_step.lock_wait`,
+        `.schedule`, `.blocks`, `.build`, `serving_dispatch`, `.fetch`,
+        `.emit`, `.mem_snapshot`, `.gauges`): a lane next to the
+        per-request lanes in the Chrome export, and under a running
+        `jax.profiler` trace a line of the XPlane beside the device's
+        ops, where they say what the host did while the device idled."""
+        with _span("serving_step") as whole:
+            wait = _span("serving_step.lock_wait").begin()
+            with self._mu:
+                wait.end()
+                whole.set(step=self._steps)
+                self._schedule()
+                did = self._prefill_one()
+                did = self._decode_once() or did
+                self._steps += 1
+                if self._steps % self.cfg.mem_sample_every == 0:
+                    with _span("serving_step.mem_snapshot"):
+                        try:
+                            self.mem_obs.snapshot(self._steps,
+                                                  device=self.cfg.device)
+                        except Exception:
+                            pass    # the ledger must never take a step down
+                with _span("serving_step.gauges"):
+                    self._update_gauges()
+                return did
+
+    def _schedule(self):     # requires: _mu
+        """The step's host-only head: reap, admit, and the accounting
+        of what was admitted (prefix hits, queue-wait samples)."""
+        with _span("serving_step.schedule") as sp:
             now = time.monotonic()
             self._reap(now)
             admitted = self.sched.admit(now=now)
@@ -681,17 +714,7 @@ class ServingEngine:
                 if qw is not None:
                     monitor.observe_hist("serving.queue_wait_ms", qw)
                     self._last_latency_obs = now
-            did = self._prefill_one()
-            did = self._decode_once() or did
-            self._steps += 1
-            if self._steps % self.cfg.mem_sample_every == 0:
-                try:
-                    self.mem_obs.snapshot(self._steps,
-                                          device=self.cfg.device)
-                except Exception:
-                    pass    # the ledger must never take a step down
-            self._update_gauges()
-            return did
+            sp.set(admitted=len(admitted), waiting=depth)
 
     def _reap(self, now=None):     # requires: _mu
         """Step-boundary enforcement of cancellation + server-side
@@ -1049,8 +1072,9 @@ class ServingEngine:
             self.sched.preempt(victim)
         new = got[0]
         args = (self.cache.k, self.cache.v, np.int32(old), np.int32(new))
-        new_k, new_v = self._dispatch("serving_fork", self._fork_jit,
-                                      args)
+        with _span("serving_dispatch", family="serving_fork"):
+            new_k, new_v = self._dispatch("serving_fork", self._fork_jit,
+                                          args)
         self.cache.swap(new_k, new_v)
         pool.free([old], owner=req.rid)
         req.blocks[bi] = new
@@ -1074,120 +1098,155 @@ class ServingEngine:
             if c_real <= 0:                     # defensive; place it
                 sched.place(req)
                 continue
-            if not sched.ensure_blocks(req, p0 + c_real,
-                                       evict=allow_evict and idx == 0):
-                continue                        # wait for free blocks
-            # a prefix hit may resume INSIDE a shared block (partial
-            # tail): fork before the chunk writes into it. Blocks past
-            # p0's are freshly allocated, so one check suffices; the
-            # fork obeys the same no-evict-while-decoding policy as the
-            # chunk's own block growth above
-            bi = p0 // self.block_size
-            if bi < len(req.blocks) and not self._cow_fork(
-                    req, bi, evict=allow_evict and idx == 0):
-                continue                        # wait / yielded
-            C = self.cfg.prefill_chunk
-            ids = np.zeros((1, C), np.int32)
-            ids[0, :c_real] = seq[p0:p0 + c_real]
-            table_row = self._table_row(req)
-            p = req.params
-            g = len(req.out_tokens)
-            args = (self._param_vals(), self.cache.k, self.cache.v,
-                    ids,
-                    np.int32(p0), np.int32(c_real),
-                    table_row,
-                    req.rng_key, np.int32(g),
-                    np.float32(p.temperature), np.int32(p.top_k),
-                    np.float32(p.top_p), np.bool_(p.greedy))
-            tok, logp, new_k, new_v = self._dispatch(
-                "serving_prefill", self._prefill_jit, args)
-            self.cache.swap(new_k, new_v)
-            monitor.incr("serving.prefill_chunks")
-            req.n_prefilled = p0 + c_real
-            if req.trace is not None:
-                req.trace.note_prefill_chunk(time.monotonic(), p0, c_real)
-            if req.n_prefilled >= len(seq):
-                # full prompt K/V now lives in this request's blocks:
-                # publish the FULL prompt blocks to the prefix index so
-                # later requests with the same prefix skip recomputing
-                sched.note_prefill_done(req)
-                # final chunk: the sampled token is the next stream token
-                # (the engine IS the API boundary: tokens must land on
-                # the host to stream; the second fetch copies a buffer
-                # the first already waited for)
-                self._emit(req, int(np.asarray(tok)),
-                           float(np.asarray(logp)))
-                if req.state == PREFILL:    # _emit finishes done ones
-                    sched.place(req)
+            with _span("serving_step.blocks", kind="prefill"):
+                # a prefix hit may resume INSIDE a shared block (partial
+                # tail): fork before the chunk writes into it. Blocks
+                # past p0's are freshly allocated, so one check
+                # suffices; the fork obeys the same
+                # no-evict-while-decoding policy as the chunk's own
+                # block growth
+                bi = p0 // self.block_size
+                ready = sched.ensure_blocks(
+                    req, p0 + c_real, evict=allow_evict and idx == 0) \
+                    and (bi >= len(req.blocks) or self._cow_fork(
+                        req, bi, evict=allow_evict and idx == 0))
+            if not ready:
+                continue                # wait for free blocks / yielded
+            with _span("serving_step.build", kind="prefill"):
+                C = self.cfg.prefill_chunk
+                ids = np.zeros((1, C), np.int32)
+                ids[0, :c_real] = seq[p0:p0 + c_real]
+                table_row = self._table_row(req)
+                p = req.params
+                g = len(req.out_tokens)
+                args = (self._param_vals(), self.cache.k, self.cache.v,
+                        ids,
+                        np.int32(p0), np.int32(c_real),
+                        table_row,
+                        req.rng_key, np.int32(g),
+                        np.float32(p.temperature), np.int32(p.top_k),
+                        np.float32(p.top_p), np.bool_(p.greedy))
+            with _span("serving_dispatch", family="serving_prefill",
+                       rid=req.rid, p0=p0, n_real=c_real):
+                tok, logp, new_k, new_v = self._dispatch(
+                    "serving_prefill", self._prefill_jit, args)
+            last = p0 + c_real >= len(seq)
+            with _span("serving_step.emit", kind="prefill",
+                       tokens=int(last)):
+                self.cache.swap(new_k, new_v)
+                # the last reference to the arenas this chunk replaced:
+                # dropped here, freeing them lies inside the span
+                del args
+                monitor.incr("serving.prefill_chunks")
+                req.n_prefilled = p0 + c_real
+                if req.trace is not None:
+                    req.trace.note_prefill_chunk(time.monotonic(), p0,
+                                                 c_real)
+                if last:
+                    # full prompt K/V now lives in this request's
+                    # blocks: publish the FULL prompt blocks to the
+                    # prefix index so later requests with the same
+                    # prefix skip recomputing
+                    sched.note_prefill_done(req)
+                    # final chunk: the sampled token is the next stream
+                    # token (the engine IS the API boundary: tokens must
+                    # land on the host to stream; the second fetch
+                    # copies a buffer the first already waited for)
+                    with _span("serving_step.fetch", kind="prefill"):
+                        tok = int(np.asarray(tok))
+                        logp = float(np.asarray(logp))
+                    self._emit(req, tok, logp)
+                    if req.state == PREFILL:    # _emit finishes done ones
+                        sched.place(req)
             return True
         return False
 
     def _decode_once(self):     # requires: _mu
         sched = self.sched
-        # grow blocks oldest-first so eviction lands on the youngest
-        for req in list(sched.admit_order):
-            if req.slot is None:
-                continue
-            sched.ensure_blocks(req, req.n_prefilled + 1, evict=True)
-            # decode writes position n_prefilled: defensively fork a
-            # still-shared tail (normally prefill already forked it)
-            bi = req.n_prefilled // self.block_size
-            if req.slot is not None and bi < len(req.blocks):
-                self._cow_fork(req, bi)
+        with _span("serving_step.blocks", kind="decode"):
+            # grow blocks oldest-first so eviction lands on the youngest
+            for req in list(sched.admit_order):
+                if req.slot is None:
+                    continue
+                sched.ensure_blocks(req, req.n_prefilled + 1, evict=True)
+                # decode writes position n_prefilled: defensively fork a
+                # still-shared tail (normally prefill already forked it)
+                bi = req.n_prefilled // self.block_size
+                if req.slot is not None and bi < len(req.blocks):
+                    self._cow_fork(req, bi)
         active = [(i, r) for i, r in enumerate(sched.running)
                   if r is not None]
         if not active:
             return False
-        S = self.cfg.max_slots
-        mb = self.max_blocks_per_seq
-        tokens = np.zeros((S,), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        tables = np.full((S, mb), NULL_BLOCK, np.int32)
-        keys = np.zeros((S, 2), np.uint32)
-        counts = np.zeros((S,), np.int32)
-        temp = np.ones((S,), np.float32)
-        top_k = np.zeros((S,), np.int32)
-        top_p = np.ones((S,), np.float32)
-        greedy = np.ones((S,), np.bool_)
-        for i, req in active:
-            p = req.params
-            tokens[i] = req.tokens_all[req.n_prefilled]
-            ctx[i] = req.n_prefilled
-            tables[i, :len(req.blocks)] = req.blocks
-            keys[i] = req.rng_key
-            counts[i] = len(req.out_tokens)
-            temp[i] = p.temperature
-            top_k[i] = p.top_k
-            top_p[i] = p.top_p
-            greedy[i] = p.greedy
-        # numpy args go straight into the jitted call: the C++ dispatch
-        # path transfers them, which profiles ~2x cheaper per step than
-        # a python-level jnp.asarray round for each array
-        args = (self._param_vals(), self.cache.k, self.cache.v,
-                tokens, ctx, tables, keys, counts, temp, top_k, top_p,
-                greedy)
-        # all-greedy batches take the sort-free program (distinct
-        # compile FAMILY, not a recompile — each variant compiles once)
-        sampling = any(not r.params.greedy for _, r in active)
-        tok, logp, new_k, new_v = self._dispatch(
-            "serving_decode_sampling" if sampling else "serving_decode",
-            self._decode_jit if sampling else self._decode_greedy_jit,
-            args)
-        self.cache.swap(new_k, new_v)
-        # host sync: the engine is the API boundary — the sampled
-        # tokens must land on the host to stream/route; logp's buffer
-        # is ready once tok's fetch has waited
-        tok = np.asarray(tok)
-        logp = np.asarray(logp)
-        monitor.incr("serving.decode_steps")
-        now = time.monotonic()
-        for i, req in active:
-            req.n_prefilled += 1
-            if req.trace is not None:
-                # O(1) per request per step: extends the coalesced
-                # decode segment (one span per stretch, never per token)
-                req.trace.note_decode(now)
-            self._emit(req, int(tok[i]), float(logp[i]), now=now)
+        with _span("serving_step.build", kind="decode"):
+            S = self.cfg.max_slots
+            mb = self.max_blocks_per_seq
+            tokens = np.zeros((S,), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            tables = np.full((S, mb), NULL_BLOCK, np.int32)
+            keys = np.zeros((S, 2), np.uint32)
+            counts = np.zeros((S,), np.int32)
+            temp = np.ones((S,), np.float32)
+            top_k = np.zeros((S,), np.int32)
+            top_p = np.ones((S,), np.float32)
+            greedy = np.ones((S,), np.bool_)
+            # what the batch attends to, for the dispatch span: slots
+            # that hold a context, and their contexts with the token
+            # this step adds
+            slots = ctx_tokens = 0
+            for i, req in active:
+                p = req.params
+                tokens[i] = req.tokens_all[req.n_prefilled]
+                ctx[i] = req.n_prefilled
+                if req.n_prefilled > 0:
+                    slots += 1
+                    ctx_tokens += req.n_prefilled + 1
+                tables[i, :len(req.blocks)] = req.blocks
+                keys[i] = req.rng_key
+                counts[i] = len(req.out_tokens)
+                temp[i] = p.temperature
+                top_k[i] = p.top_k
+                top_p[i] = p.top_p
+                greedy[i] = p.greedy
+            # numpy args go straight into the jitted call: the C++
+            # dispatch path transfers them, which profiles ~2x cheaper
+            # per step than a python-level jnp.asarray round for each
+            args = (self._param_vals(), self.cache.k, self.cache.v,
+                    tokens, ctx, tables, keys, counts, temp, top_k, top_p,
+                    greedy)
+            # all-greedy batches take the sort-free program (distinct
+            # compile FAMILY, not a recompile — each variant compiles
+            # once)
+            sampling = any(not r.params.greedy for _, r in active)
+            family = "serving_decode_sampling" if sampling \
+                else "serving_decode"
+        with _span("serving_dispatch", family=family, slots=slots,
+                   ctx_tokens=ctx_tokens):
+            tok, logp, new_k, new_v = self._dispatch(
+                family,
+                self._decode_jit if sampling else self._decode_greedy_jit,
+                args)
+        with _span("serving_step.emit", kind="decode", tokens=len(active)):
+            self.cache.swap(new_k, new_v)
+            # the last reference to the arenas this step replaced:
+            # dropped here, freeing them lies inside the span
+            del args
+            # host sync: the engine is the API boundary — the sampled
+            # tokens must land on the host to stream/route; logp's
+            # buffer is ready once tok's fetch has waited
+            with _span("serving_step.fetch", kind="decode"):
+                tok = np.asarray(tok)
+                logp = np.asarray(logp)
+            monitor.incr("serving.decode_steps")
+            now = time.monotonic()
+            for i, req in active:
+                req.n_prefilled += 1
+                if req.trace is not None:
+                    # O(1) per request per step: extends the coalesced
+                    # decode segment (one span per stretch, never per
+                    # token)
+                    req.trace.note_decode(now)
+                self._emit(req, int(tok[i]), float(logp[i]), now=now)
         return True
 
     # ------------------------------------------------------------------
@@ -1246,7 +1305,6 @@ class ServingEngine:
         req.push_token(tok, now=now)
         monitor.incr("serving.tokens_generated")
         if req.done:
-            self._finished += 1
             monitor.incr("serving.finished")
             t = req.ttft_ms()
             if t is not None:

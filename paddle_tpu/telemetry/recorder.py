@@ -18,7 +18,10 @@ Mechanics:
 - spans (`telemetry.span("name")`) are host intervals tagged with the
   recorder's rank; `distributed/collective.py` tags each eager
   collective, so per-step comm time is attributable. Spans export to a
-  multi-rank Chrome trace (sink.export_chrome_tracing).
+  multi-rank Chrome trace (sink.export_chrome_tracing), and every span
+  is also a `jax.profiler.TraceAnnotation`: while a profile runs it
+  lies in the XPlane on the device trace's clock, with or without a
+  recorder.
 - every closed step writes one JSONL record (sink.make_step_record):
   step, loss, step_ms, compile_ms, execute_ms, tokens/sec, MFU,
   mem_bytes, per-collective ms, cache hit/miss counters.
@@ -33,6 +36,7 @@ import time
 import jax
 
 from .. import monitor
+from .. import profiler as _profiler
 from . import mfu as _mfu
 from .sink import JsonlSink, make_step_record
 
@@ -126,16 +130,22 @@ class _InertWindow:
         return self
 
 
+class _OpenSpan:
+    """One row of the open-span table: what `open_spans()` and the
+    chrome export read of a span that has not ended yet."""
+    __slots__ = ("name", "cat", "rank", "attrs", "t0", "thread", "rec")
+
+
 def _push_open_span(name, cat, t0, rec=None, rank=None, attrs=None):
     """Register a just-entered span in the module-wide open-span table.
     The hang watchdog reads this table to NAME what a stalled step is
     stuck inside (e.g. `collective.all_reduce`), and chrome export
     closes these instead of dropping them. Returns the entry (identity
     is the removal token)."""
-    entry = {"name": name, "cat": cat, "t0": t0,
-             "tid": threading.get_ident(),
-             "thread": threading.current_thread().name,
-             "rec": rec, "rank": rank, "attrs": dict(attrs or {})}
+    entry = _OpenSpan()
+    entry.name, entry.cat, entry.t0 = name, cat, t0
+    entry.thread = threading.current_thread()
+    entry.rec, entry.rank, entry.attrs = rec, rank, dict(attrs or {})
     with _LOCK:
         _OPEN_SPANS.append(entry)
     return entry
@@ -143,6 +153,10 @@ def _push_open_span(name, cat, t0, rec=None, rank=None, attrs=None):
 
 def _pop_open_span(entry):
     with _LOCK:
+        # spans nest, so the one that ends is nearly always the newest
+        if _OPEN_SPANS and _OPEN_SPANS[-1] is entry:
+            _OPEN_SPANS.pop()
+            return
         try:
             _OPEN_SPANS.remove(entry)
         except ValueError:
@@ -157,40 +171,86 @@ def open_spans():
     now = time.perf_counter()
     with _LOCK:
         entries = list(_OPEN_SPANS)
-    return [{"name": e["name"], "cat": e["cat"],
-             "age_s": round(now - e["t0"], 4), "thread": e["thread"],
-             "rank": e["rank"], "attrs": e["attrs"]} for e in entries]
+    return [{"name": e.name, "cat": e.cat,
+             "age_s": round(now - e.t0, 4), "thread": e.thread.name,
+             "rank": e.rank, "attrs": dict(e.attrs)} for e in entries]
 
 
-@contextlib.contextmanager
-def span(name, cat="host", rank=None, **attrs):
-    """Record a named host span into the active recorder (and bridge it
-    into paddle_tpu.profiler's table when that profiler is enabled, so
-    existing RecordEvent consumers keep seeing one merged view). Extra
-    keyword attrs (e.g. axis/shape on collectives) ride into the span
-    dict, the chrome-trace `args`, and the watchdog's open-span dump.
-    While the body runs the span sits in the open-span table, so a hang
-    inside it is named in black-box dumps."""
-    rec = current_recorder()
-    from .. import profiler as _profiler
-    ev = _profiler.RecordEvent(name) if _profiler._GLOBAL["enabled"] else None
-    t0 = time.perf_counter()
-    if ev is not None:
-        ev._t0 = t0
-        ev._from_telemetry = True   # span() owns recorder routing here
-    entry = _push_open_span(name, cat, t0, rec=rec,
-                            rank=rank if rank is not None
-                            else (rec.rank if rec is not None else None),
-                            attrs=attrs)
-    try:
-        yield
-    finally:
-        dur = time.perf_counter() - t0
-        _pop_open_span(entry)
-        if ev is not None:
-            ev.end()
-        if rec is not None:
-            rec.add_span(name, t0, dur, cat=cat, rank=rank, args=attrs)
+_PLAIN = (int, float, str, bool)
+
+
+def _plain(attrs):
+    """Attribute values as the profiler and the chrome trace take them:
+    int, float, str or bool; anything else by its repr."""
+    return {k: (v if isinstance(v, _PLAIN) else repr(v))
+            for k, v in attrs.items()}
+
+
+class span(_OpenSpan):
+    """A named host span: `with telemetry.span("name", cat, **attrs):`,
+    or `begin()` / `end()` where the region is not a block.
+
+    It is written to three places. `jax.profiler.TraceAnnotation` puts it
+    on the profiler's clock, so whenever a profile is running the span
+    lies in the XPlane on its own thread's line, beside the device's
+    ops, with `attrs` as the event's stats; with no profile running
+    that is one near-empty native call. The context-active
+    TelemetryRecorder, if any, gets it when it ends (chrome-trace
+    export, per-step collective time), as does paddle_tpu.profiler's
+    table when that profiler is enabled. While the body runs the span
+    sits in the open-span table, so a hang inside it is named in the
+    watchdog's black-box dumps. `set(**attrs)` adds attributes known
+    only once the body has run. Attribute values are int, float, str
+    or bool (anything else is kept by its repr)."""
+    __slots__ = ("_ev", "_ann")
+
+    def __init__(self, name, cat="host", rank=None, **attrs):
+        self.name, self.cat, self.rank, self.attrs = name, cat, rank, attrs
+
+    def begin(self):
+        # the annotation starts first and ends last, so the span's own
+        # bookkeeping (encoding the attributes among it) lies inside it
+        ann = self._ann = jax.profiler.TraceAnnotation(self.name)
+        ann.__enter__()
+        if self.attrs:
+            ann.set_metadata(**_plain(self.attrs))
+        self.thread = threading.current_thread()
+        t0 = self.t0 = time.perf_counter()
+        if _profiler._GLOBAL["enabled"]:
+            ev = self._ev = _profiler.RecordEvent(self.name)
+            ev._t0 = t0
+            ev._from_telemetry = True   # the span owns recorder routing
+        else:
+            self._ev = None
+        with _LOCK:
+            rec = self.rec = _RECORDER_STACK[-1] if _RECORDER_STACK \
+                else None
+            _OPEN_SPANS.append(self)
+        if self.rank is None and rec is not None:
+            self.rank = rec.rank
+        return self
+
+    def set(self, **attrs):
+        """Attributes known only after the span began (how many
+        requests a scheduling pass admitted, say)."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**_plain(attrs))
+
+    def end(self):
+        dur = time.perf_counter() - self.t0
+        _pop_open_span(self)
+        if self._ev is not None:
+            self._ev.end()
+        if self.rec is not None:
+            self.rec.add_span(self.name, self.t0, dur, cat=self.cat,
+                              rank=self.rank, args=self.attrs)
+        self._ann.__exit__(None, None, None)
+
+    __enter__ = begin
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+        return False
 
 
 class StepTimer:
@@ -336,8 +396,7 @@ class TelemetryRecorder:
             "cat": cat, "rank": self.rank if rank is None else int(rank),
             "tid": threading.get_ident() % 1000 if tid is None else tid}
         if args:
-            sp["args"] = {k: (v if isinstance(v, (int, float, str, bool))
-                              else repr(v)) for k, v in args.items()}
+            sp["args"] = _plain(args)
         self.spans.append(sp)
 
     def open_span_dicts(self):
@@ -346,13 +405,13 @@ class TelemetryRecorder:
         chrome export includes them instead of dropping them."""
         now = time.perf_counter()
         with _LOCK:
-            entries = [e for e in _OPEN_SPANS if e["rec"] is self]
-        return [{"name": e["name"], "t0": float(e["t0"]),
-                 "dur": float(now - e["t0"]), "cat": e["cat"],
-                 "rank": self.rank if e["rank"] is None else e["rank"],
-                 "tid": e["tid"] % 1000,
+            entries = [e for e in _OPEN_SPANS if e.rec is self]
+        return [{"name": e.name, "t0": float(e.t0),
+                 "dur": float(now - e.t0), "cat": e.cat,
+                 "rank": self.rank if e.rank is None else e.rank,
+                 "tid": e.thread.ident % 1000,
                  "args": {"open": True, **{k: repr(v) for k, v
-                                           in e["attrs"].items()}}}
+                                           in e.attrs.items()}}}
                 for e in entries]
 
     # -- step lifecycle ----------------------------------------------------
