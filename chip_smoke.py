@@ -352,6 +352,36 @@ def _production_cases(sz):
                   lambda q, k, v: pd._decode_fallback(q, k, v, off, n),
                   (q, kb, vb)))
 
+    # paged decode at both serving cells' widths: contexts on every
+    # edge of the kernel's tiling, scattered pages, null tails, one
+    # idle slot, the last two slots sharing a cached prefix's pages
+    for N, H, mb in ((2, 64, 8),) if TINY else ((12, 64, 64),
+                                                 (16, 128, 128)):
+        pbs, hid = 16, N * H
+        T = pd.paged_decode_tile_rows(pbs, hid, N, 2, mb)
+        ctxs = sorted({0, pbs - 1, pbs, T - 1, T, T + 1, 2 * T + 3,
+                       mb * pbs - 1} & set(range(mb * pbs)))
+        ctxs += [int(c) for c in rs.randint(1, mb * pbs, 14 - len(ctxs))]
+        ctxs += [mb * pbs // 2 + 5] * 2
+        pages = rs.permutation(np.arange(
+            1, sum(c // pbs + 1 for c in ctxs) + 1))
+        tabs, used = np.zeros((len(ctxs) + 1, mb), np.int32), 0
+        for i, c in enumerate(ctxs):
+            tabs[i, :c // pbs + 1] = pages[used:used + c // pbs + 1]
+            used += c // pbs + 1
+        tabs[-2, :mb // 4] = tabs[-3, :mb // 4]
+        ctxs = np.asarray(ctxs + [0], np.int32)     # + the idle slot
+        arena = (len(pages) + 1, pbs, hid)
+        cases.append((
+            ("paged_decode",),
+            f"paged_decode {N}x{H} mb={mb} tile={T} rows",
+            lambda q, k, v, t, c, N=N: pd.paged_decode_attention(
+                q, k, v, t, c, N, use_kernel=True),
+            lambda q, k, v, t, c, N=N: pd.paged_decode_attention(
+                q, k, v, t, c, N, use_kernel=False),
+            (rand((len(ctxs), 1, hid)), rand(arena), rand(arena),
+             tabs, ctxs)))
+
     from paddle_tpu.ops import pallas_int8 as p8
     vocab = -(-cfg.vocab_size // p8._BLOCK_V) * p8._BLOCK_V   # row-padded
     hq = rand((16, nh), scale=1.0)

@@ -231,8 +231,12 @@ def capture_kernels(fn, args, kwargs=None, name="kernel"):
     """Run `fn(*args, **kwargs)` eagerly with pallas_call intercepted.
     Returns (captures, result): one KernelCapture per pallas_call the
     run made (>= 1), in call order."""
+    import jax
+
     records = []
-    with _patched_pallas_call(records):
+    # eagerly, through an entry that is itself jitted too: the spy
+    # wants concrete operands, and a cached trace would never reach it
+    with _patched_pallas_call(records), jax.disable_jit():
         result = fn(*args, **(kwargs or {}))
     if not records:
         raise ValueError(
@@ -274,8 +278,12 @@ def capture_kernels(fn, args, kwargs=None, name="kernel"):
                 getattr(spec, "block_shape", None),
                 getattr(spec, "index_map", None), sds.shape, sds.dtype,
                 is_output=True))
+        # VMEM scratch only: semaphores and SMEM scalars take none of
+        # the budget KN502 projects
         scratch_info = [(tuple(s.shape), np.dtype(s.dtype))
-                        for s in scratch if hasattr(s, "shape")]
+                        for s in scratch if hasattr(s, "shape")
+                        and str(getattr(s, "memory_space", "vmem"))
+                        == "vmem"]
         cname = name if len(records) == 1 else f"{name}#{ordinal}"
         captures.append(KernelCapture(
             cname, kernel, grid, in_infos, out_infos, scratch_info, nsp,

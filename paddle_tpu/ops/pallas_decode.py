@@ -1,43 +1,53 @@
-"""Pallas fused decode-attention (q_len == 1) over the KV cache.
+"""Pallas decode-side attention kernels over the KV cache.
 
-The profiled decode bottleneck at serving batch sizes is kernel COUNT,
-not bandwidth (~100 skinny fused kernels per token at B=64 — per-layer
-QK einsum, mask, softmax, AV einsum over the cache).
-This kernel computes the whole masked attention for ALL heads of one
-batch row in ONE program: the cache streams through VMEM once and the
-logits/probs never visit HBM.
+Three kernels, all inference-only (no vjp; training uses the
+flash-attention kernels of ops/pallas_attention.py):
 
-Shape trick (TPU tiling wants >=128 lanes; head_dim is 64): work in the
-[L, N*H] layout. Per-head contractions become two constant 0/1
-matmuls —
+`paged_decode_attention` (q_len == 1 over the serving engine's paged
+arenas) is memory bound: a step reads every live K and V row once and
+does a few FLOP a byte, so what it can reach is the HBM bandwidth. Its
+unit of work is a TILE of many cache pages (`paged_decode_tile_rows`:
+128-512 rows), walked only over the tiles a slot's context reaches.
+The arenas stay in HBM; a page of `[num_blocks, block_size, N*H]` is
+one contiguous run of bytes, and the kernel copies a tile's live pages
+itself (`pltpu.make_async_copy` through the scalar-prefetched block
+table) into one of two VMEM buffers while it computes on the other, so
+the fetch hides behind the arithmetic and no page past a context is
+read. Heads sit on sublanes: q is spread once a slot into `[heads,
+N*H]` rows, each holding one head's lanes and zeros elsewhere, so a
+tile costs two MXU dots in the arenas' dtype, `qh . K^T` [heads, rows]
+and `p @ V` [heads, N*H], every K and V element entering the MXU once,
+with the softmax statistics in float32 carried across tiles. On the
+v5e it runs at 55-90% of the HBM roofline at the serving cells' shapes
+(PERF.md §6, PR 27).
+
+`decode_attention` (q_len == 1 over a dense [B, L, N*H] cache, the
+run_generate path) computes the whole masked attention for ALL heads of
+one batch row in one program, in the [L, N*H] layout with two constant
+0/1 matmuls for the per-head contractions —
     logits[l, n] = sum_h K[l, n*H+h] * q[n*H+h]   = K @ (S * q_col)
     pexp[l, nh]  = probs[l, head_of(nh)]          = probs @ E
 with S [NH, 128] selecting each head's lanes into a column and
-E [128, NH] expanding a head column back over its lanes. All tiles are
-(multiple-of-8, multiple-of-128); the padded columns N..127 are never
-read back.
-
-The cache length is TILED: the grid is (B, nl)
-and the softmax accumulates online across L-tiles (running per-head
-max/denominator in VMEM scratch, the weighted-value accumulator rescaled
-by exp(m_prev - m_new) per tile), so arbitrary cache lengths and
-13B-scale hidden sizes run fused — the old whole-L VMEM gate is gone.
-The reference's fused attention loops key tiles the same way
+E [128, NH] expanding a head column back over its lanes. The cache
+length is TILED: the grid is (B, nl) and the softmax accumulates online
+across L-tiles (running per-head max/denominator in VMEM scratch, the
+weighted-value accumulator rescaled by exp(m_prev - m_new) per tile),
+as the reference's fused attention loops key tiles
 (`paddle/fluid/operators/fused/fmha_ref.h`).
 
-Besides the dense (`decode_attention`) and paged (`paged_decode_attention`)
-q_len==1 kernels, this module carries `flash_prefill_chunk`: the
-serving engine's chunked-prefill attention over the paged arena —
-flash-style online softmax across table-resolved blocks (the
-[chunk, ctx] score matrix never materializes), causal within the
-chunk, with a gather+dense fallback that reproduces the composed
-einsum math bit-for-bit so CPU serving stays identical to
-run_generate. Its q-side tiling follows ops/pallas_attention.py's
-flash forward; its paging follows the paged decode kernel.
+`flash_prefill_chunk` is the serving engine's chunked-prefill attention
+over the paged arena — flash-style online softmax across
+table-resolved blocks (the [chunk, ctx] score matrix never
+materializes), causal within the chunk. Its q-side tiling follows
+ops/pallas_attention.py's flash forward.
 
-Inference-only (no vjp) — training uses the flash-attention kernel.
+Both paged kernels have a gather+dense fallback that reproduces the
+composed einsum math of models/gpt._cached_attention bit for bit, so
+CPU serving stays identical to run_generate; it is also the parity
+reference the tests and chip_smoke.py hold the kernels to.
 """
 import functools
+import math
 
 import numpy as np
 import jax
@@ -50,6 +60,8 @@ from .kernel_registry import (VMEM_BUDGET as _VMEM_BUDGET,
 
 _COLS = 128   # head-column padding (N <= 128 heads)
 _SUB = 8      # scratch stat rows padded to the (8, 128) f32 tile minimum
+# the most rows one paged-decode tile holds (see paged_decode_tile_rows)
+_TILE_ROWS = 512
 
 
 def _interpret():
@@ -184,79 +196,180 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, s_ref, e_ref, out_ref,
         out_ref[0] = (acc_sc[:1] / denom_nh).reshape(out_ref.shape[1:])
 
 
-def _paged_kernel(tab_ref, ctx_ref, q_ref, k_ref, v_ref, s_ref, e_ref,
-                  out_ref, m_sc, l_sc, acc_sc, *, scale, bs, nl):
-    """Paged variant of `_kernel`: the L-tiles are PHYSICAL cache blocks
-    reached through the scalar-prefetched block table (the index_map
-    already resolved logical block `li` of row `b` to a physical arena
-    block), and the causal mask is computed in-kernel from the logical
-    position `li*bs + j` vs the row's context length — no mask input
-    exists because the logical->physical mapping differs per row."""
+def paged_decode_tile_rows(block_size, hidden, n_heads, itemsize,
+                           max_blocks):
+    """Rows of K and of V that one step of the paged decode kernel
+    works on: the tile policy, a pure function of what the arguments'
+    shapes show. 0 when no tile fits.
+
+    A tile is a whole number of cache pages and of 128-lane logits
+    columns, so its rows are a multiple of lcm(block_size, 128). Among
+    those it is the largest that (a) has at most `_TILE_ROWS` rows: on
+    the v5e a step's fixed cost is paid for from 256 rows on, and a
+    larger tile only computes more dead rows at a context's end, (b) is
+    no longer than the longest context a table can hold, rounded up to
+    one unit, and (c) fits `VMEM_BUDGET` with both of its buffers."""
+    unit = math.lcm(int(block_size), _COLS)
+    longest = -(-int(max_blocks) * int(block_size) // unit) * unit
+    rows = min(max(unit, _TILE_ROWS // unit * unit), longest)
+    while rows and _paged_footprint(
+            rows, hidden, n_heads, itemsize) > _VMEM_BUDGET:
+        rows -= unit
+    return rows
+
+
+def _head_rows(n_heads):
+    # one row a head, padded to the 16 sublanes a packed bf16 tile has
+    return -(-n_heads // 16) * 16
+
+
+def _paged_footprint(rows, hidden, n_heads, itemsize):
+    """KN502 projection of the paged decode kernel at a tile of `rows`:
+    K and V tiles in two buffers each (the kernel's own double buffer:
+    scratch, so charged once a buffer), q and the output block moving
+    with the slot, the per-head accumulator, and the [heads, rows] f32
+    logits/probabilities plus the [heads, hidden] product as temps."""
+    R = _head_rows(n_heads)
+    return vmem_footprint(
+        moving=[((1, hidden), itemsize), ((1, hidden), 4)],
+        scratch=[((2, rows, hidden), itemsize)] * 2
+        + [((R, hidden), 4), ((R, _COLS), 4), ((R, _COLS), 4)],
+        temp_bytes=(3 * R * rows + 2 * R * hidden) * 4)
+
+
+def paged_decode_kv_rows(ctx_lens, block_size):
+    """Rows of K (and of V) a `paged_decode_attention` call fetches a
+    layer for these contexts: every page up to the one position `ctx`
+    lies in, and no page past it."""
+    ctx = np.asarray(ctx_lens)
+    return int(((ctx // block_size + 1) * block_size).sum())
+
+
+def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
+                  k_buf, v_buf, sems, buf_ref, m_sc, l_sc, acc_sc,
+                  *, scale, bs, rows, n_heads, head_dim):
+    """One grid step a SLOT; inside it a loop over the tiles of `rows`
+    cache rows (`rows // bs` pages) that the slot's context reaches,
+    `ctx // rows + 1` of them. The arenas stay in HBM: the kernel
+    copies a tile's live pages itself, each page one contiguous run
+    found through the scalar-prefetched table, into one of two VMEM
+    buffers, and starts the next tile's copies (at a slot's last tile:
+    the next slot's first) before it computes the current one.
+
+    Heads sit on sublanes: q becomes `qh` [R, N*H], row n holding head
+    n's lanes and zeros elsewhere, once a slot. A tile's logits are
+    `qh . K^T` [R, rows] and its weighted values `p @ V` [R, N*H], both
+    in the arenas' dtype with float32 accumulation; row n of the
+    product is head n's output on head n's lanes (the other lanes are
+    never read). Softmax statistics are float32, one column a row."""
     b = pl.program_id(0)
-    li = pl.program_id(1)
+    S = pl.num_programs(0)
+    P = rows // bs
+    R = acc_sc.shape[0]
+    nh = n_heads * head_dim
     ctx = ctx_ref[b]
+    n_tiles = ctx // rows + 1
 
-    @pl.when(li == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, -1e30)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+    def each_live_page(slot_b, tile, buf, act):
+        """`act` on the K and the V copy of every page of the tile that
+        the slot's context reaches: the last tile's dead pages, whose
+        table entries are the null block or lie past the table, are
+        neither fetched nor waited for. A loop, not P copies written
+        out: a step's 4 x P descriptors would be traced and lowered
+        once a layer a program."""
+        n_live = jnp.minimum(P, ctx_ref[slot_b] // bs - tile * P + 1)
 
-    # blocks wholly past the context hold no valid key (their table
-    # entries point at the null block); skip their accumulation
-    @pl.when(li * bs <= ctx)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                # [1, NH]
-        k = k_ref[0].astype(jnp.float32)                # [bs, NH]
-        v = v_ref[0].astype(jnp.float32)                # [bs, NH]
-        s = s_ref[...]                                  # [NH, COLS]
-        e = e_ref[...]                                  # [COLS, NH]
-        pos = li * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (bs, _COLS), 0)
-        mask = jnp.where(pos <= ctx, 0.0, -1e30).astype(jnp.float32)
-        qs = s * q.T                                    # [NH, COLS]
+        def page(j, carry):
+            blk = tab_ref[slot_b, tile * P + j]
+            act(pltpu.make_async_copy(
+                k_hbm.at[blk], k_buf.at[buf, j], sems.at[0, buf]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[blk], v_buf.at[buf, j], sems.at[1, buf]))
+            return carry
+
+        jax.lax.fori_loop(0, n_live, page, 0)
+
+    def start(slot_b, tile, buf):
+        each_live_page(slot_b, tile, buf, lambda c: c.start())
+
+    def wait(slot_b, tile, buf):
+        each_live_page(slot_b, tile, buf, lambda c: c.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        # p is exactly 0 on a dead row, and 0 * NaN is NaN: rows no copy
+        # has written yet must hold numbers
+        v_buf[...] = jnp.zeros_like(v_buf)
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
+    buf0 = buf_ref[0]
+    m_sc[...] = jnp.full_like(m_sc, -1e30)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    head = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 1)
+    own = jnp.logical_and(lane >= head * head_dim,
+                          lane < (head + 1) * head_dim)
+    qh = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0) \
+        .astype(k_buf.dtype)                              # [R, NH]
+
+    def tile_step(t, carry):
+        buf = (buf0 + t) % 2
+
+        # what is computed next: this slot's next tile, or at its last
+        # tile the next slot's first
+        last = t + 1 == n_tiles
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < S))
+        def _prefetch():
+            start(jnp.where(last, b + 1, b), jnp.where(last, 0, t + 1),
+                  1 - buf)
+
+        wait(b, t, buf)
         logits = jax.lax.dot_general(
-            k, qs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bs, COLS]
-        logits = logits + mask
-        m_prev = m_sc[:1]                               # [1, COLS]
-        m_cur = jnp.max(logits, axis=0, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)                 # [1, COLS]
-        p = jnp.exp(logits - m_new)                     # [bs, COLS]
-        l_new = alpha * l_sc[:1] + jnp.sum(p, axis=0, keepdims=True)
-        pexp = jax.lax.dot_general(
-            p, e, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [bs, NH]
-        alpha_nh = jax.lax.dot_general(
-            alpha, e, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [1, NH]
-        acc_sc[:1] = acc_sc[:1] * alpha_nh + jnp.sum(
-            pexp * v, axis=0, keepdims=True)            # [1, NH]
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+            qh, k_buf[buf].reshape(rows, nh), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [R, rows]
+        pos = t * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (R, rows), 1)
+        logits = jnp.where(pos <= ctx, logits, -1e30)
+        m_prev = m_sc[:, :1]                              # [R, 1]
+        m_new = jnp.maximum(
+            m_prev, jnp.max(logits, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)                       # [R, rows]
+        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[buf].reshape(rows, nh),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [R, NH]
+        acc_sc[...] = acc_sc[...] * alpha + pv
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+        return carry
 
-    @pl.when(li == nl - 1)
-    def _finalize():
-        denom = l_sc[:1]                                # [1, COLS]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        e = e_ref[...]
-        denom_nh = jax.lax.dot_general(
-            denom, e, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [1, NH]
-        out_ref[0] = (acc_sc[:1] / denom_nh).reshape(out_ref.shape[1:])
+    jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+    buf_ref[0] = (buf0 + n_tiles) % 2
+    # every slot's first tile holds position 0, so no denominator is 0
+    # but those of the padding rows past the last head
+    denom = jnp.where(l_sc[:, :1] == 0.0, 1.0, l_sc[:, :1])
+    out = jnp.sum(jnp.where(own, acc_sc[...] / denom, 0.0),
+                  axis=0, keepdims=True)                  # [1, NH]
+    out_ref[0] = out
 
 
-def paged_decode_supported(block_size, hidden, n_heads, itemsize=2):
+def paged_decode_supported(block_size, hidden, n_heads, itemsize=2,
+                           max_blocks=_COLS):
     """Gate for the fused PAGED decode kernel (the block-pool serving
-    cache, paddle_tpu/serving/kv_cache.py): same TPU tiling constraints
-    as the dense gate, applied to one cache BLOCK instead of the whole
-    contiguous buffer — the kernel streams physical blocks through VMEM
-    one at a time via the scalar-prefetched block table."""
-    if block_size % 8 or hidden % 128 or n_heads > _COLS:
+    cache, paddle_tpu/serving/kv_cache.py): a page is a whole number of
+    the dtype's packed sublane tiles (8 rows of float32, 16 of bf16) so
+    that a page copy lands tile-aligned, the lanes are whole, and the
+    tile policy finds a tile that fits VMEM."""
+    if block_size % (_SUB * max(1, 4 // itemsize)) or hidden % _COLS \
+            or n_heads > _COLS:
         return False
-    return max(_SUB, block_size) * _per_row_bytes(hidden, itemsize) \
-        <= _VMEM_BUDGET
+    return paged_decode_tile_rows(
+        block_size, hidden, n_heads, itemsize, max_blocks) > 0
 
 
 def _paged_example(rng):
@@ -291,8 +404,16 @@ def _paged_fallback(q, k_pages, v_pages, block_tables, ctx_lens,
 @register_kernel(
     "paged_decode", example=_paged_example, fallback=_paged_fallback,
     tol=(1e-3, 1e-3),
-    notes="scalar-prefetched block table resolves logical->physical "
-          "blocks (KN505 covers the prefetch channel)")
+    notes="one grid step a slot (sequential: the tile buffers and their "
+          "in-flight copies pass from slot to slot); the arenas stay in "
+          "HBM and the kernel copies the live pages of each tile itself "
+          "through the scalar-prefetched table (KN505 covers the "
+          "prefetch channel)")
+# jitted on its own so that a model's layers, which all call it on the
+# same shapes, share one trace and one lowering of the kernel: traced
+# once a layer, the decode programs' 24 or 48 copies cost seconds of
+# every start, compile cache or not
+@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                            n_heads, use_kernel=None):
     """Decode attention (q_len == 1) over a PAGED KV cache.
@@ -306,9 +427,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     in q's dtype.
 
     Two paths, one contract:
-    - fused Pallas kernel (TPU + `paged_decode_supported`): blocks
-      stream through VMEM via the scalar-prefetched table with online
-      softmax — the cache is never materialized contiguously;
+    - fused Pallas kernel (TPU + `paged_decode_supported`): tiles of
+      `paged_decode_tile_rows` rows stream through VMEM with online
+      softmax, only over the pages each context reaches — the cache is
+      never materialized contiguously and no dead page is read;
     - gather+dense fallback everywhere else: gather the physical
       blocks into a dense [S, L, N, H] view and run the SAME composed
       masked-attention math as models/gpt._cached_attention, so a CPU
@@ -322,10 +444,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     num_blocks, bs, _ = k_pages.shape
     mb = block_tables.shape[1]
     scale = 1.0 / float(np.sqrt(H))
+    itemsize = k_pages.dtype.itemsize
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
-                      and paged_decode_supported(
-                          bs, nh, N, k_pages.dtype.itemsize))
+                      and paged_decode_supported(bs, nh, N, itemsize, mb))
     if not use_kernel:
         # gather+dense: EXACTLY the composed einsum path of
         # models/gpt._cached_attention (dtypes included) over the
@@ -344,35 +466,42 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         out = jnp.einsum("bnqk,bknh->bqnh", probs, v4.astype(q.dtype))
         return out.reshape(S, 1, nh)
 
-    sm, em = _seg_mats(N, H)
+    rows = paged_decode_tile_rows(bs, nh, N, itemsize, mb)
+    if not rows:
+        raise ValueError(
+            f"paged_decode kernel: no tile of {bs}-row pages at width "
+            f"{nh} fits VMEM (see paged_decode_supported)")
+    R = _head_rows(N)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, mb),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, 1, nh), lambda b, i, tab, ctx: (b, 0, 0)),
-            pl.BlockSpec((1, bs, nh),
-                         lambda b, i, tab, ctx: (tab[b, i], 0, 0)),
-            pl.BlockSpec((1, bs, nh),
-                         lambda b, i, tab, ctx: (tab[b, i], 0, 0)),
-            pl.BlockSpec((nh, _COLS), lambda b, i, tab, ctx: (0, 0)),
-            pl.BlockSpec((_COLS, nh), lambda b, i, tab, ctx: (0, 0)),
+            pl.BlockSpec((1, 1, nh), lambda b, tab, ctx: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, nh),
-                               lambda b, i, tab, ctx: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, nh), lambda b, tab, ctx: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((_SUB, _COLS), jnp.float32),
-            pltpu.VMEM((_SUB, _COLS), jnp.float32),
-            pltpu.VMEM((_SUB, nh), jnp.float32),
+            pltpu.VMEM((2, rows // bs, bs, nh), k_pages.dtype),
+            pltpu.VMEM((2, rows // bs, bs, nh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((R, _COLS), jnp.float32),
+            pltpu.VMEM((R, _COLS), jnp.float32),
+            pltpu.VMEM((R, nh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, bs=bs, nl=mb),
+        functools.partial(_paged_kernel, scale=scale, bs=bs, rows=rows,
+                          n_heads=N, head_dim=H),
         name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, nh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q, k_pages, v_pages, sm, em)
+      q, k_pages, v_pages)
     return out.astype(q.dtype)
 
 

@@ -49,7 +49,8 @@ from ..core import autograd
 from ..core.tensor import Tensor
 from ..generation import _cast_params
 from ..jit import bind_tensors
-from ..ops.pallas_decode import flash_prefill_chunk, paged_decode_attention
+from ..ops.pallas_decode import (flash_prefill_chunk, paged_decode_attention,
+                                 paged_decode_kv_rows)
 from ..resilience.retry import classify_failure
 from ..telemetry.mem_obs import (MemoryObservatory, is_oom,
                                  register_provider)
@@ -1208,6 +1209,8 @@ class ServingEngine:
                 top_k[i] = p.top_k
                 top_p[i] = p.top_p
                 greedy[i] = p.greedy
+            # the cache rows the attention kernel fetches a layer
+            kv_rows = paged_decode_kv_rows(ctx, self.block_size)
             # numpy args go straight into the jitted call: the C++
             # dispatch path transfers them, which profiles ~2x cheaper
             # per step than a python-level jnp.asarray round for each
@@ -1221,7 +1224,7 @@ class ServingEngine:
             family = "serving_decode_sampling" if sampling \
                 else "serving_decode"
         with _span("serving_dispatch", family=family, slots=slots,
-                   ctx_tokens=ctx_tokens):
+                   ctx_tokens=ctx_tokens, kv_rows=kv_rows):
             tok, logp, new_k, new_v = self._dispatch(
                 family,
                 self._decode_jit if sampling else self._decode_greedy_jit,

@@ -178,6 +178,151 @@ def test_paged_decode_supported_gate():
     assert not paged_decode_supported(16, 768, 200)   # heads > 128
 
 
+def _tiled_case(N, H, dtype, seed, nan_dead=False):
+    """Slots whose contexts sit on every edge of the kernel's tiling
+    (0, one page less and more a row, one tile less and more a row,
+    the table's end), over at least three tiles; two slots share the
+    physical pages of a cached prefix, one slot is idle (ctx 0, table
+    all null), every table's tail is the null block. With `nan_dead`
+    the null block and every page no live table entry reaches are NaN
+    and the idle slot is left out (position 0 of its null page is live
+    by the contract)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_decode import paged_decode_tile_rows
+
+    bs = 16
+    nh = N * H
+    itemsize = jnp.dtype(dtype).itemsize
+    # the table is a tile short of three and a page over, so that the
+    # last tile is partial and the policy still picks the cell's tile
+    rows = paged_decode_tile_rows(bs, nh, N, itemsize, 1 << 20)
+    P = rows // bs
+    mb = 2 * P + 2
+    assert paged_decode_tile_rows(bs, nh, N, itemsize, mb) == rows
+    T = P * bs
+    ctxs = [0, bs - 1, bs, T - 1, T, T + 1, mb * bs - 1, 2 * T + 3]
+    rs = np.random.RandomState(seed)
+    tables, nxt = [], 1
+    for c in ctxs:
+        n = c // bs + 1
+        tables.append(list(range(nxt, nxt + n)) + [NULL_BLOCK] * (mb - n))
+        nxt += n
+    # the last slot shares its first P + 1 pages with the one before it
+    tables[-1][:P + 1] = tables[-2][:P + 1]
+    if not nan_dead:
+        ctxs.append(0)
+        tables.append([NULL_BLOCK] * mb)
+    nb = nxt + 3                        # and three pages nobody maps
+    k = rs.randn(nb, bs, nh).astype(np.float32)
+    v = rs.randn(nb, bs, nh).astype(np.float32)
+    # copies: on the CPU jnp.asarray may alias the numpy buffer
+    clean = (jnp.asarray(k.copy(), dtype), jnp.asarray(v.copy(), dtype))
+    if nan_dead:
+        live = {b for row, c in zip(tables, ctxs)
+                for b in row[:c // bs + 1]}
+        dead = [b for b in range(nb) if b not in live]
+        assert NULL_BLOCK in dead and len(dead) >= 4
+        k[dead] = np.nan
+        v[dead] = np.nan
+    q = jnp.asarray(rs.randn(len(ctxs), 1, nh), dtype)
+    return (q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(ctxs, jnp.int32),
+            N), clean, rows
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("N,H", [(12, 64), (16, 128)])
+def test_paged_kernel_parity_at_cell_widths(N, H, dtype):
+    """The tiled kernel against gather+dense at the two serving cells'
+    head shapes, on every edge of the tiling (see _tiled_case). float32
+    arenas hold the kernel to float32 dots; in bf16 both paths round
+    the probabilities to bf16 and differ by the accumulation order."""
+    from paddle_tpu.ops.pallas_decode import paged_decode_attention
+
+    args, _, rows = _tiled_case(N, H, dtype, seed=N)
+    assert rows >= 128 and args[4].max() >= 2 * rows
+    fb = paged_decode_attention(*args, use_kernel=False)
+    kn = paged_decode_attention(*args, use_kernel=True)
+    assert kn.dtype == args[0].dtype and kn.shape == args[0].shape
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(kn, np.float32),
+                               np.asarray(fb, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("N,H", [(12, 64), (16, 128)])
+def test_paged_kernel_never_reads_a_dead_page(N, H):
+    """Only the live context: with the null block and every page that
+    no context reaches full of NaN the kernel's result is finite and is
+    the fallback's over clean pages. (The fallback gathers whole
+    tables, so over the poisoned arenas every slot whose table has a
+    null tail comes out NaN: the poison is there to be read.)"""
+    from paddle_tpu.ops.pallas_decode import paged_decode_attention
+
+    args, (k_clean, v_clean), _ = _tiled_case(N, H, "float32", seed=7,
+                                              nan_dead=True)
+    q, k_nan, v_nan, tables, ctx, n = args
+    kn = np.asarray(paged_decode_attention(*args, use_kernel=True))
+    assert np.isfinite(kn).all()
+    fb = paged_decode_attention(q, k_clean, v_clean, tables, ctx, n,
+                                use_kernel=False)
+    np.testing.assert_allclose(kn, np.asarray(fb), atol=2e-5, rtol=2e-5)
+    poisoned = np.asarray(paged_decode_attention(*args, use_kernel=False))
+    assert np.isnan(poisoned[:6]).all()
+
+
+@pytest.mark.parametrize("bs,hidden,n_heads,itemsize,mb", [
+    (16, 768, 12, 2, 64),        # gpt3-125m.serve-chat
+    (16, 2048, 16, 2, 128),      # gpt3-1.3b.serve-long
+])
+def test_paged_tile_policy_at_the_cells(bs, hidden, n_heads, itemsize, mb):
+    """The tile policy at the serving cells' shapes: 128 rows or more,
+    whole pages and whole 128-lane logits columns, under VMEM_BUDGET by
+    the registry's own footprint model."""
+    from paddle_tpu.ops.kernel_registry import VMEM_BUDGET
+    from paddle_tpu.ops.pallas_decode import (_paged_footprint,
+                                              paged_decode_supported,
+                                              paged_decode_tile_rows)
+
+    rows = paged_decode_tile_rows(bs, hidden, n_heads, itemsize, mb)
+    assert 128 <= rows <= 512
+    assert rows % bs == 0 and rows % 128 == 0
+    assert _paged_footprint(rows, hidden, n_heads, itemsize) <= VMEM_BUDGET
+    assert paged_decode_supported(bs, hidden, n_heads, itemsize, mb)
+
+
+@pytest.mark.parametrize("bs,hidden,n_heads,itemsize,mb,want", [
+    (8, 128, 4, 4, 4, 128),       # the tests' toy engines: one unit
+    (16, 256, 8, 4, 3, 128),      # kernel_lint's example
+    (16, 768, 12, 2, 12, 256),    # no longer than the table, rounded up
+    (16, 5120, 40, 2, 128, 128),  # 13B: VMEM leaves one unit
+    (48, 768, 12, 4, 64, 384),    # a page that does not divide 128
+    (16, 12288, 96, 2, 128, 0),   # 175B wide: no tile fits
+    (8, 768, 12, 2, 64, 0),       # half a packed bf16 sublane tile
+])
+def test_paged_tile_policy_adapts(bs, hidden, n_heads, itemsize, mb, want):
+    """One policy for every shape: it shrinks to what the table and
+    VMEM allow, and `paged_decode_supported` is the same answer."""
+    from paddle_tpu.ops.pallas_decode import (paged_decode_supported,
+                                              paged_decode_tile_rows)
+
+    ok = paged_decode_supported(bs, hidden, n_heads, itemsize, mb)
+    assert ok == (want > 0)
+    if ok:
+        assert paged_decode_tile_rows(
+            bs, hidden, n_heads, itemsize, mb) == want
+
+
+def test_paged_kv_rows_counts_whole_live_pages():
+    """`kv_rows` of `serving_dispatch`: every page up to the one `ctx`
+    lies in, none past it, whatever the tile."""
+    from paddle_tpu.ops.pallas_decode import paged_decode_kv_rows
+
+    assert paged_decode_kv_rows(np.asarray([0, 15, 16, 511, 512]), 16) \
+        == 16 + 16 + 32 + 512 + 528
+    assert paged_decode_kv_rows(np.zeros((32,), np.int32), 16) == 512
+
+
 # ---------------------------------------------------------------------------
 # engine correctness
 # ---------------------------------------------------------------------------

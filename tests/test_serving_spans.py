@@ -80,8 +80,10 @@ def traced(tmp_path_factory):
     def wrapper(family, jitted, args):
         if family.startswith("serving_decode"):
             ctx = np.asarray(args[4])
+            # kv_rows: every slot's pages up to the one ctx lies in
             seen.append((family, int((ctx > 0).sum()),
-                         int((ctx[ctx > 0] + 1).sum())))
+                         int((ctx[ctx > 0] + 1).sum()),
+                         int(((ctx // 16 + 1) * 16).sum())))
         elif family == "serving_prefill":
             seen.append((family, int(args[4]), int(args[5])))
         else:
@@ -202,7 +204,9 @@ def test_dispatch_attributes_equal_what_dispatch_saw(traced):
         assert st["family"] == saw[0]
         families.add(saw[0])
         if saw[0].startswith("serving_decode"):
-            assert (st["slots"], st["ctx_tokens"]) == saw[1:]
+            assert (st["slots"], st["ctx_tokens"],
+                    st["kv_rows"]) == saw[1:]
+            assert st["kv_rows"] >= st["ctx_tokens"]
         elif saw[0] == "serving_prefill":
             assert (st["p0"], st["n_real"]) == saw[1:]
             assert st["rid"] in traced.rids
