@@ -382,6 +382,68 @@ def _production_cases(sz):
             (rand((len(ctxs), 1, hid)), rand(arena), rand(arena),
              tabs, ctxs)))
 
+    # latent attention at the DeepSeek-V2 cell's shapes (128 heads over
+    # 640-lane rows, 512 of them the value): decode contexts on every
+    # edge of the tiling with scattered pages and an idle slot, and a
+    # prompt chunk at three offsets over one request's table
+    from paddle_tpu.moe import serving as moes
+    from paddle_tpu.ops import pallas_mla as pm
+    N, W, rank, pbs = (4, 128, 128, 16) if TINY else (128, 640, 512, 16)
+    mb = 8 if TINY else 576
+    T = pm.mla_tile_rows(pbs, W, rank, N, 2, mb)
+    ctxs = sorted({0, pbs - 1, pbs, T - 1, T, T + 1, 2 * T + 3,
+                   mb * pbs - 1} & set(range(mb * pbs)))
+    ctxs += [int(c) for c in rs.randint(1, mb * pbs, 12 - len(ctxs))]
+    pages = rs.permutation(np.arange(
+        1, sum(c // pbs + 1 for c in ctxs) + 1))
+    tabs, used = np.zeros((len(ctxs) + 1, mb), np.int32), 0
+    for i, c in enumerate(ctxs):
+        tabs[i, :c // pbs + 1] = pages[used:used + c // pbs + 1]
+        used += c // pbs + 1
+    ctxs = np.asarray(ctxs + [0], np.int32)         # + the idle slot
+    arena = rand((len(pages) + 1, pbs, W))
+    cases.append((
+        ("mla_paged_decode",),
+        f"mla_paged_decode {N}x{W} rank={rank} mb={mb} tile={T} rows",
+        lambda q, a, t, c: pm.mla_paged_decode(q, a, t, c, rank, 0.11,
+                                               use_kernel=True),
+        lambda q, a, t, c: pm.mla_paged_decode(q, a, t, c, rank, 0.11,
+                                               use_kernel=False),
+        (rand((len(ctxs), N, W)), arena, tabs, ctxs)))
+    C = 32 if TINY else 512
+    for p0 in (0, C + 16, (mb * pbs // 2) // pbs * pbs):
+        row = np.zeros((mb,), np.int32)
+        n_alloc = (p0 + C - 1) // pbs + 1
+        row[:n_alloc] = pages[:n_alloc]
+        cases.append((
+            ("mla_prefill_chunk",), f"mla_prefill_chunk C={C} p0={p0}",
+            lambda q, a, t, p0=p0: pm.mla_prefill_chunk(
+                q, a, t, np.int32(p0), rank, 0.11, use_kernel=True),
+            lambda q, a, t, p0=p0: pm.mla_prefill_chunk(
+                q, a, t, np.int32(p0), rank, 0.11, use_kernel=False),
+            (rand((C, N, W)), arena, row)))
+
+    # the grouped expert products at the cell's widths, 8 held experts:
+    # a decode batch's tokens and a chunk's, routed unevenly (one expert
+    # idle, one with several tiles), padding tokens routed nowhere
+    d_e, f_e, E = (128, 128, 4) if TINY else (5120, 1536, 8)
+    wg, wu = rand((E, d_e, f_e), scale=0.02), rand((E, d_e, f_e), scale=0.02)
+    wd = rand((E, f_e, d_e), scale=0.02)
+    for tokens in (32, 64 if TINY else 512):
+        xt = rand((tokens, d_e), scale=1.0)
+        ex = rs.randint(-2, E + 3, (tokens, 6)).astype(np.int32)
+        ex[ex == 1] = 3                             # expert 1 idle
+        wts = jnp.asarray(rs.rand(tokens, 6), jnp.float32)
+        live = jnp.arange(tokens) < tokens - 3
+        cases.append((
+            ("moe_grouped_ffn",),
+            f"moe_grouped_ffn {tokens} tokens d={d_e} f={f_e} E={E}",
+            lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
+                x, l, w, e, (0, E), a, b, c, use_kernel=True)[0],
+            lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
+                x, l, w, e, (0, E), a, b, c, use_kernel=False)[0],
+            (xt, live, wts, jnp.asarray(ex), wg, wu, wd)))
+
     from paddle_tpu.ops import pallas_int8 as p8
     vocab = -(-cfg.vocab_size // p8._BLOCK_V) * p8._BLOCK_V   # row-padded
     hq = rand((16, nh), scale=1.0)

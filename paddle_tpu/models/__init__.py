@@ -11,3 +11,6 @@ from .bert import (  # noqa: F401
 from .ocr import (  # noqa: F401
     CRNN, DBNet, db_loss, ctc_greedy_decode,
 )
+from .deepseek_v2 import (  # noqa: F401
+    DeepseekV2Config, DeepseekV2ForCausalLM,
+)

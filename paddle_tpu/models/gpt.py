@@ -454,6 +454,10 @@ class GPTForPretraining(Layer):
             return out.astype(hh.dtype) if amp_state().enabled else out
         return apply(head, h, w)
 
+    def served(self):
+        """This model behind the serving engine's per-layer protocol."""
+        return ServedGPT(self)
+
     def generate(self, input_ids, max_new_tokens=32, decode_strategy="greedy",
                  top_k=0, top_p=1.0, temperature=1.0, num_beams=1,
                  length_penalty=0.0, eos_token_id=None, pad_token_id=0,
@@ -513,6 +517,83 @@ class GPTForPretraining(Layer):
             m = reshape(loss_mask, [-1])
             return (losses * m).sum() / m.sum()
         return losses.mean()
+
+
+class _ServedGPTBlock:
+    """One GPTBlock behind the serving engine's per-layer protocol
+    (serving/served.py), cache kind full K/V: the exact cache-branch
+    math of GPTBlock.forward, with K/V written into the paged arenas
+    and attention read from them."""
+
+    def __init__(self, block, hidden, n_heads):
+        from ..serving.kv_cache import kv_kind
+        self.block, self.hidden, self.n_heads = block, hidden, n_heads
+        self.cache_kind = kv_kind(hidden)
+
+    def _step(self, h, pages, view, attend):
+        block, nh = self.block, self.hidden
+        y = block.ln1(h)
+        q, k, v = block.attn.project_qkv(y)
+        rows = view.blk.shape[0]
+        kp = pages[0].at[view.blk, view.off].set(
+            k._value.reshape(rows, nh).astype(pages[0].dtype))
+        vp = pages[1].at[view.blk, view.off].set(
+            v._value.reshape(rows, nh).astype(pages[1].dtype))
+        out = attend(q._value, kp, vp)
+        a = block.attn.out_proj(Tensor(out))
+        y2, h2 = block._add_ln2(h, block.dropout(a))
+        h = h2 + block.dropout(block.mlp(y2))
+        return h, (kp, vp), None
+
+    def decode(self, h, pages, view):
+        from ..ops.pallas_decode import paged_decode_attention
+
+        def attend(qv, kp, vp):
+            return paged_decode_attention(
+                qv.reshape(-1, 1, self.hidden), kp, vp, view.tables,
+                view.ctx, self.n_heads, use_kernel=view.use_kernel)
+        return self._step(h, pages, view, attend)
+
+    def prefill(self, h, pages, view):
+        from ..ops.pallas_decode import flash_prefill_chunk
+
+        def attend(qv, kp, vp):
+            # flash chunked prefill over the paged arena: the chunk's
+            # queries attend to cached blocks via the block table with
+            # in-kernel online softmax (TPU), never materializing the
+            # full [chunk, ctx] score matrix; the gather+dense fallback
+            # reproduces _cached_attention's composed einsum math
+            # exactly, so CPU serving stays bit-identical to
+            # run_generate
+            return flash_prefill_chunk(
+                qv.reshape(1, -1, self.hidden), kp, vp, view.table_row,
+                view.p0, self.n_heads, use_kernel=view.use_kernel)
+        return self._step(h, pages, view, attend)
+
+
+class ServedGPT:
+    """`GPTForPretraining` (quantized or not) as the serving engine
+    reads a model: learned positions, one full-K/V layer a block, the
+    tied head."""
+
+    def __init__(self, model):
+        c = model.config
+        self.model, self.core = model, model.gpt
+        self.max_seq_len, self.dtype = c.max_seq_len, c.dtype
+        self.layers = [_ServedGPTBlock(b, c.hidden_size, c.num_heads)
+                       for b in model.gpt.blocks]
+
+    def embed(self, ids, positions):
+        core = self.core
+        return core.drop(core.wte(Tensor(ids)) + core.wpe(Tensor(positions)))
+
+    def head(self, h, at=None):
+        import jax
+        hf = self.core.ln_f(h)
+        if at is not None:
+            hf = Tensor(jax.lax.dynamic_slice(
+                hf._value, (0, at, 0), (hf.shape[0], 1, hf.shape[-1])))
+        return self.model.lm_head(hf)._value
 
 
 def gpt_tiny_config():

@@ -19,7 +19,7 @@ from .layer.conv import (  # noqa: F401
 from .layer.norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, SyncBatchNorm,
     LayerNorm, GroupNorm, InstanceNorm1D, InstanceNorm2D, InstanceNorm3D,
-    LocalResponseNorm, SpectralNorm,
+    LocalResponseNorm, SpectralNorm, RMSNorm,
 )
 from .layer.activation import (  # noqa: F401
     ReLU, ReLU6, Sigmoid, Tanh, Silu, Swish, Mish, Softsign, Tanhshrink,

@@ -7,7 +7,7 @@ from .conv import (  # noqa: F401
 )
 from .norm import (  # noqa: F401
     layer_norm, batch_norm, instance_norm, group_norm, local_response_norm,
-    fused_add_layer_norm,
+    fused_add_layer_norm, rms_norm,
 )
 from .pooling import (  # noqa: F401
     max_pool1d, max_pool2d, max_pool3d, avg_pool1d, avg_pool2d, avg_pool3d,

@@ -38,6 +38,25 @@ def _scale_shift_bwd(res, g):
 _scale_shift.defvjp(_scale_shift_fwd, _scale_shift_bwd)
 
 
+def rms_norm_values(v, weight=None, epsilon=1e-06):
+    """RMSNorm on raw values over the last axis: the mean square and the
+    scaling in float32, the result back in the input's dtype before the
+    gain (as the Llama/DeepSeek reference code has it)."""
+    f = v.astype(jnp.float32)
+    out = (f * jax.lax.rsqrt(jnp.mean(jnp.square(f), axis=-1, keepdims=True)
+                             + epsilon)).astype(v.dtype)
+    return out if weight is None else out * weight.astype(v.dtype)
+
+
+def rms_norm(x, weight=None, epsilon=1e-06, name=None):
+    """y = x / sqrt(mean(x^2) + eps) * weight over the last axis."""
+    x = ensure_tensor(x)
+    if weight is None:
+        return apply(lambda v: rms_norm_values(v, None, epsilon), x)
+    return apply(lambda v, w: rms_norm_values(v, w, epsilon), x,
+                 ensure_tensor(weight))
+
+
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
                name=None):
     x = ensure_tensor(x)

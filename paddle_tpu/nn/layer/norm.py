@@ -132,6 +132,21 @@ class LayerNorm(Layer):
         return f"normalized_shape={self._normalized_shape}"
 
 
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis, a gain and no bias."""
+
+    def __init__(self, hidden_size, epsilon=1e-06, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [hidden_size], attr=weight_attr,
+            default_initializer=Constant(1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon)
+
+
 class GroupNorm(Layer):
     def __init__(self, num_groups, num_channels, epsilon=1e-05,
                  weight_attr=None, bias_attr=None, data_format="NCHW",

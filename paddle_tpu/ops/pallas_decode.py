@@ -209,11 +209,21 @@ def paged_decode_tile_rows(block_size, hidden, n_heads, itemsize,
     larger tile only computes more dead rows at a context's end, (b) is
     no longer than the longest context a table can hold, rounded up to
     one unit, and (c) fits `VMEM_BUDGET` with both of its buffers."""
+    return tile_rows_within(
+        block_size, max_blocks,
+        lambda rows: _paged_footprint(rows, hidden, n_heads, itemsize))
+
+
+def tile_rows_within(block_size, max_blocks, footprint):
+    """The tile policy shared by the paged kernels (`paged_decode`, the
+    latent kernels of ops/pallas_mla.py): the largest multiple of
+    lcm(block_size, 128) rows, at most `_TILE_ROWS` and no longer than
+    a table of `max_blocks` reaches, whose `footprint(rows)` fits
+    `VMEM_BUDGET`; 0 when none does."""
     unit = math.lcm(int(block_size), _COLS)
     longest = -(-int(max_blocks) * int(block_size) // unit) * unit
     rows = min(max(unit, _TILE_ROWS // unit * unit), longest)
-    while rows and _paged_footprint(
-            rows, hidden, n_heads, itemsize) > _VMEM_BUDGET:
+    while rows and footprint(rows) > _VMEM_BUDGET:
         rows -= unit
     return rows
 

@@ -42,8 +42,8 @@ CI by `tools/serving_smoke.py` (token parity with run_generate +
 eviction selfcheck).
 """
 from .kv_cache import (  # noqa: F401
-    BlockLeakError, BlockPool, PagedKVCache, PrefixIndex,
-    StaleIndexError)
+    BlockLeakError, BlockPool, CacheKind, PagedKVCache, PrefixIndex,
+    StaleIndexError, kv_kind, latent_kind)
 from .resilience import (  # noqa: F401
     AdmissionController, Deadlines, DeadlineExceededError,
     EngineDeadError, EngineDrainingError, EngineStoppedError,
@@ -55,7 +55,8 @@ from .engine import EngineConfig, ServingEngine  # noqa: F401
 from .http import ServingHTTPServer  # noqa: F401
 
 __all__ = [
-    "BlockPool", "BlockLeakError", "PagedKVCache", "PrefixIndex",
+    "BlockPool", "BlockLeakError", "CacheKind", "kv_kind", "latent_kind",
+    "PagedKVCache", "PrefixIndex",
     "StaleIndexError", "Request",
     "RequestHandle", "SamplingParams", "Scheduler", "EngineConfig",
     "ServingEngine", "ServingHTTPServer",

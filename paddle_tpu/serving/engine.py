@@ -11,12 +11,17 @@ PR-4 compile observatory can prove it (`telemetry.observed_dispatch`
 routes both steps through the signature-keyed AOT cache when an
 observatory is active).
 
-Numerics contract: the engine computes the EXACT math of
-`generation.run_generate`'s composed decode path — the same Layer
-objects (project_qkv/out_proj/_add_ln2/mlp/lm_head), the same masked
-f32-softmax attention (ops.pallas_decode.paged_decode_attention's
-gather+dense fallback mirrors models/gpt._cached_attention), the same
-f32 argmax — so a greedy stream through the batched engine is
+The engine knows no architecture: a model hands it the per-layer
+protocol of `serving/served.py` (embed, layers that each declare a
+cache kind and bring a `decode` and a `prefill` over it, the head), and
+the arenas, the fork and the sizing follow the layers' cache kinds.
+
+Numerics contract (GPT): the step computes the EXACT math of
+`generation.run_generate`'s composed decode path — `models.gpt.ServedGPT`
+runs the same Layer objects, the same masked f32-softmax attention
+(ops.pallas_decode.paged_decode_attention's gather+dense fallback
+mirrors models/gpt._cached_attention), and the engine the same f32
+argmax — so a greedy stream through the batched engine is
 token-for-token identical to a single run_generate call
 (tools/serving_smoke.py gates this in CI). Sampling slots use
 per-REQUEST fold_in(token_index) keys, so a sampled stream is also
@@ -46,11 +51,9 @@ import jax.numpy as jnp
 from .. import monitor
 from ..analysis import lockwatch
 from ..core import autograd
-from ..core.tensor import Tensor
 from ..generation import _cast_params
 from ..jit import bind_tensors
-from ..ops.pallas_decode import (flash_prefill_chunk, paged_decode_attention,
-                                 paged_decode_kv_rows)
+from ..ops.pallas_decode import paged_decode_kv_rows
 from ..resilience.retry import classify_failure
 from ..telemetry.mem_obs import (MemoryObservatory, is_oom,
                                  register_provider)
@@ -65,6 +68,7 @@ from .resilience import (AdmissionController, DeadlineExceededError,
 from .scheduler import (CANCELLED, EXPIRED, FAILED, FINISHED, PREFILL,
                         TERMINAL_STATES, RequestHandle, Request,
                         SamplingParams, Scheduler)
+from .served import ChunkView, DecodeView, read_stats, sum_stats
 
 __all__ = ["EngineConfig", "ServingEngine"]
 
@@ -174,10 +178,9 @@ class ServingEngine:
     one scheduler iteration (one prefill chunk + one decode batch);
     start()/stop() run the loop on a background thread.
 
-    `model` must expose the incremental-GPT protocol: `.gpt` core with
-    `wte/wpe/drop/blocks/ln_f` (each block: `ln1/attn/_add_ln2/mlp/
-    dropout`, attn: `project_qkv/out_proj`) plus `.lm_head(h)` —
-    i.e. GPTForPretraining, quantized or not.
+    `model.served()` must return the per-layer protocol of
+    `serving/served.py`: GPTForPretraining (quantized or not) and
+    DeepseekV2ForCausalLM implement it.
     """
 
     def __init__(self, model, config=None, sink=None, **overrides):
@@ -187,18 +190,19 @@ class ServingEngine:
             else cfg.engine_id
         self._sink = sink               # threadlint: type=JsonlSink
         self.model = model
-        mcfg = model.config
         if cfg.weights == "wo8":
             from ..quant import quantize_for_decode
             quantize_for_decode(model)
-        self.n_heads = mcfg.num_heads
-        self.hidden = mcfg.hidden_size
-        self.head_dim = self.hidden // self.n_heads
-        self.max_model_len = int(cfg.max_model_len or mcfg.max_seq_len)
+        self.served = served = model.served()
+        self.cache_kinds = tuple(l.cache_kind for l in served.layers)
+        self.max_model_len = int(cfg.max_model_len or served.max_seq_len)
         self.block_size = cfg.block_size
         self.max_blocks_per_seq = PagedKVCache.blocks_for_tokens(
             self.max_model_len, self.block_size)
-        self._compute_dtype = cfg.dtype or mcfg.dtype
+        self._compute_dtype = cfg.dtype or served.dtype
+        # "kv", "latent" or both, for the dispatch spans
+        self._cache_kind_names = "+".join(sorted(
+            {k.name for k in self.cache_kinds}))
 
         if cfg.device is not None:
             # serve from the configured device: move the weights once
@@ -214,7 +218,7 @@ class ServingEngine:
         with jax.default_device(cfg.device) if cfg.device is not None \
                 else contextlib.nullcontext():
             self.cache = PagedKVCache(   # guarded by: _mu
-                mcfg.num_layers, num_blocks, self.block_size, self.hidden,
+                self.cache_kinds, num_blocks, self.block_size,
                 dtype=self._compute_dtype)
         # guarded by: none (immutable ref; entries mutate under _mu)
         self.prefix_index = (
@@ -288,6 +292,7 @@ class ServingEngine:
             lambda eng: [p._value for p in eng._bound
                          if getattr(p, "_value", None) is not None])
         self._steps = 0                 # guarded by: _mu
+        self._stats_pending = []        # guarded by: _mu
         monitor.set_gauge("serving.kv_blocks_total", self.pool.capacity)
         monitor.set_gauge("serving.draining", 0)
         self._update_gauges()
@@ -299,44 +304,39 @@ class ServingEngine:
         cfg = self.cfg
         if cfg.num_blocks is not None:
             return int(cfg.num_blocks)
-        mcfg = self.model.config
         if cfg.kv_memory_mb:
-            per_block = (2 * mcfg.num_layers * self.block_size
-                         * self.hidden
-                         * jnp.dtype(self._compute_dtype).itemsize)
-            n = int(cfg.kv_memory_mb) * 2 ** 20 // per_block
+            n = int(cfg.kv_memory_mb) * 2 ** 20 // self._block_bytes()
             return max(2, n)
         # default: every slot can hold a full-length sequence (+ null)
         return cfg.max_slots * self.max_blocks_per_seq + 1
+
+    def _block_bytes(self):
+        """Bytes a block costs over all layers, by their cache kinds."""
+        return PagedKVCache.block_bytes(self.cache_kinds, self.block_size,
+                                        self._compute_dtype)
 
     # ------------------------------------------------------------------
     # compiled step functions
     # ------------------------------------------------------------------
     def _build_fns(self):
-        model = self.model
-        core = model.gpt
+        served = self.served
         bound = self._bound
         dtype = self.cfg.dtype
-        n_heads = self.n_heads
-        nh = self.hidden
         bs_blk = self.block_size
         mb = self.max_blocks_per_seq
-        S = self.cfg.max_slots
         C = self.cfg.prefill_chunk
-        kv_dt = jnp.dtype(self._compute_dtype)
 
-        def block_step(block, h, attend, write):
-            """One GPTBlock at decode/prefill time over the paged cache
-            — the exact cache-branch math of GPTBlock.forward, with
-            attention routed through `attend` and K/V through `write`."""
-            y = block.ln1(h)
-            q, k, v = block.attn.project_qkv(y)
-            kp, vp = write(k._value, v._value)
-            out = attend(q._value, kp, vp)
-            a = block.attn.out_proj(Tensor(out))
-            y2, h2 = block._add_ln2(h, block.dropout(a))
-            h = h2 + block.dropout(block.mlp(y2))
-            return h, kp, vp
+        def run_layers(h, k_pages, v_pages, step, view):
+            """Every layer's `step` (its decode or its prefill) over its
+            own arenas: (h, new K arenas, new V arenas, summed stats)."""
+            new_k, new_v, stats = [], [], []
+            for li, layer in enumerate(served.layers):
+                h, (kp, vp), st = getattr(layer, step)(
+                    h, (k_pages[li], v_pages[li]), view)
+                new_k.append(kp)
+                new_v.append(vp)
+                stats.append(st)
+            return h, tuple(new_k), tuple(new_v), sum_stats(stats)
 
         def select(last, rngs, temp, top_k, top_p, greedy,
                    sampling=True):
@@ -377,129 +377,89 @@ class ServingEngine:
             tok_logp = jnp.take_along_axis(logp, tok[:, None], 1)[:, 0]
             return tok, tok_logp
 
-        def decode_logits(param_vals, k_pages, v_pages, tokens, ctx,
-                          tables, use_kernel=None):
-            """Last-position logits [S, V] of one decode step plus the
-            updated arenas. use_kernel rides through to
-            paged_decode_attention (None = its platform gate); only
-            chip_smoke.py and the tests pass it, to hold the fused
-            kernel against the gather+dense path on the same step."""
+        def decode_step(param_vals, k_pages, v_pages, tokens, ctx,
+                        tables, use_kernel=None):
+            """Last-position logits [S, V] of one decode step, the
+            updated arenas and the layers' stats. use_kernel rides
+            through to the layers' attention (None = its platform
+            gate); only chip_smoke.py and the tests pass it, to hold
+            the fused kernel against the gather+dense path on the same
+            step."""
             param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
-                ids = Tensor(tokens[:, None])
-                pos = Tensor(ctx[:, None])
-                h = core.wte(ids) + core.wpe(pos)
-                h = core.drop(h)
+                h = served.embed(tokens[:, None], ctx[:, None])
                 blk = jnp.take_along_axis(
                     tables, (ctx // bs_blk)[:, None], axis=1)[:, 0]
-                off = ctx % bs_blk
-                new_k, new_v = [], []
+                view = DecodeView(blk, ctx % bs_blk, tables, ctx, ctx > 0,
+                                  use_kernel)
+                h, new_k, new_v, stats = run_layers(
+                    h, k_pages, v_pages, "decode", view)
+                last = served.head(h)[:, -1]
+            return last, new_k, new_v, stats
 
-                def write_l(layer):
-                    def write(kv, vv):
-                        kp = k_pages[layer].at[blk, off].set(
-                            kv.reshape(S, nh).astype(kv_dt))
-                        vp = v_pages[layer].at[blk, off].set(
-                            vv.reshape(S, nh).astype(kv_dt))
-                        return kp, vp
-                    return write
-
-                def attend(qv, kp, vp):
-                    return paged_decode_attention(
-                        qv.reshape(S, 1, nh), kp, vp, tables, ctx,
-                        n_heads, use_kernel=use_kernel)
-
-                for li, block in enumerate(core.blocks):
-                    h, kp, vp = block_step(block, h, attend, write_l(li))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                last = model.lm_head(core.ln_f(h))._value[:, -1]
-            return last, tuple(new_k), tuple(new_v)
+        def decode_logits(*args, **kw):
+            return decode_step(*args, **kw)[:3]
 
         def decode_fn(param_vals, k_pages, v_pages, tokens, ctx, tables,
                       keys, counts, temp, top_k, top_p, greedy,
                       sampling=True):
-            last, new_k, new_v = decode_logits(
+            last, new_k, new_v, stats = decode_step(
                 param_vals, k_pages, v_pages, tokens, ctx, tables)
             rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
                 if sampling else keys
             tok, logp = select(last, rngs, temp, top_k, top_p,
                                greedy, sampling=sampling)
-            return tok, logp, new_k, new_v
+            return tok, logp, new_k, new_v, stats
 
-        def prefill_logits(param_vals, k_pages, v_pages, ids, p0, n_real,
-                           table_row, use_kernel=None):
-            """Logits [1, V] at the chunk's last REAL position plus the
-            updated arenas, for one chunk of ONE request: ids [1, C]
-            (tail past n_real is padding -> null-block writes),
-            positions p0..p0+C-1. use_kernel as in decode_logits."""
+        def prefill_step(param_vals, k_pages, v_pages, ids, p0, n_real,
+                         table_row, use_kernel=None):
+            """Logits [1, V] at the chunk's last REAL position, the
+            updated arenas and the layers' stats, for one chunk of ONE
+            request: ids [1, C] (tail past n_real is padding ->
+            null-block writes), positions p0..p0+C-1. use_kernel as in
+            decode_step."""
             param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
                 positions = p0 + jnp.arange(C, dtype=jnp.int32)
-                h = core.wte(Tensor(ids)) + core.wpe(Tensor(positions[None]))
-                h = core.drop(h)
+                h = served.embed(ids, positions[None])
                 tmask = jnp.arange(C, dtype=jnp.int32) < n_real
                 blk = jnp.where(
                     tmask,
                     table_row[jnp.clip(positions // bs_blk, 0, mb - 1)],
                     NULL_BLOCK)
-                off = positions % bs_blk
+                view = ChunkView(blk, positions % bs_blk, table_row, p0,
+                                 positions, tmask, use_kernel)
+                h, new_k, new_v, stats = run_layers(
+                    h, k_pages, v_pages, "prefill", view)
+                last = served.head(h, at=n_real - 1)[:, -1]
+            return last, new_k, new_v, stats
 
-                def write(kv, vv):
-                    kp = k_pages_cur.at[blk, off].set(
-                        kv.reshape(C, nh).astype(kv_dt))
-                    vp = v_pages_cur.at[blk, off].set(
-                        vv.reshape(C, nh).astype(kv_dt))
-                    return kp, vp
-
-                def attend(qv, kp, vp):
-                    # flash chunked prefill over the paged arena: the
-                    # chunk's queries attend to cached blocks via the
-                    # block table with in-kernel online softmax (TPU),
-                    # never materializing the full [chunk, ctx] score
-                    # matrix; the gather+dense fallback reproduces
-                    # models/gpt._cached_attention's composed einsum
-                    # math exactly, so CPU serving stays bit-identical
-                    # to run_generate
-                    return flash_prefill_chunk(
-                        qv.reshape(1, C, nh), kp, vp, table_row, p0,
-                        n_heads, use_kernel=use_kernel)
-
-                new_k, new_v = [], []
-                for li, block in enumerate(core.blocks):
-                    k_pages_cur = k_pages[li]
-                    v_pages_cur = v_pages[li]
-                    h, kp, vp = block_step(block, h, attend, write)
-                    new_k.append(kp)
-                    new_v.append(vp)
-                hf = core.ln_f(h)
-                h_last = jax.lax.dynamic_slice(
-                    hf._value, (0, n_real - 1, 0), (1, 1, hf.shape[-1]))
-                last = model.lm_head(Tensor(h_last))._value[:, -1]
-            return last, tuple(new_k), tuple(new_v)
+        def prefill_logits(*args, **kw):
+            return prefill_step(*args, **kw)[:3]
 
         def prefill_fn(param_vals, k_pages, v_pages, ids, p0, n_real,
                        table_row, key, count, temp, top_k, top_p, greedy):
             """One prefill chunk; also samples the next token from the
             last REAL position — used only when the host knows this
             was the final chunk."""
-            last, new_k, new_v = prefill_logits(
+            last, new_k, new_v, stats = prefill_step(
                 param_vals, k_pages, v_pages, ids, p0, n_real, table_row)
             rngs = jax.random.fold_in(key, count)[None]
             tok, logp = select(last, rngs, temp[None], top_k[None],
                                top_p[None], greedy[None])
-            return tok[0], logp[0], new_k, new_v
+            return tok[0], logp[0], new_k, new_v, stats
 
         def fork_fn(k_pages, v_pages, src, dst):
             """Copy-on-write fork: duplicate physical block `src` into
-            `dst` across every layer's arenas (all rows — positions the
-            forking request has not covered yet stay masked by its
-            context length until it overwrites them)."""
-            new_k = tuple(k.at[dst].set(k[src]) for k in k_pages)
-            new_v = tuple(v.at[dst].set(v[src]) for v in v_pages)
-            return new_k, new_v
+            `dst` across every arena of every layer (all rows —
+            positions the forking request has not covered yet stay
+            masked by its context length until it overwrites them)."""
+            def fork(pages):
+                return tuple(None if a is None else a.at[dst].set(a[src])
+                             for a in pages)
+            return fork(k_pages), fork(v_pages)
 
         self._decode_logits = decode_logits
         self._prefill_logits = prefill_logits
@@ -947,10 +907,8 @@ class ServingEngine:
         with jax.default_device(self.cfg.device) \
                 if self.cfg.device is not None \
                 else contextlib.nullcontext():
-            self.cache = PagedKVCache(
-                self.cache.num_layers, self.cache.num_blocks,
-                self.cache.block_size, self.cache.hidden,
-                dtype=self.cache.dtype)
+            self.cache = self.cache.fresh()
+        self._stats_pending.clear()     # counts of the failed step's arrays
 
     def _on_step_error(self, exc):
         """A compiled step raised mid-flight (device OOM, runtime
@@ -1073,7 +1031,8 @@ class ServingEngine:
             self.sched.preempt(victim)
         new = got[0]
         args = (self.cache.k, self.cache.v, np.int32(old), np.int32(new))
-        with _span("serving_dispatch", family="serving_fork"):
+        with _span("serving_dispatch", family="serving_fork",
+                   cache_kind=self._cache_kind_names):
             new_k, new_v = self._dispatch("serving_fork", self._fork_jit,
                                           args)
         self.cache.swap(new_k, new_v)
@@ -1093,6 +1052,15 @@ class ServingEngine:
         # may then evict its way forward
         allow_evict = sched.num_running() == 0
         for idx, req in enumerate(list(sched.prefilling)):
+            gained = sched.rematch(req)
+            if gained:
+                # published by the requests prefilled ahead of this one
+                # since it was admitted
+                ps = self._prefix_stats
+                ps["tokens_saved"] += gained
+                if gained == req.prefix_cached_tokens:
+                    ps["hits"] += 1
+                    monitor.incr("serving.prefix_hits")
             seq = req.tokens_all
             p0 = req.n_prefilled
             c_real = min(self.cfg.prefill_chunk, len(seq) - p0)
@@ -1128,8 +1096,9 @@ class ServingEngine:
                         np.float32(p.temperature), np.int32(p.top_k),
                         np.float32(p.top_p), np.bool_(p.greedy))
             with _span("serving_dispatch", family="serving_prefill",
-                       rid=req.rid, p0=p0, n_real=c_real):
-                tok, logp, new_k, new_v = self._dispatch(
+                       rid=req.rid, p0=p0, n_real=c_real,
+                       cache_kind=self._cache_kind_names):
+                tok, logp, new_k, new_v, stats = self._dispatch(
                     "serving_prefill", self._prefill_jit, args)
             last = p0 + c_real >= len(seq)
             with _span("serving_step.emit", kind="prefill",
@@ -1156,9 +1125,12 @@ class ServingEngine:
                     with _span("serving_step.fetch", kind="prefill"):
                         tok = int(np.asarray(tok))
                         logp = float(np.asarray(logp))
+                        self._count_stats(stats)
                     self._emit(req, tok, logp)
                     if req.state == PREFILL:    # _emit finishes done ones
                         sched.place(req)
+                else:
+                    self._count_stats(stats, fetched=False)
             return True
         return False
 
@@ -1197,7 +1169,7 @@ class ServingEngine:
             slots = ctx_tokens = 0
             for i, req in active:
                 p = req.params
-                tokens[i] = req.tokens_all[req.n_prefilled]
+                tokens[i] = req.token_at(req.n_prefilled)
                 ctx[i] = req.n_prefilled
                 if req.n_prefilled > 0:
                     slots += 1
@@ -1209,7 +1181,8 @@ class ServingEngine:
                 top_k[i] = p.top_k
                 top_p[i] = p.top_p
                 greedy[i] = p.greedy
-            # the cache rows the attention kernel fetches a layer
+            # the cache rows the attention kernel fetches a layer; in
+            # a latent layer each is one row that is key and value
             kv_rows = paged_decode_kv_rows(ctx, self.block_size)
             # numpy args go straight into the jitted call: the C++
             # dispatch path transfers them, which profiles ~2x cheaper
@@ -1224,8 +1197,9 @@ class ServingEngine:
             family = "serving_decode_sampling" if sampling \
                 else "serving_decode"
         with _span("serving_dispatch", family=family, slots=slots,
-                   ctx_tokens=ctx_tokens, kv_rows=kv_rows):
-            tok, logp, new_k, new_v = self._dispatch(
+                   ctx_tokens=ctx_tokens, kv_rows=kv_rows,
+                   cache_kind=self._cache_kind_names):
+            tok, logp, new_k, new_v, stats = self._dispatch(
                 family,
                 self._decode_jit if sampling else self._decode_greedy_jit,
                 args)
@@ -1240,6 +1214,7 @@ class ServingEngine:
             with _span("serving_step.fetch", kind="decode"):
                 tok = np.asarray(tok)
                 logp = np.asarray(logp)
+                self._count_stats(stats)
             monitor.incr("serving.decode_steps")
             now = time.monotonic()
             for i, req in active:
@@ -1257,6 +1232,21 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _param_vals(self):
         return [p._value for p in self._bound]
+
+    def _count_stats(self, stats, fetched=True):     # requires: _mu
+        """What the layers counted in a step (routed tokens, held
+        expert pairs, ...) comes back with the step's tokens and goes
+        onto the `serving.<name>` counters where the step's tokens are
+        fetched; a chunk that fetches nothing leaves its counts for the
+        next step that does, so they never cost a wait of their own."""
+        if stats:
+            self._stats_pending.append(stats)
+        if not fetched:
+            return
+        for st in self._stats_pending:
+            for name, value in read_stats(st).items():
+                monitor.incr("serving." + name, value)
+        self._stats_pending.clear()
 
     def _table_row(self, req):
         row = np.full((self.max_blocks_per_seq,), NULL_BLOCK, np.int32)
@@ -1374,10 +1364,7 @@ class ServingEngine:
         h = self.mem_obs.headroom_bytes()
         if h is not None:
             return h
-        mcfg = self.model.config
-        per_block = (2 * mcfg.num_layers * self.block_size * self.hidden
-                     * jnp.dtype(self._compute_dtype).itemsize)
-        return self.pool.num_free * per_block
+        return self.pool.num_free * self._block_bytes()
 
     def _check_mem_headroom(self):     # requires: _mu
         """submit()'s admission consult: with a declared HBM budget and

@@ -42,20 +42,26 @@ Three layers, split host/device:
   each edge is one block's worth of token ids, each node the physical
   block holding that chunk's K/V. Admission matches a prompt against
   it and starts prefill at the first uncached token.
-- `PagedKVCache` — the DEVICE-side arenas: per layer, K and V as
-  `[num_blocks, block_size, hidden]` jnp arrays (the flat [*, n*h]
-  minor layout the fused decode kernels require — see
-  ops/pallas_decode.py). The arrays are handed to the engine's compiled
-  step functions, updated functionally, and stored back; `swap()` is
-  the single mutation point so donation stays sound.
+- `PagedKVCache` — the DEVICE-side arenas: per layer, what that
+  layer's `CacheKind` declares a token keeps. Full K/V (`kv_kind`): K
+  and V as `[num_blocks, block_size, hidden]` jnp arrays (the flat
+  [*, n*h] minor layout the fused decode kernels require — see
+  ops/pallas_decode.py). Latent (`latent_kind`): ONE arena of
+  `[num_blocks, block_size, width]` whose row is key and value at once
+  (multi-head latent attention). The arrays are handed to the engine's
+  compiled step functions, updated functionally, and stored back;
+  `swap()` is the single mutation point so donation stays sound.
 
-The attention over this layout is `ops.pallas_decode.paged_decode_attention`
-(decode) and `ops.pallas_decode.flash_prefill_chunk` (chunked prefill).
+The attention over the K/V layout is
+`ops.pallas_decode.paged_decode_attention` (decode) and
+`ops.pallas_decode.flash_prefill_chunk` (chunked prefill); over the
+latent layout `ops.pallas_mla.mla_paged_decode` and `mla_prefill_chunk`.
 """
 import jax.numpy as jnp
 
-__all__ = ["BlockPool", "BlockLeakError", "PagedKVCache", "NULL_BLOCK",
-           "PrefixIndex", "StaleIndexError"]
+__all__ = ["BlockPool", "BlockLeakError", "CacheKind", "PagedKVCache",
+           "NULL_BLOCK", "PrefixIndex", "StaleIndexError", "kv_kind",
+           "latent_kind"]
 
 
 class BlockLeakError(AssertionError):
@@ -493,26 +499,61 @@ class PrefixIndex:    # guarded by: ServingEngine._mu
         self._nodes = 0
 
 
-class PagedKVCache:
-    """Per-layer K/V arenas of shape [num_blocks, block_size, hidden].
+class CacheKind:
+    """What one layer keeps of a token in the paged arena: one arena of
+    `[num_blocks, block_size, w]` for each `w` of `widths`, one or two
+    of them. The engine sizes, forks and swaps arenas by this alone;
+    what the numbers mean is the layer's business."""
 
-    `hidden` is n_heads * head_dim; the minor dim stays flat so the
-    paged pallas kernel can stream blocks without a reshape copy (the
-    same constraint as the dense decode cache — see GPTModel.init_cache).
+    def __init__(self, name, widths):
+        self.name = str(name)
+        self.widths = tuple(int(w) for w in widths)
+        if not 1 <= len(self.widths) <= 2:
+            raise ValueError("a cache kind holds one or two arenas a layer")
+
+    @property
+    def row_width(self):
+        """Numbers a token costs in a layer of this kind."""
+        return sum(self.widths)
+
+    def __repr__(self):
+        return f"CacheKind({self.name!r}, {self.widths})"
+
+
+def kv_kind(hidden):
+    """Full attention: a K and a V row of n_heads * head_dim each."""
+    return CacheKind("kv", (hidden, hidden))
+
+
+def latent_kind(width):
+    """Latent attention: one row (compressed K/V and the shared rotary
+    key) that is key and value at once."""
+    return CacheKind("latent", (width,))
+
+
+class PagedKVCache:
+    """Per-layer arenas of shape [num_blocks, block_size, width], as
+    each layer's `CacheKind` declares: `k[l]` is the layer's first
+    arena, `v[l]` its second or None where the kind has one (a latent
+    layer). The minor dim stays flat so the paged pallas kernels can
+    stream blocks without a reshape copy (the same constraint as the
+    dense decode cache — see GPTModel.init_cache).
     """
 
-    def __init__(self, num_layers, num_blocks, block_size, hidden,
-                 dtype="bfloat16"):
-        self.num_layers = int(num_layers)
+    def __init__(self, kinds, num_blocks, block_size, dtype="bfloat16"):
+        self.kinds = tuple(kinds)
+        self.num_layers = len(self.kinds)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self.hidden = int(hidden)
         self.dtype = jnp.dtype(dtype)
-        shape = (self.num_blocks, self.block_size, self.hidden)
-        self.k = tuple(jnp.zeros(shape, self.dtype)
-                       for _ in range(self.num_layers))
-        self.v = tuple(jnp.zeros(shape, self.dtype)
-                       for _ in range(self.num_layers))
+
+        def arena(width):
+            return jnp.zeros((self.num_blocks, self.block_size, width),
+                             self.dtype)
+
+        self.k = tuple(arena(kind.widths[0]) for kind in self.kinds)
+        self.v = tuple(arena(kind.widths[1]) if len(kind.widths) > 1
+                       else None for kind in self.kinds)
         # memory-observatory tagging (telemetry/mem_obs): the live HBM
         # ledger attributes these arenas to the 'kv' bucket by querying
         # this provider FRESH each snapshot (swap() replaces the
@@ -524,14 +565,29 @@ class PagedKVCache:
             from ..telemetry import mem_obs
             mem_obs.register_provider(
                 "kv_cache.arenas", "kv", self,
-                lambda cache: list(cache.k) + list(cache.v))
+                lambda cache: cache.arenas())
         except Exception:
             pass
 
+    def arenas(self):
+        """Every arena there is, K first."""
+        return list(self.k) + [a for a in self.v if a is not None]
+
     @property
     def nbytes(self):
-        return sum(a.nbytes for a in self.k) + \
-            sum(a.nbytes for a in self.v)
+        return sum(a.nbytes for a in self.arenas())
+
+    @staticmethod
+    def block_bytes(kinds, block_size, dtype):
+        """Bytes one block costs over all layers."""
+        return sum(kind.row_width for kind in kinds) * int(block_size) \
+            * jnp.dtype(dtype).itemsize
+
+    def fresh(self):
+        """An empty cache of the same layout (after a failed step the
+        donated arenas are suspect)."""
+        return PagedKVCache(self.kinds, self.num_blocks, self.block_size,
+                            dtype=self.dtype)
 
     def swap(self, new_k, new_v):
         """Install the updated arenas returned by a compiled step. The

@@ -141,6 +141,12 @@ class Request:    # guarded by: ServingEngine._mu
     def tokens_all(self):
         return self.prompt + self.out_tokens
 
+    def token_at(self, i):
+        """`tokens_all[i]` without building the list: a decode step asks
+        this of every slot, and a prompt can be thousands of tokens."""
+        n = len(self.prompt)
+        return self.prompt[i] if i < n else self.out_tokens[i - n]
+
     @property
     def total_len(self):
         return len(self.prompt) + self.params.max_new_tokens
@@ -394,6 +400,30 @@ class Scheduler:    # guarded by: ServingEngine._mu
                 self.admissions_by_class.get(cls, 0) + 1
             admitted.append(req)
         return admitted
+
+    def rematch(self, req):
+        """A request about to compute its FIRST chunk looks at the
+        prefix index again: requests prefill one after another, so what
+        those ahead of it published since its admission (the same
+        document, asked about by several arrivals of one burst) is
+        taken instead of computed a second time. Only a request that
+        still holds exactly what admission matched is touched. Returns
+        the positions gained."""
+        if self.prefix_index is None \
+                or req.n_prefilled != req.prefix_cached_tokens \
+                or len(req.blocks) != PagedKVCache.blocks_for_tokens(
+                    req.n_prefilled, self.block_size):
+            return 0
+        blocks, cached = self.prefix_index.match(req.tokens_all, self.pool)
+        gained = cached - req.n_prefilled
+        if gained <= 0:
+            return 0
+        self.pool.incref(blocks, owner=req.rid)
+        if req.blocks:
+            self.pool.free(req.blocks, owner=req.rid)
+        req.blocks = list(blocks)
+        req.n_prefilled = req.prefix_cached_tokens = cached
+        return gained
 
     # -- step-boundary enforcement ------------------------------------------
     def reap(self, now=None):

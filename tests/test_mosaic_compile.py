@@ -65,3 +65,97 @@ def test_paged_decode_compiles_for_v5e(one_chip, mosaic, N, H, mb, dtype,
         sds((S,), jnp.int32)).lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_decode" in text
+
+
+@pytest.fixture
+def mosaic_mla(monkeypatch):
+    from paddle_tpu.moe import serving as moe_serving
+    from paddle_tpu.ops import pallas_mla
+    jitted = (pallas_mla.mla_paged_decode, pallas_mla.mla_prefill_chunk,
+              moe_serving.moe_grouped_ffn)
+    monkeypatch.setattr(pallas_mla, "_interpret", lambda: False)
+    monkeypatch.setattr(moe_serving, "_interpret", lambda: False)
+    for fn in jitted:
+        fn.clear_cache()
+    yield pallas_mla, moe_serving
+    for fn in jitted:
+        fn.clear_cache()
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
+    """deepseek-v2.serve-docs: 128 heads over 640-lane rows (576
+    numbers), 512 of them the value, tables of 576 blocks."""
+    pallas_mla, _ = mosaic_mla
+    N, W, rank, bs, mb, nb = 128, 640, 512, 16, 576, 2048
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if step == "decode":
+        assert pallas_mla.mla_tile_rows(bs, W, rank, N, 2, mb) == 512
+        fn = lambda q, a, t, c: pallas_mla.mla_paged_decode(
+            q, a, t, c, rank, 0.11, use_kernel=True)
+        args = (sds((32, N, W), bf16), sds((nb, bs, W), bf16),
+                sds((32, mb), jnp.int32), sds((32,), jnp.int32))
+    else:
+        fn = lambda q, a, t, p0: pallas_mla.mla_prefill_chunk(
+            q, a, t, p0, rank, 0.11, use_kernel=True)
+        args = (sds((512, N, W), bf16), sds((nb, bs, W), bf16),
+                sds((mb,), jnp.int32), sds((), jnp.int32))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("mla_paged_decode" if step == "decode"
+            else "mla_prefill_chunk") in text
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens):
+    """The held experts' products at DeepSeek-V2's widths (8 of the 40
+    experts, to keep the description small), for a decode batch and for
+    a chunk."""
+    _, moe_serving = mosaic_mla
+    d, f, E = 5120, 1536, 8
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, live, w, e, wg, wu, wd):
+        return moe_serving.held_expert_ffn(x, live, w, e, (0, E), wg, wu,
+                                           wd, use_kernel=True)[0]
+
+    text = jax.jit(fn).trace(
+        sds((tokens, d), bf16), sds((tokens,), jnp.bool_),
+        sds((tokens, 6), jnp.float32), sds((tokens, 6), jnp.int32),
+        sds((E, d, f), bf16), sds((E, d, f), bf16),
+        sds((E, f, d), bf16)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+
+
+@pytest.mark.parametrize("name", ["mla_paged_decode", "mla_prefill_chunk",
+                                  "moe_grouped_ffn"])
+def test_registry_example_compiles_for_v5e(one_chip, mosaic_mla, name):
+    """chip_smoke.py's kernel leg runs every registered kernel's own
+    example through Mosaic; a latent row that is not whole lanes passed
+    the interpreter and failed there."""
+    import numpy as np
+    from paddle_tpu.ops.kernel_registry import registered_kernels
+    reg = next(r for r in registered_kernels() if r.name == name)
+    args, kwargs = reg.example(np.random.default_rng(0))
+    arrays = [i for i, a in enumerate(args) if isinstance(a, np.ndarray)]
+
+    def fn(*xs):
+        full = list(args)
+        for i, x in zip(arrays, xs):
+            full[i] = x
+        return reg.fn(*full, **kwargs)
+
+    text = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(args[i].shape, args[i].dtype, sharding=one_chip)
+        for i in arrays]).lower(lowering_platforms=("tpu",)).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and name in text

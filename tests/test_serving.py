@@ -462,6 +462,20 @@ def test_submit_rejects_oversized_requests():
 # scheduler unit behavior
 # ---------------------------------------------------------------------------
 
+def test_request_token_at_is_tokens_all_indexed():
+    """What a decode step asks of every slot, without building the
+    prompt + answer list each time."""
+    from paddle_tpu.serving.scheduler import Request
+    req = Request([5, 6, 7], SamplingParams(max_new_tokens=4),
+                  np.zeros((2,), np.uint32))
+    for tok in (8, 9):
+        req.push_token(tok)
+    assert [req.token_at(i) for i in range(5)] == req.tokens_all \
+        == [5, 6, 7, 8, 9]
+    with pytest.raises(IndexError):
+        req.token_at(5)
+
+
 def test_scheduler_preempts_youngest_and_requeues_front():
     from paddle_tpu.serving.scheduler import Request, Scheduler
     pool = BlockPool(7)                          # capacity 6

@@ -196,9 +196,7 @@ def _static_projection(model, opt, eng):
         leaf_bytes(getattr(v, "shape", ()), v.dtype)
         for st in opt._states.values() for v in st.values()
         if hasattr(v, "dtype"))
-    mcfg = model.config
-    kv = (2 * mcfg.num_layers * eng.cache.num_blocks * eng.block_size
-          * eng.hidden * jnp.dtype(eng._compute_dtype).itemsize)
+    kv = eng.cache.num_blocks * eng._block_bytes()
     return params + opt_state + kv
 
 
