@@ -196,10 +196,12 @@ def leg_serve(sz):
     paddle.seed(0)
     model = GPTForPretraining(cfg)
     ecfg = EngineConfig(**sz.engine)
+    mb = -(-ecfg.max_model_len // ecfg.block_size)
     check(paged_decode_supported(ecfg.block_size, cfg.hidden_size,
-                                 cfg.num_heads)
+                                 cfg.num_heads, max_blocks=mb)
           and flash_prefill_supported(ecfg.block_size, ecfg.prefill_chunk,
-                                      cfg.hidden_size, cfg.num_heads),
+                                      cfg.hidden_size, cfg.num_heads,
+                                      max_blocks=mb),
           "the paged decode and flash prefill gates admit this engine")
     rs = np.random.RandomState(0)
 
@@ -381,6 +383,34 @@ def _production_cases(sz):
                 q, k, v, t, c, N, use_kernel=False),
             (rand((len(ctxs), 1, hid)), rand(arena), rand(arena),
              tabs, ctxs)))
+
+        # a prompt chunk over one request's scattered pages at the same
+        # widths: from position 0, inside a tile, across a tile's edge,
+        # at the table's end, and a padded chunk; the table past the
+        # chunk's last page points past the arena
+        C = 32 if TINY else 128
+        _, T = pd.flash_prefill_tiling(pbs, C, hid, N, 2, mb)
+        for p0, n_real in ((0, C), (T // 2 - 8, C), (T - C // 2, C),
+                           (mb * pbs - C, C), (T + 24, C // 2 - 3)):
+            if p0 < 0 or p0 + C > mb * pbs:
+                continue
+            row = np.full((mb,), arena[0] + 7, np.int32)
+            n_alloc = (p0 + n_real - 1) // pbs + 1
+            row[:n_alloc] = pages[:n_alloc]
+            cases.append((
+                ("flash_prefill_chunk",),
+                f"flash_prefill_chunk {N}x{H} mb={mb} tile={T} rows "
+                f"p0={p0} n_real={n_real}",
+                lambda q, k, v, t, p0=p0, n=n_real, N=N:
+                    pd.flash_prefill_chunk(
+                        q, k, v, t, np.int32(p0), N, use_kernel=True,
+                        n_real=np.int32(n))[:, :n],
+                lambda q, k, v, t, p0=p0, n=n_real, N=N, live=n_alloc:
+                    pd.flash_prefill_chunk(
+                        q, k, v, jnp.where(jnp.arange(t.shape[0]) < live,
+                                           t, 0),
+                        np.int32(p0), N, use_kernel=False)[:, :n],
+                (rand((1, C, hid)), rand(arena), rand(arena), row)))
 
     # latent attention at the DeepSeek-V2 cell's shapes (128 heads over
     # 640-lane rows, 512 of them the value): decode contexts on every

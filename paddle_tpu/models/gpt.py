@@ -567,7 +567,8 @@ class _ServedGPTBlock:
             # run_generate
             return flash_prefill_chunk(
                 qv.reshape(1, -1, self.hidden), kp, vp, view.table_row,
-                view.p0, self.n_heads, use_kernel=view.use_kernel)
+                view.p0, self.n_heads, use_kernel=view.use_kernel,
+                n_real=view.n_real)
         return self._step(h, pages, view, attend)
 
 

@@ -36,10 +36,20 @@ as the reference's fused attention loops key tiles
 (`paddle/fluid/operators/fused/fmha_ref.h`).
 
 `flash_prefill_chunk` is the serving engine's chunked-prefill attention
-over the paged arena — flash-style online softmax across
-table-resolved blocks (the [chunk, ctx] score matrix never
-materializes), causal within the chunk. Its q-side tiling follows
-ops/pallas_attention.py's flash forward.
+over the same arenas, on the same tiles: one request's C queries at
+positions p0..p0+C-1 against the pages its table row names, walked
+only up to the chunk's last real position (`walk_tiles`, which all the
+paged kernels share), causal by position. Heads are taken a 128-lane
+COLUMN at a time (`_head_columns`): a 128-lane head is a column, two
+64-lane heads share one, their queries stacked into 2C rows with the
+other head's lanes zeroed, so a tile costs a column two MXU products in
+the arenas' dtype, [Q, lanes] x [lanes, rows] and [Q, rows] x [rows,
+lanes], with float32 accumulation and statistics. All columns run in
+one grid step on whole-page copies where that fits VMEM, a group of
+columns a step on lane-sliced copies where it does not
+(`flash_prefill_tiling`). On the v5e a 128-token chunk takes 8-36 us a
+layer at the serving cells' shapes, 35-43% of the HBM roofline from a
+context of 700 rows on (PERF.md §6, PR 29).
 
 Both paged kernels have a gather+dense fallback that reproduces the
 composed einsum math of models/gpt._cached_attention bit for bit, so
@@ -62,6 +72,8 @@ _COLS = 128   # head-column padding (N <= 128 heads)
 _SUB = 8      # scratch stat rows padded to the (8, 128) f32 tile minimum
 # the most rows one paged-decode tile holds (see paged_decode_tile_rows)
 _TILE_ROWS = 512
+# the rows a prefill-chunk tile should have (see flash_prefill_tiling)
+_PREFILL_ROWS = 256
 
 
 def _interpret():
@@ -228,6 +240,11 @@ def tile_rows_within(block_size, max_blocks, footprint):
     return rows
 
 
+def _packed_rows(itemsize):
+    # sublanes of one packed tile: 8 rows of float32, 16 of bf16
+    return _SUB * max(1, 4 // itemsize)
+
+
 def _head_rows(n_heads):
     # one row a head, padded to the 16 sublanes a packed bf16 tile has
     return -(-n_heads // 16) * 16
@@ -255,16 +272,70 @@ def paged_decode_kv_rows(ctx_lens, block_size):
     return int(((ctx // block_size + 1) * block_size).sum())
 
 
+def walk_tiles(step, n_steps, last_pos, page_copies, buf_ref, compute,
+               *, bs, rows):
+    """What the paged kernels share (`paged_decode`,
+    `flash_prefill_chunk`, the latent kernels of ops/pallas_mla.py):
+    grid step `step` of `n_steps` works through the tiles of `rows`
+    cache rows (`rows // bs` pages) up to the one that holds position
+    `last_pos(step)`, calling `compute(t, buf)` on tile t once its live
+    pages lie in the VMEM buffers' half `buf`. The copies of a tile are
+    started before the tile ahead of it is computed (at a step's last
+    tile: the next step's first), so a fetch hides behind arithmetic;
+    `buf_ref` (SMEM) carries the half the next step starts on.
+
+    `page_copies(s, i, buf, j)` gives the async copies that bring
+    logical page i of step s into page j of half `buf`. The last tile's
+    dead pages, whose table entries may hold anything, are neither
+    fetched nor waited for. A loop, not P copies written out: a step's
+    descriptors would be traced and lowered once a layer a program."""
+    P = rows // bs
+
+    def each_live_page(s, tile, buf, act):
+        n_live = jnp.minimum(P, last_pos(s) // bs - tile * P + 1)
+
+        def page(j, carry):
+            for copy in page_copies(s, tile * P + j, buf, j):
+                act(copy)
+            return carry
+
+        jax.lax.fori_loop(0, n_live, page, 0)
+
+    def start(s, tile, buf):
+        each_live_page(s, tile, buf, lambda c: c.start())
+
+    @pl.when(step == 0)
+    def _first():
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
+    buf0 = buf_ref[0]
+    n_tiles = last_pos(step) // rows + 1
+
+    def tile_step(t, carry):
+        buf = (buf0 + t) % 2
+        last = t + 1 == n_tiles
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), step + 1 < n_steps))
+        def _prefetch():
+            start(jnp.where(last, step + 1, step),
+                  jnp.where(last, 0, t + 1), 1 - buf)
+
+        each_live_page(step, t, buf, lambda c: c.wait())
+        compute(t, buf)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+    buf_ref[0] = (buf0 + n_tiles) % 2
+
+
 def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
                   k_buf, v_buf, sems, buf_ref, m_sc, l_sc, acc_sc,
                   *, scale, bs, rows, n_heads, head_dim):
-    """One grid step a SLOT; inside it a loop over the tiles of `rows`
-    cache rows (`rows // bs` pages) that the slot's context reaches,
-    `ctx // rows + 1` of them. The arenas stay in HBM: the kernel
-    copies a tile's live pages itself, each page one contiguous run
-    found through the scalar-prefetched table, into one of two VMEM
-    buffers, and starts the next tile's copies (at a slot's last tile:
-    the next slot's first) before it computes the current one.
+    """One grid step a SLOT; inside it `walk_tiles` over the tiles that
+    the slot's context reaches, `ctx // rows + 1` of them. The arenas
+    stay in HBM: a page is one contiguous run found through the
+    scalar-prefetched table.
 
     Heads sit on sublanes: q becomes `qh` [R, N*H], row n holding head
     n's lanes and zeros elsewhere, once a slot. A tile's logits are
@@ -273,47 +344,23 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     product is head n's output on head n's lanes (the other lanes are
     never read). Softmax statistics are float32, one column a row."""
     b = pl.program_id(0)
-    S = pl.num_programs(0)
-    P = rows // bs
     R = acc_sc.shape[0]
     nh = n_heads * head_dim
     ctx = ctx_ref[b]
-    n_tiles = ctx // rows + 1
 
-    def each_live_page(slot_b, tile, buf, act):
-        """`act` on the K and the V copy of every page of the tile that
-        the slot's context reaches: the last tile's dead pages, whose
-        table entries are the null block or lie past the table, are
-        neither fetched nor waited for. A loop, not P copies written
-        out: a step's 4 x P descriptors would be traced and lowered
-        once a layer a program."""
-        n_live = jnp.minimum(P, ctx_ref[slot_b] // bs - tile * P + 1)
-
-        def page(j, carry):
-            blk = tab_ref[slot_b, tile * P + j]
-            act(pltpu.make_async_copy(
-                k_hbm.at[blk], k_buf.at[buf, j], sems.at[0, buf]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[blk], v_buf.at[buf, j], sems.at[1, buf]))
-            return carry
-
-        jax.lax.fori_loop(0, n_live, page, 0)
-
-    def start(slot_b, tile, buf):
-        each_live_page(slot_b, tile, buf, lambda c: c.start())
-
-    def wait(slot_b, tile, buf):
-        each_live_page(slot_b, tile, buf, lambda c: c.wait())
+    def page_copies(slot_b, i, buf, j):
+        blk = tab_ref[slot_b, i]
+        return (pltpu.make_async_copy(
+                    k_hbm.at[blk], k_buf.at[buf, j], sems.at[0, buf]),
+                pltpu.make_async_copy(
+                    v_hbm.at[blk], v_buf.at[buf, j], sems.at[1, buf]))
 
     @pl.when(b == 0)
-    def _first():
+    def _zero():
         # p is exactly 0 on a dead row, and 0 * NaN is NaN: rows no copy
         # has written yet must hold numbers
         v_buf[...] = jnp.zeros_like(v_buf)
-        buf_ref[0] = 0
-        start(0, 0, 0)
 
-    buf0 = buf_ref[0]
     m_sc[...] = jnp.full_like(m_sc, -1e30)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc_sc[...] = jnp.zeros_like(acc_sc)
@@ -324,19 +371,7 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     qh = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0) \
         .astype(k_buf.dtype)                              # [R, NH]
 
-    def tile_step(t, carry):
-        buf = (buf0 + t) % 2
-
-        # what is computed next: this slot's next tile, or at its last
-        # tile the next slot's first
-        last = t + 1 == n_tiles
-
-        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < S))
-        def _prefetch():
-            start(jnp.where(last, b + 1, b), jnp.where(last, 0, t + 1),
-                  1 - buf)
-
-        wait(b, t, buf)
+    def compute(t, buf):
         logits = jax.lax.dot_general(
             qh, k_buf[buf].reshape(rows, nh), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [R, rows]
@@ -356,10 +391,9 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
         acc_sc[...] = acc_sc[...] * alpha + pv
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
-        return carry
 
-    jax.lax.fori_loop(0, n_tiles, tile_step, 0)
-    buf_ref[0] = (buf0 + n_tiles) % 2
+    walk_tiles(b, pl.num_programs(0), lambda s: ctx_ref[s], page_copies,
+               buf_ref, compute, bs=bs, rows=rows)
     # every slot's first tile holds position 0, so no denominator is 0
     # but those of the padding rows past the last head
     denom = jnp.where(l_sc[:, :1] == 0.0, 1.0, l_sc[:, :1])
@@ -375,7 +409,7 @@ def paged_decode_supported(block_size, hidden, n_heads, itemsize=2,
     the dtype's packed sublane tiles (8 rows of float32, 16 of bf16) so
     that a page copy lands tile-aligned, the lanes are whole, and the
     tile policy finds a tile that fits VMEM."""
-    if block_size % (_SUB * max(1, 4 // itemsize)) or hidden % _COLS \
+    if block_size % _packed_rows(itemsize) or hidden % _COLS \
             or n_heads > _COLS:
         return False
     return paged_decode_tile_rows(
@@ -515,116 +549,181 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     return out.astype(q.dtype)
 
 
-def _head_group(n_heads, head_dim):
-    """Heads per prefill program. A block's lane width must be a
-    multiple of 128 (or the whole array), so heads narrower than 128
-    lanes are processed in groups that fill one 128-lane tile; 0 when
-    the head width neither divides nor is a multiple of 128, or the
-    heads do not split into whole groups."""
-    if head_dim % _COLS == 0:
-        return 1
-    if _COLS % head_dim or n_heads % (_COLS // head_dim):
-        return 0
-    return _COLS // head_dim
+def _head_columns(hidden, n_heads):
+    """(lanes of a head COLUMN, heads in it): a head of 128 lanes or a
+    multiple is a column of its own; narrower heads share one 128-lane
+    column, 128 // H of them side by side. (0, 0) when the heads do not
+    tile the lanes that way."""
+    if n_heads <= 0 or hidden % n_heads:
+        return 0, 0
+    H = hidden // n_heads
+    if H % _COLS == 0:
+        return H, 1
+    if _COLS % H or n_heads % (_COLS // H):
+        return 0, 0
+    return _COLS, _COLS // H
 
 
-def _prefill_kernel(tab_ref, p0_ref, q_ref, k_ref, v_ref, out_ref,
-                    m_sc, l_sc, acc_sc, *, scale, bs, nl, C, G, H):
-    """Flash chunked-prefill attention over the paged arena: grid
-    (head group, logical block). The chunk's C queries attend to every
-    cached block reachable through the scalar-prefetched block table
-    with ONLINE softmax (running per-row max/denominator in VMEM
-    scratch), causal within the chunk via logical positions — the full
-    [chunk, ctx] score matrix never exists. Blocks wholly past the
-    chunk's last query are skipped: every row of their score tile would
-    be masked, and a fully-masked tile at running max -1e30 would turn
-    exp(s - m) into ones and corrupt the denominator (block 0 is never
-    fully masked — key position 0 is <= every query position).
+def _prefill_footprint(rows, chunk, width, lanes, heads, itemsize):
+    """KN502 projection of the prefill-chunk kernel at a tile of `rows`
+    over a group of `width` lanes (columns of `lanes` lanes holding
+    `heads` heads each): K and V tiles in two buffers each (the
+    kernel's own double buffer), q and the output block moving with
+    the group, per column the accumulator and the two statistics over
+    its `heads * chunk` query rows, and a column's [query rows, rows]
+    f32 logits/probabilities/mask plus its spread q and product as
+    temps."""
+    Q, cols = heads * chunk, width // lanes
+    return vmem_footprint(
+        moving=[((chunk, width), itemsize)] * 2,
+        scratch=[((2, rows, width), itemsize)] * 2
+        + [((cols, Q, lanes), 4)] + [((cols, Q, _COLS), 4)] * 2,
+        temp_bytes=(3 * Q * rows + 3 * Q * lanes) * 4)
 
-    One program holds G heads side by side in its G*H lanes. Head g's
-    scores come from a full-width contraction with the other heads'
-    query lanes zeroed (no sub-128 lane slicing), and its p@v product
-    is kept on its own lanes only."""
-    li = pl.program_id(1)
-    p0 = p0_ref[0]
-    W = G * H
 
-    @pl.when(li == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, -1e30)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+def flash_prefill_tiling(block_size, chunk, hidden, n_heads, itemsize,
+                         max_blocks):
+    """(lanes a grid step of the prefill-chunk kernel works on, rows of
+    its K and V tiles): the kernel's policy, a pure function of what
+    the arguments' shapes show. (0, 0) when the heads do not tile the
+    lanes or nothing fits.
 
-    def lanes_of(g):
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-        return jnp.logical_and(lane >= g * H, lane < (g + 1) * H)
+    All heads a step where `tile_rows_within` finds them a tile of
+    `_PREFILL_ROWS` rows or the table's whole reach: the page copies
+    are then whole pages, one contiguous run each, and a tile's mask is
+    built once for every head. A wider model splits its head columns
+    into the fewest equal groups that get such a tile; where none does,
+    the single column takes what fits."""
+    lanes, heads = _head_columns(hidden, n_heads)
+    if not lanes:
+        return 0, 0
+    cols = hidden // lanes
+    want = min(_PREFILL_ROWS,
+               tile_rows_within(block_size, max_blocks, lambda rows: 0))
+    for per_step in (c for c in range(cols, 0, -1) if cols % c == 0):
+        width = per_step * lanes
+        rows = tile_rows_within(
+            block_size, max_blocks, lambda r: _prefill_footprint(
+                r, chunk, width, lanes, heads, itemsize))
+        if rows >= want or (rows and per_step == 1):
+            return width, rows
+    return 0, 0
 
-    @pl.when(li * bs <= p0 + C - 1)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                # [C, W]
-        k = k_ref[0].astype(jnp.float32)                # [bs, W]
-        v = v_ref[0].astype(jnp.float32)                # [bs, W]
-        kpos = li * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (C, bs), 1)
-        qpos = p0 + jax.lax.broadcasted_iota(jnp.int32, (C, bs), 0)
-        causal = kpos <= qpos
-        alpha_w = jnp.zeros((C, W), jnp.float32)
-        pv_w = jnp.zeros((C, W), jnp.float32)
-        for g in range(G):
-            own = lanes_of(g)
-            qg = jnp.where(own, q, 0.0) if G > 1 else q
+
+def flash_prefill_kv_rows(p0, n_real, block_size):
+    """Rows of K (and of V) a `flash_prefill_chunk` call fetches a
+    layer for the chunk whose `n_real` real positions start at `p0`:
+    every page up to the one position `p0 + n_real - 1` lies in, and no
+    page past it."""
+    return ((int(p0) + int(n_real) - 1) // block_size + 1) * block_size
+
+
+def _prefill_kernel(tab_ref, span_ref, q_ref, k_hbm, v_hbm, out_ref,
+                    k_buf, v_buf, sems, buf_ref, m_sc, l_sc, acc_sc,
+                    *, scale, bs, rows, lanes, heads):
+    """One grid step a GROUP of head columns (all of them where they
+    fit: `flash_prefill_tiling`); inside it `walk_tiles` over the tiles
+    up to the chunk's last real position `span[1]`, each tile's live
+    pages copied from the HBM arenas through the scalar-prefetched
+    table row.
+
+    A column is `lanes` lanes of `heads` heads. Its C queries become
+    Q = heads * C rows, block h holding head h's lanes and zeros
+    elsewhere, so that a tile costs a column two MXU products in the
+    arenas' dtype, `q . K^T` [Q, rows] and `p @ V` [Q, lanes], with
+    float32 accumulation and float32 softmax statistics a row; block h
+    of the product is head h's output on head h's lanes. Causal by
+    position: query row r stands at `p0 + r % C`, a padding query past
+    the last real one at that one's position. Every tile is masked: a
+    second body for the tiles wholly below `p0` measured no faster
+    (PERF.md section 6, PR 29)."""
+    g = pl.program_id(0)
+    _, C, W = q_ref.shape
+    Q, cols = heads * C, W // lanes
+    p0, last = span_ref[0], span_ref[1]
+
+    def page_copies(s, i, buf, j):
+        blk = tab_ref[i]
+        at = (blk,) if W == k_hbm.shape[2] else \
+            (blk, slice(None), pl.ds(pl.multiple_of(s * W, _COLS), W))
+        return (pltpu.make_async_copy(
+                    k_hbm.at[at], k_buf.at[buf, j], sems.at[0, buf]),
+                pltpu.make_async_copy(
+                    v_hbm.at[at], v_buf.at[buf, j], sems.at[1, buf]))
+
+    @pl.when(g == 0)
+    def _zero():
+        # p is exactly 0 on a dead row, and 0 * NaN is NaN: rows no copy
+        # has written yet must hold numbers
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_sc[...] = jnp.full_like(m_sc, -1e30)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    qpos = jnp.minimum(last, p0 + jax.lax.broadcasted_iota(
+        jnp.int32, (Q, rows), 0) % C)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (Q, rows), 1)
+    if heads > 1:
+        own = jax.lax.broadcasted_iota(jnp.int32, (Q, lanes), 0) // C \
+            == jax.lax.broadcasted_iota(jnp.int32, (Q, lanes), 1) \
+            // (lanes // heads)
+
+    def column(c):
+        return pl.ds(c * lanes, lanes)
+
+    def queries(c):
+        q = q_ref[0, :, column(c)]                        # [C, lanes]
+        if heads == 1:
+            return q
+        q = jnp.concatenate([q.astype(jnp.float32)] * heads, axis=0)
+        return jnp.where(own, q, 0.0).astype(q_ref.dtype)  # [Q, lanes]
+
+    def compute(t, buf):
+        live = t * rows + kcol <= qpos      # once a tile, every column's
+        for c in range(cols):
+            k = k_buf[buf, :, :, column(c)].reshape(rows, lanes)
+            v = v_buf[buf, :, :, column(c)].reshape(rows, lanes)
             s = jax.lax.dot_general(
-                qg, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [C, bs]
-            s = jnp.where(causal, s, -1e30)
-            m_prev = m_sc[g][:, :1]                         # [C, 1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)                 # [C, 1]
-            p = jnp.exp(s - m_new)                          # [C, bs]
-            l_new = alpha * l_sc[g][:, :1] + jnp.sum(
-                p, axis=-1, keepdims=True)
+                queries(c), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Q, rows]
+            s = jnp.where(live, s, -1e30)
+            m_prev = m_sc[c, :, :1]                       # [Q, 1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                        # [Q, rows]
+            l_new = alpha * l_sc[c, :, :1] + jnp.sum(
+                p, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [C, W]
-            alpha_w = jnp.where(own, alpha, alpha_w)
-            pv_w = jnp.where(own, pv, pv_w)
-            m_sc[g] = jnp.broadcast_to(m_new, m_sc.shape[1:])
-            l_sc[g] = jnp.broadcast_to(l_new, l_sc.shape[1:])
-        acc_sc[:] = acc_sc[:] * alpha_w + pv_w
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [Q, lanes]
+            acc_sc[c] = acc_sc[c] * alpha + pv
+            m_sc[c] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+            l_sc[c] = jnp.broadcast_to(l_new, l_sc.shape[1:])
 
-    @pl.when(li == nl - 1)
-    def _finalize():
-        l_w = jnp.ones((C, W), jnp.float32)
-        for g in range(G):
-            l = l_sc[g][:, :1]
-            l_w = jnp.where(lanes_of(g), jnp.where(l == 0.0, 1.0, l), l_w)
-        out_ref[0] = acc_sc[:] / l_w
+    walk_tiles(g, pl.num_programs(0), lambda s: last, page_copies,
+               buf_ref, compute, bs=bs, rows=rows)
+    for c in range(cols):
+        # the first tile holds position 0, which every query attends
+        o = acc_sc[c] / l_sc[c, :, :1]                    # [Q, lanes]
+        if heads > 1:
+            o = jnp.where(own, o, 0.0)
+            o = sum(o[h * C:(h + 1) * C] for h in range(heads))
+        out_ref[0, :, column(c)] = o.astype(out_ref.dtype)
 
 
 def flash_prefill_supported(block_size, chunk, hidden, n_heads,
-                            itemsize=2):
-    """Gate for the fused flash prefill-chunk kernel: TPU tiling
-    constraints on the per-group tiles (whole 128-lane head groups,
-    see _head_group) plus the KN502 VMEM projection via the shared
-    kernel_registry model (q/k/v/out blocks moving, online-softmax
-    scratch resident, f32 casts + the [C, bs] score tile as temps)."""
-    if hidden % n_heads:
+                            itemsize=2, max_blocks=_COLS):
+    """Gate for the fused flash prefill-chunk kernel: pages and the
+    chunk are whole packed sublane tiles of the dtype (8 rows of
+    float32, 16 of bf16), the heads tile the 128-lane columns
+    (`_head_columns`), and the tile policy finds a tile that fits
+    VMEM."""
+    sub = _packed_rows(itemsize)
+    if block_size % sub or chunk % sub or hidden % _COLS:
         return False
-    H = hidden // n_heads
-    G = _head_group(n_heads, H)
-    if block_size % 8 or chunk % 8 or not G:
-        return False
-    W = G * H
-    return vmem_footprint(
-        moving=[((1, chunk, W), itemsize),
-                ((1, block_size, W), itemsize),
-                ((1, block_size, W), itemsize),
-                ((1, chunk, W), 4)],
-        scratch=[((G, chunk, _COLS), 4), ((G, chunk, _COLS), 4),
-                 ((chunk, W), 4)],
-        temp_bytes=(4 * chunk * W + 2 * block_size * W
-                    + 2 * chunk * block_size) * 4) <= _VMEM_BUDGET
+    return flash_prefill_tiling(block_size, chunk, hidden, n_heads,
+                                itemsize, max_blocks)[1] > 0
 
 
 def _prefill_example(rng):
@@ -654,30 +753,37 @@ def _prefill_fallback(q, k_pages, v_pages, table_row, p0, n_heads,
 @register_kernel(
     "flash_prefill_chunk", example=_prefill_example,
     fallback=_prefill_fallback, tol=(1e-3, 1e-3),
-    notes="paged flash prefill chunk: online softmax across "
-          "table-resolved blocks, causal within the chunk; the "
-          "logical-block axis carries the running softmax state and "
-          "must stay sequential (KN501)")
+    notes="one grid step a group of head columns (sequential: the tile "
+          "buffers and their in-flight copies pass from group to "
+          "group); the arenas stay in HBM and the kernel copies the "
+          "live pages of each tile itself through the scalar-prefetched "
+          "table row, up to the chunk's last real position")
+# jitted on its own, as paged_decode_attention is: a model's layers
+# share one trace and one lowering of the kernel
+@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel"))
 def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
-                        use_kernel=None):
+                        use_kernel=None, n_real=None):
     """Chunked-prefill attention over a PAGED KV cache.
 
     q [1, C, N*H] — the chunk's queries at positions p0..p0+C-1;
     k_pages/v_pages [num_blocks, block_size, N*H] — the shared
     physical arenas, already holding this chunk's own K/V (callers
     write before attending); table_row [max_blocks] int32 — ONE
-    request's logical->physical block map (unallocated tail entries
-    point at the reserved null block 0); p0 scalar int32 — the chunk's
-    first position (a TRACED scalar: prefix-cache hits resume prefill
-    at arbitrary offsets without widening the compile-signature
-    family). Returns [1, C, N*H] in q's dtype.
+    request's logical->physical block map; p0 scalar int32 — the
+    chunk's first position (a TRACED scalar: prefix-cache hits resume
+    prefill at arbitrary offsets without widening the compile-signature
+    family); n_real scalar int32 — how many of the C positions are
+    real (all of them when None): what the queries past them return is
+    finite and means nothing. Returns [1, C, N*H] in q's dtype.
 
     Two paths, one contract:
-    - fused Pallas kernel (TPU + `flash_prefill_supported`): physical
-      blocks stream through VMEM via the scalar-prefetched table, the
-      softmax accumulates online per head — the [C, ctx] score matrix
-      is never materialized (Sarathi-style compute-dense prefill
-      chunks over a paged arena);
+    - fused Pallas kernel (TPU + `flash_prefill_supported`): tiles of
+      `flash_prefill_tiling` rows stream through VMEM with online
+      softmax, only over the pages up to position p0 + n_real - 1 —
+      the [C, ctx] score matrix is never materialized, no page past
+      that position is read and table entries past it may hold
+      anything (Sarathi-style compute-dense prefill chunks over a
+      paged arena);
     - gather+dense fallback everywhere else: gather the pages into a
       dense [1, L, N, H] view and run the SAME composed masked einsum
       math as models/gpt._cached_attention's prefill branch, so a CPU
@@ -691,10 +797,11 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
     num_blocks, bs, _ = k_pages.shape
     mb = table_row.shape[0]
     scale = 1.0 / float(np.sqrt(H))
+    itemsize = k_pages.dtype.itemsize
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
-                      and flash_prefill_supported(
-                          bs, C, nh, N, k_pages.dtype.itemsize))
+                      and flash_prefill_supported(bs, C, nh, N, itemsize,
+                                                  mb))
     if not use_kernel:
         # gather+dense: EXACTLY the composed einsum prefill math of
         # models/gpt._cached_attention over the gathered pages —
@@ -713,40 +820,47 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
         out = jnp.einsum("bnqk,bknh->bqnh", probs, v4.astype(q.dtype))
         return out.reshape(1, C, nh)
 
-    G = _head_group(N, H)
-    if not G:
+    W, rows = flash_prefill_tiling(bs, C, nh, N, itemsize, mb)
+    if not rows:
         raise ValueError(
-            f"flash_prefill_chunk kernel: {N} heads of {H} lanes do not "
-            "form whole 128-lane groups (see flash_prefill_supported)")
-    W = G * H
-    p0_arr = jnp.asarray(p0, jnp.int32).reshape((1,))
+            f"flash_prefill_chunk kernel: no tile of {bs}-row pages "
+            f"under {N} heads of {H} lanes and a chunk of {C} fits VMEM "
+            "(see flash_prefill_supported)")
+    lanes, heads = _head_columns(nh, N)
+    Q, cols = heads * C, W // lanes
+    p0 = jnp.asarray(p0, jnp.int32)
+    last = jnp.clip(p0 + (C if n_real is None else n_real) - 1,
+                    0, mb * bs - 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(N // G, mb),
+        grid=(nh // W,),
         in_specs=[
-            pl.BlockSpec((1, C, W), lambda n, i, tab, p0r: (0, 0, n)),
-            pl.BlockSpec((1, bs, W),
-                         lambda n, i, tab, p0r: (tab[i], 0, n)),
-            pl.BlockSpec((1, bs, W),
-                         lambda n, i, tab, p0r: (tab[i], 0, n)),
+            pl.BlockSpec((1, C, W), lambda g, tab, span: (0, 0, g)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, C, W),
-                               lambda n, i, tab, p0r: (0, 0, n)),
+        out_specs=pl.BlockSpec((1, C, W), lambda g, tab, span: (0, 0, g)),
         scratch_shapes=[
-            pltpu.VMEM((G, C, _COLS), jnp.float32),
-            pltpu.VMEM((G, C, _COLS), jnp.float32),
-            pltpu.VMEM((C, W), jnp.float32),
+            pltpu.VMEM((2, rows // bs, bs, W), k_pages.dtype),
+            pltpu.VMEM((2, rows // bs, bs, W), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((cols, Q, _COLS), jnp.float32),
+            pltpu.VMEM((cols, Q, _COLS), jnp.float32),
+            pltpu.VMEM((cols, Q, lanes), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_prefill_kernel, scale=scale, bs=bs, nl=mb,
-                          C=C, G=G, H=H),
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, bs=bs, rows=rows,
+                          lanes=lanes, heads=heads),
         name="flash_prefill_chunk",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, C, nh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, C, nh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(table_row.astype(jnp.int32), p0_arr, q, k_pages, v_pages)
-    return out.astype(q.dtype)
+    )(table_row.astype(jnp.int32), jnp.stack([p0, last]),
+      q.astype(k_pages.dtype), k_pages, v_pages)
 
 
 def _decode_example(rng):

@@ -37,7 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .kernel_registry import register_kernel, vmem_footprint
-from .pallas_decode import _COLS, _interpret, tile_rows_within
+from .pallas_decode import (_COLS, _interpret, tile_rows_within,
+                            walk_tiles)
 
 __all__ = ["mla_paged_decode", "mla_prefill_chunk", "mla_supported",
            "mla_tile_rows"]
@@ -94,48 +95,26 @@ def _mla_kernel(tab_ref, base_ref, q_ref, lat_hbm, out_ref,
     """Grid step g: `q_ref` [Q, W] holds the queries of Q // n_heads
     consecutive positions base[g], base[g] + 1, ... (heads minor) of
     the request whose table row is g (`own_table`: a decode slot) or 0
-    (a chunk). It walks the tiles up to the last position any of them
-    attends, `reach` at most (the table's end)."""
+    (a chunk). `walk_tiles` takes it through the tiles up to the last
+    position any of them attends, `reach` at most (the table's end)."""
     g = pl.program_id(0)
-    G = pl.num_programs(0)
-    P = rows // bs
     Q = q_ref.shape[1]
     tq = Q // n_heads
-
-    def table_row(step):
-        return step if own_table else 0
 
     def last_pos(step):
         return jnp.minimum(base_ref[step] + tq - 1, reach - 1)
 
-    def each_live_page(step, tile, slot, act):
-        # the pages of the tile that the step's last position reaches
-        n_live = jnp.minimum(P, last_pos(step) // bs - tile * P + 1)
-
-        def page(j, carry):
-            blk = tab_ref[table_row(step), tile * P + j]
-            act(pltpu.make_async_copy(
-                lat_hbm.at[blk], buf.at[slot, j], sems.at[slot]))
-            return carry
-
-        jax.lax.fori_loop(0, n_live, page, 0)
-
-    def start(step, tile, slot):
-        each_live_page(step, tile, slot, lambda c: c.start())
-
-    def wait(step, tile, slot):
-        each_live_page(step, tile, slot, lambda c: c.wait())
+    def page_copies(step, i, slot, j):
+        blk = tab_ref[step if own_table else 0, i]
+        return (pltpu.make_async_copy(
+            lat_hbm.at[blk], buf.at[slot, j], sems.at[slot]),)
 
     @pl.when(g == 0)
-    def _first():
+    def _zero():
         # p is exactly 0 on a dead row, and 0 * NaN is NaN: rows no copy
         # has written yet must hold numbers
         buf[...] = jnp.zeros_like(buf)
-        buf_ref[0] = 0
-        start(0, 0, 0)
 
-    slot0 = buf_ref[0]
-    n_tiles = last_pos(g) // rows + 1
     m_sc[...] = jnp.full_like(m_sc, -1e30)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc_sc[...] = jnp.zeros_like(acc_sc)
@@ -143,18 +122,7 @@ def _mla_kernel(tab_ref, base_ref, q_ref, lat_hbm, out_ref,
     qpos = base_ref[g] + jax.lax.broadcasted_iota(
         jnp.int32, (Q, rows), 0) // n_heads
 
-    def tile_step(t, carry):
-        slot = (slot0 + t) % 2
-        last = t + 1 == n_tiles
-
-        # what is computed next: this step's next tile, or at its last
-        # tile the next step's first
-        @pl.when(jnp.logical_or(jnp.logical_not(last), g + 1 < G))
-        def _prefetch():
-            start(jnp.where(last, g + 1, g), jnp.where(last, 0, t + 1),
-                  1 - slot)
-
-        wait(g, t, slot)
+    def compute(t, slot):
         tile = buf[slot].reshape(rows, buf.shape[-1])     # [rows, W]
         logits = jax.lax.dot_general(
             q, tile, (((1,), (1,)), ((), ())),
@@ -175,10 +143,9 @@ def _mla_kernel(tab_ref, base_ref, q_ref, lat_hbm, out_ref,
         acc_sc[...] = acc_sc[...] * alpha + pv
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
-        return carry
 
-    jax.lax.fori_loop(0, n_tiles, tile_step, 0)
-    buf_ref[0] = (slot0 + n_tiles) % 2
+    walk_tiles(g, pl.num_programs(0), last_pos, page_copies, buf_ref,
+               compute, bs=bs, rows=rows)
     # every first tile holds position 0, which every query attends
     out_ref[0] = (acc_sc[...] / l_sc[:, :1]).astype(out_ref.dtype)
 

@@ -53,7 +53,8 @@ from ..analysis import lockwatch
 from ..core import autograd
 from ..generation import _cast_params
 from ..jit import bind_tensors
-from ..ops.pallas_decode import paged_decode_kv_rows
+from ..ops.pallas_decode import (flash_prefill_kv_rows,
+                                 paged_decode_kv_rows)
 from ..resilience.retry import classify_failure
 from ..telemetry.mem_obs import (MemoryObservatory, is_oom,
                                  register_provider)
@@ -430,7 +431,7 @@ class ServingEngine:
                     table_row[jnp.clip(positions // bs_blk, 0, mb - 1)],
                     NULL_BLOCK)
                 view = ChunkView(blk, positions % bs_blk, table_row, p0,
-                                 positions, tmask, use_kernel)
+                                 n_real, positions, tmask, use_kernel)
                 h, new_k, new_v, stats = run_layers(
                     h, k_pages, v_pages, "prefill", view)
                 last = served.head(h, at=n_real - 1)[:, -1]
@@ -1097,6 +1098,8 @@ class ServingEngine:
                         np.float32(p.top_p), np.bool_(p.greedy))
             with _span("serving_dispatch", family="serving_prefill",
                        rid=req.rid, p0=p0, n_real=c_real,
+                       kv_rows=flash_prefill_kv_rows(
+                           p0, c_real, self.block_size),
                        cache_kind=self._cache_kind_names):
                 tok, logp, new_k, new_v, stats = self._dispatch(
                     "serving_prefill", self._prefill_jit, args)

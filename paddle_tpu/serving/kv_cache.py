@@ -56,6 +56,9 @@ The attention over the K/V layout is
 `ops.pallas_decode.paged_decode_attention` (decode) and
 `ops.pallas_decode.flash_prefill_chunk` (chunked prefill); over the
 latent layout `ops.pallas_mla.mla_paged_decode` and `mla_prefill_chunk`.
+All four leave the arenas in HBM and copy the live pages of a tile of
+128-512 rows themselves, through the block table, up to the last
+position attended: a table entry past it is never read.
 """
 import jax.numpy as jnp
 

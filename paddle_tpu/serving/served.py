@@ -44,7 +44,7 @@ DecodeView = collections.namedtuple(
 # of them real. blk/off [C] (padding rows go to the null block);
 # table_row [max_blocks]; positions [C]; live [C] bool.
 ChunkView = collections.namedtuple(
-    "ChunkView", "blk off table_row p0 positions live use_kernel")
+    "ChunkView", "blk off table_row p0 n_real positions live use_kernel")
 
 
 def sum_stats(per_layer):

@@ -32,12 +32,16 @@ def one_chip():
 def mosaic(monkeypatch):
     """The kernels decide between Mosaic and the interpreter by the
     default backend, which is the CPU here."""
+    jitted = (pallas_decode.paged_decode_attention,
+              pallas_decode.flash_prefill_chunk)
     monkeypatch.setattr(pallas_decode, "_interpret", lambda: False)
-    # the entry is jitted: no trace made under the other answer may be
-    # found again, by these tests or after them
-    pallas_decode.paged_decode_attention.clear_cache()
+    # the entries are jitted: no trace made under the other answer may
+    # be found again, by these tests or after them
+    for fn in jitted:
+        fn.clear_cache()
     yield
-    pallas_decode.paged_decode_attention.clear_cache()
+    for fn in jitted:
+        fn.clear_cache()
 
 
 @pytest.mark.parametrize("N,H,mb,dtype,rows", [
@@ -65,6 +69,37 @@ def test_paged_decode_compiles_for_v5e(one_chip, mosaic, N, H, mb, dtype,
         sds((S,), jnp.int32)).lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_decode" in text
+
+
+@pytest.mark.parametrize("N,H,mb,C,dtype,tiling", [
+    (12, 64, 64, 128, jnp.bfloat16, (768, 512)),     # gpt3-125m.serve-chat
+    (16, 128, 128, 128, jnp.bfloat16, (2048, 256)),  # gpt3-1.3b.serve-long
+    (40, 128, 128, 128, jnp.bfloat16, (1280, 512)),  # 13B: groups of columns
+    (4, 32, 3, 16, jnp.float32, (128, 128)),         # the registry's example
+])
+def test_flash_prefill_chunk_compiles_for_v5e(one_chip, mosaic, N, H, mb, C,
+                                              dtype, tiling):
+    """The prefill-chunk kernel at the cells' widths: pairs of 64-lane
+    heads in a column and whole 128-lane heads, all columns a grid step
+    on whole-page copies; at 13B's width a group of columns a step on
+    lane-sliced copies."""
+    nh, bs, nb = N * H, 16, 4 * mb
+    assert pallas_decode.flash_prefill_tiling(
+        bs, C, nh, N, jnp.dtype(dtype).itemsize, mb) == tiling
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def attend(q, k, v, table_row, p0, n_real):
+        return pallas_decode.flash_prefill_chunk(
+            q, k, v, table_row, p0, N, use_kernel=True, n_real=n_real)
+
+    text = jax.jit(attend).trace(
+        sds((1, C, nh), dtype), sds((nb, bs, nh), dtype),
+        sds((nb, bs, nh), dtype), sds((mb,), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and "flash_prefill_chunk" in text
 
 
 @pytest.fixture

@@ -541,10 +541,153 @@ class TestFlashPrefillKernel:
 
     def test_supported_gate(self):
         from paddle_tpu.ops.pallas_decode import flash_prefill_supported
-        assert flash_prefill_supported(16, 128, 768, 12)
-        assert not flash_prefill_supported(6, 128, 768, 12)   # bs % 8
-        assert not flash_prefill_supported(16, 12, 768, 12)   # chunk % 8
-        assert not flash_prefill_supported(16, 128, 768, 7)   # nh % N
+        assert flash_prefill_supported(16, 128, 768, 12, max_blocks=64)
+        assert flash_prefill_supported(16, 128, 2048, 16, max_blocks=128)
+        assert not flash_prefill_supported(6, 128, 768, 12,
+                                           max_blocks=64)   # bs % 8
+        assert not flash_prefill_supported(16, 12, 768, 12,
+                                           max_blocks=64)   # chunk % 8
+        assert not flash_prefill_supported(16, 128, 768, 7,
+                                           max_blocks=64)   # nh % N
+        # float32 pages are whole tiles from 8 rows on, bf16 from 16
+        assert flash_prefill_supported(8, 8, 128, 4, 4, max_blocks=4)
+        assert not flash_prefill_supported(8, 16, 128, 4, 2, max_blocks=4)
+        # heads that do not tile the 128-lane columns: 96 lanes a head
+        assert not flash_prefill_supported(16, 128, 1152, 12,
+                                           max_blocks=64)
+
+    @pytest.mark.parametrize("hidden,n_heads,mb,tiling", [
+        (768, 12, 64, (768, 512)),        # gpt3-125m.serve-chat
+        (2048, 16, 128, (2048, 256)),     # gpt3-1.3b.serve-long
+        (5120, 40, 128, (1280, 512)),     # 13B: its columns in groups
+        (2048, 16, 8, (2048, 128)),       # a table shorter than a tile
+    ])
+    def test_tile_policy_fits_vmem(self, hidden, n_heads, mb, tiling):
+        """The gate is the tile policy's answer, and what the policy
+        chooses lies within VMEM_BUDGET by the kernel's own footprint;
+        a wide model gets a narrower group of head columns a step, not
+        the fallback."""
+        from paddle_tpu.ops.kernel_registry import VMEM_BUDGET
+        from paddle_tpu.ops.pallas_decode import (_head_columns,
+                                                  _prefill_footprint,
+                                                  flash_prefill_supported,
+                                                  flash_prefill_tiling)
+        width, rows = flash_prefill_tiling(16, 128, hidden, n_heads, 2, mb)
+        assert (width, rows) == tiling
+        lanes, heads = _head_columns(hidden, n_heads)
+        assert hidden % width == 0 and width % lanes == 0
+        assert rows % 128 == 0 and rows % 16 == 0
+        assert _prefill_footprint(rows, 128, width, lanes, heads, 2) \
+            <= VMEM_BUDGET
+        assert flash_prefill_supported(16, 128, hidden, n_heads, 2, mb)
+
+    def test_gate_says_no_where_no_tile_fits(self):
+        """A chunk so long that one column's statistics alone pass the
+        budget: the gate answers False and the caller takes the
+        fallback."""
+        from paddle_tpu.ops.pallas_decode import (flash_prefill_supported,
+                                                  flash_prefill_tiling)
+        assert flash_prefill_tiling(16, 8192, 5120, 40, 2, 128) == (0, 0)
+        assert not flash_prefill_supported(16, 8192, 5120, 40, 2, 128)
+
+    def test_kv_rows_hand_count(self):
+        from paddle_tpu.ops.pallas_decode import flash_prefill_kv_rows
+        # whole pages up to the one the last real position lies in
+        assert flash_prefill_kv_rows(0, 1, 16) == 16
+        assert flash_prefill_kv_rows(0, 16, 16) == 16
+        assert flash_prefill_kv_rows(0, 17, 16) == 32
+        assert flash_prefill_kv_rows(576, 128, 16) == 704
+        assert flash_prefill_kv_rows(1408, 5, 16) == 1424    # padded
+        assert flash_prefill_kv_rows(13, 3, 16) == 16         # mid-page
+
+    @pytest.mark.parametrize("H", [64, 128])
+    @pytest.mark.parametrize("case,p0,n_real,dtype,split", [
+        ("start", 0, 32, "float32", False),
+        ("inside_a_tile", 40, 32, "float32", False),
+        ("crosses_a_tile_edge", 112, 32, "float32", False),
+        ("past_two_tiles", 300, 32, "float32", False),
+        ("padded_chunk", 120, 11, "float32", False),
+        ("bf16_arenas", 232, 32, "bfloat16", False),
+        ("a_column_a_step", 200, 32, "float32", True),
+    ])
+    def test_tiles_over_the_live_context(self, monkeypatch, H, case, p0,
+                                         n_real, dtype, split):
+        """The kernel on 128-row tiles against gather+dense. The table
+        past the chunk's last page holds out-of-range entries and every
+        page the chunk does not reach holds NaN: a result that is
+        finite and equal read nothing past the live context."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops import pallas_decode as pd
+        monkeypatch.setattr(pd, "_TILE_ROWS", 128)
+        if split:
+            monkeypatch.setattr(pd, "flash_prefill_tiling",
+                                lambda *a: (max(H, 128), 128))
+        pd.flash_prefill_chunk.clear_cache()
+        rng = np.random.default_rng(p0 + H)
+        N, bs, C, mb = 4, 16, 32, 24
+        nh = N * H
+        q = 0.3 * rng.standard_normal((1, C, nh)).astype(np.float32)
+        kp = 0.3 * rng.standard_normal((mb + 3, bs, nh)).astype(np.float32)
+        vp = 0.3 * rng.standard_normal((mb + 3, bs, nh)).astype(np.float32)
+        table = rng.permutation(np.arange(1, mb + 3)).astype(np.int32)[:mb]
+        n_live = (p0 + n_real - 1) // bs + 1
+        dirty_table = table.copy()
+        dirty_table[n_live:] = 1 << 20
+        dead = np.setdiff1d(np.arange(mb + 3), table[:n_live])
+        dirty_k, dirty_v = kp.copy(), vp.copy()
+        dirty_k[dead] = np.nan
+        dirty_v[dead] = np.nan
+        cast = lambda a: jnp.asarray(a, dtype)
+        try:
+            got = pd.flash_prefill_chunk(
+                cast(q), cast(dirty_k), cast(dirty_v), dirty_table,
+                np.int32(p0), N, use_kernel=True, n_real=np.int32(n_real))
+        finally:
+            pd.flash_prefill_chunk.clear_cache()
+        want = pd.flash_prefill_chunk(
+            cast(q), cast(kp), cast(vp), table, np.int32(p0), N,
+            use_kernel=False)
+        got = np.asarray(got, np.float32)
+        assert got.shape == (1, C, nh) and np.isfinite(got).all()
+        tol = 2e-2 if dtype == "bfloat16" else 1e-4
+        np.testing.assert_allclose(
+            got[0, :n_real], np.asarray(want, np.float32)[0, :n_real],
+            rtol=tol, atol=tol)
+
+    def test_padded_last_chunk_through_the_engine(self):
+        """The engine's own prefill step on its own arenas, a full
+        chunk and then a padded one (n_real < C), with the kernel and
+        through gather+dense: the same logits at the last real
+        position, the same rows written in every block but the null
+        block, which takes the padding rows."""
+        import functools
+        import jax
+        model = _small_gpt()
+        eng = ServingEngine(model, max_slots=2, block_size=8,
+                            prefill_chunk=16, max_model_len=64,
+                            dtype="float32")
+        params = eng._param_vals()
+        rs = np.random.RandomState(3)
+        table = np.arange(1, eng.max_blocks_per_seq + 1, dtype=np.int32)
+        k, v = eng.cache.k, eng.cache.v
+        for p0, n_real in ((0, 16), (16, 5)):
+            ids = np.zeros((1, 16), np.int32)
+            ids[0, :n_real] = rs.randint(0, 512, (n_real,))
+            outs = {uk: jax.jit(functools.partial(
+                eng._prefill_logits, use_kernel=uk))(
+                    params, k, v, ids, np.int32(p0), np.int32(n_real),
+                    table) for uk in (True, False)}
+            kernel, dense = (jax.tree_util.tree_leaves(outs[uk])
+                             for uk in (True, False))
+            assert kernel[0].shape == (1, 512)
+            for got, want in zip(kernel, dense, strict=True):
+                got, want = np.asarray(got), np.asarray(want)
+                assert got.dtype == np.float32
+                if got.ndim == 3:               # an arena: drop block 0
+                    got, want = got[1:], want[1:]
+                np.testing.assert_allclose(got, want, rtol=2e-4,
+                                           atol=2e-4)
+            _, k, v = outs[True]
 
     def test_registered_and_doctor_clean(self):
         from paddle_tpu.analysis.kernel_lint import lint_kernel

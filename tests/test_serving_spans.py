@@ -196,6 +196,7 @@ def test_phases_carry_their_attributes(traced):
 
 
 def test_dispatch_attributes_equal_what_dispatch_saw(traced):
+    from paddle_tpu.ops.pallas_decode import flash_prefill_kv_rows
     spans = traced.named("serving_dispatch")
     assert len(spans) == len(traced.seen) > 10
     families = set()
@@ -210,6 +211,9 @@ def test_dispatch_attributes_equal_what_dispatch_saw(traced):
         elif saw[0] == "serving_prefill":
             assert (st["p0"], st["n_real"]) == saw[1:]
             assert st["rid"] in traced.rids
+            # whole 16-row pages up to the chunk's last real position
+            assert st["kv_rows"] == flash_prefill_kv_rows(
+                st["p0"], st["n_real"], 16)
     assert families == {"serving_prefill", "serving_decode", "serving_fork"}
 
 
