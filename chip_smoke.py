@@ -5,10 +5,10 @@ points a user calls, at the full published width of GPT-3 125M
 (12 layers, d=768, 12 heads of 64, vocab 50304), and checks what comes
 out by the repo's own means:
 
-  train     paddle.jit.TrainStep (bench.py's trainer) at 24 x 1024:
+  train     paddle.jit.TrainStep (AdamW, bf16 autocast) at 24 x 1024:
             finite falling loss, the flash kernels in the compiled
             step, loss parity with the composed attention path
-  serve     serving.ServingEngine (bench_serving.py's configuration)
+  serve     serving.ServingEngine (16 slots, 16-token pages, wo8)
             answers seeded requests: token counts, a quiesced pool,
             paged_decode / flash_prefill_chunk in the compiled steps,
             logit parity with the gather+dense path
@@ -118,8 +118,35 @@ def kernels_in(obs, family):
 # train leg
 # ---------------------------------------------------------------------------
 
+def build_gpt_train_step(cfg, amp_on=True):
+    """The GPT trainer the train leg checks: seeded model, AdamW, bf16
+    autocast, one fused TrainStep. Returns (model, step)."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, optimizer
+    from paddle_tpu.models.gpt import GPTForPretraining
+
+    paddle.seed(0)
+    model = GPTForPretraining(cfg)
+    opt = optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
+                          parameters=model.parameters())
+
+    def loss_fn(ids, labels):
+        with amp.auto_cast(enable=amp_on, dtype="bfloat16"):
+            return model.loss(ids, labels)
+
+    return model, paddle.jit.TrainStep(model, loss_fn, opt)
+
+
+def seeded_token_batch(vocab_size, batch, seq):
+    """Fixed (ids, labels) int32 batch from seed 0."""
+    import paddle_tpu as paddle
+    rs = np.random.RandomState(0)
+    return tuple(
+        paddle.to_tensor(rs.randint(0, vocab_size, (batch, seq)), "int32")
+        for _ in range(2))
+
+
 def leg_train(sz):
-    from bench import build_gpt_train_step, seeded_token_batch
     from paddle_tpu import telemetry
     from paddle_tpu.flags import get_flag, set_flags
     from paddle_tpu.telemetry.mfu import flops_drift, model_flops_per_token
@@ -576,7 +603,6 @@ def leg_four_chip(sz):
     import re
     import jax
     import paddle_tpu as paddle
-    from bench import seeded_token_batch
     from paddle_tpu import amp, optimizer, telemetry
     from paddle_tpu import distributed as dist
     from paddle_tpu.distributed import env

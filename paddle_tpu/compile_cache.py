@@ -1,10 +1,9 @@
 """Placement of JAX's persistent compilation cache.
 
-The entry scripts (chip_smoke.py, bench.py, bench_extra.py,
-bench_serving.py) call `enable()` before their first trace so a second
-run in the same checkout does not compile the train step, the engine's
-prefill/decode families and the kernels from nothing. Library code sets
-no cache on import.
+The entry scripts (chip_smoke.py, benchmark/run.py) call `enable()`
+before their first trace so a second run in the same checkout does not
+compile the train step, the engine's prefill/decode families and the
+kernels from nothing. Library code sets no cache on import.
 
 The directory is part of the cache key, so it must not move between
 runs: either the operator places it with `JAX_COMPILATION_CACHE_DIR`

@@ -61,16 +61,17 @@ _register(
 _register(
     "pallas_attention_min_seq", 512, int,
     "Sequence length at which attention dispatch switches from the composed "
-    "XLA path to the Pallas blockwise kernel. Measured on v5e "
-    "(tools/tpu_microbench.py attn:128,256,512): XLA wins at <=256, "
-    "Pallas 1.77x at 512, 2.6x at 1024, 3.0x at 2048.")
+    "XLA path to the Pallas blockwise kernel. XLA won at <=256, Pallas "
+    "1.77x at 512, 2.6x at 1024, 3.0x at 2048: measured on an earlier "
+    "revision (jax 0.4.37), not since.")
 _register(
     "use_pallas_decode_attention", True, bool,
     "Use the fused Pallas decode-attention kernel (ops/pallas_decode.py)"
     " for q_len==1 KV-cache attention when shapes qualify (TPU, cache "
     "len %8==0, n_heads*head_dim %128==0). One kernel per layer instead "
-    "of the einsum+mask+softmax+einsum chain; measured 91 vs 117 us per "
-    "call at B=64/L=256 on an earlier revision (jax 0.4.37).")
+    "of the einsum+mask+softmax+einsum chain; 91 vs 117 us per call at "
+    "B=64/L=256, measured on an earlier revision (jax 0.4.37), not "
+    "since.")
 _register(
     "use_fused_ce", False, bool,
     "Use the chunked fused projection+cross-entropy for LM losses "
@@ -82,11 +83,12 @@ _register(
     "Use the Pallas fused residual+LayerNorm kernel "
     "(ops/pallas_layernorm.py) at the transformer residual+ln2 site "
     "where shapes divide (rows%256==0, d%128==0, TPU backend). "
-    "Measured ISOLATED 1.69x vs composed XLA at [16384,768] fwd+bwd on "
-    "v5e (tools/tpu_microbench.py) but NET-SLOWER end-to-end: GPT-1.3B-"
-    "dims block MFU 0.611->0.387 (the vjp's f32 residual-sum output "
-    "doubles HBM writes at d=2048, and XLA fuses the composed add+LN "
-    "into neighboring ops). Off (default) composes add+LN in XLA.")
+    "ISOLATED 1.69x vs composed XLA at [16384,768] fwd+bwd but "
+    "NET-SLOWER end-to-end: GPT-1.3B-dims block MFU 0.611->0.387 (the "
+    "vjp's f32 residual-sum output doubles HBM writes at d=2048, and "
+    "XLA fuses the composed add+LN into neighboring ops); measured on "
+    "an earlier revision (jax 0.4.37), not since. Off (default) "
+    "composes add+LN in XLA.")
 _register(
     "use_pallas_attention", True, bool,
     "Master switch for the Pallas flash-attention kernel; off forces the "

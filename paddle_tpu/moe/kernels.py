@@ -42,31 +42,6 @@ __all__ = ["moe_gather", "moe_combine", "gather_fallback",
 _BLOCK_ROWS = 128
 
 
-def _resolve_rows(kernel, d, dtype, n_src):
-    """Output-block row count for one dispatch/combine call: the
-    hand-tuned _BLOCK_ROWS default, overridden by a `kernellab --tune`d
-    config from the kernel DB when the opt-in PADDLE_TPU_KERNEL_DB flag
-    is set. A tuned value must re-pass the SAME KN502 feasibility the
-    support gate projects (rows block moving, src resident) — an edited
-    DB can never force an infeasible block — and must keep the (8, 128)
-    f32 sublane tiling."""
-    import os
-    if not os.environ.get("PADDLE_TPU_KERNEL_DB", "").strip():
-        return _BLOCK_ROWS
-    try:
-        from ..telemetry import kernel_obs
-        rows = kernel_obs.tuned_param(
-            kernel, "block_rows", match={"d": int(d)},
-            validate=lambda v: (isinstance(v, int) and v >= 8
-                                and v % 8 == 0
-                                and fits_vmem(
-                                    moving=[((v, d), dtype)],
-                                    resident=[((n_src, d), dtype)])))
-        return rows if rows is not None else _BLOCK_ROWS
-    except Exception:
-        return _BLOCK_ROWS
-
-
 def _interpret():
     return jax.default_backend() != "tpu"
 
@@ -185,7 +160,7 @@ def _gather_pallas(src, idx):
         return _widened(_gather_pallas, src, idx)
     n_src, d = src.shape
     n_out = idx.shape[0]
-    rows = _resolve_rows("moe_gather", d, src.dtype, n_src)
+    rows = _BLOCK_ROWS
     idx_p = _pad_to(idx.astype(jnp.int32), rows, n_src)
     n_pad = idx_p.shape[0]
     grid = (n_pad // rows,)
@@ -291,7 +266,7 @@ def _combine_pallas(src, idx, w):
         return _widened(_combine_pallas, src, idx, w)
     n_src, d = src.shape
     n, k = idx.shape
-    rows = _resolve_rows("moe_combine", d, src.dtype, n_src)
+    rows = _BLOCK_ROWS
     pad = (-n) % rows
     idx_p = _pad_to(idx.astype(jnp.int32), rows, n_src)
     w_p = _pad_to(w.astype(jnp.float32), rows, 0.0)

@@ -29,20 +29,12 @@ DEFAULT_BLOCK_K = None
 
 
 def _resolve_blocks(sq, block_q, block_k, for_bwd=False):
-    """Measured block policy (v5e sweeps with tools/tpu_microbench.py
-    and the sweep spec now owned by telemetry/kernel_obs, on an earlier
-    revision under jax 0.4.37): bk=1024 wins at every shape tested (512..16384, D 64/128).
-    The backward's whole-slice dq VMEM accumulator caps bq at 512 beyond
-    sq=8192 (the constraint is governed by sq, not sk); the forward has
-    no such working set and keeps bq=1024 everywhere. Explicit block
-    args override. When the opt-in PADDLE_TPU_KERNEL_DB flag is set, a
-    `kernellab --tune`d config for this (family, sq) overrides the
-    policy defaults — never an explicit arg — and any DB miss falls
-    back to the defaults below."""
-    if block_q is None and block_k is None:
-        tuned = _tuned_blocks(sq, for_bwd)
-        if tuned is not None:
-            return tuned
+    """Block policy, a function of the shape alone (measured on an
+    earlier revision (jax 0.4.37), not since): bk=1024 at every shape
+    (512..16384, D 64/128). The backward's whole-slice dq VMEM
+    accumulator caps bq at 512 beyond sq=8192 (the constraint is
+    governed by sq, not sk); the forward has no such working set and
+    keeps bq=1024 everywhere. Explicit block args override."""
     if block_k is None:
         block_k = 1024
     if block_q is None:
@@ -50,19 +42,6 @@ def _resolve_blocks(sq, block_q, block_k, for_bwd=False):
     return block_q, block_k
 
 
-def _tuned_blocks(sq, for_bwd):
-    """The kernel-DB consult, opt-in and failure-proof: anything short
-    of a valid tuned (block_q, block_k) pair answers None and the
-    hand-tuned policy applies. Import is lazy and flag-gated so the
-    default path never touches telemetry."""
-    import os
-    if not os.environ.get("PADDLE_TPU_KERNEL_DB", "").strip():
-        return None
-    try:
-        from ..telemetry import kernel_obs
-        return kernel_obs.tuned_blocks(None, sq, for_bwd=for_bwd)
-    except Exception:
-        return None
 _LANES = 128  # stats buffers padded to a full lane register
 _SUB = 8     # row-stats (lse/delta) replicated over 8 sublanes so their
              # [.., _SUB, bq] blocks satisfy the TPU (8, 128) tile minimum

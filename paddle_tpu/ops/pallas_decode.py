@@ -109,26 +109,8 @@ def decode_attention_supported(max_len, hidden, n_heads, itemsize=2):
 @functools.lru_cache(maxsize=64)
 def _pick_bl(L, hidden, itemsize):
     """Largest multiple-of-8 divisor of L whose tile fits the VMEM
-    budget (scan is at trace time only). A `kernellab --tune`d L-tile
-    from the kernel DB overrides the policy when the opt-in
-    PADDLE_TPU_KERNEL_DB flag is set — but only if it passes the SAME
-    feasibility bounds (multiple-of-8 divisor of L under the budget):
-    a hand-edited DB can never force an infeasible tile."""
+    budget (scan is at trace time only)."""
     per_row = _per_row_bytes(hidden, itemsize)
-    import os
-    if os.environ.get("PADDLE_TPU_KERNEL_DB", "").strip():
-        try:
-            from ..telemetry import kernel_obs
-            bl = kernel_obs.tuned_param(
-                "decode_fused", "block_l",
-                match={"L": int(L), "hidden": int(hidden)},
-                validate=lambda v: (isinstance(v, int) and v >= 8
-                                    and v % 8 == 0 and L % v == 0
-                                    and v * per_row <= _VMEM_BUDGET))
-            if bl is not None:
-                return bl
-        except Exception:
-            pass
     cap = max(_SUB, min(L, _VMEM_BUDGET // per_row))
     bl = (cap // 8) * 8
     while bl > 8 and L % bl:

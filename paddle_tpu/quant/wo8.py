@@ -138,10 +138,9 @@ def _holds_wo8(layer):
 
 
 def quantize_for_decode(model, bits=8, min_features=0):
-    """THE weight-only-int8 entry for decode consumers — bench.py's
-    `decode_wo8` phase and the serving engine's `weights="wo8"` mode
-    share this one implementation (ISSUE 8 satellite: no bench-local
-    quantization drift). Thin discipline over `quantize_weights_int8`:
+    """THE weight-only-int8 entry for decode consumers — the serving
+    engine's `weights="wo8"` mode and every other decode caller share
+    this one implementation. Thin discipline over `quantize_weights_int8`:
 
     - idempotent: an already-quantized model is a no-op (returns 0),
       so an engine built over a pre-quantized checkpoint doesn't

@@ -36,10 +36,10 @@ per request.
   MetricsServer pattern; detects client disconnects and cancels the
   abandoned request.
 
-Benchmarked by `bench_serving.py` (offered-load sweep -> typed
-kind=bench `serving.*` records gated by tools/bench_gate.py); smoked in
-CI by `tools/serving_smoke.py` (token parity with run_generate +
-eviction selfcheck).
+Measured on the chip by the serving cells of `BENCHMARK.json`
+(`benchmark/`, read through `PERF.md`); smoked in CI by
+`tools/serving_smoke.py` (token parity with run_generate + eviction
+selfcheck).
 """
 from .kv_cache import (  # noqa: F401
     BlockLeakError, BlockPool, CacheKind, PagedKVCache, PrefixIndex,

@@ -64,7 +64,7 @@ from .reqtrace import (  # noqa: F401
     RequestTrace, RequestTracer, decompose, dominant_cause,
     trace_chrome_spans)
 from .sink import (  # noqa: F401
-    JsonlSink, export_chrome_tracing, make_bench_record, make_ckpt_record,
+    JsonlSink, export_chrome_tracing, make_ckpt_record,
     make_memsnap_record, make_phase_record, make_reqtrace_record,
     make_serving_record, make_step_record, read_jsonl,
     validate_step_record)
@@ -74,7 +74,7 @@ __all__ = [
     "TelemetryRecorder", "StepTimer", "span", "auto_step",
     "current_recorder", "open_spans", "JsonlSink", "read_jsonl",
     "make_step_record", "make_phase_record", "make_ckpt_record",
-    "make_bench_record", "make_serving_record", "make_reqtrace_record",
+    "make_serving_record", "make_reqtrace_record",
     "make_memsnap_record",
     "MemoryObservatory", "is_oom", "register_provider", "snapshot_ledger",
     "RequestTrace", "RequestTracer", "decompose", "dominant_cause",

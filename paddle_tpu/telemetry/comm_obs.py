@@ -22,31 +22,29 @@ measures them. This module closes that loop:
   collective moves. CPU backends have no entry in the peak tables, so
   bw_frac / predicted_ms are None there — no roofline, no drift to
   judge (the kernel_obs exemption rule).
-- **CommDB** — tools/comm_db.json: best-known latency per
-  (op, axis-size, payload, backend) key, rolled forward only by
-  ``commlab --update-db`` with the kernel_db keep-best /
-  refuse-non-finite semantics. A measured collective drifting a
-  multiplicative band BELOW its DB row fires the `comm_bw_degraded`
-  rule (telemetry/health.py); the DB reference rides ON the record
-  (db_ms) so in-flight and offline replays judge identically.
+- **CommDB** — a JSON file at a path the caller names: best-known
+  latency per (op, axis-size, payload, backend) key, rolled forward
+  only by ``commlab --db PATH --update-db`` with the KernelDB
+  keep-best / refuse-non-finite semantics. A measured collective
+  drifting a multiplicative band BELOW its DB row fires the
+  `comm_bw_degraded` rule (telemetry/health.py); the DB reference rides
+  ON the record (db_ms) so in-flight and offline replays judge
+  identically.
 - per-step attribution lands through TelemetryRecorder: wall-time
   ``collective.*`` spans aggregate into the step record's ``comm_ms``
   / ``comm_frac`` fields (spans tagged ``traced=True`` by
   distributed/collective.py cover trace time and are excluded), and
   per-rank step-boundary skew feeds the `straggler` rule.
 
-Opt-in flag: set ``PADDLE_TPU_COMM_DB=/path/to/comm_db.json`` (or
-``=1`` for the checked-in tools/comm_db.json) to let measurements
-attach their DB reference (db_ms) for the drift rule. Unset (the
-default), measurements carry no reference and the rule has no
-jurisdiction — CI smoke sweeps on arbitrary hosts stay quiet.
+A measurement attaches its DB reference (db_ms) only when the caller
+hands `measure_collective` / `sweep_mesh` a CommDB; without one it
+carries no reference and the rule has no jurisdiction.
 
 Every measurement is emitted as a typed ``kind=commbench`` record
 (telemetry/sink.make_commbench_record, cross-checked by
 tools/trace_check.py) and mirrored as ``comm.*`` gauges on /metrics.
 CLI: tools/commlab.py (--smoke / --selfcheck / --update-db).
 """
-import functools
 import json
 import math
 import os
@@ -59,9 +57,9 @@ from .. import monitor
 from .sink import make_commbench_record
 
 __all__ = [
-    "CommDB", "CommMeasureResult", "DEFAULT_DB_PATH", "PAYLOAD_MAX_BYTES",
-    "PAYLOAD_MIN_BYTES", "SWEEP_OPS", "attribution", "db_flag_path",
-    "db_key", "device_peak_ici_bw", "measure_collective", "payload_sweep",
+    "CommDB", "CommMeasureResult", "PAYLOAD_MAX_BYTES",
+    "PAYLOAD_MIN_BYTES", "SWEEP_OPS", "attribution", "db_key",
+    "device_peak_ici_bw", "measure_collective", "payload_sweep",
     "rank_step_skew", "sweep_axes", "sweep_mesh", "sweep_program",
     "wire_bytes",
 ]
@@ -77,12 +75,7 @@ SWEEP_OPS = ("psum", "all_gather", "reduce_scatter", "all_to_all",
 PAYLOAD_MIN_BYTES = 256 * 1024
 PAYLOAD_MAX_BYTES = 256 * 1024 * 1024
 
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-DEFAULT_DB_PATH = os.path.join(_REPO, "tools", "comm_db.json")
-
 DB_SCHEMA = 1
-ENV_FLAG = "PADDLE_TPU_COMM_DB"
 
 # second dim of every swept operand: one full lane register, so payload
 # rounding only ever moves along the first (sharded) dim
@@ -292,10 +285,9 @@ def measure_collective(op, axis, mesh=None, payload_bytes=PAYLOAD_MIN_BYTES,
     """Measure one (op, axis, payload) point on the live mesh:
     median-of-k wall time of the AOT-compiled collective, attributed
     against the planner's peak tables. Deterministic given `clock`
-    (tests inject a fake counter). When the PADDLE_TPU_COMM_DB flag is
-    set (or `db` is passed), the best-known DB latency for this key is
-    attached as `db_ms` — the reference the `comm_bw_degraded` rule
-    judges against."""
+    (tests inject a fake counter). When a CommDB is passed as `db`,
+    its best-known latency for this key is attached as `db_ms` — the
+    reference the `comm_bw_degraded` rule judges against."""
     import jax
     from jax.sharding import NamedSharding
 
@@ -316,9 +308,8 @@ def measure_collective(op, axis, mesh=None, payload_bytes=PAYLOAD_MIN_BYTES,
     n = int(mesh.shape[axis])
     attr = attribution(op, actual, n, time_ms, over_dcn=over_dcn)
     db_ms = None
-    ref = db if db is not None else _flagged_db()
-    if ref is not None:
-        db_ms = ref.best_ms(op, n, actual, backend)
+    if db is not None:
+        db_ms = db.best_ms(op, n, actual, backend)
     res = CommMeasureResult(
         op=op, axis=str(axis), axis_size=n, payload_bytes=actual,
         backend=backend, time_ms=time_ms, compile_ms=compile_ms,
@@ -404,7 +395,7 @@ def rank_step_skew(records):
 
 
 # ---------------------------------------------------------------------------
-# persistent measurement DB (the kernel_db contract)
+# persistent measurement DB (the KernelDB contract)
 # ---------------------------------------------------------------------------
 
 def _finite(v):
@@ -412,12 +403,13 @@ def _finite(v):
 
 
 class CommDB:
-    """tools/comm_db.json: best-known latency per (op, axis-size,
-    payload, backend) key. `update` REFUSES non-finite rows (the
-    bench_gate --update-baseline contract) and with keep_best skips
-    rows slower than the incumbent — losing a race is not an error."""
+    """A JSON file at `path`: best-known latency per (op, axis-size,
+    payload, backend) key. `update` REFUSES non-finite rows (a NaN in
+    the reference would silently disarm every later comparison) and
+    with keep_best skips rows slower than the incumbent — losing a
+    race is not an error."""
 
-    def __init__(self, path=DEFAULT_DB_PATH):
+    def __init__(self, path):
         self.path = path
         self.entries = {}
         self.comment = ""
@@ -508,34 +500,3 @@ class CommDB:
             f.write("\n")
         os.replace(tmp, path)
         return path
-
-
-# ---------------------------------------------------------------------------
-# opt-in DB reference resolution (the kernel_obs flag pattern)
-# ---------------------------------------------------------------------------
-
-def db_flag_path():
-    """The opt-in flag: PADDLE_TPU_COMM_DB unset/empty/'0' -> None (no
-    DB reference attached, the drift rule has no jurisdiction); '1' ->
-    the checked-in tools/comm_db.json; anything else -> that path."""
-    raw = os.environ.get(ENV_FLAG, "").strip()
-    if not raw or raw == "0":
-        return None
-    return DEFAULT_DB_PATH if raw == "1" else raw
-
-
-@functools.lru_cache(maxsize=8)
-def _load_db(path):
-    try:
-        return CommDB(path)
-    except Exception:
-        return None
-
-
-def clear_db_cache():
-    _load_db.cache_clear()
-
-
-def _flagged_db():
-    path = db_flag_path()
-    return _load_db(path) if path else None

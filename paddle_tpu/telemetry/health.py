@@ -104,12 +104,12 @@ class HealthConfig:
                       kernel.
     comm_bw_tol       multiplicative tolerance between a commbench
                       record's measured time_ms and its best-known DB
-                      latency db_ms (telemetry/comm_obs via
-                      tools/comm_db.json): `comm_bw_degraded` fires when
+                      latency db_ms (telemetry/comm_obs.CommDB):
+                      `comm_bw_degraded` fires when
                       measured exceeds (1+tol) x db_ms — ONE-SIDED,
                       faster than the DB is good news the next
                       --update-db rolls in. Latched per op. Records
-                      without db_ms (flag off, or no DB row for the
+                      without db_ms (no DB given, or no row for the
                       key) are exempt: no reference, no jurisdiction.
     straggler_rel     per-rank step-boundary skew rule: a rank whose
                       step_ms exceeds the step's fastest rank by this
@@ -638,7 +638,7 @@ class AnomalyDetector:
         faster is good news the next --update-db rolls into the DB.
         Latched per op (a sweep measures one op at many payloads — one
         page, not N) and re-armed by an in-band measurement. Records
-        without db_ms (PADDLE_TPU_COMM_DB off, or no row for this key)
+        without db_ms (no DB given, or no row for this key)
         are exempt: no reference, no jurisdiction."""
         c = self.config
         found = []

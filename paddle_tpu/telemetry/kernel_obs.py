@@ -21,33 +21,24 @@ module *measures* them:
   fractions, a compute- vs memory-bound verdict, and the
   roofline-predicted time the `kernel_time_drift` rule
   (telemetry/health.py) judges measured time against.
-- **KernelDB** — tools/kernel_db.json: best-known timing + chosen
-  config per (kernel, shape-signature, dtype, backend) key. Rolled
-  forward only by `kernellab --update-db`, which refuses non-finite
-  rows exactly like `bench_gate --update-baseline`.
-- **tune_flash_fwd / tuned_blocks** — the config-search hook: enumerate
-  the (block_q, block_k) candidate space (the absorbed
-  tools/attn_tune.py sweep spec, ATTN_SWEEP_BQ x ATTN_SWEEP_BK) with
-  `kernel_registry.vmem_footprint` (KN502) as the feasibility predicate
-  and measured time as the objective; the winner is KN504
+- **KernelDB** — a JSON file at a path the caller names: best-known
+  timing + chosen config per (kernel, shape-signature, dtype, backend)
+  key. Rolled forward only by `kernellab --db PATH --update-db`, which
+  refuses non-finite rows.
+- **tune_flash_fwd** — the config search: enumerate the
+  (block_q, block_k) candidate space (ATTN_SWEEP_BQ x ATTN_SWEEP_BK)
+  with `kernel_registry.vmem_footprint` (KN502) as the feasibility
+  predicate and measured time as the objective; the winner is KN504
   parity-re-fuzzed (`kernel_lint.check_fallback_parity`) before it may
-  be persisted. `ops/pallas_attention._resolve_blocks` and the
-  decode/MoE block choices consult the DB through `tuned_blocks` /
-  `tuned_param` ONLY when the opt-in env flag below is set, with the
-  hand-tuned defaults as fallback.
-
-Opt-in flag: set ``PADDLE_TPU_KERNEL_DB=/path/to/kernel_db.json`` (or
-``=1`` for the checked-in tools/kernel_db.json) to let kernel call
-sites resolve tuned configs from the DB. Unset (the default), the
-measured hand-tuned policies apply and this module is never imported on
-the hot path.
+  be persisted. The kernels never read the DB: their block policy is
+  a function of the shape alone, and a tuned config reaches them as an
+  edit to that policy.
 
 Every measurement is emitted as a typed ``kind=kernelbench`` record
 (telemetry/sink.make_kernelbench_record, validated by
 tools/trace_check.py) and mirrored as ``kernel.*`` gauges on /metrics.
 CLI: tools/kernellab.py (--smoke / --selfcheck / --tune / --update-db).
 """
-import functools
 import json
 import math
 import os
@@ -61,24 +52,16 @@ from .mfu import device_peak_flops, device_peak_hbm_bw
 from .sink import make_kernelbench_record
 
 __all__ = [
-    "ATTN_SWEEP_BQ", "ATTN_SWEEP_BK", "DEFAULT_DB_PATH", "KernelDB",
-    "MeasureResult", "db_flag_path", "db_key", "measure_kernel",
-    "measure_registry", "roofline", "shape_signature", "traced_cost",
-    "tune_flash_fwd", "tuned_blocks", "tuned_param",
+    "ATTN_SWEEP_BQ", "ATTN_SWEEP_BK", "KernelDB", "MeasureResult",
+    "db_key", "measure_kernel", "measure_registry", "roofline",
+    "shape_signature", "traced_cost", "tune_flash_fwd",
 ]
 
-# the flash-attention sweep space, absorbed verbatim from the round-5
-# tools/attn_tune.py harness so the tuner and the historical sweeps can
-# never drift (attn_tune imports these back)
+# the flash-attention sweep space tune_flash_fwd searches
 ATTN_SWEEP_BQ = (256, 512, 1024, 2048)
 ATTN_SWEEP_BK = (512, 1024, 2048)
 
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-DEFAULT_DB_PATH = os.path.join(_REPO, "tools", "kernel_db.json")
-
 DB_SCHEMA = 1
-ENV_FLAG = "PADDLE_TPU_KERNEL_DB"
 
 
 # ---------------------------------------------------------------------------
@@ -342,13 +325,12 @@ def _finite(v):
 
 
 class KernelDB:
-    """tools/kernel_db.json: best-known timing + chosen config per
+    """A JSON file at `path`: best-known timing + chosen config per
     (kernel, shape-signature, dtype, backend) key. `update` REFUSES
-    non-finite rows (the bench_gate --update-baseline contract): a NaN
-    that slips into the baseline would silently disarm every future
-    comparison against it."""
+    non-finite rows: a NaN that slips into the baseline would silently
+    disarm every future comparison against it."""
 
-    def __init__(self, path=DEFAULT_DB_PATH):
+    def __init__(self, path):
         self.path = path
         self.entries = {}
         self.comment = ""
@@ -440,80 +422,6 @@ class KernelDB:
             f.write("\n")
         os.replace(tmp, path)
         return path
-
-
-# ---------------------------------------------------------------------------
-# opt-in DB-backed config resolution (the _resolve_blocks hook)
-# ---------------------------------------------------------------------------
-
-def db_flag_path():
-    """The opt-in flag: PADDLE_TPU_KERNEL_DB unset/empty/'0' -> None
-    (hand-tuned defaults, no DB I/O on the hot path); '1' -> the
-    checked-in tools/kernel_db.json; anything else -> that path."""
-    raw = os.environ.get(ENV_FLAG, "").strip()
-    if not raw or raw == "0":
-        return None
-    return DEFAULT_DB_PATH if raw == "1" else raw
-
-
-@functools.lru_cache(maxsize=8)
-def _load_db(path):
-    try:
-        return KernelDB(path)
-    except Exception:
-        return None
-
-
-def clear_db_cache():
-    _load_db.cache_clear()
-
-
-def tuned_param(kernel, param, match=None, validate=None):
-    """Resolve one tuned config value for `kernel` from the flagged DB,
-    or None (caller keeps its hand-tuned default). `match` narrows on
-    entry config keys (e.g. {'sq': 16384}); `validate` is a predicate
-    the value must pass (feasibility re-checked at the call site — a DB
-    edited by hand can never force an infeasible block). Of the
-    matching entries, the fastest wins."""
-    path = db_flag_path()
-    if path is None:
-        return None
-    db = _load_db(path)
-    if db is None:
-        return None
-    best_v, best_ms = None, None
-    for _, e in db.lookup(kernel):
-        cfg = e.get("config") or {}
-        if param not in cfg:
-            continue
-        if match and any(cfg.get(k) != v for k, v in match.items()):
-            continue
-        v = cfg[param]
-        if validate is not None and not validate(v):
-            continue
-        ms = e.get("best_ms")
-        if not _finite(ms):
-            continue
-        if best_ms is None or ms < best_ms:
-            best_v, best_ms = v, ms
-    return best_v
-
-
-def tuned_blocks(family, sq, for_bwd=False):
-    """The `_resolve_blocks` consult: (block_q, block_k) for the flash
-    family ('flash_fwd' / 'flash_bwd') at sequence length sq, or None.
-    Entries are written by `kernellab --tune` with config
-    {'sq': sq, 'block_q': bq, 'block_k': bk}."""
-    kernel = "flash_bwd" if for_bwd else "flash_fwd"
-    if family:
-        kernel = family
-    bq = tuned_param(kernel, "block_q", match={"sq": int(sq)},
-                     validate=lambda v: isinstance(v, int) and v >= 128)
-    bk = tuned_param(kernel, "block_k", match={"sq": int(sq)},
-                     validate=lambda v: isinstance(v, int) and v >= 128)
-    if bq is None or bk is None:
-        return None
-    return bq, bk
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,8 @@
 """Structured metrics sink: one JSONL record per step + Chrome trace export.
 
 This is the serialization half of the flight recorder. Every consumer of
-training/bench metrics in the repo (TelemetryRecorder, TelemetryCallback,
-bench.py phases, tools/trace_check.py) speaks the same schema, so a
-`BENCH_*.json` entry and a training-run log are directly comparable.
+training metrics in the repo (TelemetryRecorder, TelemetryCallback,
+tools/trace_check.py) speaks the same schema.
 
 Reference analogs: the profiler's `profiler.proto` serialized output and
 `tools/CrossStackProfiler`'s per-rank chrome-trace merge; JAX's
@@ -537,68 +536,6 @@ def make_fleet_record(event, rank=0, replica=None, to_replica=None,
     return rec
 
 
-BENCH_RECORD_KEYS = ("schema", "kind", "metric", "value")
-
-# the SERVING bench-metric family (bench_serving.py over
-# paddle_tpu/serving): one source of truth for metric names + gate
-# directions so the bench emitter, the rolling baseline
-# (tools/bench_baseline.json), and tools/trace_check.py's serving
-# cross-rules cannot drift. Directions are the bench_gate vocabulary:
-# 'higher' fails when the value drops, 'lower' when it rises (latency),
-# 'info' is recorded but never gated.
-SERVING_BENCH_METRICS = {
-    "serving.single_stream_tokens_per_sec": "higher",
-    "serving.throughput_tokens_per_sec": "higher",
-    "serving.throughput_vs_single": "higher",
-    "serving.ttft_p50_ms": "lower",
-    "serving.ttft_p99_ms": "lower",
-    "serving.tpot_p50_ms": "lower",
-    "serving.tpot_p99_ms": "lower",
-    "serving.requests": "info",
-    "serving.preemptions": "info",
-    "serving.kv_block_utilization_peak": "info",
-    # the serving-resilience drill's rated-load leg (tools/
-    # serving_drill.py --rated-only): throughput at rated load with SLO
-    # deadlines armed, queue-wait p99 under admission control, and the
-    # shed count — direction 'lower' over a 0.0 baseline means ANY shed
-    # at rated load fails the gate (the SLO sweep must run shed-free)
-    "serving.rated_throughput_tokens_per_sec": "higher",
-    "serving.rated_queue_wait_ms_p99": "lower",
-    "serving.rated_shed": "lower",
-    # the prefix-sharing sweep (bench_serving.py shared-prefix phase):
-    # N requests over K templates through a warm prefix-cache engine
-    # vs a cold-cache control with identical token streams. hit_rate
-    # and tokens_saved are deterministic for the seeded workload
-    # (direction 'higher': a drop means the matcher stopped finding
-    # prefixes it used to); tokens_offered is the denominator that
-    # makes tokens_saved auditable (info); the TTFT rows quote the
-    # WARM engine, and the speedup row is warm-vs-cold at p50 — the
-    # whole point of the cache
-    "serving.prefix_hit_rate": "higher",
-    "serving.prefill_tokens_saved": "higher",
-    "serving.prefill_tokens_offered": "info",
-    "serving.prefix_ttft_p50_ms": "lower",
-    "serving.prefix_ttft_p99_ms": "lower",
-    "serving.prefix_ttft_speedup": "higher",
-    "serving.prefix_tokens_recomputed_per_request": "lower",
-    # the request tracer's cost (bench_serving.py trace_overhead_phase):
-    # rated-level throughput with tracing on vs off as a fraction lost,
-    # direction 'lower' so bench_gate holds the tracer to its <=2%
-    # budget once a device round seeds the row — a tracer that starts
-    # doing per-token host work fails the gate like any regression
-    "serving.trace_overhead_frac": "lower",
-    # the fleet-tier rated leg (bench_serving.py --fleet N): aggregate
-    # rated throughput over N replicas, and scaling efficiency —
-    # aggregate / (N x the single-replica rated figure measured in the
-    # same run). Direction 'higher' on both: a router whose efficiency
-    # decays is paying routing/affinity overhead the ROADMAP's
-    # ~linear-scaling target does not allow. replicas is the
-    # denominator that makes the efficiency row auditable (info).
-    "fleet.rated_throughput_tokens_per_sec": "higher",
-    "fleet.scaling_efficiency": "higher",
-    "fleet.replicas": "info",
-}
-
 # required keys of a Kernel Doctor result record (analysis/kernel_lint
 # via tools/kerneldoctor.py); optional: module, fn, grid, vmem_bytes,
 # vmem_budget, flops_declared, flops_counted, has_fallback
@@ -744,9 +681,8 @@ def make_kernelbench_record(kernel, sig, backend, kernel_ms, rank=0,
     execute median (compile_ms rides separately, the PR-4 split);
     roofline fractions are achieved/peak in [0, 1]; `predicted_ms` is
     the roofline floor the kernel_time_drift rule judges against.
-    Non-finite timings become None + an error note, like
-    make_bench_record — the validators fail them loudly rather than
-    letting a NaN ride the ledger."""
+    Non-finite timings become None + an error note — the validators
+    fail them loudly rather than letting a NaN ride the ledger."""
     def _clean(v):
         if v is None:
             return None, False
@@ -836,7 +772,7 @@ def make_commbench_record(op, axis, axis_size, payload_bytes, backend,
     `DCN_BW_BYTES` peaks; `predicted_ms` is the analytic floor
     `calibration_from_comm_records` ratios against; `db_ms` is the
     best-known DB latency the comm_bw_degraded rule judges against
-    (absent when the PADDLE_TPU_COMM_DB flag is off — no reference, no
+    (absent when the measurement was given no DB — no reference, no
     jurisdiction). Non-finite timings become None + an error note, like
     make_kernelbench_record — a NaN never rides the ledger silently."""
     def _clean(v):
@@ -1061,43 +997,9 @@ def make_plan_record(model, chosen, candidates_considered,
     return rec
 
 
-def make_bench_record(metric, value, unit=None, rank=0, device=None,
-                      bench_round=None, baseline=None, **extra):
-    """One benchmark RESULT as a first-class typed record (kind='bench')
-    — the perf-regression gate's unit of account (tools/bench_gate.py).
-    Distinct from kind='phase' (a phase's raw metric dict): a bench
-    record is one tracked scalar with its identity (metric name, device,
-    round) so baselines diff record-against-record. Non-finite values
-    are kept as None + an error note (the gate fails them loudly)."""
-    rec = {
-        "schema": SCHEMA_VERSION,
-        "kind": "bench",
-        "rank": int(rank),
-        "metric": str(metric),
-    }
-    bad = isinstance(value, float) and (value != value or
-                                        value in (float("inf"),
-                                                  float("-inf")))
-    rec["value"] = None if bad or value is None else float(value)
-    if bad:
-        rec["error"] = f"non-finite value {value!r}"
-    if unit is not None:
-        rec["unit"] = str(unit)
-    if device is not None:
-        rec["device"] = str(device)
-    if bench_round is not None:
-        rec["round"] = int(bench_round)
-    if baseline is not None:
-        rec["baseline"] = float(baseline)
-    for k, v in extra.items():
-        if v is not None:
-            rec[k] = v
-    return rec
-
-
 def make_phase_record(phase, metrics, rank=0):
-    """A bench-phase record (bench.py): same envelope, kind='phase', the
-    phase's metric dict under 'metrics'. Non-finite floats become None —
+    """A phase record: same envelope, kind='phase', the phase's metric
+    dict under 'metrics'. Non-finite floats become None —
     json.dumps would otherwise emit bare NaN/Infinity tokens, which are
     invalid for strict JSON consumers (jq, Chrome)."""
     clean = {}
@@ -1223,20 +1125,6 @@ def validate_step_record(rec):
         if cause is not None and (not isinstance(cause, list) or
                                   not all(isinstance(c, str) for c in cause)):
             problems.append(f"'cause' not a list of strings: {cause!r}")
-        return problems
-    if kind == "bench":
-        for key in BENCH_RECORD_KEYS:
-            if key not in rec:
-                problems.append(f"bench record missing '{key}'")
-        v = rec.get("value")
-        if v is not None and not isinstance(v, (int, float)):
-            problems.append(f"'value' not numeric: {v!r}")
-        if isinstance(v, float) and (v != v or v in (float("inf"),
-                                                     float("-inf"))):
-            problems.append(f"'value' non-finite: {v!r}")
-        if v is None and "error" not in rec:
-            problems.append("bench record with null value carries no "
-                            "'error' note")
         return problems
     if kind == "kernel_lint":
         for key in KERNEL_RECORD_KEYS:

@@ -9,6 +9,7 @@ Resolution order (reference analog: the prebuilt-vs-source duality of
 """
 import os
 import subprocess
+import tempfile
 import threading
 
 _lock = threading.Lock()
@@ -44,8 +45,20 @@ def native_lib_path(name, source=None, extra_flags=()):
                 os.path.getmtime(so) < os.path.getmtime(src)):
             os.makedirs(out_dir, exist_ok=True)
             inc = os.path.join(repo_csrc(), "third_party")
-            subprocess.run(["g++", *_FLAGS, f"-I{inc}", src,
-                            "-o", so + ".tmp", *extra_flags],
-                           check=True, capture_output=True)
-            os.replace(so + ".tmp", so)
+            # _lock orders this process's threads only: other processes
+            # (xdist workers, loader children) build at the same time, so
+            # each links into a name of its own and the rename publishes
+            # a whole library whichever finishes last.
+            fd, tmp = tempfile.mkstemp(prefix=f"lib{name}.", suffix=".so.tmp",
+                                       dir=out_dir)
+            os.close(fd)
+            try:
+                subprocess.run(["g++", *_FLAGS, f"-I{inc}", src,
+                                "-o", tmp, *extra_flags],
+                               check=True, capture_output=True)
+                os.chmod(tmp, 0o755)    # mkstemp made it 0600
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
     return so

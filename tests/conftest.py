@@ -13,8 +13,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 # The tier-1 verify pass runs the whole suite under a hard wall clock on a
 # small shared host, and most of that budget is XLA compile passes that buy
 # nothing for tiny test graphs: backend optimization level 1 cuts suite wall
-# time ~20% with identical pass/fail results (bench.py is unaffected — this
-# is pytest-only).  Opt out (e.g. to chase an optimization-sensitive
+# time ~20% with identical pass/fail results (pytest-only).  Opt out (e.g. to chase an optimization-sensitive
 # miscompile) with PADDLE_TPU_TEST_FULL_XLA_OPT=1 or an explicit
 # --xla_backend_optimization_level in XLA_FLAGS.
 if (not os.environ.get("PADDLE_TPU_TEST_FULL_XLA_OPT")

@@ -207,7 +207,7 @@ def test_commbench_cross_rules_catch_doctored_claims(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# CommDB: round-trip, keep-best, refuse non-finite, opt-in flag
+# CommDB: round-trip, keep-best, refuse non-finite
 # ---------------------------------------------------------------------------
 
 def test_comm_db_roundtrip_keep_best_refuse(tmp_path):
@@ -239,21 +239,8 @@ def test_comm_db_roundtrip_keep_best_refuse(tmp_path):
     assert reloaded.entries == db.entries
 
 
-def test_db_flag_opt_in(tmp_path, monkeypatch):
-    monkeypatch.delenv(comm_obs.ENV_FLAG, raising=False)
-    comm_obs.clear_db_cache()
-    assert comm_obs.db_flag_path() is None
-    monkeypatch.setenv(comm_obs.ENV_FLAG, "0")
-    assert comm_obs.db_flag_path() is None
-    monkeypatch.setenv(comm_obs.ENV_FLAG, "1")
-    assert comm_obs.db_flag_path() == comm_obs.DEFAULT_DB_PATH
-    monkeypatch.setenv(comm_obs.ENV_FLAG, str(tmp_path / "x.json"))
-    assert comm_obs.db_flag_path() == str(tmp_path / "x.json")
-    comm_obs.clear_db_cache()
-
-
 def test_measure_attaches_db_reference_when_db_passed(tmp_path):
-    """An explicit db= (or the env flag) makes the measurement carry
+    """An explicit db= makes the measurement carry
     db_ms — the reference the comm_bw_degraded rule judges against,
     riding ON the record so replay judges identically."""
     mesh = env.build_mesh(dp=2, mp=4)
@@ -453,7 +440,7 @@ def test_straggler_exemptions():
 def test_rank_step_skew_offline():
     recs = [_step_rec(0, 0, 100.0), _step_rec(0, 1, 160.0),
             _step_rec(1, 0, 90.0),                       # single rank
-            {"kind": "bench", "metric": "x", "value": 1}]
+            {"kind": "phase", "phase": "x", "metrics": {}}]
     skew = comm_obs.rank_step_skew(recs)
     assert skew == {0: {0: 0.0, 1: 60.0}}
 
@@ -628,12 +615,9 @@ def test_commlab_smoke_subprocess(tmp_path):
         env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
     recs = [json.loads(x) for x in open(tele)]
-    comm = [r for r in recs if r.get("kind") == "commbench"]
-    bench = [r for r in recs if r.get("kind") == "bench"]
-    # every (op, axis) measured; one smoke_ms bench row per op
-    assert {(r["op"], r["axis"]) for r in comm} \
+    assert {r.get("kind") for r in recs} == {"commbench"}
+    # every (op, axis) measured
+    assert {(r["op"], r["axis"]) for r in recs} \
         == {(op, ax) for op in comm_obs.SWEEP_OPS for ax in ("dp", "mp")}
-    assert {r["metric"] for r in bench} \
-        == {f"comm.{op}.smoke_ms" for op in comm_obs.SWEEP_OPS}
     problems, _ = trace_check.check_pair(tele)
     assert problems == []

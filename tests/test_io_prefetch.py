@@ -6,7 +6,6 @@ invariant (ADVICE.md round-5 debt; since the Kernel Doctor landed it
 is asserted through KN501 rather than a source grep).
 """
 import inspect
-import os
 import threading
 import time
 
@@ -236,21 +235,6 @@ def test_device_iterator_close_joins_stage_and_leaves_queue_empty():
             f"trial {trial}: {it._q.qsize()} batch(es) left pinned"
         with pytest.raises(StopIteration):
             next(it)
-
-
-def test_bench_gate_update_baseline_refuses_null_metrics(tmp_path):
-    """--update-baseline on a run with a null tracked value must refuse:
-    rolling it forward would silently drop the metric from gate
-    coverage (the regressed specimen carries exactly such a null)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate", os.path.join(os.path.dirname(__file__), "..",
-                                   "tools", "bench_gate.py"))
-    bg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bg)
-    rc = bg.update_baseline(str(bg.SPECIMEN), str(tmp_path / "base.json"))
-    assert rc == 4
-    assert not (tmp_path / "base.json").exists()
 
 
 def test_device_iterator_repeated_stop_and_post_close_next():

@@ -527,8 +527,8 @@ def test_kerneldoctor_cli_telemetry(tmp_path):
     assert out.returncode == 0, out.stdout + out.stderr
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import trace_check
-    *counts, problems = trace_check.check_metrics_jsonl(str(tele))
+    problems, stats = trace_check.check_pair(str(tele))
     assert problems == []
-    assert counts[8] >= 12           # n_kernel records
+    assert stats["n_kernel"] >= 12
     rep = json.loads(report.read_text())
     assert rep["summary"]["n"] == 0

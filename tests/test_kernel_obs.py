@@ -1,8 +1,7 @@
 """Kernel observatory (paddle_tpu/telemetry/kernel_obs.py + the
 kernellab CLI): injectable-clock timing determinism, hand-computed
 roofline fractions, the persistent timing DB (round-trip, non-finite
-refusal, key stability), the flag-gated tuned-config resolution with
-hand-tuned defaults as fallback, KN504 re-fuzz on tuned configs, the
+refusal, key stability), KN504 re-fuzz on tuned configs, the
 kernel_time_drift rule in both directions, the kind=kernelbench record
 schema + trace_check cross-rules, and the CLI gates."""
 import itertools
@@ -19,7 +18,7 @@ from paddle_tpu.telemetry import kernel_obs, sink
 from paddle_tpu.telemetry.health import AnomalyDetector, HealthConfig
 from paddle_tpu.telemetry.kernel_obs import (
     KernelDB, MeasureResult, db_key, measure_kernel, roofline,
-    shape_signature, tuned_blocks, tuned_param)
+    shape_signature)
 from paddle_tpu.ops.kernel_registry import get_kernel
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -28,7 +27,7 @@ import trace_check  # noqa: E402
 
 # registration is import-driven: pull in every kernel-owning module
 from paddle_tpu.moe import kernels as _moe_kernels        # noqa: F401,E402
-from paddle_tpu.ops import pallas_attention               # noqa: E402
+from paddle_tpu.ops import pallas_attention               # noqa: F401,E402
 from paddle_tpu.ops import pallas_decode                  # noqa: F401,E402
 from paddle_tpu.ops import pallas_int8                    # noqa: F401,E402
 from paddle_tpu.ops import pallas_layernorm               # noqa: F401,E402
@@ -278,104 +277,6 @@ def test_db_tuple_entry_backfills_axes_from_key(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# flag-gated tuned-config resolution
-# ---------------------------------------------------------------------------
-
-def _write_db(tmp_path, entries):
-    db = KernelDB(str(tmp_path / "db.json"))
-    db.update(entries)
-    db.save()
-    return db.path
-
-
-@pytest.fixture
-def clean_flag(monkeypatch):
-    monkeypatch.delenv(kernel_obs.ENV_FLAG, raising=False)
-    kernel_obs.clear_db_cache()
-    yield monkeypatch
-    kernel_obs.clear_db_cache()
-
-
-def test_tuned_param_none_without_flag(clean_flag, tmp_path):
-    _write_db(tmp_path, [(db_key("k1", "s", "f32", "cpu"),
-                          {"best_ms": 1.0, "config": {"p": 7}})])
-    assert tuned_param("k1", "p") is None
-
-
-def test_tuned_param_resolves_fastest_match(clean_flag, tmp_path):
-    path = _write_db(tmp_path, [
-        (db_key("k1", "s1", "f32", "cpu"),
-         {"best_ms": 5.0, "config": {"p": 7, "sq": 1024}}),
-        (db_key("k1", "s2", "f32", "cpu"),
-         {"best_ms": 1.0, "config": {"p": 9, "sq": 1024}}),
-        (db_key("k1", "s3", "f32", "cpu"),
-         {"best_ms": 0.1, "config": {"p": 3, "sq": 2048}}),
-    ])
-    clean_flag.setenv(kernel_obs.ENV_FLAG, path)
-    kernel_obs.clear_db_cache()
-    # fastest entry wins within the match; other sq excluded
-    assert tuned_param("k1", "p", match={"sq": 1024}) == 9
-    # the validate predicate is the call site's feasibility re-check:
-    # a hand-edited DB can never force an infeasible value through
-    assert tuned_param("k1", "p", match={"sq": 1024},
-                       validate=lambda v: v % 2 == 0) is None
-    assert tuned_param("nope", "p") is None
-
-
-def test_tuned_blocks_requires_both_blocks(clean_flag, tmp_path):
-    path = _write_db(tmp_path, [
-        (db_key("flash_fwd", "s", "f32", "cpu"),
-         {"best_ms": 1.0, "config": {"sq": 512, "block_q": 256}})])
-    clean_flag.setenv(kernel_obs.ENV_FLAG, path)
-    kernel_obs.clear_db_cache()
-    assert tuned_blocks(None, 512) is None   # block_k missing
-    db2 = KernelDB(str(tmp_path / "db2.json"))
-    db2.update([(db_key("flash_fwd", "s", "f32", "cpu"),
-                 {"best_ms": 1.0,
-                  "config": {"sq": 512, "block_q": 256,
-                             "block_k": 512}})])
-    path2 = db2.save()
-    clean_flag.setenv(kernel_obs.ENV_FLAG, path2)
-    kernel_obs.clear_db_cache()
-    assert tuned_blocks(None, 512) == (256, 512)
-    assert tuned_blocks(None, 4096) is None  # other sq: no entry
-
-
-def test_resolve_blocks_defaults_without_flag(clean_flag):
-    # hand-tuned defaults hold when the flag is off...
-    assert pallas_attention._resolve_blocks(16384, None, None) == \
-        (1024, 1024)
-    assert pallas_attention._resolve_blocks(16384, None, None,
-                                            for_bwd=True) == (512, 1024)
-
-
-def test_resolve_blocks_consults_db_explicit_wins(clean_flag, tmp_path):
-    path = _write_db(tmp_path, [
-        (db_key("flash_fwd", "s", "f32", "cpu"),
-         {"best_ms": 1.0,
-          "config": {"sq": 1024, "block_q": 256, "block_k": 512}})])
-    clean_flag.setenv(kernel_obs.ENV_FLAG, path)
-    kernel_obs.clear_db_cache()
-    assert pallas_attention._resolve_blocks(1024, None, None) == \
-        (256, 512)
-    # ...explicit caller blocks always beat the DB
-    assert pallas_attention._resolve_blocks(1024, 2048, 2048) == \
-        (2048, 2048)
-    # unreadable DB path degrades to the defaults, never raises
-    clean_flag.setenv(kernel_obs.ENV_FLAG,
-                      str(tmp_path / "missing.json"))
-    kernel_obs.clear_db_cache()
-    assert pallas_attention._resolve_blocks(1024, None, None) == \
-        (1024, 1024)
-
-
-def test_moe_resolve_rows_default_without_flag(clean_flag):
-    from paddle_tpu.moe import kernels as mk
-    assert mk._resolve_rows("moe_gather", 256, np.float32, 1024) == \
-        mk._BLOCK_ROWS
-
-
-# ---------------------------------------------------------------------------
 # config search
 # ---------------------------------------------------------------------------
 
@@ -456,7 +357,6 @@ def test_drift_specimen_schema_valid_and_trips():
 @pytest.mark.slow
 def test_kernellab_selfcheck_cli():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop(kernel_obs.ENV_FLAG, None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "kernellab.py"),
          "--selfcheck"],
@@ -468,7 +368,6 @@ def test_kernellab_selfcheck_cli():
 @pytest.mark.slow
 def test_kernellab_smoke_cli(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop(kernel_obs.ENV_FLAG, None)
     out = str(tmp_path / "smoke.jsonl")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "kernellab.py"),
@@ -476,9 +375,6 @@ def test_kernellab_smoke_cli(tmp_path):
         capture_output=True, text=True, env=env, cwd=REPO, timeout=600)
     assert proc.returncode == 0, proc.stderr + proc.stdout
     recs = [json.loads(line) for line in open(out)]
-    kb = [r for r in recs if r["kind"] == "kernelbench"]
-    bench = [r for r in recs if r["kind"] == "bench"]
     from paddle_tpu.ops.kernel_registry import registered_kernels
-    assert len(kb) == len(registered_kernels())
-    assert {r["metric"] for r in bench} == \
-        {f"kernel.{r['kernel']}.smoke_ms" for r in kb}
+    assert len(recs) == len(registered_kernels())
+    assert {r["kind"] for r in recs} == {"kernelbench"}

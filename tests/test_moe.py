@@ -453,8 +453,8 @@ def test_128k_preset_sp_layout_passes_battery():
 
 def test_128k_preset_tiny_dims_trains_on_sp_mesh():
     """The preset's ring+remat composition runs a finite sharded train
-    step on a dp x sp mesh at test dims (the full-size run is a TPU
-    bench point — bench.py ringattn_128k)."""
+    step on a dp x sp mesh at test dims (the full size has not been run on the
+    chip)."""
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
     cfg = GPTConfig.gpt3_1_3b_128k(
         hidden_size=32, num_layers=2, num_heads=4, max_seq_len=64,

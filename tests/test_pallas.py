@@ -214,3 +214,17 @@ def test_add_layer_norm_dispatcher_cpu_path():
     ref = (s - s.mean(-1, keepdims=True)) / np.sqrt(
         s.var(-1, keepdims=True) + 1e-5)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("sq,block_q,block_k,for_bwd,want", [
+    (512, None, None, False, (1024, 1024)),
+    (8192, None, None, True, (1024, 1024)),     # the backward's last full bq
+    (16384, None, None, False, (1024, 1024)),   # the forward has no cap
+    (16384, None, None, True, (512, 1024)),     # dq accumulator caps bq
+    (16384, 2048, None, True, (2048, 1024)),    # an explicit block wins...
+    (1024, None, 512, False, (1024, 512)),      # ...each on its own
+])
+def test_block_policy_is_a_table_of_the_shape(sq, block_q, block_k,
+                                              for_bwd, want):
+    from paddle_tpu.ops.pallas_attention import _resolve_blocks
+    assert _resolve_blocks(sq, block_q, block_k, for_bwd) == want

@@ -6,7 +6,7 @@ runs, and the only trace is the planner's own cached proxy jaxpr.
 Covers: abstract-param/rule parity against the live GPT model (the
 pin that keeps placement-as-data and placement-in-code identical),
 the 1.3B v5p-32 and 13B two-level 2x8 parity against the hand-written
-MULTICHIP_r05 plans, search determinism, infeasibility with a named
+plans of the pre-chip dry runs, search determinism, infeasibility with a named
 binding constraint, kind=plan telemetry records through
 tools/trace_check.py (incl. the >15% projection-drift gate),
 observatory calibration, and the distributed-layer wiring
@@ -75,13 +75,13 @@ def test_meshspec_quacks_like_a_mesh():
 
 
 # ---------------------------------------------------------------------------
-# parity vs the hand-written MULTICHIP_r05 plans
+# parity vs the hand-written plans of the pre-chip dry runs
 # ---------------------------------------------------------------------------
 
 def test_plan_1_3b_v5p32_beats_handwritten():
     """Acceptance pin: plan() on GPT-1.3B / v5p-32 is Graph-Doctor
     clean and beats the hand-written dp=4/mp=2/pp=2/zero-1/mb=2 spec
-    (MULTICHIP_r05 part 3) on BOTH projected per-device HBM and
+    on BOTH projected per-device HBM and
     modeled cost."""
     cfg = GPTConfig.gpt3_1_3b(max_seq_len=2048)
     chosen = plan(cfg, 32, chip="v5p", verify="full")
@@ -101,7 +101,7 @@ def test_plan_1_3b_v5p32_beats_handwritten():
 
 
 def test_plan_13b_two_level_2x8_reproduces_handwritten():
-    """The MULTICHIP_r05 part-4 plan — 13B on 2 slices x 8 chips, dp
+    """The hand-written plan for 13B on 2 slices x 8 chips, dp
     over the slice (DCN) axis, mp=8 inner, ZeRO-3 — comes back out of
     the planner when given the fixed topology, at hand-written HBM and
     cost or better."""
@@ -202,7 +202,7 @@ def test_plan_record_roundtrip_and_drift_gate(tmp_path):
     good = tmp_path / "plans.jsonl"
     good.write_text(json.dumps(rec) + "\n")
     *counts, problems = _trace_check(str(good))
-    assert problems == [] and counts[5] == 1
+    assert problems == [] and counts[4] == 1       # n_plan
 
     # measured-vs-projected drift >15% must fail (the PR-4 rule
     # mirrored onto the planner's own numbers)

@@ -394,9 +394,11 @@ class TestEngineIntegration:
             assert "admit" in kinds and "decode" in kinds
 
     def test_trace_check_clean(self, traced_run):
-        *counts, probs = _trace_check_path(traced_run["path"])
+        sys.path.insert(0, TOOLS)
+        import trace_check
+        probs, stats = trace_check.check_pair(traced_run["path"])
         assert probs == []
-        assert counts[9] == 3               # n_reqtrace
+        assert stats["n_reqtrace"] == 3
 
     def test_zero_recompiles_under_tracing(self, traced_run):
         fams = {}
@@ -436,12 +438,6 @@ class TestEngineIntegration:
         eng.run_until_idle()
         assert len(h.output_tokens) == 3
         assert h._req.trace is None
-
-
-def _trace_check_path(path):
-    sys.path.insert(0, TOOLS)
-    import trace_check
-    return trace_check.check_metrics_jsonl(path)
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,7 @@
 """Continuous-batching serving engine (paddle_tpu/serving): block-pool
 allocator, paged-vs-dense attention parity, engine-vs-run_generate
 token parity (the numerics contract the CPU smoke gates), eviction
-recompute, sampling independence, Config routing, and the serving
-bench-record family rules."""
+recompute, sampling independence and Config routing."""
 import json
 
 import numpy as np
@@ -570,66 +569,6 @@ def test_quantize_for_decode_idempotent_and_loud():
     q = [m for m in model.sublayers()
          if isinstance(m, WeightOnlyInt8Linear)]
     assert len(q) == 8
-
-
-# ---------------------------------------------------------------------------
-# serving bench-record family (trace_check rules)
-# ---------------------------------------------------------------------------
-
-def _bench_line(metric, value, unit="ms", device="cpu"):
-    from paddle_tpu.telemetry import make_bench_record
-    return make_bench_record(metric, value, unit=unit, device=device)
-
-
-def test_trace_check_serving_family_rules(tmp_path):
-    import sys as _sys
-    import os as _os
-    _sys.path.insert(0, _os.path.join(_os.path.dirname(__file__), "..",
-                                      "tools"))
-    import trace_check
-
-    # clean serving records pass
-    good = tmp_path / "good.jsonl"
-    recs = [_bench_line("serving.ttft_p50_ms", 10.0),
-            _bench_line("serving.ttft_p99_ms", 30.0),
-            _bench_line("serving.throughput_tokens_per_sec", 100.0,
-                        unit="tokens/sec")]
-    good.write_text("\n".join(json.dumps(r) for r in recs) + "\n")
-    problems, stats = trace_check.check_pair(str(good))
-    assert problems == [] and stats["n_bench"] == 3
-
-    # inverted percentiles fail
-    bad = tmp_path / "bad.jsonl"
-    recs = [_bench_line("serving.tpot_p50_ms", 50.0),
-            _bench_line("serving.tpot_p99_ms", 5.0)]
-    bad.write_text("\n".join(json.dumps(r) for r in recs) + "\n")
-    problems, _ = trace_check.check_pair(str(bad))
-    assert any("inverted" in p for p in problems)
-
-    # undeclared serving metric + missing unit fail
-    bad2 = tmp_path / "bad2.jsonl"
-    recs = [_bench_line("serving.made_up_metric", 1.0),
-            _bench_line("serving.ttft_p99_ms", 1.0, unit=None)]
-    bad2.write_text("\n".join(json.dumps(r) for r in recs) + "\n")
-    problems, _ = trace_check.check_pair(str(bad2))
-    assert any("not in the declared family" in p for p in problems)
-    assert any("carries no unit" in p for p in problems)
-
-
-def test_serving_metrics_in_baseline_and_declared_family_agree():
-    """The rolling baseline's serving rows must be exactly the declared
-    family with matching directions — a drift here silently un-gates a
-    metric. The family spans two prefixes: serving.* (one engine) and
-    fleet.* (the bench_serving --fleet leg over N replicas)."""
-    import os as _os
-    from paddle_tpu.telemetry.sink import SERVING_BENCH_METRICS
-    base = json.load(open(_os.path.join(
-        _os.path.dirname(__file__), "..", "tools", "bench_baseline.json")))
-    rows = {k: v for k, v in base["metrics"].items()
-            if k.startswith(("serving.", "fleet."))}
-    assert set(rows) == set(SERVING_BENCH_METRICS)
-    for name, spec in rows.items():
-        assert spec["direction"] == SERVING_BENCH_METRICS[name], name
 
 
 @pytest.mark.slow

@@ -10,8 +10,7 @@ divergence with streams bit-identical to cold-cache runs, preemption
 and warm-restart recompute-replay over prefix hits, index flush on
 arena rebuild and drain), the `flash_prefill_chunk` kernel's
 registration and fallback parity, the enable_prefix_cache knob
-routing, telemetry fields + trace_check cross-rules, and the seeded
-determinism of the bench's shared-prefix phase.
+routing, and telemetry fields + trace_check cross-rules.
 """
 import os
 import sys
@@ -700,7 +699,7 @@ class TestFlashPrefillKernel:
 
 
 # ---------------------------------------------------------------------------
-# telemetry cross-rules + bench determinism
+# telemetry cross-rules
 # ---------------------------------------------------------------------------
 
 def test_trace_check_prefix_cross_rules():
@@ -733,22 +732,3 @@ def test_trace_check_prefix_cross_rules():
                                           "expired": 0},
                                   prefix_blocks_shared=2)]
     assert any("SHARED" in p for p in check(shared))
-
-
-@pytest.mark.slow
-def test_shared_prefix_bench_phase_seeded_determinism():
-    """Two runs of the bench's shared-prefix phase with the same seed
-    must produce identical streams and identical hit accounting."""
-    sys.path.insert(0, os.path.dirname(TOOLS))
-    import bench_serving
-    model = _small_gpt(seed=7)
-    a = bench_serving.shared_prefix_phase(model, on_tpu=False, seed=0,
-                                          n_requests=6)
-    b = bench_serving.shared_prefix_phase(model, on_tpu=False, seed=0,
-                                          n_requests=6)
-    assert a["_streams"] == b["_streams"]
-    for key in ("serving.prefix_hit_rate", "serving.prefill_tokens_saved",
-                "serving.prefill_tokens_offered", "prefix_hits"):
-        assert a[key] == b[key], key
-    assert a["prefix_streams_identical"] and b["prefix_streams_identical"]
-    assert a["serving.prefix_hit_rate"] > 0

@@ -298,19 +298,6 @@ def test_drill_specimens_are_caught():
     assert not any("still allocated" in p for p in miss)
 
 
-def test_rated_rows_in_baseline_and_family():
-    """The drill's rated-load rows ride the same declared-family
-    contract as the PR-8 serving rows."""
-    from paddle_tpu.telemetry.sink import SERVING_BENCH_METRICS
-    for name in ("serving.rated_throughput_tokens_per_sec",
-                 "serving.rated_queue_wait_ms_p99",
-                 "serving.rated_shed"):
-        assert name in SERVING_BENCH_METRICS
-    base = json.load(open(os.path.join(TOOLS, "bench_baseline.json")))
-    assert base["metrics"]["serving.rated_shed"]["value"] == 0.0
-    assert base["metrics"]["serving.rated_shed"]["direction"] == "lower"
-
-
 def test_metrics_http_healthz_has_serving_section():
     from paddle_tpu.telemetry.metrics_http import MetricsServer
     monitor.incr("serving.shed", 0)

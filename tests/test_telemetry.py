@@ -19,7 +19,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _gpt_step():
-    """Tiny GPT + fused TrainStep (the bench.py CPU-smoke config)."""
+    """Tiny GPT + fused TrainStep (toy sizes)."""
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
     cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
                     num_heads=2, max_seq_len=32, dropout=0.0,
@@ -216,7 +216,7 @@ def test_telemetry_callback_model_fit(tmp_path):
 
 
 def test_phase_record_schema():
-    """bench.py phase records validate under the same schema; non-finite
+    """Phase records validate under the same schema; non-finite
     metric values must not leak bare NaN/Infinity into the JSONL."""
     rec = telemetry.make_phase_record(
         "gpt3_125m_train", {"tokens_per_sec": 1000.0, "mfu": 0.5})

@@ -1,67 +1,8 @@
-"""Op-bench tooling + compiled cost-model feedback.
-
-Reference analogs: `tools/test_ci_op_benchmark.sh` +
-`tools/check_op_benchmark_result.py:1`; `hapi/dynamic_flops.py` for the
-flops surface (the compiled path uses XLA's own cost analysis).
-"""
-import json
-import os
-import subprocess
-import sys
-
+"""Compiled cost-model feedback: `hapi.flops.flops_compiled` reads XLA's
+own cost analysis (reference analog: `hapi/dynamic_flops.py`)."""
 import numpy as np
 
-import paddle_tpu as paddle
 import paddle_tpu.nn as nn
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_op_bench_runs_and_gate_passes(tmp_path):
-    env = dict(os.environ,
-               XLA_FLAGS=os.environ.get("XLA_FLAGS", ""),
-               JAX_PLATFORMS="cpu")
-    base = str(tmp_path / "base.json")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "op_bench.py"),
-         "--out", base, "--iters", "2", "--small", "--cpu"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert out.returncode == 0, out.stderr[-1500:]
-    data = json.load(open(base))
-    assert "matmul_f32" in data and data["matmul_f32"]["ms"] > 0
-
-    # identical runs pass the gate
-    gate = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "check_op_benchmark_result.py"),
-         base, base], capture_output=True, text=True)
-    assert gate.returncode == 0, gate.stdout
-    assert "OK" in gate.stdout
-
-
-def test_op_bench_gate_catches_regression(tmp_path):
-    base = {"_device": "x", "matmul_f32": {"ms": 1.0},
-            "softmax": {"ms": 2.0}}
-    cur = {"_device": "x", "matmul_f32": {"ms": 1.5},       # +50%
-           "softmax": {"ms": 2.0}}
-    bp, cp = str(tmp_path / "b.json"), str(tmp_path / "c.json")
-    json.dump(base, open(bp, "w"))
-    json.dump(cur, open(cp, "w"))
-    gate = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "check_op_benchmark_result.py"),
-         bp, cp, "--threshold", "0.15"],
-        capture_output=True, text=True)
-    assert gate.returncode == 8
-    assert "REGRESSED" in gate.stdout
-    # missing case also fails
-    cur2 = {"_device": "x", "matmul_f32": {"ms": 1.0}}
-    json.dump(cur2, open(cp, "w"))
-    gate2 = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "check_op_benchmark_result.py"),
-         bp, cp], capture_output=True, text=True)
-    assert gate2.returncode == 8 and "MISSING" in gate2.stdout
 
 
 def test_flops_compiled_matches_analytic():

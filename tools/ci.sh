@@ -1,10 +1,10 @@
 #!/usr/bin/env bash
-# CI gate (reference analog: paddle_build.sh + tools/test_ci_op_benchmark.sh
-# + check_api_compatible.py rolled into the TPU build's three checks):
-#   1. native libs compile (cmake if available, else direct g++)
-#   2. full pytest suite on the 8-virtual-device CPU mesh
-#   3. op-level perf regression gate vs the recorded baseline (TPU only;
-#      skipped automatically elsewhere — see tools/op_bench.py)
+# CI gate (reference analog: paddle_build.sh + check_api_compatible.py):
+# native libs compile, the static and observatory selfchecks, the
+# serving / fleet / resilience drills, and the full pytest suite on the
+# 8-virtual-device CPU mesh. Correctness, counts and compile families
+# only: nothing here is a speed. Speeds are read on the chip through
+# BENCHMARK.json + benchmark/ (PERF.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -24,7 +24,7 @@ stage() {
   if [ -n "$1" ]; then echo "== $1 =="; fi
 }
 
-stage "[1/11] native build"
+stage "[1/10] native build"
 if command -v cmake >/dev/null && command -v ninja >/dev/null; then
   cmake -S csrc -B csrc/build/cmake -G Ninja >/dev/null
   cmake --build csrc/build/cmake >/dev/null
@@ -53,13 +53,13 @@ csrc/build/predictor_smoke "$SMOKE_DIR/m" csrc/build/libpjrt_mock.so \
     | grep -q "^OK" && echo "native serving smoke OK"
 rm -rf "$SMOKE_DIR"
 
-stage "[2/11] api-surface audit"
+stage "[2/10] api-surface audit"
 python tools/api_audit.py --out api_gap.json --strict
 # signature-level diff (check_api_compatible.py analog): param names,
 # relative order, and no new required params vs the reference
 python tools/api_sig_audit.py --out api_sig_gap.json --strict
 
-stage "[3/11] graph doctor + framework lint"
+stage "[3/10] graph doctor + framework lint"
 # pre-flight static analysis (paddle_tpu/analysis): the GPT config's
 # traced step + sharding specs must lint clean, every rule family must
 # demonstrably fire on its broken specimen, and a new framework-lint
@@ -140,140 +140,40 @@ JAX_PLATFORMS=cpu python tools/commlab.py --selfcheck
 # OOM postmortem must round-trip with its suspects named
 JAX_PLATFORMS=cpu python tools/memwatch.py --selfcheck
 
-stage "[4/11] training health + compile observatory + bench gates"
-# the health monitor's offline analyzer (tools/healthwatch.py) replays
-# the SAME anomaly rules the in-flight monitor runs:
-#   a) the CPU smoke-bench telemetry (GPT + ResNet phases, plus the
-#      PR-11 moe_train MoE train phase and ringattn_128k long-context
-#      attention phase — their moe_*/ringattn_* typed records gate
-#      against the seeded baseline rows below) must come back clean —
-#      a recorded phase error or non-finite metric fails the build;
-#   b) the checked-in broken specimen must trip EVERY anomaly family
-#      (NaN step, loss spike, grad explosion, step-time regression) —
-#      proof the watcher can still see what it gates on (the
-#      graphdoctor selfcheck pattern).
-rm -f /tmp/bench_health_ci.jsonl   # the sink appends; stale phases lie
-# stderr to a plain file (no tee process substitution: bash would not
-# wait for it, and the fork grep below could race an unflushed log)
-JAX_PLATFORMS=cpu python bench.py --cpu \
-    --telemetry /tmp/bench_health_ci.jsonl > /tmp/bench_health_ci.json \
-    2> /tmp/bench_health_ci.err \
-    || { cat /tmp/bench_health_ci.err >&2
-         echo "FATAL: smoke bench failed"; exit 1; }
-cat /tmp/bench_health_ci.err >&2
-# fork-safety gate (PR 6): os.fork() under the multithreaded JAX parent
-# is a real deadlock hazard (CPython warns about it at run time) — the
-# io.prefetch rebuild removed every fork, and this grep keeps it removed
-if grep -E "os\.fork" /tmp/bench_health_ci.err; then
-  echo "FATAL: os.fork() under multithreaded JAX reappeared in the bench log"
-  exit 1
-fi
-# serving bench (bench_serving.py): the offered-load sweep appends its
-# typed serving.* kind=bench records + the engine's compile records to
-# the SAME telemetry file, so the health/compile/bench gates below
-# cover the serving engine too (a recompiling engine loop or a missing
-# serving metric fails stage 4 exactly like a training regression).
-# --check-vs-single 1.3 is the hard floor for the continuous-batching
-# win on the 2-core CI host (measured 1.9-2.2x; CPU decode is
-# compute-bound so the batching yield is modest — the 2x+ headline
-# binds on weight-bandwidth-bound accelerators)
-JAX_PLATFORMS=cpu python bench_serving.py --cpu \
-    --telemetry /tmp/bench_health_ci.jsonl --check-vs-single 1.3 \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: serving bench failed"; exit 1; }
-# serving-resilience rated-load leg (tools/serving_drill.py
-# --rated-only): offered load at the engine's rated level with SLO
-# deadlines ARMED must run shed-free; its serving.rated_* typed bench
-# records land in the SAME gated file so bench_gate covers regressions
-# in the resilience path itself (the full chaos drill runs in stage 6)
-JAX_PLATFORMS=cpu python tools/serving_drill.py --rated-only \
-    --telemetry /tmp/bench_health_ci.jsonl \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: serving rated-load leg failed"; exit 1; }
-# fleet-tier rated leg (bench_serving.py --cpu --fleet 2): the same
-# concurrent wave through a FleetRouter over 2 in-process replicas vs
-# over 1 — fleet.rated_throughput_tokens_per_sec +
-# fleet.scaling_efficiency land in the SAME gated file (baseline rows
-# seeded, wide 0.5 threshold: CPU efficiency measures host contention,
-# not router overhead), and the shared-prefix affinity leg must show a
-# fleet-wide prefix hit rate > 0 with every hit CONCENTRATED on the
-# rendezvous-affine replica and streams bit-identical to a cold
-# prefix-cache-off single engine (exit 4 otherwise)
-JAX_PLATFORMS=cpu python bench_serving.py --cpu --fleet 2 \
-    --telemetry /tmp/bench_health_ci.jsonl \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: fleet bench leg failed"; exit 1; }
+stage "[4/10] observatory smokes + health/compile specimens"
 # kernel-lab smoke (tools/kernellab.py --smoke): every registered
-# Pallas kernel measured once — compile-excluded median-of-k, declared
-# fallback timed on the SAME inputs — with the kind=kernelbench
-# records gated through trace_check inside the tool (exit 13 on any
-# finding) and its kernel.<name>.smoke_ms kind=bench rows appended to
-# the SAME gated file, so bench_gate tracks kernel smoke timings
-# record-against-record like every other metric (direction 'info'
-# until a TPU round binds the device) and healthwatch replays the
-# kernel_time_drift rule over the measurements below
-JAX_PLATFORMS=cpu python tools/kernellab.py --smoke \
-    --telemetry /tmp/bench_health_ci.jsonl \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: kernel-lab smoke failed"; exit 1; }
+# Pallas kernel run once against its declared fallback on the SAME
+# inputs, the kind=kernelbench records gated through trace_check and
+# the kernel_time_drift rule inside the tool (exit 13 on any finding)
+JAX_PLATFORMS=cpu python tools/kernellab.py --smoke
 # comm-lab smoke (tools/commlab.py --smoke): every shard_map collective
-# measured over every size>1 axis of the dp=2,mp=4 mesh at the CPU
-# smoke rungs — compile-excluded median-of-k — with the kind=commbench
+# over every size>1 axis of the dp=2,mp=4 mesh, the kind=commbench
 # records gated through trace_check AND the comm_audit wire-byte leg
-# inside the tool (exit 13 on any finding) and its comm.<op>.smoke_ms
-# kind=bench rows appended to the SAME gated file, so bench_gate tracks
-# collective smoke timings record-against-record (direction 'info'
-# until a real-mesh round binds the device) and healthwatch replays the
-# comm_bw_degraded rule over the measurements below (quiet here:
-# PADDLE_TPU_COMM_DB is off in CI, so no DB reference rides the
-# records and the rule has no jurisdiction)
-JAX_PLATFORMS=cpu python tools/commlab.py --smoke \
-    --telemetry /tmp/bench_health_ci.jsonl \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: comm-lab smoke failed"; exit 1; }
+# inside the tool (exit 13 on any finding)
+JAX_PLATFORMS=cpu python tools/commlab.py --smoke
 # memory-watch smoke (tools/memwatch.py --smoke): the live HBM ledger
 # sampled over a real serving engine + optimizer step with every
 # tagging hook exercised, gated through trace_check inside the tool
 # (exit 14 on any finding — invalid record, fired rule, failed
-# projection reconciliation) with its kind=memsnap records appended to
-# the SAME gated file, so healthwatch below replays the hbm_pressure /
-# kv_thrash / mem_projection_drift rules over the identical records
-# (quiet here: the smoke budget is generous and the ledger reconciles)
-JAX_PLATFORMS=cpu python tools/memwatch.py --smoke \
-    --telemetry /tmp/bench_health_ci.jsonl \
-    2>> /tmp/bench_health_ci.err \
-    || { tail -40 /tmp/bench_health_ci.err >&2
-         echo "FATAL: memory-watch smoke failed"; exit 1; }
-JAX_PLATFORMS=cpu python tools/healthwatch.py /tmp/bench_health_ci.jsonl
+# projection reconciliation)
+JAX_PLATFORMS=cpu python tools/memwatch.py --smoke
+# the health monitor's offline analyzer (tools/healthwatch.py) replays
+# the SAME anomaly rules the in-flight monitor runs: the checked-in
+# broken specimen must trip EVERY anomaly family (NaN step, loss
+# spike, grad explosion, step-time regression) — proof the watcher can
+# still see what it gates on (the graphdoctor selfcheck pattern). That
+# a clean run stays clean is tier-1's (tests/test_health.py)
 JAX_PLATFORMS=cpu python tools/healthwatch.py \
     tools/specimens/health_anomalous.jsonl \
     --expect nan,loss_spike,grad_explosion,step_time_regression
-# compile observatory (tools/compile_report.py), same two-sided gate:
-#   a) the smoke-bench compile log (bench.py phases run under a
-#      CompileObservatory sharing the telemetry sink) must come back
-#      clean — a retrace storm or a cause-less recompile fails;
-#   b) the checked-in thrash specimen must trip the storm rule AND the
-#      causes must name the thrashing argument.
-JAX_PLATFORMS=cpu python tools/compile_report.py /tmp/bench_health_ci.jsonl
+# compile observatory (tools/compile_report.py): the checked-in thrash
+# specimen must trip the storm rule AND the causes must name the
+# thrashing argument (clean runs: tests/test_compile_obs.py; the fork
+# ban: tests/test_io_prefetch.py)
 JAX_PLATFORMS=cpu python tools/compile_report.py --selfcheck \
     tools/specimens/compile_thrash.jsonl --expect-arg batch
-# perf-regression gate (tools/bench_gate.py), same two-sided pattern:
-#   a) the checked-in REGRESSED specimen must fail the gate with every
-#      injected defect family (value regression, missing tracked
-#      metric, null value) and a baseline-identical run must pass;
-#   b) the smoke bench's typed kind=bench records must gate clean
-#      against the rolling baseline (CPU records are device-skipped —
-#      the value gate binds on the bench host — but schema problems or
-#      a missing record stream still fail).
-JAX_PLATFORMS=cpu python tools/bench_gate.py --selfcheck
-JAX_PLATFORMS=cpu python tools/bench_gate.py /tmp/bench_health_ci.jsonl
 
-stage "[5/11] serving engine smoke"
+stage "[5/10] serving engine smoke"
 # continuous-batching serving gate (paddle_tpu/serving +
 # tools/serving_smoke.py), the two-sided pattern:
 #   a) N concurrent streamed requests through the real engine loop
@@ -289,8 +189,7 @@ stage "[5/11] serving engine smoke"
 # every finished request must yield a validated kind=reqtrace record
 # whose spans sum to its end-to-end latency, /metrics must expose
 # parseable Prometheus latency histograms tracking the legacy gauges,
-# /traces must serve the exemplar timelines, and the tracing-on vs
-# tracing-off schedule must stay inside the overhead bound.
+# and /traces must serve the exemplar timelines.
 JAX_PLATFORMS=cpu python tools/serving_smoke.py
 JAX_PLATFORMS=cpu python tools/serving_smoke.py --selfcheck
 # tail-latency attribution gate (tools/tail_report.py), two-sided:
@@ -307,7 +206,7 @@ JAX_PLATFORMS=cpu python tools/serving_smoke.py --selfcheck
 #      right on the actual traces.
 JAX_PLATFORMS=cpu python tools/tail_report.py --selfcheck
 
-stage "[6/11] serving resilience drill"
+stage "[6/10] serving resilience drill"
 # serving robustness gate (paddle_tpu/serving/resilience +
 # tools/serving_drill.py), the two-sided pattern:
 #   a) --selfcheck first proves the failures are VISIBLE: the
@@ -328,7 +227,7 @@ stage "[6/11] serving resilience drill"
 #      kind=serving ledger that passes trace_check.
 JAX_PLATFORMS=cpu python tools/serving_drill.py --selfcheck
 
-stage "[7/11] fleet drill"
+stage "[7/10] fleet drill"
 # fleet-tier robustness gate (paddle_tpu/fleet + tools/fleet_drill.py),
 # the two-sided pattern one tier above the serving drill:
 #   a) --selfcheck first proves the failures are VISIBLE: the
@@ -350,7 +249,7 @@ stage "[7/11] fleet drill"
 # run: tools/fleet_drill.py with no flags.)
 JAX_PLATFORMS=cpu python tools/fleet_drill.py --selfcheck
 
-stage "[8/11] resilience chaos drill"
+stage "[8/10] resilience chaos drill"
 # fault-tolerance gate (paddle_tpu.resilience + tools/chaos_drill.py):
 #   a) the checked-in corrupt-checkpoint specimen
 #      (tools/specimens/ckpt_corrupt) must be REJECTED by manifest
@@ -365,7 +264,7 @@ stage "[8/11] resilience chaos drill"
 #      telemetry ledger validating under tools/trace_check.py.
 JAX_PLATFORMS=cpu python tools/chaos_drill.py --selfcheck
 
-stage "[9/11] elastic mesh drill"
+stage "[9/10] elastic mesh drill"
 # host-loss gate (distributed.elastic + resilience.reshard +
 # tools/elastic_drill.py), the two-sided pattern:
 #   a) the checked-in cross-layout specimen
@@ -382,26 +281,11 @@ stage "[9/11] elastic mesh drill"
 #      by tools/trace_check.py.
 JAX_PLATFORMS=cpu python tools/elastic_drill.py --selfcheck
 
-stage "[10/11] test suite"
+stage "[10/10] test suite"
 # 4 xdist shards (reference `tools/parallel_UT_rule.py` CI sharding):
 # each worker process builds its own 8-virtual-device CPU platform
 python -m pytest tests/ -q -n auto --dist loadfile
 
-stage "[11/11] op benchmark gate"
-# runs only where JAX reports a TPU. The check is its own short
-# process, gone (and the chip released) before op_bench starts
-if python -c "import jax, sys; \
-sys.exit(0 if jax.default_backend() == 'tpu' else 3)"; then
-  python tools/op_bench.py --out /tmp/op_bench_current.json
-  # threshold 0.25: wide enough that host jitter on a best-of-2
-  # single-loop timing does not flap the gate, tight enough to catch a
-  # real kernel regression
-  python tools/check_op_benchmark_result.py \
-      tools/op_bench_baseline_v5e.json /tmp/op_bench_current.json \
-      --threshold 0.25
-else
-  echo "not a TPU host: op-bench gate skipped"
-fi
-stage ""   # close the last stage so the ledger covers all eleven
+stage ""   # close the last stage so the ledger covers all ten
 echo "stage wall times: ${STAGE_TIMES} (total ${SECONDS}s)"
 echo "CI OK"

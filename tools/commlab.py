@@ -17,8 +17,8 @@ you is what CI gates on.
 
     JAX_PLATFORMS=cpu python tools/commlab.py \
         [--report lab.json] [--telemetry run.jsonl] [--mesh dp=2,mp=4] \
-        [--payloads 16384,65536] [--warmup N] [--k N] [--db PATH] \
-        [--update-db]
+        [--payloads 16384,65536] [--warmup N] [--k N] \
+        [--db PATH --update-db]
 
 Modes:
   (default)    sweep every (op, axis, payload), print the table
@@ -26,9 +26,7 @@ Modes:
                CPU-scale rungs, records gated through
                tools/trace_check.py AND the comm_audit third honesty
                leg (claimed wire_bytes vs a re-trace of the same sweep
-               program), zero findings or exit 13; with --telemetry
-               also emits kind=bench `comm.<op>.smoke_ms` rows for
-               bench_gate
+               program), zero findings or exit 13
   --selfcheck  proof the lab itself works: the checked-in specimen
                (tools/specimens/commbench_degraded.jsonl) must trip
                `comm_bw_degraded` BY NAME through the real
@@ -38,11 +36,9 @@ Modes:
                rule; the DB must refuse non-finite rows and round-trip
                losslessly
 
-The DB (tools/comm_db.json) only ever rolls forward through
---update-db, which refuses non-finite rows and keeps the best-known
-latency per (op, axis-size, payload, backend) key — the bench_gate
---update-baseline contract. Reading it back into measurements is
-opt-in via PADDLE_TPU_COMM_DB (see telemetry/comm_obs).
+The DB is a JSON file at the path --db names; it only ever rolls
+forward through --update-db, which refuses non-finite rows and keeps
+the best-known latency per (op, axis-size, payload, backend) key.
 
 Exit codes: 0 clean; 13 findings (invalid records, degraded
 collectives, dishonest wire-byte claims); 9 selfcheck miss (the lab
@@ -174,28 +170,6 @@ def _audit_findings(records, mesh):
     return comm_audit.check_commbench_wire_bytes(records, mesh=mesh)
 
 
-def _bench_rows(results):
-    """kind=bench `comm.<op>.smoke_ms` rows for the perf gate: one
-    tracked scalar per op (median over its sweep points) so bench_gate
-    diffs smoke timings record-against-record like every other gated
-    metric."""
-    import statistics
-
-    from paddle_tpu.telemetry import sink
-
-    by_op = {}
-    for r in results:
-        by_op.setdefault(r.op, []).append(r.time_ms)
-    rows = []
-    backend = results[0].backend if results else "cpu"
-    for op in sorted(by_op):
-        rows.append(sink.make_bench_record(
-            metric=f"comm.{op}.smoke_ms",
-            value=statistics.median(by_op[op]),
-            unit="ms", device=backend))
-    return rows
-
-
 def run_smoke(args, trace_check):
     """The ci.sh leg: every (op, size>1 axis) measured at the smoke
     rungs, records gated, drift rule consulted, wire-byte claims
@@ -271,19 +245,11 @@ def run_selfcheck():
         ok = False
 
     # b) clean sweep: measure here, records validate, wire-byte claims
-    # audit clean, the rule stays quiet. The PADDLE_TPU_COMM_DB flag is
-    # cleared for the duration — selfcheck must answer the same on
-    # every host, whatever DB the environment points at.
-    saved = os.environ.pop(comm_obs.ENV_FLAG, None)
-    comm_obs.clear_db_cache()
-    try:
-        mesh = _build_mesh("dp=2,mp=4")
-        results = comm_obs.sweep_mesh(
-            mesh=mesh, payloads=[SMOKE_PAYLOADS[0]], warmup=1, k=2)
-    finally:
-        if saved is not None:
-            os.environ[comm_obs.ENV_FLAG] = saved
-        comm_obs.clear_db_cache()
+    # audit clean, the rule stays quiet (no DB is given, so no
+    # measurement carries a reference)
+    mesh = _build_mesh("dp=2,mp=4")
+    results = comm_obs.sweep_mesh(
+        mesh=mesh, payloads=[SMOKE_PAYLOADS[0]], warmup=1, k=2)
     records = [r.to_record() for r in results]
     clean_problems = _validate_records(records, trace_check, "clean")
     clean_problems += [f"audit: {p}"
@@ -337,8 +303,7 @@ def main(argv=None):
     ap.add_argument("--report", default=None,
                     help="write the JSON report here")
     ap.add_argument("--telemetry", default=None,
-                    help="append kind=commbench records (and in "
-                         "--smoke, kind=bench rows) to this JSONL")
+                    help="append kind=commbench records to this JSONL")
     ap.add_argument("--mesh", default="dp=2,mp=4",
                     help="mesh spec to build when none is installed "
                          "(default dp=2,mp=4 — the 8-device CI mesh)")
@@ -352,7 +317,7 @@ def main(argv=None):
                     help="timed samples per point; median reported "
                          "(default 5)")
     ap.add_argument("--db", default=None,
-                    help="comm DB path (default tools/comm_db.json)")
+                    help="comm DB path (required by --update-db)")
     ap.add_argument("--update-db", action="store_true",
                     help="roll measured rows into the DB (keep-best; "
                          "non-finite rows refused)")
@@ -366,6 +331,8 @@ def main(argv=None):
                          "sweep quiet/audited + DB refuse/round-trip "
                          "proof")
     args = ap.parse_args(argv)
+    if args.update_db and not args.db:
+        ap.error("--update-db needs --db PATH")
 
     import jax
     from paddle_tpu.telemetry import comm_obs, sink
@@ -388,15 +355,12 @@ def main(argv=None):
                   "non-finite rows and round-trips")
         return 0 if ok else 9
 
-    db_path = args.db or comm_obs.DEFAULT_DB_PATH
     records = []
-    bench_rows = []
     problems = []
     results = []
 
     if args.smoke:
         results, records, problems = run_smoke(args, trace_check)
-        bench_rows = _bench_rows(results)
     else:
         results = run_sweep(args)
         print_table(results)
@@ -408,14 +372,14 @@ def main(argv=None):
         problems += [a.message for a in drifts]
 
     if args.update_db and not problems:
-        db = comm_obs.CommDB(db_path)
+        db = comm_obs.CommDB(args.db)
         updated, refused = db.update(results)
         for key, why in refused:
             problems.append(f"--update-db {key}: {why}")
         if updated:
             db.save()
             print(f"comm db: {len(updated)} row(s) rolled forward "
-                  f"-> {db_path}")
+                  f"-> {args.db}")
             # db_update records must reference a measured row: re-emit
             # the winning measurement with event=db_update so the
             # trace_check cross-rule can tie the update to its source
@@ -432,7 +396,7 @@ def main(argv=None):
 
     if args.telemetry:
         out = sink.JsonlSink(args.telemetry)
-        for rec in records + bench_rows:
+        for rec in records:
             out.write(rec)
         out.close()
 

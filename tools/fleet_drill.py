@@ -48,7 +48,7 @@ processes and must share numerics with the in-process reference.
     carry the failover/replay_spliced records it claims to gate on.
 
 Exit codes: 0 ok; 12 findings; 9 selfcheck miss. Distinct from
-trace_check 7 / chaos_drill 8 / serving_drill 11 / bench_gate 4 /
+trace_check 7 / chaos_drill 8 / serving_drill 11 /
 memwatch 14 so CI logs disambiguate.
 """
 import argparse

@@ -7,7 +7,7 @@ The point of sharing the rule engine: what pages you in production is
 exactly what CI gates on. Two modes:
 
     # gate mode (default): a clean run must stay clean
-    python tools/healthwatch.py bench_telemetry.jsonl run.jsonl
+    python tools/healthwatch.py run.jsonl
 
     # selfcheck mode: a broken specimen must trip EVERY listed family —
     # proof the rules can still see the defects they gate on (the
@@ -18,7 +18,7 @@ exactly what CI gates on. Two modes:
 Step records (kind=step) run the rolling-window rules (NaN/Inf, loss
 spike, grad explosion, step-time regression — compile steps exempt)
 plus the per-rank straggler rule (step-boundary skew across ranks of
-the same step); phase records (kind=phase, bench.py output) are checked
+the same step); phase records (kind=phase) are checked
 for recorded errors and non-finite metrics; checkpoint records
 (kind=ckpt, paddle_tpu.resilience) run the checkpoint_failed /
 checkpoint_stall rules; mesh-observatory records (kind=commbench,
@@ -39,8 +39,8 @@ isolate one rule family without muting the others at the source.
 Exit codes: 0 clean / all expected families fired; 5 findings in gate
 mode; 9 an expected family did NOT fire (the watcher itself is broken).
 Distinct from trace_check's 7 and graphdoctor's 8/9 family so CI logs
-disambiguate. Used by tools/ci.sh against the smoke-bench JSONL and the
-checked-in anomalous specimen.
+disambiguate. Used by tools/ci.sh against the checked-in anomalous
+specimen.
 """
 import argparse
 import json

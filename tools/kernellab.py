@@ -17,14 +17,13 @@ kind=kernelbench records; measured-vs-roofline drift feeds the SAME
 
     JAX_PLATFORMS=cpu python tools/kernellab.py \
         [--report lab.json] [--telemetry run.jsonl] [--seeds N] \
-        [--warmup N] [--k N] [--db PATH] [--update-db]
+        [--warmup N] [--k N] [--db PATH --update-db]
 
 Modes:
   (default)    measure every registered kernel, print the table
   --smoke      the ci.sh leg: every kernel measured once (cheap
                warmup/k), records gated through tools/trace_check.py,
-               zero findings or exit 13; with --telemetry also emits
-               kind=bench `kernel.<name>.smoke_ms` rows for bench_gate
+               zero findings or exit 13
   --selfcheck  two-sided proof the lab itself works: the checked-in
                drift specimen (tools/specimens/kernelbench_drift.jsonl)
                must trip `kernel_time_drift` BY NAME in BOTH directions
@@ -35,13 +34,12 @@ Modes:
                (block_q, block_k) candidates, KN502 vmem_footprint as
                the feasibility predicate, measured time as the
                objective, KN504 parity re-fuzzed on the winner; with
-               --update-db the winner lands in the DB that
-               ops/pallas_attention._resolve_blocks consults behind
-               PADDLE_TPU_KERNEL_DB
+               --db PATH --update-db the winner lands in that DB
 
-The DB (tools/kernel_db.json) only ever rolls forward through
---update-db, which refuses non-finite rows — the bench_gate
---update-baseline contract.
+The DB is a JSON file at the path --db names; it only ever rolls
+forward through --update-db, which refuses non-finite rows. The
+kernels never read it: a tuned config reaches them as an edit to their
+block policy.
 
 Exit codes: 0 clean; 13 findings (invalid records, drifting kernels,
 failed tune parity); 9 selfcheck miss (the lab itself is broken).
@@ -124,20 +122,6 @@ def _drift_findings(records, detector=None):
     for rec in records:
         found.extend(det.observe(rec))
     return [a for a in found if a.kind == "kernel_time_drift"]
-
-
-def _bench_rows(results):
-    """kind=bench `kernel.<name>.smoke_ms` rows for the perf gate: one
-    tracked scalar per kernel so bench_gate diffs smoke timings
-    record-against-record like every other gated metric."""
-    from paddle_tpu.telemetry import sink
-
-    rows = []
-    for r in results:
-        rows.append(sink.make_bench_record(
-            metric=f"kernel.{r.kernel}.smoke_ms", value=r.kernel_ms,
-            unit="ms", device=r.backend))
-    return rows
 
 
 def run_smoke(args, trace_check):
@@ -260,7 +244,7 @@ def run_tune(args, trace_check):
     if args.tune not in ("flash_fwd", "flash_fwd_rect"):
         return None, [f"--tune {args.tune}: only the flash_fwd family "
                       "has a search space wired up (block_q/block_k "
-                      "over the absorbed attn_tune sweep)"], []
+                      "over kernel_obs.ATTN_SWEEP_BQ x ATTN_SWEEP_BK)"], []
     winner, results, skipped = kernel_obs.tune_flash_fwd(
         seq=args.seq, warmup=args.warmup, k=args.k)
     problems, records = [], []
@@ -295,8 +279,7 @@ def main(argv=None):
     ap.add_argument("--report", default=None,
                     help="write the JSON report here")
     ap.add_argument("--telemetry", default=None,
-                    help="append kind=kernelbench records (and in "
-                         "--smoke, kind=bench rows) to this JSONL")
+                    help="append kind=kernelbench records to this JSONL")
     ap.add_argument("--seeds", type=int, default=1,
                     help="example seeds per kernel — the examples "
                          "derive shapes AND dtypes from the rng, so "
@@ -307,9 +290,9 @@ def main(argv=None):
                     help="timed samples per kernel; median reported "
                          "(default 5)")
     ap.add_argument("--db", default=None,
-                    help="timing DB path (default tools/kernel_db.json)")
+                    help="timing DB path (required by --update-db)")
     ap.add_argument("--update-db", action="store_true",
-                    help="roll measured/tuned rows into the DB "
+                    help="roll measured/tuned rows into the --db file "
                          "(non-finite rows refused)")
     ap.add_argument("--smoke", action="store_true",
                     help="the ci.sh leg: every kernel once, records "
@@ -324,6 +307,8 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=1024,
                     help="sequence length for --tune (default 1024)")
     args = ap.parse_args(argv)
+    if args.update_db and not args.db:
+        ap.error("--update-db needs --db PATH")
 
     import jax
     from paddle_tpu.telemetry import kernel_obs, sink
@@ -345,9 +330,7 @@ def main(argv=None):
                   "clean, DB refuses non-finite rows and round-trips")
         return 0 if ok else 9
 
-    db_path = args.db or kernel_obs.DEFAULT_DB_PATH
     records = []
-    bench_rows = []
     problems = []
     results = []
     winner = None
@@ -357,7 +340,6 @@ def main(argv=None):
         problems += _validate_records(records, trace_check, "tune")
     elif args.smoke:
         results, records, problems = run_smoke(args, trace_check)
-        bench_rows = _bench_rows(results)
     else:
         seeds = tuple(1234 + i for i in range(max(1, args.seeds)))
         results = run_measure(seeds=seeds, warmup=args.warmup, k=args.k)
@@ -368,7 +350,7 @@ def main(argv=None):
         problems += [a.message for a in drifts]
 
     if args.update_db and not problems:
-        db = kernel_obs.KernelDB(db_path)
+        db = kernel_obs.KernelDB(args.db)
         if winner is not None:
             key = kernel_obs.db_key(
                 winner["kernel"], winner["sig"], winner["dtype"],
@@ -383,7 +365,7 @@ def main(argv=None):
         if updated:
             db.save()
             print(f"kernel db: {len(updated)} row(s) rolled forward "
-                  f"-> {db_path}")
+                  f"-> {args.db}")
             # db_update records must reference a measured row: carry
             # the key of what actually landed (trace_check cross-rule)
             for key in updated:
@@ -401,7 +383,7 @@ def main(argv=None):
 
     if args.telemetry:
         out = sink.JsonlSink(args.telemetry)
-        for rec in records + bench_rows:
+        for rec in records:
             out.write(rec)
         out.close()
 

@@ -30,12 +30,8 @@ resilience). Default run:
      cancelled+expired+finished+failed == admitted, and the combined
      kind=serving ledger must pass tools/trace_check.py.
   7. **Rated-load leg** — the shed-free SLO leg: offered load at the
-     engine's rated level with deadlines ARMED must run with ZERO
-     sheds; its throughput/queue-wait-p99/shed-count land as typed
-     kind=bench records (`serving.rated_*`) for tools/bench_gate.py.
-
---rated-only runs just leg 7 appending to --telemetry (the CI stage-4
-bench file, so the perf gate covers the resilience path).
+     engine's rated level with deadlines ARMED must complete every
+     stream with ZERO sheds, zero expiries and a quiesced pool.
 
 --selfcheck (the graphdoctor pattern — prove the failures are visible):
   - the checked-in LEAK specimen (tools/specimens/serving_leak.jsonl —
@@ -48,7 +44,7 @@ bench file, so the perf gate covers the resilience path).
 
 Exit codes: 0 ok; 11 findings; 9 selfcheck miss. Distinct from
 trace_check 7 / healthwatch 5 / compile_report 6 / chaos_drill 8 /
-bench_gate 4 / serving_smoke 10 so CI logs disambiguate.
+serving_smoke 10 so CI logs disambiguate.
 """
 import argparse
 import json
@@ -339,23 +335,17 @@ def overload_fault_leg(model, sink, findings, n_wave=8, max_new=12,
     return engine
 
 
-def rated_leg(model, sink, findings, waves=3, max_new=12,
-              emit_bench=True):
+def rated_leg(model, sink, findings, waves=3, max_new=12):
     """Leg 7: the shed-free SLO leg at rated load. Deadlines are ARMED
     (generous — rated load must never trip them) so the run exercises
-    the enforcement machinery, and the results land as typed
-    serving.rated_* bench records for the perf gate."""
-    import jax
-    from paddle_tpu import monitor, telemetry
+    the enforcement machinery."""
     from paddle_tpu.serving import (Deadlines, SamplingParams,
                                     ServingEngine)
 
-    # tracing OFF for the bench leg: this harness offers each wave as
-    # one burst, so the late admissions are queue-dominated BY DESIGN —
-    # their reqtrace records in the stage-4 gated file would trip the
-    # healthwatch tail_latency rule on a healthy run (the tracer's own
-    # gates live in serving_smoke / tail_report / bench_serving's
-    # overhead leg, not here)
+    # tracing OFF: this harness offers each wave as one burst, so the
+    # late admissions are queue-dominated BY DESIGN — their reqtrace
+    # records would trip the healthwatch tail_latency rule on a healthy
+    # run (the tracer's own gates live in serving_smoke / tail_report)
     engine = ServingEngine(model, max_slots=4, block_size=8,
                            prefill_chunk=8, max_model_len=64,
                            max_queue=32, sink=sink, enable_tracing=False)
@@ -369,7 +359,6 @@ def rated_leg(model, sink, findings, waves=3, max_new=12,
                for i in range(n_req)]
     slo = Deadlines(queue_wait_s=60.0, ttft_s=120.0, total_s=300.0)
     engine.start()
-    t0 = time.monotonic()
     handles = [engine.submit(p, SamplingParams(max_new_tokens=max_new),
                              deadlines=slo) for p in prompts]
     done = [None] * n_req
@@ -383,11 +372,9 @@ def rated_leg(model, sink, findings, waves=3, max_new=12,
         t.start()
     for t in threads:
         t.join(timeout=600)
-    wall_s = time.monotonic() - t0
     engine.drain(timeout=120)
     engine.stop()
 
-    n_tokens = sum(len(d) for d in done if d)
     if any(d is None or len(d) != max_new for d in done):
         findings.append("rated-load leg: not every stream completed")
     shed = engine._counts["shed"]
@@ -400,27 +387,10 @@ def rated_leg(model, sink, findings, waves=3, max_new=12,
         engine.pool.assert_quiesced()
     except AssertionError as e:
         findings.append(f"rated-load leg leaked KV blocks: {e}")
-    qwait_p99 = monitor.get_gauge("serving.queue_wait_ms_p99", 0.0)
-    throughput = n_tokens / wall_s if wall_s > 0 else 0.0
-    results = {
-        "serving.rated_throughput_tokens_per_sec": (round(throughput, 1),
-                                                    "tokens/sec"),
-        "serving.rated_queue_wait_ms_p99": (round(float(qwait_p99), 2),
-                                            "ms"),
-        "serving.rated_shed": (shed, "requests"),
-    }
-    if emit_bench and sink is not None:
-        dev = jax.devices()[0].device_kind
-        for name, (value, unit) in results.items():
-            sink.write(telemetry.make_bench_record(
-                name, value, unit=unit, device=dev))
-    print(f"rated load: {n_req} requests, {n_tokens} tokens in "
-          f"{wall_s:.2f}s -> {throughput:.1f} tok/s, queue-wait p99 "
-          f"{qwait_p99:.1f}ms, {shed} shed")
-    return results
+    print(f"rated load: {n_req} requests, {shed} shed, {expired} expired")
 
 
-def drill(telemetry_path=None, rated_only=False, n_wave=8, max_new=12):
+def drill(telemetry_path=None, n_wave=8, max_new=12):
     from paddle_tpu import telemetry
 
     findings = []
@@ -436,22 +406,20 @@ def drill(telemetry_path=None, rated_only=False, n_wave=8, max_new=12):
     _lockwatch_arm()
     sink = telemetry.JsonlSink(telemetry_path)
     model = _build()
-    if not rated_only:
-        overload_fault_leg(model, sink, findings, n_wave=n_wave,
-                           max_new=max_new)
+    overload_fault_leg(model, sink, findings, n_wave=n_wave,
+                       max_new=max_new)
     rated_leg(model, sink, findings)
     findings += _lockwatch_close(sink)
     sink.close()
-    if not rated_only:
-        # the combined lifecycle ledger must validate — including the
-        # per-engine quiesce accounting cross-rules
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import trace_check
-        problems, stats = trace_check.check_pair(telemetry_path)
-        findings += [f"telemetry invalid: {p}" for p in problems]
-        if stats.get("n_serving", 0) == 0:
-            findings.append("no kind=serving records in the drill "
-                            "ledger — the engine emitted nothing")
+    # the combined lifecycle ledger must validate — including the
+    # per-engine quiesce accounting cross-rules
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import trace_check
+    problems, stats = trace_check.check_pair(telemetry_path)
+    findings += [f"telemetry invalid: {p}" for p in problems]
+    if stats.get("n_serving", 0) == 0:
+        findings.append("no kind=serving records in the drill "
+                        "ledger — the engine emitted nothing")
     print(f"serving drill: {len(findings)} finding(s) "
           f"(ledger: {telemetry_path})")
     for f in findings:
@@ -503,9 +471,6 @@ def selfcheck():
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--selfcheck", action="store_true")
-    ap.add_argument("--rated-only", action="store_true",
-                    help="run only the rated-load SLO leg (CI stage 4 "
-                         "appends its bench records to the gated file)")
     ap.add_argument("--telemetry", default=None,
                     help="JSONL ledger path (appended)")
     ap.add_argument("--wave", type=int, default=8)
@@ -516,8 +481,7 @@ def main(argv=None):
         jax.config.update("jax_platforms", "cpu")
     if args.selfcheck:
         return selfcheck()
-    return drill(args.telemetry, rated_only=args.rated_only,
-                 n_wave=args.wave, max_new=args.max_new)
+    return drill(args.telemetry, n_wave=args.wave, max_new=args.max_new)
 
 
 if __name__ == "__main__":

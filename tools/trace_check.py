@@ -11,7 +11,7 @@ tests/test_telemetry.py and runnable standalone:
     python tools/trace_check.py run.jsonl [trace.json]
 
 Exit 0 when valid; exit 7 with a problem listing otherwise (distinct
-from pytest/op-bench gate codes so CI logs disambiguate).
+from pytest's own codes so CI logs disambiguate).
 """
 import json
 import os
@@ -22,7 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def check_metrics_jsonl(path):
     """Returns (n_records, n_step_records, n_compile_records,
-    n_ckpt_records, n_bench_records, n_plan_records, n_elastic_records,
+    n_ckpt_records, n_plan_records, n_elastic_records,
     n_serving_records, n_kernel_records, n_reqtrace_records,
     n_kernelbench_records, n_thread_lint_records, n_commbench_records,
     n_memsnap_records, n_fleet_records, problems). Positional
@@ -40,7 +40,7 @@ def check_metrics_jsonl(path):
     records = []
     try:
         if os.path.getsize(path) == 0:
-            return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
+            return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
                 f"{path}: empty metrics file (0 bytes): no step was "
                 "ever recorded"]
         with open(path) as f:
@@ -53,7 +53,7 @@ def check_metrics_jsonl(path):
                 except json.JSONDecodeError as e:
                     problems.append(f"{path}:{i + 1}: not JSON: {e}")
     except OSError as e:
-        return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
+        return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
             f"{path}: unreadable: {e}"]
     if not records:
         problems.append(f"{path}: no records")
@@ -62,7 +62,6 @@ def check_metrics_jsonl(path):
             problems.append(f"{path}:{i + 1}: {p}")
     problems += check_compile_records(records, path)
     problems += check_ckpt_records(records, path)
-    problems += check_bench_records(records, path)
     problems += check_plan_records(records, path)
     problems += check_elastic_records(records, path)
     problems += check_moe_records(records, path)
@@ -80,8 +79,6 @@ def check_metrics_jsonl(path):
                      if isinstance(r, dict) and r.get("kind") == "compile")
     n_ckpt = sum(1 for r in records
                  if isinstance(r, dict) and r.get("kind") == "ckpt")
-    n_bench = sum(1 for r in records
-                  if isinstance(r, dict) and r.get("kind") == "bench")
     n_plan = sum(1 for r in records
                  if isinstance(r, dict) and r.get("kind") == "plan")
     n_elastic = sum(1 for r in records
@@ -108,7 +105,7 @@ def check_metrics_jsonl(path):
                     and r.get("kind") == "memsnap")
     n_fleet = sum(1 for r in records
                   if isinstance(r, dict) and r.get("kind") == "fleet")
-    return (len(records), n_steps, n_compiles, n_ckpt, n_bench, n_plan,
+    return (len(records), n_steps, n_compiles, n_ckpt, n_plan,
             n_elastic, n_serving, n_kernel, n_reqtrace, n_kernelbench,
             n_thread_lint, n_commbench, n_memsnap, n_fleet, problems)
 
@@ -128,9 +125,8 @@ def check_compile_records(records, path):
     Untracked records (jax.monitoring stream — no signature, so no
     cause is derivable) are exempt from the cause rules AND from the
     monotonicity rule: their step counter is per-observatory-session,
-    and a rolling telemetry file legitimately appends several sessions
-    (bench.py then bench_serving.py in one CI stage), each restarting
-    the shared '(jax)' family at step 0.
+    and a rolling telemetry file legitimately appends several sessions,
+    each restarting the shared '(jax)' family at step 0.
     """
     problems = []
     last_step = {}
@@ -215,73 +211,6 @@ def check_ckpt_records(records, path):
             problems.append(
                 f"{path}:{i + 1}: ckpt {event} references step {step} "
                 f"(rank {rank}) that no commit in this ledger landed")
-    return problems
-
-
-def check_bench_records(records, path):
-    """Cross-record rules for typed bench results (kind=bench, the
-    perf-regression gate's input — see tools/bench_gate.py):
-
-    - metric names must be non-empty (an unnamed result can never be
-      gated against a baseline);
-    - the same metric for the same device/round must not repeat with
-      DIFFERENT units — the gate diffs values record-against-record and
-      a silent unit flip would fake a 1000x regression or win;
-    - the SERVING family (`serving.*`, bench_serving.py) additionally:
-      every gated serving metric must be one the family declares
-      (sink.SERVING_BENCH_METRICS — an undeclared name can never join
-      the baseline), must carry a unit, and within one device/round the
-      latency percentiles must be ordered (p50 <= p99 for TTFT and
-      TPOT — inverted percentiles mean the producer's accounting is
-      broken, and a gate fed broken percentiles gates nothing).
-
-    Per-record shape (value numeric/null, null carries an error note)
-    is already enforced by sink.validate_step_record.
-    """
-    from paddle_tpu.telemetry.sink import SERVING_BENCH_METRICS
-
-    problems = []
-    units = {}
-    serving_vals = {}
-    for i, rec in enumerate(records):
-        if not isinstance(rec, dict) or rec.get("kind") != "bench":
-            continue
-        metric = rec.get("metric")
-        if not metric or not str(metric).strip():
-            problems.append(f"{path}:{i + 1}: bench record with empty "
-                            "metric name")
-            continue
-        metric = str(metric)
-        key = (metric, rec.get("device"), rec.get("round"))
-        unit = rec.get("unit")
-        if key in units and units[key] != unit:
-            problems.append(
-                f"{path}:{i + 1}: bench metric {metric!r} repeats with "
-                f"unit {unit!r} after {units[key]!r}")
-        units[key] = unit
-        if metric.startswith("serving."):
-            if metric not in SERVING_BENCH_METRICS:
-                problems.append(
-                    f"{path}:{i + 1}: serving bench metric {metric!r} "
-                    "is not in the declared family "
-                    "(telemetry.sink.SERVING_BENCH_METRICS)")
-            elif unit is None:
-                problems.append(
-                    f"{path}:{i + 1}: serving bench metric {metric!r} "
-                    "carries no unit")
-            if isinstance(rec.get("value"), (int, float)):
-                serving_vals[key] = (i, float(rec["value"]))
-    for fam in ("ttft", "tpot", "prefix_ttft"):
-        for (metric, device, rnd), (i, p50) in list(serving_vals.items()):
-            if metric != f"serving.{fam}_p50_ms":
-                continue
-            hit = serving_vals.get(
-                (f"serving.{fam}_p99_ms", device, rnd))
-            if hit is not None and p50 > hit[1]:
-                problems.append(
-                    f"{path}:{i + 1}: serving.{fam}_p50_ms {p50} > "
-                    f"serving.{fam}_p99_ms {hit[1]} — inverted "
-                    "percentiles")
     return problems
 
 
@@ -1240,13 +1169,13 @@ def check_pair(jsonl_path, trace_path=None):
     """Full validation. Returns (problems, stats): problems == [] means
     valid; stats carries the already-computed counts so callers don't
     re-parse the files."""
-    (n_rec, n_steps, n_compiles, n_ckpt, n_bench, n_plan, n_elastic,
+    (n_rec, n_steps, n_compiles, n_ckpt, n_plan, n_elastic,
      n_serving, n_kernel, n_reqtrace, n_kernelbench, n_thread_lint,
      n_commbench, n_memsnap, n_fleet, problems) = \
         check_metrics_jsonl(jsonl_path)
     stats = {"n_records": n_rec, "n_steps": n_steps,
              "n_compiles": n_compiles, "n_ckpt": n_ckpt,
-             "n_bench": n_bench, "n_plan": n_plan,
+             "n_plan": n_plan,
              "n_elastic": n_elastic, "n_serving": n_serving,
              "n_kernel": n_kernel, "n_reqtrace": n_reqtrace,
              "n_kernelbench": n_kernelbench,
@@ -1296,8 +1225,6 @@ def main(argv):
         msg += f" ({stats['n_compiles']} compile events)"
     if stats.get("n_ckpt"):
         msg += f" ({stats['n_ckpt']} ckpt events)"
-    if stats.get("n_bench"):
-        msg += f" ({stats['n_bench']} bench results)"
     if stats.get("n_plan"):
         msg += f" ({stats['n_plan']} plan records)"
     if stats.get("n_elastic"):
