@@ -11,6 +11,15 @@ PR-4 compile observatory can prove it (`telemetry.observed_dispatch`
 routes both steps through the signature-keyed AOT cache when an
 observatory is active).
 
+The loop keeps ONE decode step in flight: `step()` dispatches decode
+n+1 before it fetches decode n's tokens, and the continuing slots take
+their input tokens from n's output on the device. Everything else n+1
+needs (contexts, block tables, sampling counts, which slots end by
+`max_new_tokens`) is host arithmetic, so the host's scheduling,
+emitting and watching run under the device's time instead of between
+two programs. The host waits only where it needs a value: a request
+with an EOS runs one step past it and that step's token is discarded.
+
 The engine knows no architecture: a model hands it the per-layer
 protocol of `serving/served.py` (embed, layers that each declare a
 cache kind and bring a `decode` and a `prefill` over it, the head), and
@@ -39,6 +48,7 @@ histograms at every step and at scrape time, age-stamped by
 (telemetry.reqtrace) ride the attached sink as kind=reqtrace records,
 with the slowest-K exemplars on `GET /traces`.
 """
+import collections
 import contextlib
 import functools
 import threading
@@ -81,6 +91,13 @@ _ENGINE_IDS = _itertools.count()
 
 # every span this module writes: `telemetry.span`, cat="serving"
 _span = functools.partial(_telemetry_span, cat="serving")
+
+
+# A decode step that was dispatched and not retired: its tokens (`tok`,
+# `logp`) are still on the device. `entries` are the (slot, request)
+# pairs it sampled for; `stats` what the layers counted in it and in the
+# chunks dispatched before it.
+_Flight = collections.namedtuple("_Flight", "tok logp stats entries")
 
 
 class EngineConfig:
@@ -216,8 +233,7 @@ class ServingEngine:
 
         num_blocks = self._resolve_num_blocks()
         self.pool = BlockPool(num_blocks)   # guarded by: _mu
-        with jax.default_device(cfg.device) if cfg.device is not None \
-                else contextlib.nullcontext():
+        with self._on_device():
             self.cache = PagedKVCache(   # guarded by: _mu
                 self.cache_kinds, num_blocks, self.block_size,
                 dtype=self._compute_dtype)
@@ -247,7 +263,7 @@ class ServingEngine:
         self._stopped = False   # guarded by: none (stop-path flag, set without the lock by design)
         self._draining = False  # guarded by: _mu
         self._dead = False      # guarded by: _mu
-        self._restarts = 0      # guarded by: none (serve-loop-thread confined) — CONSECUTIVE failed-step restarts
+        self._restarts = 0      # guarded by: none (stepping-thread confined) — CONSECUTIVE failed-step restarts
         self._sleep = time.sleep        # injectable (tests pin backoff)
         self._join_timeout_s = 30.0     # stop(): loop-join bound
         self._stop_lock_timeout_s = 5.0  # stop(): wedged-lock bound
@@ -294,6 +310,7 @@ class ServingEngine:
                          if getattr(p, "_value", None) is not None])
         self._steps = 0                 # guarded by: _mu
         self._stats_pending = []        # guarded by: _mu
+        self._in_flight = None          # guarded by: _mu — the _Flight not retired yet
         monitor.set_gauge("serving.kv_blocks_total", self.pool.capacity)
         monitor.set_gauge("serving.draining", 0)
         self._update_gauges()
@@ -462,8 +479,24 @@ class ServingEngine:
                              for a in pages)
             return fork(k_pages), fork(v_pages)
 
+        def merge_fn(prev_tok, host_tok, from_host):
+            """The input tokens of a decode step whose batch changed:
+            the continuing slots' are the output of the step in flight,
+            still on the device; a slot placed or replayed since has
+            its on the host."""
+            return jnp.where(from_host, host_tok, prev_tok)
+
         self._decode_logits = decode_logits
         self._prefill_logits = prefill_logits
+        # compiled here, ahead of time: the first batch to change
+        # membership with a step in flight must not compile anything
+        slot = functools.partial(jax.ShapeDtypeStruct,
+                                 (self.cfg.max_slots,))
+        with self._on_device():
+            self._merge_tokens = jax.jit(merge_fn).lower(
+                slot(jnp.int32), slot(jnp.int32), slot(jnp.bool_)).compile()
+            # stands in for the output of a step when none is in flight
+            self._no_tokens = jnp.zeros((self.cfg.max_slots,), jnp.int32)
         donate = (1, 2) if jax.default_backend() == "tpu" else ()
         self._decode_jit = jax.jit(
             functools.partial(decode_fn, sampling=True),
@@ -476,6 +509,12 @@ class ServingEngine:
             fork_fn,
             donate_argnums=(0, 1) if jax.default_backend() == "tpu"
             else ())
+
+    def _on_device(self):
+        """Allocate and compile for the configured device, where one
+        is configured."""
+        return jax.default_device(self.cfg.device) \
+            if self.cfg.device is not None else contextlib.nullcontext()
 
     def _dispatch(self, family, jitted, args):
         """Route through the PR-4 compile observatory when one is
@@ -616,22 +655,39 @@ class ServingEngine:
     def step(self):
         """One scheduler iteration: reap (cancellations + deadlines),
         admit, at most one prefill chunk, one decode batch. Returns
-        True when any work was done. The whole iteration, the wait for
-        the engine's lock included, is one `serving_step` telemetry span
-        whose children name its phases (`serving_step.lock_wait`,
-        `.schedule`, `.blocks`, `.build`, `serving_dispatch`, `.fetch`,
-        `.emit`, `.mem_snapshot`, `.gauges`): a lane next to the
-        per-request lanes in the Chrome export, and under a running
-        `jax.profiler` trace a line of the XPlane beside the device's
-        ops, where they say what the host did while the device idled."""
+        True when any work was done.
+
+        The decode batch is dispatched BEFORE the previous call's is
+        fetched: this call schedules, grows blocks, builds and
+        dispatches decode n+1 while n runs, and only then waits for n's
+        tokens, emits them, finalizes, and samples memory and gauges —
+        all under n+1's time on the device. A final chunk's first token
+        is fetched in the same late phase and its request takes a slot
+        then, so no wait stands between two dispatches.
+
+        The whole iteration, the wait for the engine's lock included,
+        is one `serving_step` telemetry span whose children name its
+        phases (`serving_step.lock_wait`, `.schedule`, `.blocks`,
+        `.build`, `serving_dispatch`, `.fetch`, `.emit`,
+        `.mem_snapshot`, `.gauges`): a lane next to the per-request
+        lanes in the Chrome export, and under a running `jax.profiler`
+        trace a line of the XPlane beside the device's ops, where they
+        say what the host did while the device idled."""
         with _span("serving_step") as whole:
             wait = _span("serving_step.lock_wait").begin()
             with self._mu:
                 wait.end()
                 whole.set(step=self._steps)
-                self._schedule()
-                did = self._prefill_one()
-                did = self._decode_once() or did
+                try:
+                    self._schedule()
+                    prev = self._in_flight
+                    did, first = self._prefill_one()
+                    self._in_flight = self._decode_dispatch(prev)
+                    did = self._retire(prev, first) or did \
+                        or self._in_flight is not None
+                except BaseException:
+                    self._void_in_flight()
+                    raise
                 self._steps += 1
                 if self._steps % self.cfg.mem_sample_every == 0:
                     with _span("serving_step.mem_snapshot"):
@@ -699,13 +755,51 @@ class ServingEngine:
                         f"({req.deadlines!r})", which=why),
                     counter="serving.deadline_exceeded", reason=why)
 
+    def _has_work(self):     # requires: _mu
+        """Requests to serve, or a dispatched step whose tokens nobody
+        fetched yet: what `sched.has_work()` cannot see."""
+        return self._in_flight is not None or self.sched.has_work()
+
+    def _void_in_flight(self):     # requires: _mu
+        """A step raised. Errors surface a step late — at the fetch of
+        step n with step n+1 already queued behind it — so the tokens of
+        up to two decode steps and of a last chunk are on the device and
+        will never be fetched. Drop them, and take every request back to
+        the newest token the host holds: the positions past it are
+        computed again, to the same K/V and (by `fold_in(key, count)`)
+        the same samples, so whoever steps again — the serve loop after
+        `_on_step_error`, or a caller that drives `step()` by hand —
+        loses no token and repeats none."""
+        self._in_flight = None
+        self._stats_pending.clear()
+        for req in self.sched.prefilling + \
+                [r for r in self.sched.running if r is not None]:
+            req.n_prefilled = min(req.n_prefilled, len(req.tokens_all) - 1)
+
+    def _flush(self):     # requires: _mu
+        """Retire the step in flight outside `step()`: the callers that
+        hand the engine back (run_until_idle, stop, emit_quiesce) leave
+        nothing on the device unfetched. Writes no `serving_step.*`
+        span: those belong to the loop's thread."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            try:
+                self._emit_flight(flight, *self._fetch_flight(flight))
+            except BaseException:
+                self._void_in_flight()
+                raise
+
     def run_until_idle(self, max_steps=None):
         n = 0
-        while self.sched.has_work():
+        while max_steps is None or n < max_steps:
+            with self._mu:
+                if not self._has_work():
+                    return n
             self.step()
             n += 1
-            if max_steps is not None and n >= max_steps:
-                break
+        with self._mu:
+            self._flush()   # cut short by max_steps: nothing stays in flight
+            self._update_gauges()
         return n
 
     def start(self):    # threadlint: lock-free (caller-serialized lifecycle; flags are none-guarded)
@@ -761,6 +855,10 @@ class ServingEngine:
             return joined
         try:
             self._stopped = True
+            try:
+                self._flush()   # the loop's last step: its tokens are real
+            except Exception:   # noqa: BLE001 — stop() must get to the end
+                pass
             leftovers = (list(self.sched.waiting)
                          + list(self.sched.prefilling)
                          + [r for r in self.sched.running
@@ -810,7 +908,7 @@ class ServingEngine:
         if loop_alive:
             while True:
                 with self._cv:
-                    if not self.sched.has_work() or self._dead:
+                    if not self._has_work() or self._dead:
                         break
                     self._cv.wait(timeout=0.05)
                 if timeout is not None and \
@@ -821,7 +919,8 @@ class ServingEngine:
                     return False
         else:
             self.run_until_idle()
-        completed = not self.sched.has_work()
+        with self._mu:
+            completed = not self._has_work()
         if completed and self.prefix_index is not None:
             # a drain precedes a restart or shutdown: the arenas (and
             # their physical ids) do not survive it, so the index must
@@ -847,6 +946,7 @@ class ServingEngine:
         tools/trace_check.py enforces it) plus the pool's allocation
         count (must be zero — a leak here is a dropped request)."""
         with self._mu:
+            self._flush()
             ps = self._prefix_stats
             offered = ps["tokens_offered"]
             self._record("quiesce", kv_blocks_used=self.pool.num_used,
@@ -868,7 +968,7 @@ class ServingEngine:
             with self._cv:
                 if self._stopping:
                     return
-                if not self.sched.has_work():
+                if not self._has_work():
                     self._cv.wait(timeout=0.1)
                     continue
             try:
@@ -883,7 +983,6 @@ class ServingEngine:
                 if backoff:
                     self._sleep(backoff)
                 continue
-            self._restarts = 0          # a completed step resets the cap
             with self._cv:
                 self._cv.notify_all()   # wake drain()/result() waiters
             if not did:
@@ -905,9 +1004,7 @@ class ServingEngine:
         self.sched.pool = self.pool
         if self.prefix_index is not None:
             self.prefix_index.bind(self.pool)
-        with jax.default_device(self.cfg.device) \
-                if self.cfg.device is not None \
-                else contextlib.nullcontext():
+        with self._on_device():
             self.cache = self.cache.fresh()
         self._stats_pending.clear()     # counts of the failed step's arrays
 
@@ -936,6 +1033,10 @@ class ServingEngine:
         kind = classify_failure(exc)
         traceback.print_exc()
         with self._mu:
+            # `step()` voided what it had in flight when it raised (the
+            # failed step n and the step n+1 queued behind it): no token
+            # of either is fetched, and the requests below are failed,
+            # or requeued and replay them
             if is_oom(exc):
                 # capture-on-failure: write the postmortem BEFORE the
                 # arena rebuild below frees the evidence (the ledger
@@ -1033,7 +1134,8 @@ class ServingEngine:
         new = got[0]
         args = (self.cache.k, self.cache.v, np.int32(old), np.int32(new))
         with _span("serving_dispatch", family="serving_fork",
-                   cache_kind=self._cache_kind_names):
+                   cache_kind=self._cache_kind_names,
+                   in_flight=int(self._in_flight is not None)):
             new_k, new_v = self._dispatch("serving_fork", self._fork_jit,
                                           args)
         self.cache.swap(new_k, new_v)
@@ -1045,6 +1147,10 @@ class ServingEngine:
         return True
 
     def _prefill_one(self):     # requires: _mu
+        """Dispatch at most one chunk of one request. Returns (did,
+        first): `first` is (request, token, logp) when the chunk was the
+        request's last — its sampled token is the stream's next one and
+        is still on the device; `_retire` fetches it."""
         sched = self.sched
         # prefill growth normally WAITS for blocks instead of evicting
         # (a not-yet-streaming request must never thrash the decode
@@ -1100,18 +1206,22 @@ class ServingEngine:
                        rid=req.rid, p0=p0, n_real=c_real,
                        kv_rows=flash_prefill_kv_rows(
                            p0, c_real, self.block_size),
-                       cache_kind=self._cache_kind_names):
+                       cache_kind=self._cache_kind_names,
+                       in_flight=int(self._in_flight is not None)):
                 tok, logp, new_k, new_v, stats = self._dispatch(
                     "serving_prefill", self._prefill_jit, args)
             last = p0 + c_real >= len(seq)
-            with _span("serving_step.emit", kind="prefill",
-                       tokens=int(last)):
+            with _span("serving_step.emit", kind="prefill", tokens=0):
                 self.cache.swap(new_k, new_v)
                 # the last reference to the arenas this chunk replaced:
                 # dropped here, freeing them lies inside the span
                 del args
                 monitor.incr("serving.prefill_chunks")
                 req.n_prefilled = p0 + c_real
+                if stats:
+                    # fetched with the next tokens that are, so the
+                    # counts never cost a wait of their own
+                    self._stats_pending.append(stats)
                 if req.trace is not None:
                     req.trace.note_prefill_chunk(time.monotonic(), p0,
                                                  c_real)
@@ -1119,30 +1229,34 @@ class ServingEngine:
                     # full prompt K/V now lives in this request's
                     # blocks: publish the FULL prompt blocks to the
                     # prefix index so later requests with the same
-                    # prefix skip recomputing
+                    # prefix skip recomputing (whoever reads them is
+                    # dispatched after this chunk)
                     sched.note_prefill_done(req)
-                    # final chunk: the sampled token is the next stream
-                    # token (the engine IS the API boundary: tokens must
-                    # land on the host to stream; the second fetch
-                    # copies a buffer the first already waited for)
-                    with _span("serving_step.fetch", kind="prefill"):
-                        tok = int(np.asarray(tok))
-                        logp = float(np.asarray(logp))
-                        self._count_stats(stats)
-                    self._emit(req, tok, logp)
-                    if req.state == PREFILL:    # _emit finishes done ones
-                        sched.place(req)
-                else:
-                    self._count_stats(stats, fetched=False)
-            return True
-        return False
+            return True, ((req, tok, logp) if last else None)
+        return False, None
 
-    def _decode_once(self):     # requires: _mu
+    def _decode_dispatch(self, prev):     # requires: _mu
+        """Build and dispatch one decode batch; returns its `_Flight`,
+        or None when no slot has a token to decode. `prev` is the
+        flight dispatched by the previous call and not retired yet: a
+        slot that continues from it takes its input token from `prev`'s
+        output on the device, and everything else about its step
+        (context, blocks, sampling count, whether `prev`'s token is its
+        last by `max_new_tokens`) is known to the host without it."""
         sched = self.sched
+        # the requests whose newest token is prev's, still unfetched
+        carried = set() if prev is None else {
+            req.rid for slot, req in prev.entries
+            if sched.running[slot] is req}
+
+        def leaving(req):
+            return req.rid in carried and \
+                len(req.out_tokens) + 1 >= req.params.max_new_tokens
+
         with _span("serving_step.blocks", kind="decode"):
             # grow blocks oldest-first so eviction lands on the youngest
             for req in list(sched.admit_order):
-                if req.slot is None:
+                if req.slot is None or leaving(req):
                     continue
                 sched.ensure_blocks(req, req.n_prefilled + 1, evict=True)
                 # decode writes position n_prefilled: defensively fork a
@@ -1151,13 +1265,14 @@ class ServingEngine:
                 if req.slot is not None and bi < len(req.blocks):
                     self._cow_fork(req, bi)
         active = [(i, r) for i, r in enumerate(sched.running)
-                  if r is not None]
+                  if r is not None and not leaving(r)]
         if not active:
-            return False
+            return None
         with _span("serving_step.build", kind="decode"):
             S = self.cfg.max_slots
             mb = self.max_blocks_per_seq
             tokens = np.zeros((S,), np.int32)
+            from_host = np.zeros((S,), np.bool_)
             ctx = np.zeros((S,), np.int32)
             tables = np.full((S, mb), NULL_BLOCK, np.int32)
             keys = np.zeros((S, 2), np.uint32)
@@ -1172,14 +1287,19 @@ class ServingEngine:
             slots = ctx_tokens = 0
             for i, req in active:
                 p = req.params
-                tokens[i] = req.token_at(req.n_prefilled)
+                on_device = req.rid in carried
+                if not on_device:
+                    tokens[i] = req.token_at(req.n_prefilled)
+                    from_host[i] = True
                 ctx[i] = req.n_prefilled
                 if req.n_prefilled > 0:
                     slots += 1
                     ctx_tokens += req.n_prefilled + 1
                 tables[i, :len(req.blocks)] = req.blocks
                 keys[i] = req.rng_key
-                counts[i] = len(req.out_tokens)
+                # the index of the token this step samples: prev's
+                # token is counted although it has not arrived
+                counts[i] = len(req.out_tokens) + on_device
                 temp[i] = p.temperature
                 top_k[i] = p.top_k
                 top_p[i] = p.top_p
@@ -1187,9 +1307,24 @@ class ServingEngine:
             # the cache rows the attention kernel fetches a layer; in
             # a latent layer each is one row that is key and value
             kv_rows = paged_decode_kv_rows(ctx, self.block_size)
-            # numpy args go straight into the jitted call: the C++
-            # dispatch path transfers them, which profiles ~2x cheaper
-            # per step than a python-level jnp.asarray round for each
+            # the continuing slots' tokens are prev's output, still on
+            # the device, and in a steady step that is all of them: the
+            # array goes in as it is (a slot that holds no request reads
+            # whatever prev sampled there: its context is 0, so the step
+            # masks it). A slot placed or replayed since has its token
+            # on the host, and the merge program puts the two together:
+            # ~0.45 ms of host a call (PERF.md §6), paid where the
+            # batch changed and not every step
+            if from_host.any():
+                tokens = self._merge_tokens(
+                    self._no_tokens if prev is None else prev.tok,
+                    tokens, from_host)
+            else:
+                tokens = prev.tok
+            # the other numpy args go straight into the jitted call: the
+            # C++ dispatch path transfers them, which profiles ~2x
+            # cheaper per step than a python-level jnp.asarray round for
+            # each
             args = (self._param_vals(), self.cache.k, self.cache.v,
                     tokens, ctx, tables, keys, counts, temp, top_k, top_p,
                     greedy)
@@ -1201,34 +1336,94 @@ class ServingEngine:
                 else "serving_decode"
         with _span("serving_dispatch", family=family, slots=slots,
                    ctx_tokens=ctx_tokens, kv_rows=kv_rows,
-                   cache_kind=self._cache_kind_names):
+                   cache_kind=self._cache_kind_names,
+                   in_flight=int(prev is not None)):
             tok, logp, new_k, new_v, stats = self._dispatch(
                 family,
                 self._decode_jit if sampling else self._decode_greedy_jit,
                 args)
-        with _span("serving_step.emit", kind="decode", tokens=len(active)):
+        with _span("serving_step.emit", kind="decode", tokens=0):
             self.cache.swap(new_k, new_v)
             # the last reference to the arenas this step replaced:
             # dropped here, freeing them lies inside the span
             del args
-            # host sync: the engine is the API boundary — the sampled
-            # tokens must land on the host to stream/route; logp's
-            # buffer is ready once tok's fetch has waited
-            with _span("serving_step.fetch", kind="decode"):
-                tok = np.asarray(tok)
-                logp = np.asarray(logp)
-                self._count_stats(stats)
             monitor.incr("serving.decode_steps")
-            now = time.monotonic()
-            for i, req in active:
+            if prev is not None:
+                monitor.incr("serving.decode_steps_overlapped")
+            for _, req in active:
+                # position n_prefilled is written by a program that is
+                # dispatched: whatever reads it is dispatched later
                 req.n_prefilled += 1
-                if req.trace is not None:
-                    # O(1) per request per step: extends the coalesced
-                    # decode segment (one span per stretch, never per
-                    # token)
-                    req.trace.note_decode(now)
-                self._emit(req, int(tok[i]), float(logp[i]), now=now)
-        return True
+            if stats:
+                self._stats_pending.append(stats)
+            stats, self._stats_pending = self._stats_pending, []
+        return _Flight(tok, logp, stats, active)
+
+    def _fetch_flight(self, flight):     # requires: _mu
+        """Host sync: the engine is the API boundary — the sampled
+        tokens must land on the host to stream/route; logp's buffer is
+        ready once tok's fetch has waited, and so are the counts of the
+        step and of every chunk dispatched before it."""
+        tok = np.asarray(flight.tok)
+        logp = np.asarray(flight.logp)
+        self._count_stats(flight.stats)
+        return tok, logp
+
+    def _emit_flight(self, flight, tok, logp):     # requires: _mu
+        """Hand a fetched step's tokens to its requests; returns how
+        many. A request that ended while the step was in flight — by
+        the EOS the previous fetch brought, a cancel, a deadline — gets
+        nothing: its token is discarded, never emitted, never counted
+        as generated. One that was preempted meanwhile keeps its token
+        (computed before its blocks went) and replays from it."""
+        now = time.monotonic()
+        emitted = 0
+        for slot, req in flight.entries:
+            if req.state in TERMINAL_STATES:
+                monitor.incr("serving.tokens_discarded")
+                continue
+            if req.slot == slot and req.trace is not None:
+                # O(1) per request per step: extends the coalesced
+                # decode segment (one span per stretch, never per
+                # token)
+                req.trace.note_decode(now)
+            self._emit(req, int(tok[slot]), float(logp[slot]), now=now)
+            emitted += 1
+        return emitted
+
+    def _retire(self, flight, first):     # requires: _mu
+        """The late phase of a step, run while the decode batch just
+        dispatched is on the device: fetch and emit the previous
+        batch's tokens, then the first token of the request whose last
+        chunk this step dispatched, which takes a slot now and decodes
+        from the next step on. Returns True when there was anything."""
+        if flight is not None:
+            with _span("serving_step.emit", kind="decode") as sp:
+                with _span("serving_step.fetch", kind="decode"):
+                    tok, logp = self._fetch_flight(flight)
+                # a decode step ran to its end: that, or a request
+                # finished (`_emit`), resets the restart cap. A step
+                # that only dispatched proves nothing — errors surface
+                # at the fetch — and a replay's prefill alone must not
+                # keep an engine whose decode always fails alive
+                self._restarts = 0
+                sp.set(tokens=self._emit_flight(flight, tok, logp))
+        if first is not None:
+            req, tok, logp = first
+            with _span("serving_step.emit", kind="prefill", tokens=1):
+                with _span("serving_step.fetch", kind="prefill"):
+                    tok = int(np.asarray(tok))
+                    logp = float(np.asarray(logp))
+                    # nothing was dispatched after the chunk, or a
+                    # decode batch took the counts with it
+                    self._count_stats(self._stats_pending)
+                    self._stats_pending = []
+                # a request the decode batch's block growth preempted
+                # since keeps its first token and waits again
+                self._emit(req, tok, logp)
+                if req.state == PREFILL:    # _emit finishes done ones
+                    self.sched.place(req)
+        return flight is not None or first is not None
 
     # ------------------------------------------------------------------
     # helpers
@@ -1236,20 +1431,14 @@ class ServingEngine:
     def _param_vals(self):
         return [p._value for p in self._bound]
 
-    def _count_stats(self, stats, fetched=True):     # requires: _mu
-        """What the layers counted in a step (routed tokens, held
-        expert pairs, ...) comes back with the step's tokens and goes
-        onto the `serving.<name>` counters where the step's tokens are
-        fetched; a chunk that fetches nothing leaves its counts for the
-        next step that does, so they never cost a wait of their own."""
-        if stats:
-            self._stats_pending.append(stats)
-        if not fetched:
-            return
-        for st in self._stats_pending:
+    def _count_stats(self, pending):     # requires: _mu
+        """What the layers counted in the steps of `pending` (routed
+        tokens, held expert pairs, ...) goes onto the `serving.<name>`
+        counters. Called where tokens dispatched no earlier were just
+        fetched, so reading the counts waits for nothing."""
+        for st in pending:
             for name, value in read_stats(st).items():
                 monitor.incr("serving." + name, value)
-        self._stats_pending.clear()
 
     def _table_row(self, req):
         row = np.full((self.max_blocks_per_seq,), NULL_BLOCK, np.int32)
@@ -1301,6 +1490,7 @@ class ServingEngine:
         req.push_token(tok, now=now)
         monitor.incr("serving.tokens_generated")
         if req.done:
+            self._restarts = 0
             monitor.incr("serving.finished")
             t = req.ttft_ms()
             if t is not None:

@@ -87,7 +87,10 @@ class SamplingParams:
 
 class Request:    # guarded by: ServingEngine._mu
     """One in-flight generation. `tokens_all` = prompt + generated; the
-    positions 0..n_prefilled-1 have K/V in the paged cache. A decode
+    positions 0..n_prefilled-1 have K/V in the paged cache, or will
+    have by a program already dispatched: the engine advances
+    n_prefilled when it dispatches a decode step and appends the sampled
+    token when it fetches it, a step later. A decode
     step consumes tokens_all[n_prefilled] (writing its K/V at that
     position) and appends the next sampled token. Preemption resets
     n_prefilled to 0 and frees the blocks — nothing else — so recompute
@@ -283,9 +286,10 @@ class Scheduler:    # guarded by: ServingEngine._mu
     """Slot + block bookkeeping for the continuous-batching loop.
 
     Invariants:
-    - `running[slot]` is None or a Request with state RUNNING and
-      n_prefilled == len(tokens_all) (its next decode consumes its own
-      last token... see Request docstring);
+    - `running[slot]` is None or a Request with state RUNNING whose
+      next decode consumes its own last token at position n_prefilled
+      (see Request docstring; with a decode step in flight that token
+      is the step's output, not yet in `out_tokens`);
     - a PREFILL request holds blocks for positions < n_prefilled plus
       whatever the next chunk needs, but no slot until prefill is done;
     - preemption frees ALL of a victim's blocks and re-queues it at the
@@ -499,7 +503,13 @@ class Scheduler:    # guarded by: ServingEngine._mu
             # drops THIS request's reference only: a prefix-shared
             # block survives at refcount > 0, a cached one parks at
             # refcount 0 under the index (preemption touches private
-            # blocks, never the shared cache)
+            # blocks, never the shared cache).
+            # The engine may still have a step on the device that reads
+            # and writes these blocks (it keeps one decode step in
+            # flight). Freeing them now is safe because the device runs
+            # programs in dispatch order and each takes the arenas from
+            # the one before it (donated): whoever is handed a block
+            # next touches it in a program dispatched after this one
             self.pool.free(req.blocks, owner=req.rid)
             req.blocks = []
         if req.slot is not None:

@@ -217,6 +217,118 @@ def test_dispatch_attributes_equal_what_dispatch_saw(traced):
     assert families == {"serving_prefill", "serving_decode", "serving_fork"}
 
 
+def test_dispatch_says_whether_a_step_was_in_flight(traced):
+    """`in_flight`: was a decode step dispatched and not yet fetched
+    when this program was. The loop keeps one in flight whenever a
+    batch is decoding, so most decode dispatches say 1; the first
+    after an idle engine says 0."""
+    spans = traced.named("serving_dispatch")
+    assert all(ev[3]["in_flight"] in (0, 1) for ev in spans)
+    decodes = [ev[3]["in_flight"] for ev in spans
+               if ev[3]["family"] == "serving_decode"]
+    assert decodes[0] == 0 or 0 in decodes
+    assert sum(decodes) >= 0.8 * len(decodes)
+    assert {ev[3]["in_flight"] for ev in spans
+            if ev[3]["family"] == "serving_prefill"} == {0, 1}
+
+
+def test_each_phase_occurs_once_in_a_steady_step(traced):
+    """A step that dispatched a decode batch behind one in flight has
+    every phase of PERF.md's table: once the four that frame it, once
+    a decode batch's blocks, build, dispatch and fetch, and the two
+    emits around the fetch (the arenas' swap, the tokens)."""
+    line = traced.line_with("serving_step")
+    steady = 0
+    for st in traced.named("serving_step", line):
+        inside = [e for e in line if e is not st and _inside(e, st)]
+        if not any(e[0] == "serving_dispatch"
+                   and e[3]["family"] == "serving_decode"
+                   and e[3]["in_flight"] for e in inside):
+            continue
+        steady += 1
+        count = {}
+        for e in inside:
+            key = e[0] if "kind" not in e[3] else (e[0], e[3]["kind"])
+            count[key] = count.get(key, 0) + 1
+        for name in ("serving_step.lock_wait", "serving_step.schedule",
+                     "serving_step.mem_snapshot", "serving_step.gauges",
+                     ("serving_step.blocks", "decode"),
+                     ("serving_step.build", "decode"),
+                     ("serving_step.fetch", "decode")):
+            assert count.get(name) == 1, (name, count)
+        assert count.get(("serving_step.emit", "decode")) == 2, count
+    assert steady >= 10
+
+
+def test_a_steady_run_overlaps_nine_steps_in_ten():
+    """50 decode steps of one request: every one but the first is
+    dispatched while the one before it is still unfetched."""
+    from paddle_tpu import monitor
+    eng = ServingEngine(_small_gpt(), config=EngineConfig(
+        max_slots=2, block_size=16, prefill_chunk=32, max_model_len=128))
+    steps = monitor.get("serving.decode_steps", 0)
+    over = monitor.get("serving.decode_steps_overlapped", 0)
+    h = eng.submit(np.arange(1, 20, dtype=np.int32),
+                   SamplingParams(max_new_tokens=51))
+    eng.run_until_idle(max_steps=500)
+    assert len(h.output_tokens) == 51
+    steps = monitor.get("serving.decode_steps", 0) - steps
+    over = monitor.get("serving.decode_steps_overlapped", 0) - over
+    assert steps == 50 and steps >= over >= 0.9 * steps
+
+
+def test_dispatch_arguments_are_laid_out_as_the_taps_read_them():
+    """The benchmark's traced runs wrap `_dispatch` and refuse a run
+    whose decode call has not 12 arguments with int32 [max_slots] at 3
+    (tokens; a device array when they continue from the step in flight)
+    and 4 (the real contexts, on the host) and the tables at 5, or whose
+    prefill call has not 13 with ids, p0 and n_real at 3, 4, 5."""
+    S, C, length = 4, 32, 128
+    eng = ServingEngine(_small_gpt(), config=EngineConfig(
+        max_slots=S, block_size=16, prefill_chunk=C, max_model_len=length))
+    seen, dispatch = [], eng._dispatch
+
+    def int32(x, shape):
+        x = np.asarray(x)
+        assert x.dtype == np.int32 and x.shape == shape, (x.dtype, x.shape)
+        return x
+
+    def tap(family, jitted, args):
+        if family == "serving_decode":
+            assert len(args) == 12
+            tokens = int32(args[3], (S,))
+            assert isinstance(args[4], np.ndarray)
+            ctx = int32(args[4], (S,))
+            tables = np.asarray(args[5])
+            assert tables.ndim == 2 and tables.shape[0] == S
+            assert ctx.min() >= 0 and ctx.max() < length
+            assert ((0 <= tokens) & (tokens < 512)).all()
+            seen.append((family, sorted(ctx[ctx > 0].tolist())))
+        elif family == "serving_prefill":
+            assert len(args) == 13
+            int32(args[3], (1, C))
+            p0, n_real = int(int32(args[4], ())), int(int32(args[5], ()))
+            assert 0 <= p0 and 1 <= n_real <= C and p0 + n_real <= length
+            seen.append((family, p0, n_real))
+        return dispatch(family, jitted, args)
+
+    eng._dispatch = tap
+    rs = np.random.RandomState(5)
+    handles = [eng.submit(rs.randint(1, 512, n).astype(np.int32),
+                          SamplingParams(max_new_tokens=6))
+               for n in (40, 7)]
+    eng.run_until_idle(max_steps=500)
+    assert all(len(h.output_tokens) == 6 for h in handles)
+    assert ("serving_prefill", 0, 32) in seen and \
+        ("serving_prefill", 32, 8) in seen
+    # the contexts are the real ones, step by step, with a step in flight
+    contexts = [e[1] for e in seen if e[0] == "serving_decode"]
+    assert contexts[0] == [40]
+    both = [c for c in contexts if len(c) == 2]
+    assert both and all(b[0] == a[0] + 1 and b[1] == a[1] + 1
+                        for a, b in zip(both, both[1:]))
+
+
 def test_submit_is_on_the_callers_thread_with_the_rid(traced):
     line = traced.line_with("serving_submit")
     assert line is not traced.line_with("serving_step")

@@ -250,7 +250,10 @@ def run_smoke(telemetry=None, steps=6):
     for i in range(1, steps + 1):
         eng.step()
         records.append(obs.snapshot(i))
-    list(h.tokens())
+    # the engine keeps a decode step in flight: the sampled steps end
+    # with the newest tokens still on the device
+    eng.run_until_idle(max_steps=100)
+    h.result(timeout=60)
     if obs.sink is not None:
         obs.sink.close()
 

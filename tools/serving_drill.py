@@ -455,10 +455,12 @@ def selfcheck():
         if "leaker" not in str(e):
             misses.append("assert_quiesced fired but did not name the "
                           "leaking owner")
-    # 4) the mini drill must come back clean (the wave must still
-    #    exceed the slot count or the shed probes have no queue to
-    #    bounce off)
-    if drill(n_wave=8, max_new=8) != 0:
+    # 4) the mini drill must come back clean. The wave has to outlast
+    #    the probes that lean on it (three HTTP sheds, the TTFT probe
+    #    and the hangup take ~60 ms together): four rounds of 40 tokens
+    #    are ~350 ms of a toy model's steps on a CPU, where two rounds
+    #    of 8 were ~65 ms and lost that race more often than not
+    if drill(n_wave=16, max_new=40) != 0:
         misses.append("mini drill reported findings on a healthy "
                       "engine")
     for m in misses:

@@ -207,11 +207,12 @@ def _drill_preemption(model, rs):
     eng = _tiny_engine(model, max_slots=4, num_blocks=9,
                        enable_prefix_cache=False)
     _warm(eng, rs)
-    # three long survivors + one short victim: the youngest
-    # block-holder gets evicted and then WAITS for a long survivor to
-    # free blocks before its replay — preemption time dwarfs its own
-    # short decode
-    for max_new in (12, 12, 12, 6):
+    # three long answers + one short: the pool holds two of the long
+    # ones to their ends, so the youngest long block-holder is evicted
+    # again and again (six times) and WAITS for blocks before each
+    # replay — requeue waits and replayed chunks outweigh its decode
+    # with room to spare, whatever the host's timing
+    for max_new in (40, 40, 40, 2):
         eng.submit(rs.randint(0, 256, (16,)).tolist(),
                    SamplingParams(max_new_tokens=max_new))
     eng.run_until_idle(max_steps=20000)
