@@ -501,6 +501,75 @@ def _production_cases(sz):
                 x, l, w, e, (0, E), a, b, c, use_kernel=False)[0],
             (xt, live, wts, jnp.asarray(ex), wg, wu, wd)))
 
+    # the Granite-4.0-H cell's shapes: grouped-query heads (32 over 8 of
+    # 64) in both paged kernels with contexts on every edge of the
+    # tiling, the state step over 64 slots (idle ones among them, rows
+    # out of order) and the chunked scan over a padded chunk of 512
+    from paddle_tpu.ops import pallas_ssm as ps
+    N, Nk, H, pbs = (8, 2, 64, 16) if TINY else (32, 8, 64, 16)
+    mb, wk = (8 if TINY else 160), Nk * H
+    gq = dict(kv_heads=Nk, scale=1.0 / 64)
+    T = pd.paged_decode_tile_rows(pbs, wk, Nk, 2, mb, N // Nk)
+    ctxs = sorted({0, pbs - 1, pbs, T - 1, T, T + 1, 2 * T + 3,
+                   mb * pbs - 1} & set(range(mb * pbs)))
+    ctxs += [int(c) for c in rs.randint(1, mb * pbs, 12 - len(ctxs))]
+    pages = rs.permutation(np.arange(
+        1, sum(c // pbs + 1 for c in ctxs) + 1))
+    tabs, used = np.zeros((len(ctxs) + 1, mb), np.int32), 0
+    for i, c in enumerate(ctxs):
+        tabs[i, :c // pbs + 1] = pages[used:used + c // pbs + 1]
+        used += c // pbs + 1
+    ctxs = np.asarray(ctxs + [0], np.int32)         # + the idle slot
+    arena = (len(pages) + 1, pbs, wk)
+    cases.append((
+        ("paged_decode",),
+        f"paged_decode {N} heads over {Nk}x{H} mb={mb} tile={T} rows",
+        lambda q, k, v, t, c: pd.paged_decode_attention(
+            q, k, v, t, c, N, use_kernel=True, **gq),
+        lambda q, k, v, t, c: pd.paged_decode_attention(
+            q, k, v, t, c, N, use_kernel=False, **gq),
+        (rand((len(ctxs), 1, N * H)), rand(arena), rand(arena), tabs,
+         ctxs)))
+    C = 32 if TINY else 512
+    for p0, n_real in ((0, C), (C, C), (mb * pbs - C, C), (C + 24, C // 2 - 3)):
+        row = np.zeros((mb,), np.int32)
+        n_alloc = (p0 + n_real - 1) // pbs + 1
+        row[:n_alloc] = pages[:n_alloc]
+        cases.append((
+            ("flash_prefill_chunk",),
+            f"flash_prefill_chunk {N} heads over {Nk}x{H} C={C} p0={p0} "
+            f"n_real={n_real}",
+            lambda q, k, v, t, p0=p0, n=n_real: pd.flash_prefill_chunk(
+                q, k, v, t, np.int32(p0), N, use_kernel=True,
+                n_real=np.int32(n), **gq)[:, :n],
+            lambda q, k, v, t, p0=p0, n=n_real: pd.flash_prefill_chunk(
+                q, k, v, t, np.int32(p0), N, use_kernel=False, **gq)[:, :n],
+            (rand((1, C, N * H)), rand(arena), rand(arena), row)))
+    Ns, D, Hm, S = (16, 256, 8, 6) if TINY else (128, 4096, 64, 64)
+    f32 = jnp.float32
+    rows = rs.permutation(np.arange(1, S + 1)).astype(np.int32)
+    live = np.ones((S,), bool)
+    live[[1, S - 2]] = False
+    rows[~live] = 0
+    cases.append((
+        ("mamba2_state_step",),
+        f"mamba2_state_step {S} slots over [{Ns}, {D}]",
+        lambda *a: ps.mamba2_state_step(*a, use_kernel=True),
+        lambda *a: ps.mamba2_state_step(*a, use_kernel=False),
+        (rand((S + 1, Ns, D), f32, 1.0), rows, live,
+         jnp.asarray(rs.uniform(0.5, 1.0, (S, D)), f32),
+         rand((S, D), f32), rand((S, Ns)), rand((S, Ns)))))
+    Cs, piece = (256, 128) if TINY else (512, 256)
+    dt = rs.uniform(0.001, 0.1, (Cs, Hm)).astype(np.float32)
+    dt[Cs - 37:] = 0.0                              # padding positions
+    cases.append((
+        ("mamba2_chunk_scan",),
+        f"mamba2_chunk_scan C={Cs} in pieces of {piece} over [{Ns}, {D}]",
+        lambda *a: ps.mamba2_chunk_scan(*a, piece=piece, use_kernel=True),
+        lambda *a: ps.mamba2_chunk_scan(*a, piece=piece, use_kernel=False),
+        (rand((Cs, D)), dt, -jnp.asarray(rs.uniform(1, 16, (Hm,)), f32),
+         rand((Cs, Ns)), rand((Cs, Ns)), rand((Ns, D), f32, 1.0))))
+
     from paddle_tpu.ops import pallas_int8 as p8
     vocab = -(-cfg.vocab_size // p8._BLOCK_V) * p8._BLOCK_V   # row-padded
     hq = rand((16, nh), scale=1.0)

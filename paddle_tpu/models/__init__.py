@@ -14,3 +14,6 @@ from .ocr import (  # noqa: F401
 from .deepseek_v2 import (  # noqa: F401
     DeepseekV2Config, DeepseekV2ForCausalLM,
 )
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridForCausalLM,
+)

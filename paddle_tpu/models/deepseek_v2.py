@@ -31,11 +31,12 @@ tests hold the absorbed path to. There is no training path.
 import jax
 import jax.numpy as jnp
 
-from ..core.tensor import Parameter, Tensor
+from ..core.tensor import Tensor
 from ..moe.serving import held_expert_ffn, route_group_limited
 from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
 from ..ops.pallas_mla import mla_paged_decode, mla_prefill_chunk
+from .blocks import GatedMLP, Weights, default_make, matmul
 from ..ops.rotary import (apply_rotary, rotary_cos_sin, yarn_inv_freq,
                           yarn_mscale)
 
@@ -121,24 +122,6 @@ class DeepseekV2Config:
         return -(-width // _LANES) * _LANES
 
 
-class _Weights(Layer):
-    """A layer whose parameters come from `make(name, shape, kind)`:
-    kind "w" a matrix, "g" a norm's gain."""
-
-    def __init__(self, make, prefix):
-        super().__init__()
-        self._make, self._prefix = make, prefix
-
-    def param(self, name, shape, kind="w"):
-        return Parameter(self._make(self._prefix + name, tuple(shape), kind),
-                         trainable=False)
-
-
-def _matmul(x, w):
-    return jnp.dot(x, w.astype(x.dtype), preferred_element_type=jnp.float32) \
-        .astype(x.dtype)
-
-
 def _einsum(spec, a, b):
     """A contraction batched over heads, summed in float32, out in a's
     dtype. The CPU runtime has no batched bfloat16 product with a
@@ -152,22 +135,7 @@ def _einsum(spec, a, b):
     return out.astype(a.dtype)
 
 
-class GatedMLP(_Weights):
-    """down(silu(gate(x)) * up(x))."""
-
-    def __init__(self, make, prefix, d, width):
-        super().__init__(make, prefix)
-        self.gate = self.param("gate", (d, width))
-        self.up = self.param("up", (d, width))
-        self.down = self.param("down", (width, d))
-
-    def run(self, x):
-        g = _matmul(x, self.gate._value)
-        return _matmul(jax.nn.silu(g) * _matmul(x, self.up._value),
-                       self.down._value)
-
-
-class ExpertLayer(_Weights):
+class ExpertLayer(Weights):
     """Shared experts (one gated MLP of their summed width) plus this
     model's share of the routed experts."""
 
@@ -202,7 +170,7 @@ class ExpertLayer(_Weights):
         return self.shared.run(x) + routed, stats
 
 
-class MLAttention(_Weights):
+class MLAttention(Weights):
     def __init__(self, make, prefix, c):
         super().__init__(make, prefix)
         d, H = c.hidden_size, c.num_attention_heads
@@ -230,11 +198,11 @@ class MLAttention(_Weights):
         c = self.c
         T, H = x.shape[0], c.num_attention_heads
         eps = c.rms_norm_eps
-        cq = rms_norm_values(_matmul(x, self.q_a._value),
+        cq = rms_norm_values(matmul(x, self.q_a._value),
                              self.q_a_norm._value, eps)
-        q = _matmul(cq, self.q_b._value).reshape(
+        q = matmul(cq, self.q_b._value).reshape(
             T, H, c.qk_nope_head_dim + c.qk_rope_head_dim)
-        kv = _matmul(x, self.kv_a._value)
+        kv = matmul(x, self.kv_a._value)
         c_kv = rms_norm_values(kv[:, :c.kv_lora_rank],
                                self.kv_a_norm._value, eps)
         cos, sin = rotary_cos_sin(positions, self._inv_freq,
@@ -261,7 +229,7 @@ class MLAttention(_Weights):
     def output(self, o_lat):
         """o_lat [T, H, rank] (softmax-weighted c_kv) -> [T, d]."""
         o = _einsum("thc,hcv->thv", o_lat, self.w_uv._value)
-        return _matmul(o.reshape(o.shape[0], -1), self.o._value)
+        return matmul(o.reshape(o.shape[0], -1), self.o._value)
 
     def dense(self, x, positions):
         """Causal attention of one whole sequence with k and v a head
@@ -276,10 +244,10 @@ class MLAttention(_Weights):
         causal = positions[None, :, None] >= positions[None, None, :]
         probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
         o = _einsum("hts,shv->thv", probs.astype(x.dtype), v)
-        return _matmul(o.reshape(o.shape[0], -1), self.o._value)
+        return matmul(o.reshape(o.shape[0], -1), self.o._value)
 
 
-class DeepseekV2Block(_Weights):
+class DeepseekV2Block(Weights):
     def __init__(self, make, prefix, c, dense):
         super().__init__(make, prefix)
         d = c.hidden_size
@@ -365,20 +333,6 @@ class ServedDeepseekV2:
         return self.model.logits(h)
 
 
-def _default_make(config):
-    """Parameters nobody handed over: N(0, initializer_range) matrices
-    and unit gains, from the default generator, in the config's dtype."""
-    from ..core.random import default_generator
-    dtype = jnp.dtype(config.dtype)
-
-    def make(name, shape, kind):
-        if kind == "g":
-            return jnp.ones(shape, dtype)
-        return (config.initializer_range * jax.random.normal(
-            default_generator().split(), shape, jnp.float32)).astype(dtype)
-    return make
-
-
 class DeepseekV2ForCausalLM(Layer):
     """`make(name, shape, kind)` supplies each parameter (a checkpoint
     loader, seeded weights drawn on the device); by default they are
@@ -387,8 +341,8 @@ class DeepseekV2ForCausalLM(Layer):
     def __init__(self, config, make=None):
         super().__init__()
         c = self.config = config
-        make = make or _default_make(c)
-        top = _Weights(make, "")
+        make = make or default_make(c)
+        top = Weights(make, "")
         self.embed = top.param("embed", (c.vocab_size, c.hidden_size))
         self.blocks = LayerList([
             DeepseekV2Block(make, f"blocks.{i}.", c,

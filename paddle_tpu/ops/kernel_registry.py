@@ -215,6 +215,7 @@ def _load_inventory():
     from . import pallas_int8  # noqa: F401
     from . import pallas_layernorm  # noqa: F401
     from . import pallas_mla  # noqa: F401
+    from . import pallas_ssm  # noqa: F401
     from ..moe import kernels  # noqa: F401
     from ..moe import serving  # noqa: F401
     return True

@@ -51,6 +51,14 @@ columns a step on lane-sliced copies where it does not
 layer at the serving cells' shapes, 35-43% of the HBM roofline from a
 context of 700 rows on (PERF.md §6, PR 29).
 
+Both paged kernels take grouped-query heads (`kv_heads`: the arenas are
+`kv_heads * head_dim` wide and `n_heads // kv_heads` query heads read
+each K/V head). The query heads are regrouped outside the kernel into
+`group` rows over the arenas' lanes, member i of every K/V head side by
+side; `paged_decode` puts the members on further sublanes of the same
+two products, `flash_prefill_chunk` gives each member grid steps of its
+own. With `kv_heads == n_heads` both are what they were.
+
 Both paged kernels have a gather+dense fallback that reproduces the
 composed einsum math of models/gpt._cached_attention bit for bit, so
 CPU serving stays identical to run_generate; it is also the parity
@@ -191,10 +199,11 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, s_ref, e_ref, out_ref,
 
 
 def paged_decode_tile_rows(block_size, hidden, n_heads, itemsize,
-                           max_blocks):
+                           max_blocks, group=1):
     """Rows of K and of V that one step of the paged decode kernel
     works on: the tile policy, a pure function of what the arguments'
-    shapes show. 0 when no tile fits.
+    shapes show. 0 when no tile fits. `hidden` and `n_heads` are the
+    arenas' (the K/V heads); `group` query heads read each.
 
     A tile is a whole number of cache pages and of 128-lane logits
     columns, so its rows are a multiple of lcm(block_size, 128). Among
@@ -205,7 +214,8 @@ def paged_decode_tile_rows(block_size, hidden, n_heads, itemsize,
     one unit, and (c) fits `VMEM_BUDGET` with both of its buffers."""
     return tile_rows_within(
         block_size, max_blocks,
-        lambda rows: _paged_footprint(rows, hidden, n_heads, itemsize))
+        lambda rows: _paged_footprint(rows, hidden, n_heads, itemsize,
+                                      group))
 
 
 def tile_rows_within(block_size, max_blocks, footprint):
@@ -232,15 +242,15 @@ def _head_rows(n_heads):
     return -(-n_heads // 16) * 16
 
 
-def _paged_footprint(rows, hidden, n_heads, itemsize):
+def _paged_footprint(rows, hidden, n_heads, itemsize, group=1):
     """KN502 projection of the paged decode kernel at a tile of `rows`:
     K and V tiles in two buffers each (the kernel's own double buffer:
     scratch, so charged once a buffer), q and the output block moving
     with the slot, the per-head accumulator, and the [heads, rows] f32
     logits/probabilities plus the [heads, hidden] product as temps."""
-    R = _head_rows(n_heads)
+    R = group * _head_rows(n_heads)
     return vmem_footprint(
-        moving=[((1, hidden), itemsize), ((1, hidden), 4)],
+        moving=[((group, hidden), itemsize), ((group, hidden), 4)],
         scratch=[((2, rows, hidden), itemsize)] * 2
         + [((R, hidden), 4), ((R, _COLS), 4), ((R, _COLS), 4)],
         temp_bytes=(3 * R * rows + 2 * R * hidden) * 4)
@@ -313,7 +323,7 @@ def walk_tiles(step, n_steps, last_pos, page_copies, buf_ref, compute,
 
 def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
                   k_buf, v_buf, sems, buf_ref, m_sc, l_sc, acc_sc,
-                  *, scale, bs, rows, n_heads, head_dim):
+                  *, scale, bs, rows, n_heads, head_dim, group):
     """One grid step a SLOT; inside it `walk_tiles` over the tiles that
     the slot's context reaches, `ctx // rows + 1` of them. The arenas
     stay in HBM: a page is one contiguous run found through the
@@ -324,9 +334,15 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     `qh . K^T` [R, rows] and its weighted values `p @ V` [R, N*H], both
     in the arenas' dtype with float32 accumulation; row n of the
     product is head n's output on head n's lanes (the other lanes are
-    never read). Softmax statistics are float32, one column a row."""
+    never read). Softmax statistics are float32, one column a row.
+
+    `n_heads` are the K/V heads. With `group` query heads to each, q
+    arrives as [group, N*H], row i holding member i of every K/V head on
+    that head's lanes, and the sublanes hold `group` blocks of heads:
+    row i * R/group + n is member i of K/V head n."""
     b = pl.program_id(0)
     R = acc_sc.shape[0]
+    Rk = R // group
     nh = n_heads * head_dim
     ctx = ctx_ref[b]
 
@@ -346,12 +362,15 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     m_sc[...] = jnp.full_like(m_sc, -1e30)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc_sc[...] = jnp.zeros_like(acc_sc)
-    head = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 0)
+    head = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 0) % Rk
     lane = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 1)
     own = jnp.logical_and(lane >= head * head_dim,
                           lane < (head + 1) * head_dim)
-    qh = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0) \
-        .astype(k_buf.dtype)                              # [R, NH]
+    q = q_ref[0].astype(jnp.float32)                      # [group, NH]
+    if group > 1:
+        q = jnp.concatenate([jnp.broadcast_to(q[i:i + 1], (Rk, nh))
+                             for i in range(group)], axis=0)
+    qh = jnp.where(own, q, 0.0).astype(k_buf.dtype)       # [R, NH]
 
     def compute(t, buf):
         logits = jax.lax.dot_general(
@@ -379,23 +398,25 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     # every slot's first tile holds position 0, so no denominator is 0
     # but those of the padding rows past the last head
     denom = jnp.where(l_sc[:, :1] == 0.0, 1.0, l_sc[:, :1])
-    out = jnp.sum(jnp.where(own, acc_sc[...] / denom, 0.0),
-                  axis=0, keepdims=True)                  # [1, NH]
-    out_ref[0] = out
+    out = jnp.where(own, acc_sc[...] / denom, 0.0)
+    for i in range(group):                                # [1, NH] each
+        out_ref[0, i:i + 1] = jnp.sum(out[i * Rk:(i + 1) * Rk],
+                                      axis=0, keepdims=True)
 
 
 def paged_decode_supported(block_size, hidden, n_heads, itemsize=2,
-                           max_blocks=_COLS):
+                           max_blocks=_COLS, group=1):
     """Gate for the fused PAGED decode kernel (the block-pool serving
     cache, paddle_tpu/serving/kv_cache.py): a page is a whole number of
     the dtype's packed sublane tiles (8 rows of float32, 16 of bf16) so
     that a page copy lands tile-aligned, the lanes are whole, and the
-    tile policy finds a tile that fits VMEM."""
+    tile policy finds a tile that fits VMEM. `hidden` and `n_heads` are
+    the arenas' (the K/V heads), `group` the query heads to each."""
     if block_size % _packed_rows(itemsize) or hidden % _COLS \
-            or n_heads > _COLS:
+            or n_heads * group > _COLS:
         return False
     return paged_decode_tile_rows(
-        block_size, hidden, n_heads, itemsize, max_blocks) > 0
+        block_size, hidden, n_heads, itemsize, max_blocks, group) > 0
 
 
 def _paged_example(rng):
@@ -421,10 +442,29 @@ def _paged_example(rng):
 
 
 def _paged_fallback(q, k_pages, v_pages, block_tables, ctx_lens,
-                    n_heads, use_kernel=None):
+                    n_heads, use_kernel=None, **kw):
     # the in-function gather+dense path IS the declared exact fallback
     return paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                  ctx_lens, n_heads, use_kernel=False)
+                                  ctx_lens, n_heads, use_kernel=False, **kw)
+
+
+def _regroup(q, kv_heads, group):
+    """q [A, T, N*H] with N = kv_heads * group -> [A, group, T,
+    kv_heads*H]: row i holds member i of every K/V head's query heads,
+    side by side on that K/V head's lanes."""
+    A, T, nh = q.shape
+    H = nh // (kv_heads * group)
+    return jnp.transpose(q.reshape(A, T, kv_heads, group, H),
+                         (0, 3, 1, 2, 4)).reshape(A, group, T, kv_heads * H)
+
+
+def _ungroup(o, kv_heads, group):
+    """The inverse of `_regroup`: [A, group, T, kv_heads*H] -> [A, T,
+    N*H] with the query heads in their own order."""
+    A, _, T, wk = o.shape
+    H = wk // kv_heads
+    return jnp.transpose(o.reshape(A, group, T, kv_heads, H),
+                         (0, 2, 3, 1, 4)).reshape(A, T, group * wk)
 
 
 @register_kernel(
@@ -439,13 +479,18 @@ def _paged_fallback(q, k_pages, v_pages, block_tables, ctx_lens,
 # same shapes, share one trace and one lowering of the kernel: traced
 # once a layer, the decode programs' 24 or 48 copies cost seconds of
 # every start, compile cache or not
-@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel",
+                                             "kv_heads", "scale"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
-                           n_heads, use_kernel=None):
+                           n_heads, use_kernel=None, kv_heads=None,
+                           scale=None):
     """Decode attention (q_len == 1) over a PAGED KV cache.
 
-    q [S, 1, N*H]; k_pages/v_pages [num_blocks, block_size, N*H] — the
-    shared physical arenas; block_tables [S, max_blocks] int32 mapping
+    q [S, 1, N*H]; k_pages/v_pages [num_blocks, block_size, Nk*H] — the
+    shared physical arenas, Nk = `kv_heads` (default N: as many K/V
+    heads as query heads; fewer: query head n reads K/V head
+    n // (N // Nk)); `scale` on the scores (default H ** -0.5);
+    block_tables [S, max_blocks] int32 mapping
     each row's logical block i to a physical block (unallocated tail
     entries point at the reserved null block 0); ctx_lens [S] int32 —
     each row's current position (keys at logical positions 0..ctx are
@@ -467,21 +512,28 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         raise ValueError("paged_decode_attention is q_len==1 only")
     N = n_heads
     H = nh // N
+    Nk = N if kv_heads is None else int(kv_heads)
+    if N % Nk:
+        raise ValueError(f"{N} query heads over {Nk} K/V heads")
+    G, wk = N // Nk, Nk * H
     num_blocks, bs, _ = k_pages.shape
     mb = block_tables.shape[1]
-    scale = 1.0 / float(np.sqrt(H))
+    scale = 1.0 / float(np.sqrt(H)) if scale is None else float(scale)
     itemsize = k_pages.dtype.itemsize
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
-                      and paged_decode_supported(bs, nh, N, itemsize, mb))
+                      and paged_decode_supported(bs, wk, Nk, itemsize, mb,
+                                                 G))
     if not use_kernel:
         # gather+dense: EXACTLY the composed einsum path of
         # models/gpt._cached_attention (dtypes included) over the
         # gathered pages — bit-parity with the dense decode cache is
         # what makes the CPU serving smoke token-identical
         L = mb * bs
-        k4 = k_pages[block_tables].reshape(S, L, N, H)
-        v4 = v_pages[block_tables].reshape(S, L, N, H)
+        k4 = k_pages[block_tables].reshape(S, L, Nk, H)
+        v4 = v_pages[block_tables].reshape(S, L, Nk, H)
+        if G > 1:       # each K/V head under the query heads that read it
+            k4, v4 = jnp.repeat(k4, G, axis=2), jnp.repeat(v4, G, axis=2)
         q4 = q.reshape(S, 1, N, H)
         logits = jnp.einsum("bqnh,bknh->bnqk", q4, k4.astype(q.dtype),
                             preferred_element_type=jnp.float32) * scale
@@ -492,42 +544,46 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         out = jnp.einsum("bnqk,bknh->bqnh", probs, v4.astype(q.dtype))
         return out.reshape(S, 1, nh)
 
-    rows = paged_decode_tile_rows(bs, nh, N, itemsize, mb)
+    rows = paged_decode_tile_rows(bs, wk, Nk, itemsize, mb, G)
     if not rows:
         raise ValueError(
             f"paged_decode kernel: no tile of {bs}-row pages at width "
-            f"{nh} fits VMEM (see paged_decode_supported)")
-    R = _head_rows(N)
+            f"{wk} fits VMEM (see paged_decode_supported)")
+    R = G * _head_rows(Nk)
+    # [S, G, wk]: as it is where every query head has its own K/V head
+    qg = _regroup(q, Nk, G)[:, :, 0] if G > 1 else q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, 1, nh), lambda b, tab, ctx: (b, 0, 0)),
+            pl.BlockSpec((1, G, wk), lambda b, tab, ctx: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, nh), lambda b, tab, ctx: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, wk), lambda b, tab, ctx: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, rows // bs, bs, nh), k_pages.dtype),
-            pltpu.VMEM((2, rows // bs, bs, nh), v_pages.dtype),
+            pltpu.VMEM((2, rows // bs, bs, wk), k_pages.dtype),
+            pltpu.VMEM((2, rows // bs, bs, wk), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((R, _COLS), jnp.float32),
             pltpu.VMEM((R, _COLS), jnp.float32),
-            pltpu.VMEM((R, nh), jnp.float32),
+            pltpu.VMEM((R, wk), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, rows=rows,
-                          n_heads=N, head_dim=H),
+                          n_heads=Nk, head_dim=H, group=G),
         name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, nh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, G, wk), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      qg, k_pages, v_pages)
+    if G > 1:
+        out = _ungroup(out[:, :, None], Nk, G)
     return out.astype(q.dtype)
 
 
@@ -602,7 +658,7 @@ def flash_prefill_kv_rows(p0, n_real, block_size):
 
 def _prefill_kernel(tab_ref, span_ref, q_ref, k_hbm, v_hbm, out_ref,
                     k_buf, v_buf, sems, buf_ref, m_sc, l_sc, acc_sc,
-                    *, scale, bs, rows, lanes, heads):
+                    *, scale, bs, rows, lanes, heads, group):
     """One grid step a GROUP of head columns (all of them where they
     fit: `flash_prefill_tiling`); inside it `walk_tiles` over the tiles
     up to the chunk's last real position `span[1]`, each tile's live
@@ -618,7 +674,13 @@ def _prefill_kernel(tab_ref, span_ref, q_ref, k_hbm, v_hbm, out_ref,
     position: query row r stands at `p0 + r % C`, a padding query past
     the last real one at that one's position. Every tile is masked: a
     second body for the tiles wholly below `p0` measured no faster
-    (PERF.md section 6, PR 29)."""
+    (PERF.md section 6, PR 29).
+
+    With `group` query heads to a K/V head, q is [group, C, lanes of the
+    arenas], member i of every K/V head on that head's lanes, and the
+    grid runs the members of a group of columns one after another: step
+    s works on member s % group of column group s // group, and fetches
+    that group's K and V again."""
     g = pl.program_id(0)
     _, C, W = q_ref.shape
     Q, cols = heads * C, W // lanes
@@ -627,7 +689,8 @@ def _prefill_kernel(tab_ref, span_ref, q_ref, k_hbm, v_hbm, out_ref,
     def page_copies(s, i, buf, j):
         blk = tab_ref[i]
         at = (blk,) if W == k_hbm.shape[2] else \
-            (blk, slice(None), pl.ds(pl.multiple_of(s * W, _COLS), W))
+            (blk, slice(None),
+             pl.ds(pl.multiple_of(s // group * W, _COLS), W))
         return (pltpu.make_async_copy(
                     k_hbm.at[at], k_buf.at[buf, j], sems.at[0, buf]),
                 pltpu.make_async_copy(
@@ -726,10 +789,10 @@ def _prefill_example(rng):
 
 
 def _prefill_fallback(q, k_pages, v_pages, table_row, p0, n_heads,
-                      use_kernel=None):
+                      use_kernel=None, **kw):
     # the in-function gather+dense path IS the declared exact fallback
     return flash_prefill_chunk(q, k_pages, v_pages, table_row, p0,
-                               n_heads, use_kernel=False)
+                               n_heads, use_kernel=False, **kw)
 
 
 @register_kernel(
@@ -742,15 +805,19 @@ def _prefill_fallback(q, k_pages, v_pages, table_row, p0, n_heads,
           "table row, up to the chunk's last real position")
 # jitted on its own, as paged_decode_attention is: a model's layers
 # share one trace and one lowering of the kernel
-@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel",
+                                             "kv_heads", "scale"))
 def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
-                        use_kernel=None, n_real=None):
+                        use_kernel=None, n_real=None, kv_heads=None,
+                        scale=None):
     """Chunked-prefill attention over a PAGED KV cache.
 
     q [1, C, N*H] — the chunk's queries at positions p0..p0+C-1;
-    k_pages/v_pages [num_blocks, block_size, N*H] — the shared
+    k_pages/v_pages [num_blocks, block_size, Nk*H] — the shared
     physical arenas, already holding this chunk's own K/V (callers
-    write before attending); table_row [max_blocks] int32 — ONE
+    write before attending), Nk = `kv_heads` (default N; fewer: query
+    head n reads K/V head n // (N // Nk)); `scale` on the scores
+    (default H ** -0.5); table_row [max_blocks] int32 — ONE
     request's logical->physical block map; p0 scalar int32 — the
     chunk's first position (a TRACED scalar: prefix-cache hits resume
     prefill at arbitrary offsets without widening the compile-signature
@@ -776,13 +843,17 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
         raise ValueError("flash_prefill_chunk takes one request's chunk")
     N = n_heads
     H = nh // N
+    Nk = N if kv_heads is None else int(kv_heads)
+    if N % Nk:
+        raise ValueError(f"{N} query heads over {Nk} K/V heads")
+    G, wk = N // Nk, Nk * H
     num_blocks, bs, _ = k_pages.shape
     mb = table_row.shape[0]
-    scale = 1.0 / float(np.sqrt(H))
+    scale = 1.0 / float(np.sqrt(H)) if scale is None else float(scale)
     itemsize = k_pages.dtype.itemsize
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
-                      and flash_prefill_supported(bs, C, nh, N, itemsize,
+                      and flash_prefill_supported(bs, C, wk, Nk, itemsize,
                                                   mb))
     if not use_kernel:
         # gather+dense: EXACTLY the composed einsum prefill math of
@@ -790,8 +861,10 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
         # bit-parity with the dense path keeps CPU engine streams
         # token-identical to run_generate
         L = mb * bs
-        k4 = k_pages[table_row].reshape(1, L, N, H)
-        v4 = v_pages[table_row].reshape(1, L, N, H)
+        k4 = k_pages[table_row].reshape(1, L, Nk, H)
+        v4 = v_pages[table_row].reshape(1, L, Nk, H)
+        if G > 1:       # each K/V head under the query heads that read it
+            k4, v4 = jnp.repeat(k4, G, axis=2), jnp.repeat(v4, G, axis=2)
         logits = jnp.einsum("bqnh,bknh->bnqk", q.reshape(1, C, N, H),
                             k4.astype(q.dtype),
                             preferred_element_type=jnp.float32) * scale
@@ -802,26 +875,29 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
         out = jnp.einsum("bnqk,bknh->bqnh", probs, v4.astype(q.dtype))
         return out.reshape(1, C, nh)
 
-    W, rows = flash_prefill_tiling(bs, C, nh, N, itemsize, mb)
+    W, rows = flash_prefill_tiling(bs, C, wk, Nk, itemsize, mb)
     if not rows:
         raise ValueError(
             f"flash_prefill_chunk kernel: no tile of {bs}-row pages "
-            f"under {N} heads of {H} lanes and a chunk of {C} fits VMEM "
+            f"under {Nk} heads of {H} lanes and a chunk of {C} fits VMEM "
             "(see flash_prefill_supported)")
-    lanes, heads = _head_columns(nh, N)
+    lanes, heads = _head_columns(wk, Nk)
     Q, cols = heads * C, W // lanes
     p0 = jnp.asarray(p0, jnp.int32)
     last = jnp.clip(p0 + (C if n_real is None else n_real) - 1,
                     0, mb * bs - 1)
+    # [G, C, wk]: as it is where every query head has its own K/V head
+    qg = _regroup(q, Nk, G)[0] if G > 1 else q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nh // W,),
+        grid=(wk // W * G,),
         in_specs=[
-            pl.BlockSpec((1, C, W), lambda g, tab, span: (0, 0, g)),
+            pl.BlockSpec((1, C, W), lambda s, tab, span: (s % G, 0, s // G)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, C, W), lambda g, tab, span: (0, 0, g)),
+        out_specs=pl.BlockSpec((1, C, W),
+                               lambda s, tab, span: (s % G, 0, s // G)),
         scratch_shapes=[
             pltpu.VMEM((2, rows // bs, bs, W), k_pages.dtype),
             pltpu.VMEM((2, rows // bs, bs, W), v_pages.dtype),
@@ -832,17 +908,18 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
             pltpu.VMEM((cols, Q, lanes), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=scale, bs=bs, rows=rows,
-                          lanes=lanes, heads=heads),
+                          lanes=lanes, heads=heads, group=G),
         name="flash_prefill_chunk",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, C, nh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((G, C, wk), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(table_row.astype(jnp.int32), jnp.stack([p0, last]),
-      q.astype(k_pages.dtype), k_pages, v_pages)
+      qg.astype(k_pages.dtype), k_pages, v_pages)
+    return _ungroup(out[None], Nk, G) if G > 1 else out
 
 
 def _decode_example(rng):

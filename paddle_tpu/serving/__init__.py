@@ -43,7 +43,7 @@ selfcheck).
 """
 from .kv_cache import (  # noqa: F401
     BlockLeakError, BlockPool, CacheKind, PagedKVCache, PrefixIndex,
-    StaleIndexError, kv_kind, latent_kind)
+    RowPool, StaleIndexError, kv_kind, latent_kind, state_kind)
 from .resilience import (  # noqa: F401
     AdmissionController, Deadlines, DeadlineExceededError,
     EngineDeadError, EngineDrainingError, EngineStoppedError,
@@ -56,6 +56,7 @@ from .http import ServingHTTPServer  # noqa: F401
 
 __all__ = [
     "BlockPool", "BlockLeakError", "CacheKind", "kv_kind", "latent_kind",
+    "state_kind", "RowPool",
     "PagedKVCache", "PrefixIndex",
     "StaleIndexError", "Request",
     "RequestHandle", "SamplingParams", "Scheduler", "EngineConfig",

@@ -24,6 +24,11 @@ The engine knows no architecture: a model hands it the per-layer
 protocol of `serving/served.py` (embed, layers that each declare a
 cache kind and bring a `decode` and a `prefill` over it, the head), and
 the arenas, the fork and the sizing follow the layers' cache kinds.
+Where a layer keeps rows by REQUEST (a recurrent state) a request holds
+one row of `[max_slots + 1, ...]` arenas from its admission to its
+release, the steps carry those arenas like the paged ones, and no prefix
+index is built: a block of K/V without the state at its boundary cannot
+resume a request.
 
 Numerics contract (GPT): the step computes the EXACT math of
 `generation.run_generate`'s composed decode path — `models.gpt.ServedGPT`
@@ -51,6 +56,7 @@ with the slowest-K exemplars on `GET /traces`.
 import collections
 import contextlib
 import functools
+import logging
 import threading
 import time
 
@@ -70,7 +76,8 @@ from ..telemetry.mem_obs import (MemoryObservatory, is_oom,
                                  register_provider)
 from ..telemetry.recorder import span as _telemetry_span
 from ..telemetry.reqtrace import RequestTracer
-from .kv_cache import NULL_BLOCK, BlockPool, PagedKVCache, PrefixIndex
+from .kv_cache import (NULL_BLOCK, NULL_ROW, BlockPool, PagedKVCache,
+                       PrefixIndex, RowPool)
 from .resilience import (AdmissionController, DeadlineExceededError,
                          EngineDeadError, EngineDrainingError,
                          EngineStoppedError, MemoryPressureError,
@@ -197,8 +204,8 @@ class ServingEngine:
     start()/stop() run the loop on a background thread.
 
     `model.served()` must return the per-layer protocol of
-    `serving/served.py`: GPTForPretraining (quantized or not) and
-    DeepseekV2ForCausalLM implement it.
+    `serving/served.py`: GPTForPretraining (quantized or not),
+    DeepseekV2ForCausalLM and GraniteHybridForCausalLM implement it.
     """
 
     def __init__(self, model, config=None, sink=None, **overrides):
@@ -218,7 +225,7 @@ class ServingEngine:
         self.max_blocks_per_seq = PagedKVCache.blocks_for_tokens(
             self.max_model_len, self.block_size)
         self._compute_dtype = cfg.dtype or served.dtype
-        # "kv", "latent" or both, for the dispatch spans
+        # "kv", "latent", "kv+state", ..., for the dispatch spans
         self._cache_kind_names = "+".join(sorted(
             {k.name for k in self.cache_kinds}))
 
@@ -233,20 +240,35 @@ class ServingEngine:
 
         num_blocks = self._resolve_num_blocks()
         self.pool = BlockPool(num_blocks)   # guarded by: _mu
+        # some layer keeps rows by request: one row a request beside its
+        # blocks, as many rows as slots (admission bounds running +
+        # prefilling by max_slots)
+        self.rows = RowPool(cfg.max_slots) if any(   # guarded by: _mu
+            k.by_request for k in self.cache_kinds) else None
         with self._on_device():
             self.cache = PagedKVCache(   # guarded by: _mu
                 self.cache_kinds, num_blocks, self.block_size,
-                dtype=self._compute_dtype)
+                dtype=self._compute_dtype,
+                request_rows=cfg.max_slots if self.rows else 0)
+        # a block of K/V without the state at its boundary cannot resume
+        # a request, and the index cannot snapshot a state: a model that
+        # keeps rows by request gets no prefix match
+        if self.rows and cfg.enable_prefix_cache:
+            logging.getLogger(__name__).info(
+                "engine %d: the model keeps per-request state; the prefix "
+                "cache is off whatever enable_prefix_cache says",
+                self.engine_id)
         # guarded by: none (immutable ref; entries mutate under _mu)
         self.prefix_index = (
             PrefixIndex(self.block_size, pool=self.pool)
-            if cfg.enable_prefix_cache else None)
+            if cfg.enable_prefix_cache and not self.rows else None)
         # the Scheduler object carries no lock of its own: every one of
         # its methods runs under the engine lock (its class line says
         # `# guarded by: ServingEngine._mu`); the REFERENCE never moves
         self.sched = Scheduler(self.pool, self.block_size, cfg.max_slots,
                                self.max_model_len,
-                               prefix_index=self.prefix_index)
+                               prefix_index=self.prefix_index,
+                               row_pool=self.rows)
 
         named = list(model.named_parameters()) + [
             (n, b) for n, b in model.named_buffers() if b is not None]
@@ -311,6 +333,7 @@ class ServingEngine:
         self._steps = 0                 # guarded by: _mu
         self._stats_pending = []        # guarded by: _mu
         self._in_flight = None          # guarded by: _mu — the _Flight not retired yet
+        self._voided = []               # guarded by: _mu — requeued by _void_in_flight
         monitor.set_gauge("serving.kv_blocks_total", self.pool.capacity)
         monitor.set_gauge("serving.draining", 0)
         self._update_gauges()
@@ -396,9 +419,10 @@ class ServingEngine:
             return tok, tok_logp
 
         def decode_step(param_vals, k_pages, v_pages, tokens, ctx,
-                        tables, use_kernel=None):
+                        tables, rows=None, use_kernel=None):
             """Last-position logits [S, V] of one decode step, the
-            updated arenas and the layers' stats. use_kernel rides
+            updated arenas and the layers' stats. rows [S]: the slots'
+            request rows, where the model keeps any. use_kernel rides
             through to the layers' attention (None = its platform
             gate); only chip_smoke.py and the tests pass it, to hold
             the fused kernel against the gather+dense path on the same
@@ -410,7 +434,7 @@ class ServingEngine:
                 blk = jnp.take_along_axis(
                     tables, (ctx // bs_blk)[:, None], axis=1)[:, 0]
                 view = DecodeView(blk, ctx % bs_blk, tables, ctx, ctx > 0,
-                                  use_kernel)
+                                  use_kernel, rows)
                 h, new_k, new_v, stats = run_layers(
                     h, k_pages, v_pages, "decode", view)
                 last = served.head(h)[:, -1]
@@ -420,10 +444,10 @@ class ServingEngine:
             return decode_step(*args, **kw)[:3]
 
         def decode_fn(param_vals, k_pages, v_pages, tokens, ctx, tables,
-                      keys, counts, temp, top_k, top_p, greedy,
+                      keys, counts, temp, top_k, top_p, greedy, rows=None,
                       sampling=True):
             last, new_k, new_v, stats = decode_step(
-                param_vals, k_pages, v_pages, tokens, ctx, tables)
+                param_vals, k_pages, v_pages, tokens, ctx, tables, rows)
             rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
                 if sampling else keys
             tok, logp = select(last, rngs, temp, top_k, top_p,
@@ -431,11 +455,12 @@ class ServingEngine:
             return tok, logp, new_k, new_v, stats
 
         def prefill_step(param_vals, k_pages, v_pages, ids, p0, n_real,
-                         table_row, use_kernel=None):
+                         table_row, row=None, use_kernel=None):
             """Logits [1, V] at the chunk's last REAL position, the
             updated arenas and the layers' stats, for one chunk of ONE
             request: ids [1, C] (tail past n_real is padding ->
-            null-block writes), positions p0..p0+C-1. use_kernel as in
+            null-block writes), positions p0..p0+C-1; row: the request's
+            row, where the model keeps any. use_kernel as in
             decode_step."""
             param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
@@ -448,7 +473,7 @@ class ServingEngine:
                     table_row[jnp.clip(positions // bs_blk, 0, mb - 1)],
                     NULL_BLOCK)
                 view = ChunkView(blk, positions % bs_blk, table_row, p0,
-                                 n_real, positions, tmask, use_kernel)
+                                 n_real, positions, tmask, use_kernel, row)
                 h, new_k, new_v, stats = run_layers(
                     h, k_pages, v_pages, "prefill", view)
                 last = served.head(h, at=n_real - 1)[:, -1]
@@ -458,12 +483,14 @@ class ServingEngine:
             return prefill_step(*args, **kw)[:3]
 
         def prefill_fn(param_vals, k_pages, v_pages, ids, p0, n_real,
-                       table_row, key, count, temp, top_k, top_p, greedy):
+                       table_row, key, count, temp, top_k, top_p, greedy,
+                       row=None):
             """One prefill chunk; also samples the next token from the
             last REAL position — used only when the host knows this
             was the final chunk."""
             last, new_k, new_v, stats = prefill_step(
-                param_vals, k_pages, v_pages, ids, p0, n_real, table_row)
+                param_vals, k_pages, v_pages, ids, p0, n_real, table_row,
+                row)
             rngs = jax.random.fold_in(key, count)[None]
             tok, logp = select(last, rngs, temp[None], top_k[None],
                                top_p[None], greedy[None])
@@ -471,12 +498,14 @@ class ServingEngine:
 
         def fork_fn(k_pages, v_pages, src, dst):
             """Copy-on-write fork: duplicate physical block `src` into
-            `dst` across every arena of every layer (all rows —
+            `dst` across every paged arena of every layer (all rows —
             positions the forking request has not covered yet stay
             masked by its context length until it overwrites them)."""
             def fork(pages):
-                return tuple(None if a is None else a.at[dst].set(a[src])
-                             for a in pages)
+                return tuple(
+                    a if a is None or kind.by_request
+                    else a.at[dst].set(a[src])
+                    for a, kind in zip(pages, self.cache_kinds))
             return fork(k_pages), fork(v_pages)
 
         def merge_fn(prev_tok, host_tok, from_host):
@@ -509,6 +538,9 @@ class ServingEngine:
             fork_fn,
             donate_argnums=(0, 1) if jax.default_backend() == "tpu"
             else ())
+        # one request's row of every request-row arena (`request_rows`)
+        self._rows_jit = jax.jit(
+            lambda arenas, row: [a[row] for a in arenas])
 
     def _on_device(self):
         """Allocate and compile for the configured device, where one
@@ -649,6 +681,36 @@ class ServingEngine:
             self._cv.notify_all()
         return True
 
+    def request_rows(self, handle):
+        """What a live request keeps by row (`CacheKind.request_rows`: a
+        recurrent layer's state): `(ids, rows)`, `ids` the tokens whose
+        positions the rows have taken in and `rows` {layer: (array,
+        ...)} over the layers that keep rows, each array the request's
+        row sliced out of its arena on the device (the caller copies
+        what it wants to the host). None where the model keeps no rows
+        or the request holds none (waiting, preempted, finished). The
+        step in flight is retired first, so the rows are those after
+        `ids` and no token later. For inspection and for checks against
+        a reference: it takes the engine's lock and `request_bytes` of
+        device memory."""
+        req = handle._req
+        with self._mu:
+            if req.row is None:
+                return None
+            self._flush()
+            if req.row is None or not req.n_prefilled:
+                return None     # its last token came with the flush
+            ids = np.asarray(req.tokens_all[:req.n_prefilled], np.int32)
+            at = [(layer, len(kind.request_rows))
+                  for layer, kind in enumerate(self.cache.kinds)
+                  if kind.by_request]
+            got = iter(self._rows_jit(
+                [a for layer, n in at for a in
+                 (self.cache.k[layer], self.cache.v[layer])[:n]],
+                np.int32(req.row)))
+        return ids, {layer: tuple(next(got) for _ in range(n))
+                     for layer, n in at}
+
     # ------------------------------------------------------------------
     # the engine loop
     # ------------------------------------------------------------------
@@ -772,6 +834,16 @@ class ServingEngine:
         loses no token and repeats none."""
         self._in_flight = None
         self._stats_pending.clear()
+        if self.rows:
+            # a position computed twice moves a recurrent state twice:
+            # such a model's requests give their rows back and replay
+            # from position 0 (oldest first at the waiting front).
+            # `_on_step_error` still counts them among the step's
+            # requests: a permanent fault fails them
+            self._voided = list(self.sched.admit_order)
+            for req in reversed(self._voided):
+                self.sched.requeue(req)
+            return
         for req in self.sched.prefilling + \
                 [r for r in self.sched.running if r is not None]:
             req.n_prefilled = min(req.n_prefilled, len(req.tokens_all) - 1)
@@ -1002,6 +1074,8 @@ class ServingEngine:
             self.prefix_index.flush()
         self.pool = BlockPool(self.pool.num_blocks)
         self.sched.pool = self.pool
+        if self.rows:
+            self.rows = self.sched.row_pool = RowPool(self.cfg.max_slots)
         if self.prefix_index is not None:
             self.prefix_index.bind(self.pool)
         with self._on_device():
@@ -1046,8 +1120,9 @@ class ServingEngine:
                         msg, step=self._steps, device=self.cfg.device)
                 except Exception:
                     pass  # forensics must never mask the real failure
-            active = [r for r in self.sched.admit_order
+            active = [r for r in self.sched.admit_order + self._voided
                       if r.state not in TERMINAL_STATES]
+            self._voided = []
             if kind == "permanent":
                 for req in active:
                     self._finalize(req, FAILED, "failed", error=msg,
@@ -1202,10 +1277,13 @@ class ServingEngine:
                         req.rng_key, np.int32(g),
                         np.float32(p.temperature), np.int32(p.top_k),
                         np.float32(p.top_p), np.bool_(p.greedy))
+                if self.rows:
+                    args += (np.int32(req.row),)
             with _span("serving_dispatch", family="serving_prefill",
                        rid=req.rid, p0=p0, n_real=c_real,
                        kv_rows=flash_prefill_kv_rows(
                            p0, c_real, self.block_size),
+                       state_rows=int(req.row is not None),
                        cache_kind=self._cache_kind_names,
                        in_flight=int(self._in_flight is not None)):
                 tok, logp, new_k, new_v, stats = self._dispatch(
@@ -1281,6 +1359,7 @@ class ServingEngine:
             top_k = np.zeros((S,), np.int32)
             top_p = np.ones((S,), np.float32)
             greedy = np.ones((S,), np.bool_)
+            rows = np.full((S,), NULL_ROW, np.int32)
             # what the batch attends to, for the dispatch span: slots
             # that hold a context, and their contexts with the token
             # this step adds
@@ -1296,6 +1375,8 @@ class ServingEngine:
                     slots += 1
                     ctx_tokens += req.n_prefilled + 1
                 tables[i, :len(req.blocks)] = req.blocks
+                if req.row is not None:
+                    rows[i] = req.row
                 keys[i] = req.rng_key
                 # the index of the token this step samples: prev's
                 # token is counted although it has not arrived
@@ -1328,6 +1409,8 @@ class ServingEngine:
             args = (self._param_vals(), self.cache.k, self.cache.v,
                     tokens, ctx, tables, keys, counts, temp, top_k, top_p,
                     greedy)
+            if self.rows:
+                args += (rows,)
             # all-greedy batches take the sort-free program (distinct
             # compile FAMILY, not a recompile — each variant compiles
             # once)
@@ -1336,6 +1419,7 @@ class ServingEngine:
                 else "serving_decode"
         with _span("serving_dispatch", family=family, slots=slots,
                    ctx_tokens=ctx_tokens, kv_rows=kv_rows,
+                   state_rows=int(np.count_nonzero(rows)),
                    cache_kind=self._cache_kind_names,
                    in_flight=int(prev is not None)):
             tok, logp, new_k, new_v, stats = self._dispatch(
@@ -1508,6 +1592,8 @@ class ServingEngine:
         monitor.set_gauge("serving.running", self.sched.num_running())
         monitor.set_gauge("serving.prefilling", len(self.sched.prefilling))
         monitor.set_gauge("serving.kv_blocks_used", self.pool.num_used)
+        if self.rows:
+            monitor.set_gauge("serving.state_rows_live", self.rows.num_live)
         ps = self._prefix_stats
         offered = ps["tokens_offered"]
         monitor.set_gauge("serving.prefix_hit_rate",

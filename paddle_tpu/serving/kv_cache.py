@@ -48,9 +48,14 @@ Three layers, split host/device:
   [*, n*h] minor layout the fused decode kernels require — see
   ops/pallas_decode.py). Latent (`latent_kind`): ONE arena of
   `[num_blocks, block_size, width]` whose row is key and value at once
-  (multi-head latent attention). The arrays are handed to the engine's
-  compiled step functions, updated functionally, and stored back;
-  `swap()` is the single mutation point so donation stays sound.
+  (multi-head latent attention). A layer whose memory is by REQUEST
+  and not by token (`state_kind`: a recurrent state) keeps arenas of
+  `[rows + 1, ...]` instead, one row a request handed out by the
+  `RowPool`; the prefix index cannot share such a layer's memory, so
+  the engine builds none for a model that has one. The arrays are
+  handed to the engine's compiled step functions, updated
+  functionally, and stored back; `swap()` is the single mutation
+  point so donation stays sound.
 
 The attention over the K/V layout is
 `ops.pallas_decode.paged_decode_attention` (decode) and
@@ -60,11 +65,13 @@ All four leave the arenas in HBM and copy the live pages of a tile of
 128-512 rows themselves, through the block table, up to the last
 position attended: a table entry past it is never read.
 """
+import math
+
 import jax.numpy as jnp
 
 __all__ = ["BlockPool", "BlockLeakError", "CacheKind", "PagedKVCache",
-           "NULL_BLOCK", "PrefixIndex", "StaleIndexError", "kv_kind",
-           "latent_kind"]
+           "NULL_BLOCK", "NULL_ROW", "PrefixIndex", "RowPool",
+           "StaleIndexError", "kv_kind", "latent_kind", "state_kind"]
 
 
 class BlockLeakError(AssertionError):
@@ -89,6 +96,10 @@ class StaleIndexError(RuntimeError):
 # padded batch slots and masked prefill tails (their values are
 # garbage by construction and never read back)
 NULL_BLOCK = 0
+
+# row 0 of a request-row arena is never handed out: a decode slot that
+# holds no request reads and writes it
+NULL_ROW = 0
 
 _UNSET = object()
 
@@ -503,28 +514,55 @@ class PrefixIndex:    # guarded by: ServingEngine._mu
 
 
 class CacheKind:
-    """What one layer keeps of a token in the paged arena: one arena of
-    `[num_blocks, block_size, w]` for each `w` of `widths`, one or two
-    of them. The engine sizes, forks and swaps arenas by this alone;
-    what the numbers mean is the layer's business."""
+    """What one layer keeps in the cache: one or two arenas. There are
+    two families.
 
-    def __init__(self, name, widths):
+    Paged by TOKEN (`widths`): one arena of `[num_blocks, block_size, w]`
+    for each `w`; a token costs a row of each and the block pool hands
+    the pages out.
+
+    Rows by REQUEST (`request_rows`): one arena of `[rows + 1, *shape]`
+    in `dtype` for each `(shape, dtype)`; a request costs one row of
+    each whatever its length (a recurrent layer's state), row 0 is the
+    null row, and the `RowPool` hands the rows out.
+
+    The engine sizes, forks and swaps arenas by this alone; what the
+    numbers mean is the layer's business."""
+
+    def __init__(self, name, widths=(), request_rows=()):
         self.name = str(name)
         self.widths = tuple(int(w) for w in widths)
-        if not 1 <= len(self.widths) <= 2:
+        self.request_rows = tuple(
+            (tuple(int(n) for n in shape), jnp.dtype(dtype))
+            for shape, dtype in request_rows)
+        if bool(self.widths) == bool(self.request_rows):
+            raise ValueError("a cache kind is paged by token (widths) or "
+                             "keeps rows by request (request_rows)")
+        if not 1 <= len(self.widths) + len(self.request_rows) <= 2:
             raise ValueError("a cache kind holds one or two arenas a layer")
+
+    @property
+    def by_request(self):
+        return bool(self.request_rows)
 
     @property
     def row_width(self):
         """Numbers a token costs in a layer of this kind."""
         return sum(self.widths)
 
+    @property
+    def request_bytes(self):
+        """Bytes a request costs in a layer of this kind, whatever its
+        length."""
+        return sum(math.prod(shape) * dtype.itemsize
+                   for shape, dtype in self.request_rows)
+
     def __repr__(self):
-        return f"CacheKind({self.name!r}, {self.widths})"
+        return f"CacheKind({self.name!r}, {self.widths or self.request_rows})"
 
 
 def kv_kind(hidden):
-    """Full attention: a K and a V row of n_heads * head_dim each."""
+    """Full attention: a K and a V row of kv_heads * head_dim each."""
     return CacheKind("kv", (hidden, hidden))
 
 
@@ -534,29 +572,78 @@ def latent_kind(width):
     return CacheKind("latent", (width,))
 
 
+def state_kind(*request_rows):
+    """A recurrent layer: what a request keeps between tokens, each a
+    `(shape, dtype)`, e.g. a convolution's tail and the state itself."""
+    return CacheKind("state", request_rows=request_rows)
+
+
+class RowPool:    # guarded by: ServingEngine._mu
+    """The rows 1..n of the request-row arenas (row 0 is the null row).
+    A request takes one when it is admitted to prefill and gives it back
+    when it is released or preempted; admission bounds running +
+    prefilling by `max_slots`, which is `n`, so a row is always free."""
+
+    def __init__(self, n):
+        self.capacity = int(n)
+        self._free = list(range(self.capacity, NULL_ROW, -1))   # LIFO
+        self._owner = {}          # row -> owner tag
+
+    @property
+    def num_live(self):
+        return len(self._owner)
+
+    def take(self, owner=None):
+        if not self._free:
+            raise RuntimeError(
+                f"RowPool: all {self.capacity} request rows are taken")
+        row = self._free.pop()
+        self._owner[row] = owner
+        return row
+
+    def give(self, row):
+        if row not in self._owner:
+            raise ValueError(f"give of request row {row} nobody holds")
+        del self._owner[row]
+        self._free.append(row)
+
+    def assert_quiesced(self):
+        if self._owner:
+            raise BlockLeakError(
+                f"{len(self._owner)} request row(s) still held at "
+                f"quiesce: {sorted(self._owner.items())}")
+
+
 class PagedKVCache:
-    """Per-layer arenas of shape [num_blocks, block_size, width], as
-    each layer's `CacheKind` declares: `k[l]` is the layer's first
-    arena, `v[l]` its second or None where the kind has one (a latent
-    layer). The minor dim stays flat so the paged pallas kernels can
-    stream blocks without a reshape copy (the same constraint as the
-    dense decode cache — see GPTModel.init_cache).
+    """Per-layer arenas, as each layer's `CacheKind` declares: `k[l]` is
+    the layer's first arena, `v[l]` its second or None where the kind
+    has one (a latent layer). A kind paged by token gets
+    `[num_blocks, block_size, width]` in the cache's dtype: the minor
+    dim stays flat so the paged pallas kernels can stream blocks without
+    a reshape copy (the same constraint as the dense decode cache — see
+    GPTModel.init_cache). A kind that keeps rows by request gets
+    `[request_rows + 1, *shape]` in the dtype it declares.
     """
 
-    def __init__(self, kinds, num_blocks, block_size, dtype="bfloat16"):
+    def __init__(self, kinds, num_blocks, block_size, dtype="bfloat16",
+                 request_rows=0):
         self.kinds = tuple(kinds)
         self.num_layers = len(self.kinds)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.dtype = jnp.dtype(dtype)
+        self.request_rows = int(request_rows)
 
-        def arena(width):
-            return jnp.zeros((self.num_blocks, self.block_size, width),
-                             self.dtype)
+        def arenas(kind):
+            if kind.by_request:
+                return [jnp.zeros((self.request_rows + 1,) + shape, dt)
+                        for shape, dt in kind.request_rows]
+            return [jnp.zeros((self.num_blocks, self.block_size, width),
+                              self.dtype) for width in kind.widths]
 
-        self.k = tuple(arena(kind.widths[0]) for kind in self.kinds)
-        self.v = tuple(arena(kind.widths[1]) if len(kind.widths) > 1
-                       else None for kind in self.kinds)
+        pairs = [(arenas(kind) + [None])[:2] for kind in self.kinds]
+        self.k = tuple(first for first, _ in pairs)
+        self.v = tuple(second for _, second in pairs)
         # memory-observatory tagging (telemetry/mem_obs): the live HBM
         # ledger attributes these arenas to the 'kv' bucket by querying
         # this provider FRESH each snapshot (swap() replaces the
@@ -582,15 +669,21 @@ class PagedKVCache:
 
     @staticmethod
     def block_bytes(kinds, block_size, dtype):
-        """Bytes one block costs over all layers."""
+        """Bytes one block costs over all layers (those that keep rows
+        by request cost a block nothing)."""
         return sum(kind.row_width for kind in kinds) * int(block_size) \
             * jnp.dtype(dtype).itemsize
+
+    @staticmethod
+    def request_bytes(kinds):
+        """Bytes one request row costs over all layers."""
+        return sum(kind.request_bytes for kind in kinds)
 
     def fresh(self):
         """An empty cache of the same layout (after a failed step the
         donated arenas are suspect)."""
         return PagedKVCache(self.kinds, self.num_blocks, self.block_size,
-                            dtype=self.dtype)
+                            dtype=self.dtype, request_rows=self.request_rows)
 
     def swap(self, new_k, new_v):
         """Install the updated arenas returned by a compiled step. The

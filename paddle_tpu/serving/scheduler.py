@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from .. import monitor
 from .kv_cache import BlockPool, PagedKVCache
 from .resilience import PRIORITIES, expired_reason
 
@@ -116,6 +117,7 @@ class Request:    # guarded by: ServingEngine._mu
         self.blocks = []                    # physical block ids (in order)
         self.prefix_cached_tokens = 0       # positions covered by a hit
         self.slot = None                    # decode batch slot, when RUNNING
+        self.row = None                     # request row (kv_cache.RowPool)
         self.preemptions = 0
         self.error = None
         self.failure = None                 # typed exception for the stream
@@ -292,6 +294,10 @@ class Scheduler:    # guarded by: ServingEngine._mu
       is the step's output, not yet in `out_tokens`);
     - a PREFILL request holds blocks for positions < n_prefilled plus
       whatever the next chunk needs, but no slot until prefill is done;
+    - with a `row_pool` (a model whose layers keep rows by request) a
+      request holds one row from its admission to prefill until it is
+      released, preempted or requeued: the row is the request's and not
+      the slot's, because a request prefilling has no slot;
     - preemption frees ALL of a victim's blocks and re-queues it at the
       FRONT of the waiting line (it already paid for its progress once);
     - the waiting queue is ordered by priority class (FIFO within a
@@ -301,8 +307,9 @@ class Scheduler:    # guarded by: ServingEngine._mu
     """
 
     def __init__(self, pool, block_size, max_slots, max_model_len,
-                 prefix_index=None):
+                 prefix_index=None, row_pool=None):
         self.pool = pool
+        self.row_pool = row_pool           # kv_cache.RowPool or None
         self.block_size = int(block_size)
         self.max_slots = int(max_slots)
         self.max_model_len = int(max_model_len)
@@ -397,6 +404,9 @@ class Scheduler:    # guarded by: ServingEngine._mu
             if req.admit_time is None:      # requeues keep the first
                 req.admit_time = now if now is not None \
                     else time.monotonic()
+            if self.row_pool is not None:
+                req.row = self.row_pool.take(owner=req.rid)
+                monitor.incr("serving.state_rows_taken")
             self.prefilling.append(req)
             self.admit_order.append(req)
             cls = req.priority_class
@@ -515,6 +525,12 @@ class Scheduler:    # guarded by: ServingEngine._mu
         if req.slot is not None:
             self.running[req.slot] = None
             req.slot = None
+        if req.row is not None:
+            # as with the blocks: whoever is handed the row next starts
+            # from zeros in a program dispatched after this one
+            self.row_pool.give(req.row)
+            req.row = None
+            monitor.incr("serving.state_rows_released")
         if req in self.prefilling:
             self.prefilling.remove(req)
         if req in self.admit_order:
@@ -529,6 +545,8 @@ class Scheduler:    # guarded by: ServingEngine._mu
         step fault."""
         if req in self.waiting:
             return
+        if req.row is not None and req.n_prefilled:
+            monitor.incr("serving.state_replays")   # a state thrown away
         self._release(req)
         req.n_prefilled = 0
         req.state = WAITING
@@ -540,7 +558,6 @@ class Scheduler:    # guarded by: ServingEngine._mu
 
     def preempt(self, req):
         """Evict-by-recompute: `requeue` plus the preemption ledger."""
-        from .. import monitor
         if req.trace is not None and req not in self.waiting:
             # the trace marks WHY the request goes back to the queue
             # (before requeue resets n_prefilled — the span records how

@@ -19,13 +19,21 @@ and every layer has
 
 `h` is the model's own (a Tensor, an array): the engine only hands it
 from one call to the next. `pages` is the layer's pair of arenas
-`(k, v)`, `v` None where the kind has one; a layer writes the step's
-rows into them where the view says and attends over them. `stats` is
-None or a dict of float32 scalars the engine sums over the layers and
-fetches with the step's tokens (`serving.<name>` counters).
+`(k, v)`, `v` None where the kind has one. There are two families of
+cache kind (`kv_cache.CacheKind`): paged by TOKEN, where a layer writes
+the step's rows into the pages the view names (`blk`, `off`) and attends
+over them through the block table; and rows by REQUEST, where a layer
+reads what its request kept at `view.rows` / `view.row` of
+`[rows + 1, ...]` arenas and writes it back there (a recurrent state: a
+chunk that starts at position 0 starts from zeros, whatever the row
+held; padding positions leave it alone). `stats` is None or a dict of
+float32 scalars the engine sums over the layers and fetches with the
+step's tokens (`serving.<name>` counters).
 
-The first implementers are `models.gpt.GPTForPretraining` (full K/V)
-and `models.deepseek_v2.DeepseekV2ForCausalLM` (latent).
+The implementers are `models.gpt.GPTForPretraining` (full K/V),
+`models.deepseek_v2.DeepseekV2ForCausalLM` (latent) and
+`models.granite_hybrid.GraniteHybridForCausalLM` (grouped-query K/V in
+its attention layers, request rows in its Mamba-2 layers).
 """
 import collections
 
@@ -36,15 +44,19 @@ import numpy as np
 # that position ctx[s] of slot s is written to; tables [S, max_blocks];
 # ctx [S] the position of the step's token (keys 0..ctx are attended);
 # live [S] bool: slots that hold a request; use_kernel rides to the
-# attention kernels (None: their platform gate).
+# attention kernels (None: their platform gate); rows [S]: each slot's
+# request row (the null row 0 where the slot holds no request, and for
+# a model that keeps no rows by request).
 DecodeView = collections.namedtuple(
-    "DecodeView", "blk off tables ctx live use_kernel")
+    "DecodeView", "blk off tables ctx live use_kernel rows")
 
 # One chunk of C positions p0..p0+C-1 of one request, the first n_real
 # of them real. blk/off [C] (padding rows go to the null block);
-# table_row [max_blocks]; positions [C]; live [C] bool.
+# table_row [max_blocks]; positions [C]; live [C] bool; row: the
+# request's row.
 ChunkView = collections.namedtuple(
-    "ChunkView", "blk off table_row p0 n_real positions live use_kernel")
+    "ChunkView",
+    "blk off table_row p0 n_real positions live use_kernel row")
 
 
 def sum_stats(per_layer):

@@ -38,6 +38,18 @@ def pytest_configure(config):
                    "(tools/ci.sh stage 5 still runs these)")
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_inherited_mesh():
+    """A test file starts without the global mesh the file before it on
+    the same worker may have left (`dist.build_mesh` sets one and few
+    tests clear it): which file that is depends on how xdist deals the
+    files out, and `models/gpt.py` shards activations by whatever mesh
+    is current."""
+    from paddle_tpu.distributed import env
+    env.clear_mesh()
+    yield
+
+
 @pytest.fixture(autouse=True)
 def _fixed_seed():
     import paddle_tpu as paddle

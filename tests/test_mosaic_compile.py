@@ -194,3 +194,97 @@ def test_registry_example_compiles_for_v5e(one_chip, mosaic_mla, name):
         for i in arrays]).lower(lowering_platforms=("tpu",)).compile() \
         .as_text()
     assert "tpu_custom_call" in text and name in text
+
+
+# -- granite-4.0-h-micro.serve-many -------------------------------------
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_grouped_query_attention_compiles_for_v5e(one_chip, mosaic, step):
+    """32 query heads over 8 K/V heads of 64: arenas 512 lanes wide,
+    tables of 160 blocks, 64 slots, chunks of 512."""
+    N, Nk, H, bs, mb, nb = 32, 8, 64, 16, 160, 2048
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kw = dict(use_kernel=True, kv_heads=Nk, scale=0.015625)
+    pages = sds((nb, bs, Nk * H), bf16)
+    if step == "decode":
+        assert pallas_decode.paged_decode_tile_rows(
+            bs, Nk * H, Nk, 2, mb, N // Nk) == 512
+        fn = lambda q, k, v, t, c: pallas_decode.paged_decode_attention(
+            q, k, v, t, c, N, **kw)
+        args = (sds((64, 1, N * H), bf16), pages, pages,
+                sds((64, mb), jnp.int32), sds((64,), jnp.int32))
+    else:
+        assert pallas_decode.flash_prefill_tiling(
+            bs, 512, Nk * H, Nk, 2, mb)[1] > 0
+        fn = lambda q, k, v, t, p0, n: pallas_decode.flash_prefill_chunk(
+            q, k, v, t, p0, N, n_real=n, **kw)
+        args = (sds((1, 512, N * H), bf16), pages, pages,
+                sds((mb,), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_decode" if step == "decode"
+            else "flash_prefill_chunk") in text
+
+
+@pytest.fixture
+def mosaic_ssm(monkeypatch):
+    from paddle_tpu.ops import pallas_ssm
+    jitted = (pallas_ssm.mamba2_state_step, pallas_ssm.mamba2_chunk_scan)
+    monkeypatch.setattr(pallas_ssm, "_interpret", lambda: False)
+    for fn in jitted:
+        fn.clear_cache()
+    yield pallas_ssm
+    for fn in jitted:
+        fn.clear_cache()
+
+
+@pytest.mark.parametrize("kernel", ["mamba2_state_step",
+                                    "mamba2_chunk_scan"])
+def test_mamba2_kernels_compile_for_v5e(one_chip, mosaic_ssm, kernel):
+    """64 heads of 64 over a state of 128: 65 rows of [128, 4096]
+    float32, 64 slots; a chunk of 512 in pieces of 256."""
+    ssm = mosaic_ssm
+    N, D, H, S, C = 128, 4096, 64, 64, 512
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if kernel == "mamba2_state_step":
+        assert ssm.state_step_tile(N, D) == 2048
+        fn = lambda *a: ssm.mamba2_state_step(*a, use_kernel=True)
+        args = (sds((S + 1, N, D), f32), sds((S,), jnp.int32),
+                sds((S,), jnp.bool_), sds((S, D), f32), sds((S, D), f32),
+                sds((S, N), bf16), sds((S, N), bf16))
+    else:
+        assert ssm.chunk_scan_tile(C, 256, N, D, D // H) == 512
+        fn = lambda *a: ssm.mamba2_chunk_scan(*a, piece=256,
+                                              use_kernel=True)
+        args = (sds((C, D), bf16), sds((C, H), f32), sds((H,), f32),
+                sds((C, N), bf16), sds((C, N), bf16), sds((N, D), f32))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text
+
+
+@pytest.mark.parametrize("name", ["mamba2_state_step", "mamba2_chunk_scan"])
+def test_ssm_registry_example_compiles_for_v5e(one_chip, mosaic_ssm, name):
+    import numpy as np
+    from paddle_tpu.ops.kernel_registry import registered_kernels
+    reg = next(r for r in registered_kernels() if r.name == name)
+    args, kwargs = reg.example(np.random.default_rng(0))
+
+    def fn(*xs):
+        return reg.fn(*xs, **kwargs)
+
+    text = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        for a in args]).lower(lowering_platforms=("tpu",)).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and name in text
