@@ -442,7 +442,8 @@ def _production_cases(sz):
     # latent attention at the DeepSeek-V2 cell's shapes (128 heads over
     # 640-lane rows, 512 of them the value): decode contexts on every
     # edge of the tiling with scattered pages and an idle slot, and a
-    # prompt chunk at three offsets over one request's table
+    # prompt chunk at three offsets over one request's table and a
+    # partly filled one
     from paddle_tpu.moe import serving as moes
     from paddle_tpu.ops import pallas_mla as pm
     N, W, rank, pbs = (4, 128, 128, 16) if TINY else (128, 640, 512, 16)
@@ -468,16 +469,25 @@ def _production_cases(sz):
                                                use_kernel=False),
         (rand((len(ctxs), N, W)), arena, tabs, ctxs)))
     C = 32 if TINY else 512
-    for p0 in (0, C + 16, (mb * pbs // 2) // pbs * pbs):
-        row = np.zeros((mb,), np.int32)
-        n_alloc = (p0 + C - 1) // pbs + 1
+    mid = (mb * pbs // 2) // pbs * pbs
+    # the last: a question's chunk that resumes inside a block, its
+    # table past the last real position's page pointing outside the arena
+    for p0, n_real in ((0, C), (C + 16, C), (mid, C),
+                       (mid + 24, C // 2 - 3)):
+        row = np.full((mb,), arena.shape[0] + 7, np.int32)
+        n_alloc = (p0 + n_real - 1) // pbs + 1
         row[:n_alloc] = pages[:n_alloc]
         cases.append((
-            ("mla_prefill_chunk",), f"mla_prefill_chunk C={C} p0={p0}",
-            lambda q, a, t, p0=p0: pm.mla_prefill_chunk(
-                q, a, t, np.int32(p0), rank, 0.11, use_kernel=True),
-            lambda q, a, t, p0=p0: pm.mla_prefill_chunk(
-                q, a, t, np.int32(p0), rank, 0.11, use_kernel=False),
+            ("mla_prefill_chunk",),
+            f"mla_prefill_chunk C={C} p0={p0} n_real={n_real}",
+            lambda q, a, t, p0=p0, n=n_real: pm.mla_prefill_chunk(
+                q, a, t, np.int32(p0), rank, 0.11, use_kernel=True,
+                n_real=np.int32(n)),
+            lambda q, a, t, p0=p0, n=n_real, live=n_alloc:
+                pm.mla_prefill_chunk(
+                    q, a, jnp.where(jnp.arange(t.shape[0]) < live, t, 0),
+                    np.int32(p0), rank, 0.11, use_kernel=False,
+                    n_real=np.int32(n)),
             (rand((C, N, W)), arena, row)))
 
     # the grouped expert products at the cell's widths, 8 held experts:

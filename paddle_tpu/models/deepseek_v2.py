@@ -307,7 +307,8 @@ class _ServedBlock:
         def attend(q, lat):
             return mla_prefill_chunk(
                 q, lat, view.table_row, view.p0, c.kv_lora_rank,
-                c.softmax_scale(), use_kernel=view.use_kernel)
+                c.softmax_scale(), use_kernel=view.use_kernel,
+                n_real=view.n_real)
         return self._step(h, pages, view, view.positions, attend)
 
 

@@ -23,7 +23,8 @@ computes on the other):
     over the slot's own context.
 `mla_prefill_chunk`  one grid step a GROUP of `tq` consecutive chunk
     positions of one request: `[tq * heads, W]` queries, causal by
-    position, over that request's context.
+    position, over that request's context; the groups past the chunk's
+    last real position (`n_real`) do nothing.
 
 Both have a gather+dense fallback in the same absorbed arithmetic (the
 CPU path, and what the tests and chip_smoke.py hold the kernel to).
@@ -91,21 +92,33 @@ def mla_supported(block_size, width, rank, q_rows, itemsize=2,
 
 def _mla_kernel(tab_ref, base_ref, q_ref, lat_hbm, out_ref,
                 buf, sems, buf_ref, m_sc, l_sc, acc_sc,
-                *, scale, bs, rows, n_heads, rank, own_table, reach):
+                *, scale, bs, rows, n_heads, rank, chunk, reach):
     """Grid step g: `q_ref` [Q, W] holds the queries of Q // n_heads
     consecutive positions base[g], base[g] + 1, ... (heads minor) of
-    the request whose table row is g (`own_table`: a decode slot) or 0
-    (a chunk). `walk_tiles` takes it through the tiles up to the last
-    position any of them attends, `reach` at most (the table's end)."""
+    the request whose table row is g (a decode slot) or 0 (`chunk`).
+    `walk_tiles` takes it through the tiles up to the last position any
+    of them attends, `reach` at most (the table's end).
+
+    For a chunk `base_ref` holds, past the G bases, the chunk's last
+    real position and the number of grid steps that hold a real one.
+    Those steps attend up to that position at most, and their rows past
+    it come out zero; the steps after them fetch nothing, compute
+    nothing and write zeros."""
     g = pl.program_id(0)
     Q = q_ref.shape[1]
     tq = Q // n_heads
+    if chunk:
+        G = pl.num_programs(0)
+        end, n_live = base_ref[G], base_ref[G + 1]
 
     def last_pos(step):
-        return jnp.minimum(base_ref[step] + tq - 1, reach - 1)
+        last = base_ref[step] + tq - 1
+        if chunk:
+            last = jnp.minimum(last, end)
+        return jnp.minimum(last, reach - 1)
 
     def page_copies(step, i, slot, j):
-        blk = tab_ref[step if own_table else 0, i]
+        blk = tab_ref[0 if chunk else step, i]
         return (pltpu.make_async_copy(
             lat_hbm.at[blk], buf.at[slot, j], sems.at[slot]),)
 
@@ -115,45 +128,63 @@ def _mla_kernel(tab_ref, base_ref, q_ref, lat_hbm, out_ref,
         # has written yet must hold numbers
         buf[...] = jnp.zeros_like(buf)
 
-    m_sc[...] = jnp.full_like(m_sc, -1e30)
-    l_sc[...] = jnp.zeros_like(l_sc)
-    acc_sc[...] = jnp.zeros_like(acc_sc)
-    q = q_ref[0]                                          # [Q, W]
-    qpos = base_ref[g] + jax.lax.broadcasted_iota(
-        jnp.int32, (Q, rows), 0) // n_heads
+    def attend():
+        m_sc[...] = jnp.full_like(m_sc, -1e30)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        q = q_ref[0]                                      # [Q, W]
+        qpos = base_ref[g] + jax.lax.broadcasted_iota(
+            jnp.int32, (Q, rows), 0) // n_heads
 
-    def compute(t, slot):
-        tile = buf[slot].reshape(rows, buf.shape[-1])     # [rows, W]
-        logits = jax.lax.dot_general(
-            q, tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [Q, rows]
-        kpos = t * rows + jax.lax.broadcasted_iota(
-            jnp.int32, (Q, rows), 1)
-        logits = jnp.where(kpos <= qpos, logits, -1e30)
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(
-            m_prev, jnp.max(logits, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)                       # [Q, rows]
-        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(tile.dtype), tile[:, :rank],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [Q, rank]
-        acc_sc[...] = acc_sc[...] * alpha + pv
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+        def compute(t, slot):
+            tile = buf[slot].reshape(rows, buf.shape[-1])  # [rows, W]
+            logits = jax.lax.dot_general(
+                q, tile, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Q, rows]
+            kpos = t * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (Q, rows), 1)
+            logits = jnp.where(kpos <= qpos, logits, -1e30)
+            m_prev = m_sc[:, :1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)                   # [Q, rows]
+            l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(tile.dtype), tile[:, :rank],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [Q, rank]
+            acc_sc[...] = acc_sc[...] * alpha + pv
+            m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+            l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
 
-    walk_tiles(g, pl.num_programs(0), last_pos, page_copies, buf_ref,
-               compute, bs=bs, rows=rows)
-    # every first tile holds position 0, which every query attends
-    out_ref[0] = (acc_sc[...] / l_sc[:, :1]).astype(out_ref.dtype)
+        # the last step to walk starts no copy for a step that waits for
+        # none
+        walk_tiles(g, n_live if chunk else pl.num_programs(0),
+                   last_pos, page_copies, buf_ref, compute, bs=bs,
+                   rows=rows)
+        # every first tile holds position 0, which every query attends
+        out = acc_sc[...] / l_sc[:, :1]
+        if chunk:
+            out = jnp.where(qpos[:, :1] <= end, out, 0.0)
+        out_ref[0] = out.astype(out_ref.dtype)
+
+    def dead():
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+
+    if chunk:
+        pl.when(g < n_live)(attend)
+        pl.when(g >= n_live)(dead)
+    else:
+        attend()
 
 
-def _plan(q, pages, tables, *, rank, scale, n_heads, own_table, name):
+def _plan(q, pages, tables, *, rank, scale, n_heads, chunk, name):
     """(kernel, pallas_call keywords) for q [G, Q, W] over tables
-    [S or 1, max_blocks]; the operands are (tables, base [G], q,
-    pages)."""
+    [S or 1, max_blocks]; the operands are (tables, base, q, pages),
+    base [G] for a decode step's slots and [G + 2] for a chunk (the
+    groups' first positions, the last real position, the number of
+    groups that hold a real one)."""
     G, Q, W = q.shape
     _, bs, _ = pages.shape
     mb = tables.shape[1]
@@ -166,7 +197,11 @@ def _plan(q, pages, tables, *, rank, scale, n_heads, own_table, name):
         num_scalar_prefetch=2,
         grid=(G,),
         in_specs=[
-            pl.BlockSpec((1, Q, W), lambda g, tab, base: (g, 0, 0)),
+            # a group with no real position fetches no queries: it stays
+            # on the block of the last one that has
+            pl.BlockSpec((1, Q, W), (lambda g, tab, base: (
+                jnp.minimum(g, base[G + 1] - 1), 0, 0)) if chunk
+                else (lambda g, tab, base: (g, 0, 0))),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, Q, rank), lambda g, tab, base: (g, 0, 0)),
@@ -181,7 +216,7 @@ def _plan(q, pages, tables, *, rank, scale, n_heads, own_table, name):
     )
     kernel = functools.partial(
         _mla_kernel, scale=scale, bs=bs, rows=rows, n_heads=n_heads,
-        rank=rank, own_table=own_table, reach=mb * bs)
+        rank=rank, chunk=chunk, reach=mb * bs)
     return kernel, dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, Q, rank), q.dtype),
@@ -254,7 +289,7 @@ def mla_paged_decode(q, pages, tables, ctx, rank, scale, use_kernel=None):
         lat = pages[tables].reshape(S, -1, W)
         return _dense(q[:, None], lat, ctx[:, None], rank, scale)[:, 0]
     kernel, how = _plan(q, pages, tables, rank=rank, scale=scale, n_heads=N,
-                        own_table=True, name="mla_paged_decode")
+                        chunk=False, name="mla_paged_decode")
     return pl.pallas_call(kernel, name="mla_paged_decode",
                           interpret=_interpret(), **how)(
         tables.astype(jnp.int32), ctx.astype(jnp.int32),
@@ -294,11 +329,20 @@ def _chunk_group(C, n_heads, itemsize):
           "positions a grid step over one request's table")
 @functools.partial(jax.jit, static_argnames=("rank", "scale", "use_kernel"))
 def mla_prefill_chunk(q, pages, table_row, p0, rank, scale,
-                      use_kernel=None):
+                      use_kernel=None, n_real=None):
     """Chunked-prefill attention over a latent paged cache that already
     holds the chunk's own rows. q [C, N, W]: the absorbed queries at
     positions p0..p0+C-1 (p0 a traced int32); table_row [max_blocks]
-    int32, ONE request's block table. Returns [C, N, rank].
+    int32, ONE request's block table; n_real (a traced int32, 1..C; all
+    C when None): how many of the positions are real. Returns
+    [C, N, rank], zero at the positions past the real ones.
+
+    The kernel's grid is one step a group of positions whatever n_real
+    is (one program for every length of chunk), but only the groups
+    that hold a real position work, and only up to position
+    p0 + n_real - 1: no page past the one it lies in is fetched, and
+    table entries past it may hold anything (`flash_prefill_chunk`'s
+    contract).
 
     It stays absorbed: at 8k of context a 512-token chunk costs 1.17
     TFLOP a layer this way against 0.62 with k and v a head expanded
@@ -314,15 +358,19 @@ def mla_prefill_chunk(q, pages, table_row, p0, rank, scale,
                       and mla_supported(bs, W, rank, tq * N,
                                         pages.dtype.itemsize, mb))
     positions = p0 + jnp.arange(C, dtype=jnp.int32)
+    n = jnp.clip(jnp.asarray(C if n_real is None else n_real, jnp.int32),
+                 1, C)
     if not use_kernel:
         lat = pages[table_row].reshape(1, -1, W)
-        return _dense(q[None], lat, positions[None], rank, scale)[0]
+        out = _dense(q[None], lat, positions[None], rank, scale)[0]
+        return jnp.where((positions < p0 + n)[:, None, None], out, 0)
     qg = q.reshape(C // tq, tq * N, W)
     kernel, how = _plan(qg, pages, table_row[None], rank=rank, scale=scale,
-                        n_heads=N, own_table=False,
-                        name="mla_prefill_chunk")
+                        n_heads=N, chunk=True, name="mla_prefill_chunk")
+    base = jnp.concatenate([
+        positions[::tq], jnp.stack([p0 + n - 1, -(-n // tq)])])
     out = pl.pallas_call(kernel, name="mla_prefill_chunk",
                          interpret=_interpret(), **how)(
-        table_row[None].astype(jnp.int32), positions[::tq],
+        table_row[None].astype(jnp.int32), base.astype(jnp.int32),
         qg.astype(pages.dtype), pages)
     return out.reshape(C, N, rank)

@@ -1295,6 +1295,10 @@ class ServingEngine:
                 # dropped here, freeing them lies inside the span
                 del args
                 monitor.incr("serving.prefill_chunks")
+                # how much of what the chunks' kernels were handed was
+                # padding: they work on the real positions only
+                monitor.incr("serving.prefill_positions_real", c_real)
+                monitor.incr("serving.prefill_positions_padded", C - c_real)
                 req.n_prefilled = p0 + c_real
                 if stats:
                     # fetched with the next tokens that are, so the

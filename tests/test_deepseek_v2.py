@@ -3,6 +3,7 @@ the latent paged cache, the absorbed attention, the drop-free expert
 layer and its share of an expert-parallel deployment, held to the plain
 reference of benchmark/reference/deepseek_v2.py (float32, attention not
 absorbed, no cache)."""
+import functools
 import math
 import os
 import sys
@@ -10,6 +11,7 @@ import sys
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -17,7 +19,8 @@ from benchmark.drivers.serve_mla import seeded_program_model   # noqa: E402
 from benchmark.reference import deepseek_v2 as ref             # noqa: E402
 from paddle_tpu.moe.serving import (held_expert_ffn,           # noqa: E402
                                     route_group_limited)
-from paddle_tpu.ops import rotary                              # noqa: E402
+from paddle_tpu.ops import pallas_mla, rotary                  # noqa: E402
+from paddle_tpu.ops.kernel_registry import registered_kernels  # noqa: E402
 from paddle_tpu.serving import (EngineConfig, SamplingParams,  # noqa: E402
                                 ServingEngine)
 from paddle_tpu.serving.kv_cache import (PagedKVCache, kv_kind,  # noqa: E402
@@ -52,10 +55,15 @@ def engine(dtype=None, m=TINY, **kw):
         dtype=dtype, **kw))
 
 
-def served_logits(eng, prompt, n_new):
+def served_logits(eng, prompt, n_new, use_kernel=None):
     """Logits of the positions len(prompt)-1 .. +n_new-1, taken from the
     engine's own compiled prefill and decode steps over its arenas,
-    feeding the reference's greedy tokens."""
+    feeding the reference's greedy tokens. `use_kernel` True: through
+    the Pallas kernels (in the interpreter here)."""
+    prefill = jax.jit(functools.partial(eng._prefill_logits,
+                                        use_kernel=use_kernel))
+    decode = jax.jit(functools.partial(eng._decode_logits,
+                                       use_kernel=use_kernel))
     C, bs = eng.cfg.prefill_chunk, eng.block_size
     mb = eng.max_blocks_per_seq
     table = np.zeros((mb,), np.int32)
@@ -67,8 +75,8 @@ def served_logits(eng, prompt, n_new):
         n = min(C, len(prompt) - p0)
         ids = np.zeros((1, C), np.int32)
         ids[0, :n] = prompt[p0:p0 + n]
-        last, k, v = jax.jit(eng._prefill_logits)(
-            params, k, v, ids, np.int32(p0), np.int32(n), table)
+        last, k, v = prefill(params, k, v, ids, np.int32(p0), np.int32(n),
+                             table)
     out.append(np.asarray(last[0]))
     S = eng.cfg.max_slots
     seq = list(prompt)
@@ -78,18 +86,24 @@ def served_logits(eng, prompt, n_new):
         ctx = np.zeros((S,), np.int32)
         tables = np.zeros((S, mb), np.int32)
         toks[1], ctx[1], tables[1] = seq[-1], len(seq) - 1, table
-        last, k, v = jax.jit(eng._decode_logits)(
-            params, k, v, toks, ctx, tables)
+        last, k, v = decode(params, k, v, toks, ctx, tables)
         out.append(np.asarray(last[1]))
     return np.stack(out), seq
 
 
-def test_engine_through_latent_cache_matches_reference():
+@pytest.mark.parametrize("use_kernel", [None, True],
+                         ids=["fallback", "kernels"])
+def test_engine_through_latent_cache_matches_reference(use_kernel):
+    """A prompt of one full chunk and a partly filled one (32 + 13): the
+    last chunk's attention is handed n_real and the head reads its last
+    real position."""
     rng = np.random.default_rng(3)
     prompt = rng.integers(1, TINY["vocab_size"], 45)
-    got, seq = served_logits(engine(), prompt, 6)
+    got, seq = served_logits(engine(), prompt, 6, use_kernel)
     want = np.asarray(ref.full_logits(TINY, SEED, SCALE, np.asarray(seq)))
     assert np.abs(got - want[len(prompt) - 1:]).max() < TOL
+    if use_kernel:
+        return
     # the tolerance sees precision: the same run with the program in
     # bfloat16 (weights, activations and latent cache) must fail it
     low, seq_low = served_logits(engine(dtype="bfloat16"), prompt, 6)
@@ -107,6 +121,79 @@ def test_absorbed_decode_equals_dense_attention():
     got, seq = served_logits(eng, prompt, 4)
     dense = np.asarray(eng.model(np.asarray(seq)[None])._value[0])
     assert np.abs(got - dense[len(prompt) - 1:]).max() < TOL
+
+
+def _chunk_case(seed, p0, n_real, C=32, mb=8, bs=16, N=4, W=256):
+    """A chunk at p0 over a table whose entries past the page of the
+    last real position point at a block of NaN, and the same table with
+    every page real (what the reference gathers)."""
+    rng = np.random.default_rng(seed)
+    pages = 0.1 * rng.standard_normal((mb + 2, bs, W)).astype(np.float32)
+    pages[mb + 1] = np.nan
+    q = 0.1 * rng.standard_normal((C, N, W)).astype(np.float32)
+    whole = np.arange(1, mb + 1, dtype=np.int32)
+    live = (p0 + n_real - 1) // bs + 1
+    row = np.where(np.arange(mb) < live, whole, mb + 1).astype(np.int32)
+    return q, pages, row, whole
+
+
+@pytest.mark.parametrize("p0", [48, 53], ids=["aligned", "inside_a_block"])
+@pytest.mark.parametrize("n_real", [1, 3, 4, 30, 32])
+def test_chunk_kernel_works_on_its_real_positions_only(p0, n_real):
+    """The real positions are the dense reference's, the padded ones
+    exactly zero, and no page past the last real position's is read (a
+    prefix hit resumes inside a block; groups of 4 positions a grid
+    step, so 1, 3 and 30 end inside a group)."""
+    rank, scale = 128, 0.125
+    q, pages, row, whole = _chunk_case(n_real, p0, n_real)
+    got = np.asarray(pallas_mla.mla_prefill_chunk(
+        q, pages, row, np.int32(p0), rank, scale, use_kernel=True,
+        n_real=np.int32(n_real)))
+    positions = p0 + jnp.arange(q.shape[0], dtype=jnp.int32)
+    want = np.asarray(pallas_mla._dense(
+        q[None], pages[whole].reshape(1, -1, q.shape[-1]), positions[None],
+        rank, scale)[0])
+    reg = next(r for r in registered_kernels()
+               if r.name == "mla_prefill_chunk")
+    assert not np.isnan(got).any()
+    np.testing.assert_allclose(got[:n_real], want[:n_real],
+                               rtol=reg.tol[0], atol=reg.tol[1])
+    assert not got[n_real:].any()
+    # the gather+dense path keeps the same contract over a sound table
+    dense = np.asarray(pallas_mla.mla_prefill_chunk(
+        q, pages, whole, np.int32(p0), rank, scale, use_kernel=False,
+        n_real=np.int32(n_real)))
+    assert np.array_equal(dense[:n_real], want[:n_real])
+    assert not dense[n_real:].any()
+
+
+def test_a_full_chunk_is_the_chunk_without_n_real():
+    q, pages, _, whole = _chunk_case(0, 53, 32)
+    args = (q, pages, whole, np.int32(53), 128, 0.125)
+    full = pallas_mla.mla_prefill_chunk(*args, use_kernel=True,
+                                        n_real=np.int32(32))
+    plain = pallas_mla.mla_prefill_chunk(*args, use_kernel=True)
+    assert np.array_equal(np.asarray(full), np.asarray(plain))
+
+
+def test_prefill_positions_real_and_padded_add_up():
+    """Every chunk is dispatched at `prefill_chunk` positions; the two
+    counters say how many of them were a prompt's."""
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, TINY["vocab_size"], n) for n in (64, 45, 10)]
+    eng = engine(enable_prefix_cache=False)
+    before = eng.metrics_snapshot()
+    _streams(eng, prompts, n_new=2)
+    after = eng.metrics_snapshot()
+
+    def grew(name):
+        return after.get("serving." + name, 0) - before.get(
+            "serving." + name, 0)
+    assert grew("prefill_chunks") == 2 + 2 + 1
+    assert grew("prefill_positions_real") == 64 + 45 + 10
+    assert grew("prefill_positions_padded") == (32 - 13) + (32 - 10)
+    assert grew("prefill_positions_real") + grew("prefill_positions_padded") \
+        == grew("prefill_chunks") * eng.cfg.prefill_chunk
 
 
 def _streams(eng, prompts, n_new=6):

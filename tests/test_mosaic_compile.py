@@ -120,7 +120,9 @@ def mosaic_mla(monkeypatch):
 @pytest.mark.parametrize("step", ["decode", "chunk"])
 def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
     """deepseek-v2.serve-docs: 128 heads over 640-lane rows (576
-    numbers), 512 of them the value, tables of 576 blocks."""
+    numbers), 512 of them the value, tables of 576 blocks; a chunk of
+    512 with its number of real positions traced (one program for every
+    length of question)."""
     pallas_mla, _ = mosaic_mla
     N, W, rank, bs, mb, nb = 128, 640, 512, 16, 576, 2048
     bf16 = jnp.bfloat16
@@ -135,10 +137,11 @@ def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
         args = (sds((32, N, W), bf16), sds((nb, bs, W), bf16),
                 sds((32, mb), jnp.int32), sds((32,), jnp.int32))
     else:
-        fn = lambda q, a, t, p0: pallas_mla.mla_prefill_chunk(
-            q, a, t, p0, rank, 0.11, use_kernel=True)
+        fn = lambda q, a, t, p0, n_real: pallas_mla.mla_prefill_chunk(
+            q, a, t, p0, rank, 0.11, use_kernel=True, n_real=n_real)
         args = (sds((512, N, W), bf16), sds((nb, bs, W), bf16),
-                sds((mb,), jnp.int32), sds((), jnp.int32))
+                sds((mb,), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32))
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text
