@@ -14,7 +14,10 @@ out by the repo's own means:
             logit parity with the gather+dense path
   kernels   every registered Pallas kernel compiled by Mosaic against
             its declared fallback, then the production tile shapes the
-            two legs above do not reach
+            two legs above do not reach (the DeepSeek-V2, Granite and
+            K-EXAONE cells': latent attention, expert products, the
+            Mamba-2 step and scan, a window layer's chunk and its
+            decode over rings, 64/8 heads of 128 to 13k)
   four_chip dist.ShardedTrainStep on a dp=2 x mp=2 mesh when there are
             four devices; reported as not run otherwise
 
@@ -579,6 +582,108 @@ def _production_cases(sz):
         lambda *a: ps.mamba2_chunk_scan(*a, piece=piece, use_kernel=False),
         (rand((Cs, D)), dt, -jnp.asarray(rs.uniform(1, 16, (Hm,)), f32),
          rand((Cs, Ns)), rand((Cs, Ns)), rand((Ns, D), f32, 1.0))))
+
+    # the K-EXAONE cell's shapes: 64 query heads over 8 K/V heads of 128
+    # (queries 8,192 lanes wide, arenas 1,024). A window layer: the
+    # banded chunk over a request's ring (from an empty ring, from the
+    # middle of a window, deep in a request; one real position, a part,
+    # all) and decode over 64 rings as one 128-row page a request, under
+    # its own name. A full layer: both paged kernels with contexts to
+    # 13k. The expert products at 6,144 x 2,048 over 16 held experts
+    def exaone_cases():
+        # a scope of its own: the lambdas of the blocks above read
+        # their N, H, gq ... when they are called
+        out = []
+        N, Nk, H, Wd = (4, 2, 128, 128) if TINY else (64, 8, 128, 128)
+        C, S, mb, pbs = (256, 6, 24, 16) if TINY else (512, 64, 832, 16)
+        wk = Nk * H
+        gq = dict(kv_heads=Nk, scale=H ** -0.5)
+        rk, rv = rand((S + 1, Wd, wk)), rand((S + 1, Wd, wk))
+        for p0, n_real in ((0, C), (0, 1), (100, 253), (4632, C), (4632, 253)):
+            out.append((
+                ("window_prefill_chunk",),
+                f"window_prefill_chunk {N} heads over {Nk}x{H} window={Wd} "
+                f"C={C} p0={p0} n_real={n_real}",
+                lambda q, k, v, a, b, p0=p0, n=n_real: pd.window_prefill_chunk(
+                    q, k, v, a, b, np.int32(S - 1), np.int32(p0), N,
+                    n_real=np.int32(n), use_kernel=True, **gq)[:n],
+                lambda q, k, v, a, b, p0=p0, n=n_real: pd.window_prefill_chunk(
+                    q, k, v, a, b, np.int32(S - 1), np.int32(p0), N,
+                    n_real=np.int32(n), use_kernel=False, **gq)[:n],
+                (rand((C, N * H)), rand((C, wk)), rand((C, wk)), rk, rv)))
+        rows = rs.permutation(np.arange(1, S + 1)).astype(np.int32)
+        ctxs = rs.randint(1, 13000, (S,)).astype(np.int32)
+        ctxs[:5] = (0, 5, Wd - 1, Wd, 4 * Wd + 3)
+        rows[0] = 0                                     # the idle slot
+        out.append((
+            ("paged_decode_window",),
+            f"paged_decode_window {N} heads over {Nk}x{H}, {S} rings of {Wd}",
+            lambda q, a, b, r, c: pd.paged_decode_attention(
+                q, a, b, r[:, None], jnp.minimum(c, Wd - 1), N,
+                name="paged_decode_window", use_kernel=True, **gq),
+            lambda q, a, b, r, c: pd.paged_decode_attention(
+                q, a, b, r[:, None], jnp.minimum(c, Wd - 1), N,
+                use_kernel=False, **gq),
+            (rand((S, 1, N * H)), rk, rv, rows, ctxs)))
+        T = pd.paged_decode_tile_rows(pbs, wk, Nk, 2, mb, N // Nk)
+        ctxs = sorted({0, pbs - 1, T - 1, T, 2 * T + 3, mb * pbs - 1}
+                      & set(range(mb * pbs)))
+        ctxs += [int(c) for c in rs.randint(1, mb * pbs, 9 - len(ctxs))]
+        pages = rs.permutation(np.arange(
+            1, sum(c // pbs + 1 for c in ctxs) + 1))
+        tabs, used = np.zeros((len(ctxs) + 1, mb), np.int32), 0
+        for i, c in enumerate(ctxs):
+            tabs[i, :c // pbs + 1] = pages[used:used + c // pbs + 1]
+            used += c // pbs + 1
+        ctxs = np.asarray(ctxs + [0], np.int32)         # + the idle slot
+        arena = (len(pages) + 1, pbs, wk)
+        out.append((
+            ("paged_decode",),
+            f"paged_decode {N} heads over {Nk}x{H} mb={mb} tile={T} rows",
+            lambda q, k, v, t, c: pd.paged_decode_attention(
+                q, k, v, t, c, N, use_kernel=True, **gq),
+            lambda q, k, v, t, c: pd.paged_decode_attention(
+                q, k, v, t, c, N, use_kernel=False, **gq),
+            (rand((len(ctxs), 1, N * H)), rand(arena), rand(arena), tabs,
+             ctxs)))
+        Cf = 32 if TINY else 512
+        for p0, n_real in ((0, Cf), (mb * pbs - Cf, Cf),
+                           (Cf + 24, Cf // 2 - 3)):
+            row = np.zeros((mb,), np.int32)
+            n_alloc = (p0 + n_real - 1) // pbs + 1
+            row[:n_alloc] = pages[:n_alloc]
+            out.append((
+                ("flash_prefill_chunk",),
+                f"flash_prefill_chunk {N} heads over {Nk}x{H} C={Cf} p0={p0} "
+                f"n_real={n_real}",
+                lambda q, k, v, t, p0=p0, n=n_real: pd.flash_prefill_chunk(
+                    q, k, v, t, np.int32(p0), N, use_kernel=True,
+                    n_real=np.int32(n), **gq)[:, :n],
+                lambda q, k, v, t, p0=p0, n=n_real: pd.flash_prefill_chunk(
+                    q, k, v, t, np.int32(p0), N, use_kernel=False,
+                    **gq)[:, :n],
+                (rand((1, Cf, N * H)), rand(arena), rand(arena), row)))
+        d_x, f_x, E_x = (128, 128, 4) if TINY else (6144, 2048, 16)
+        wg, wu = rand((E_x, d_x, f_x), scale=0.02), \
+            rand((E_x, d_x, f_x), scale=0.02)
+        wd = rand((E_x, f_x, d_x), scale=0.02)
+        for tokens in (64, 128 if TINY else 512):
+            xt = rand((tokens, d_x), scale=1.0)
+            ex = rs.randint(-4, E_x + 5, (tokens, 8)).astype(np.int32)
+            ex[ex == 1] = 3                             # expert 1 idle
+            wts = jnp.asarray(rs.rand(tokens, 8), jnp.float32)
+            live = jnp.arange(tokens) < tokens - 3
+            out.append((
+                ("moe_grouped_ffn",),
+                f"moe_grouped_ffn {tokens} tokens d={d_x} f={f_x} E={E_x}",
+                lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
+                    x, l, w, e, (0, E_x), a, b, c, use_kernel=True)[0],
+                lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
+                    x, l, w, e, (0, E_x), a, b, c, use_kernel=False)[0],
+                (xt, live, wts, jnp.asarray(ex), wg, wu, wd)))
+        return out
+
+    cases.extend(exaone_cases())
 
     from paddle_tpu.ops import pallas_int8 as p8
     vocab = -(-cfg.vocab_size // p8._BLOCK_V) * p8._BLOCK_V   # row-padded

@@ -17,3 +17,6 @@ from .deepseek_v2 import (  # noqa: F401
 from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridForCausalLM,
 )
+from .exaone_moe import (  # noqa: F401
+    ExaoneMoeConfig, ExaoneMoeForCausalLM,
+)

@@ -1,14 +1,18 @@
-"""What the served decoder models share (`deepseek_v2`, `granite_hybrid`):
-parameters handed over leaf by leaf, the matmul with a float32 sum, and
-the gated MLP. RMSNorm is `nn.functional.norm.rms_norm_values`.
+"""What the served decoder models share (`deepseek_v2`, `granite_hybrid`,
+`exaone_moe`): parameters handed over leaf by leaf, the matmul with a
+float32 sum, the gated MLP, the expert layer around a model's own router,
+and the head behind the serving engine's protocol. RMSNorm is
+`nn.functional.norm.rms_norm_values`.
 """
 import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Parameter
+from ..moe.serving import held_expert_ffn
 from ..nn import Layer
 
-__all__ = ["GatedMLP", "Weights", "default_make", "matmul"]
+__all__ = ["GatedMLP", "HeldExperts", "ServedDecoder", "Weights",
+           "default_make", "matmul"]
 
 
 class Weights(Layer):
@@ -43,6 +47,57 @@ class GatedMLP(Weights):
         g = matmul(x, self.gate._value)
         return matmul(jax.nn.silu(g) * matmul(x, self.up._value),
                       self.down._value)
+
+
+class HeldExperts(Weights):
+    """The shared experts (one gated MLP of their summed width) plus
+    this model's share of the routed experts, `self.c.held = (first,
+    count)` of its config. A subclass sets `self.c`, makes the router's
+    parameters, then `build_experts`, and brings `route(x)` -> (weights
+    [T, k], experts [T, k])."""
+
+    def build_experts(self, d, f, n_shared):
+        count = self.c.held[1]
+        self.shared = GatedMLP(self._make, self._prefix + "shared.", d,
+                               f * n_shared)
+        self.experts_gate = self.param("experts_gate", (count, d, f))
+        self.experts_up = self.param("experts_up", (count, d, f))
+        self.experts_down = self.param("experts_down", (count, f, d))
+
+    def run(self, x, live=None, use_kernel=None):
+        """(shared(x) + the held experts' weighted sum, the step's
+        routing counts)."""
+        if live is None:
+            live = jnp.ones((x.shape[0],), bool)
+        weights, experts = self.route(x)
+        routed, stats = held_expert_ffn(
+            x, live, weights, experts, self.c.held, self.experts_gate._value,
+            self.experts_up._value, self.experts_down._value,
+            use_kernel=use_kernel)
+        return self.shared.run(x) + routed, stats
+
+
+class ServedDecoder:
+    """A decoder model as the serving engine reads it
+    (serving/served.py): `layers` one served block a layer, the
+    embedding a lookup, the head the model's `logits`. `h` is a plain
+    array [tokens, d]: a decode step's slots or a chunk's positions."""
+
+    def __init__(self, model, layers):
+        c = model.config
+        self.model = model
+        self.max_seq_len, self.dtype = c.max_seq_len, c.dtype
+        self.layers = layers
+
+    def embed(self, ids, positions):
+        return self.model.embed._value[ids.reshape(-1)]
+
+    def head(self, h, at=None):
+        if at is not None:
+            h = jax.lax.dynamic_slice(h, (at, 0), (1, h.shape[1]))[None]
+        else:
+            h = h[:, None]
+        return self.model.logits(h)
 
 
 def default_make(config):

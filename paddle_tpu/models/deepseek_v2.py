@@ -32,11 +32,12 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
-from ..moe.serving import held_expert_ffn, route_group_limited
+from ..moe.serving import route_group_limited
 from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
 from ..ops.pallas_mla import mla_paged_decode, mla_prefill_chunk
-from .blocks import GatedMLP, Weights, default_make, matmul
+from .blocks import (GatedMLP, HeldExperts, ServedDecoder, Weights,
+                     default_make, matmul)
 from ..ops.rotary import (apply_rotary, rotary_cos_sin, yarn_inv_freq,
                           yarn_mscale)
 
@@ -135,39 +136,23 @@ def _einsum(spec, a, b):
     return out.astype(a.dtype)
 
 
-class ExpertLayer(Weights):
-    """Shared experts (one gated MLP of their summed width) plus this
-    model's share of the routed experts."""
+class ExpertLayer(HeldExperts):
+    """Shared experts plus this model's share of the routed experts,
+    behind the group-limited softmax router."""
 
     def __init__(self, make, prefix, c):
         super().__init__(make, prefix)
-        d, f = c.hidden_size, c.moe_intermediate_size
-        count = c.held[1]
         self.c = c
-        self.router = self.param("router", (d, c.n_routed_experts))
-        self.shared = GatedMLP(make, prefix + "shared.", d,
-                               f * c.n_shared_experts)
-        self.experts_gate = self.param("experts_gate", (count, d, f))
-        self.experts_up = self.param("experts_up", (count, d, f))
-        self.experts_down = self.param("experts_down", (count, f, d))
+        self.router = self.param("router", (c.hidden_size,
+                                            c.n_routed_experts))
+        self.build_experts(c.hidden_size, c.moe_intermediate_size,
+                           c.n_shared_experts)
 
     def route(self, x):
         c = self.c
         return route_group_limited(
             x, self.router._value, c.n_group, c.topk_group,
             c.num_experts_per_tok, c.routed_scaling_factor)
-
-    def run(self, x, live=None, use_kernel=None):
-        """(shared(x) + the held experts' weighted sum, the step's
-        routing counts)."""
-        if live is None:
-            live = jnp.ones((x.shape[0],), bool)
-        weights, experts = self.route(x)
-        routed, stats = held_expert_ffn(
-            x, live, weights, experts, self.c.held, self.experts_gate._value,
-            self.experts_up._value, self.experts_down._value,
-            use_kernel=use_kernel)
-        return self.shared.run(x) + routed, stats
 
 
 class MLAttention(Weights):
@@ -312,28 +297,6 @@ class _ServedBlock:
         return self._step(h, pages, view, view.positions, attend)
 
 
-class ServedDeepseekV2:
-    """The model as the serving engine reads it (serving/served.py).
-    `h` is a plain array [tokens, d]: a decode step's slots or a
-    chunk's positions."""
-
-    def __init__(self, model):
-        c = model.config
-        self.model = model
-        self.max_seq_len, self.dtype = c.max_seq_len, c.dtype
-        self.layers = [_ServedBlock(b, c) for b in model.blocks]
-
-    def embed(self, ids, positions):
-        return self.model.embed._value[ids.reshape(-1)]
-
-    def head(self, h, at=None):
-        if at is not None:
-            h = jax.lax.dynamic_slice(h, (at, 0), (1, h.shape[1]))[None]
-        else:
-            h = h[:, None]
-        return self.model.logits(h)
-
-
 class DeepseekV2ForCausalLM(Layer):
     """`make(name, shape, kind)` supplies each parameter (a checkpoint
     loader, seeded weights drawn on the device); by default they are
@@ -375,4 +338,5 @@ class DeepseekV2ForCausalLM(Layer):
 
     def served(self):
         """This model behind the serving engine's per-layer protocol."""
-        return ServedDeepseekV2(self)
+        return ServedDecoder(self, [_ServedBlock(b, self.config)
+                                    for b in self.blocks])

@@ -45,7 +45,7 @@ from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
 from ..ops.pallas_decode import flash_prefill_chunk, paged_decode_attention
 from ..ops.pallas_ssm import mamba2_chunk_scan, mamba2_state_step
-from .blocks import GatedMLP, Weights, default_make, matmul
+from .blocks import GatedMLP, ServedDecoder, Weights, default_make, matmul
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM"]
 
@@ -343,26 +343,11 @@ class _ServedBlock:
         return h, pages, None
 
 
-class ServedGraniteHybrid:
-    """The model as the serving engine reads it (serving/served.py).
-    `h` is a plain array [tokens, d]: a decode step's slots or a
-    chunk's positions."""
-
-    def __init__(self, model):
-        c = model.config
-        self.model = model
-        self.max_seq_len, self.dtype = c.max_seq_len, c.dtype
-        self.layers = [_ServedBlock(b) for b in model.blocks]
+class ServedGraniteHybrid(ServedDecoder):
+    """`ServedDecoder` with the embedding's multiplier."""
 
     def embed(self, ids, positions):
         return self.model.embedded(ids.reshape(-1))
-
-    def head(self, h, at=None):
-        if at is not None:
-            h = jax.lax.dynamic_slice(h, (at, 0), (1, h.shape[1]))[None]
-        else:
-            h = h[:, None]
-        return self.model.logits(h)
 
 
 def _default_make(config):
@@ -441,4 +426,5 @@ class GraniteHybridForCausalLM(Layer):
 
     def served(self):
         """This model behind the serving engine's per-layer protocol."""
-        return ServedGraniteHybrid(self)
+        return ServedGraniteHybrid(
+            self, [_ServedBlock(b) for b in self.blocks])

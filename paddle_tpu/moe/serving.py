@@ -9,6 +9,11 @@ here every token's chosen experts are computed, whatever the load.
                           their best expert, the top `k` experts among
                           those groups (DeepSeek-V2's
                           `group_limited_greedy`)
+    route_sigmoid_topk    sigmoid scores in float32, the top `k` of
+                          score + a learned bias, the chosen scores
+                          renormalised to sum to 1 and scaled (the
+                          bias chooses and does not weigh: DeepSeek-V3's
+                          gate without its group limit)
     held_expert_ffn       the gated expert MLPs of the experts HELD
                           HERE, `held=(first, count)`, over the tokens
                           routed to them: the layer's share of an
@@ -35,8 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.kernel_registry import register_kernel
 
-__all__ = ["route_group_limited", "held_expert_ffn", "moe_grouped_ffn",
-           "group_by_expert"]
+__all__ = ["route_group_limited", "route_sigmoid_topk", "held_expert_ffn",
+           "moe_grouped_ffn", "group_by_expert"]
 
 _F_TILE = 256               # columns of an expert's width a grid step takes
 _VMEM_LIMIT = 40 * 2 ** 20  # three weight blocks in two buffers each
@@ -62,6 +67,22 @@ def route_group_limited(x, w_gate, n_group, topk_group, k, scale=1.0):
         jnp.arange(T)[:, None], groups].set(True)
     masked = jnp.where(jnp.repeat(kept, E // n_group, axis=1), scores, 0.0)
     weights, experts = jax.lax.top_k(masked, k)
+    return weights * scale, experts.astype(jnp.int32)
+
+
+def route_sigmoid_topk(x, w_gate, bias, k, scale=1.0, renorm=True):
+    """x [T, d], w_gate [d, E], bias [E] -> (weights [T, k] float32,
+    experts [T, k] int32). Scores are a float32 sigmoid an expert; the
+    chosen experts are the top `k` of score + bias; the weights are the
+    chosen experts' SCORES (without the bias), divided by their sum
+    (+ 1e-20) where `renorm`, times `scale`."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, w_gate.astype(x.dtype),
+                preferred_element_type=jnp.float32))
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, experts, axis=1)
+    if renorm:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
     return weights * scale, experts.astype(jnp.int32)
 
 

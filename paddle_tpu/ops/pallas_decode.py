@@ -480,10 +480,10 @@ def _ungroup(o, kv_heads, group):
 # once a layer, the decode programs' 24 or 48 copies cost seconds of
 # every start, compile cache or not
 @functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel",
-                                             "kv_heads", "scale"))
+                                             "kv_heads", "scale", "name"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                            n_heads, use_kernel=None, kv_heads=None,
-                           scale=None):
+                           scale=None, name="paged_decode"):
     """Decode attention (q_len == 1) over a PAGED KV cache.
 
     q [S, 1, N*H]; k_pages/v_pages [num_blocks, block_size, Nk*H] — the
@@ -494,8 +494,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     each row's logical block i to a physical block (unallocated tail
     entries point at the reserved null block 0); ctx_lens [S] int32 —
     each row's current position (keys at logical positions 0..ctx are
-    valid, matching `off` in `decode_attention`). Returns [S, 1, N*H]
-    in q's dtype.
+    valid, matching `off` in `decode_attention`). `name` is the
+    kernel's name in a device trace: a caller whose arenas are not the
+    block pool's (a window layer's rings, one `window`-row page a
+    request: `models/exaone_moe.py`) gives its own, so that the trace
+    tells the two apart. Returns [S, 1, N*H] in q's dtype.
 
     Two paths, one contract:
     - fused Pallas kernel (TPU + `paged_decode_supported`): tiles of
@@ -574,7 +577,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, rows=rows,
                           n_heads=Nk, head_dim=H, group=G),
-        name="paged_decode",
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, G, wk), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -920,6 +923,211 @@ def flash_prefill_chunk(q, k_pages, v_pages, table_row, p0, n_heads,
     )(table_row.astype(jnp.int32), jnp.stack([p0, last]),
       qg.astype(k_pages.dtype), k_pages, v_pages)
     return _ungroup(out[None], Nk, G) if G > 1 else out
+
+
+def window_ring_positions(p0, window):
+    """The position each of a ring's `window` rows holds before a chunk
+    that starts at `p0`: the largest p < p0 with p % window == row,
+    negative where the request has written no such position yet (so at
+    p0 == 0 every row, whatever its last owner left there)."""
+    row = jnp.arange(window, dtype=jnp.int32)
+    base = p0 // window * window
+    return base + row - jnp.where(row < p0 - base, 0, window)
+
+
+def window_ring_write(ring, row, chunk, p0, n_real):
+    """`ring` [rows + 1, window, w] with request row `row` taking in the
+    chunk's rows `chunk` [C, w] at positions p0.., the first `n_real`
+    real: afterwards row r of the ring holds the largest position
+    <= p0 + n_real - 1 that is r modulo `window`, from the chunk where
+    that position is the chunk's, as it was where it is older."""
+    window = ring.shape[1]
+    r = jnp.arange(window, dtype=jnp.int32)
+    last = p0 + n_real - 1
+    pos = last - (last - r) % window
+    new = jnp.where((pos >= p0)[:, None],
+                    chunk[jnp.clip(pos - p0, 0, chunk.shape[0] - 1)]
+                    .astype(ring.dtype), ring[row])
+    return ring.at[row].set(new)
+
+
+def _window_kernel(at_ref, q_ref, kp_ref, vp_ref, ko_ref, vo_ref, rk_ref,
+                   rv_ref, out_ref, *, scale, window, head_dim, group):
+    """Grid (K/V head g, query tile t of `window` rows). A query at
+    chunk row i sees the keys at rows i - window + 1 .. i: tile t sees
+    its own tile of the chunk's keys and the tile before it, which for
+    tile 0 is the ring (the request's last `window` positions before the
+    chunk). Two products a query head, `q . [K_prev | K_own]^T`
+    [window, 2 window] and `p @ [V_prev | V_own]` [window, H], masked by
+    position: visible iff the key's position is >= 0 and within
+    `window` behind the query's, the query's own included."""
+    t = pl.program_id(1)
+    p0, n_real = at_ref[1], at_ref[2]
+    W, H = window, head_dim
+
+    @pl.when(t * W >= n_real)
+    def _dead():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(t * W < n_real)
+    def _live():
+        first = t == 0
+        kk = jnp.concatenate(
+            [jnp.where(first, rk_ref[0], kp_ref[...]), ko_ref[...]], axis=0)
+        vv = jnp.concatenate(
+            [jnp.where(first, rv_ref[0], vp_ref[...]), vo_ref[...]], axis=0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (W, 2 * W), 1)
+        base = p0 // W * W
+        ring_pos = base + col - jnp.where(col < p0 - base, 0, W)
+        kpos = jnp.where(jnp.logical_and(first, col < W), ring_pos,
+                         p0 + (t - 1) * W + col)
+        qpos = p0 + t * W + jax.lax.broadcasted_iota(
+            jnp.int32, (W, 2 * W), 0)
+        seen = jnp.logical_and(
+            kpos >= 0, jnp.logical_and(kpos <= qpos, qpos - kpos < W))
+        real = t * W + jax.lax.broadcasted_iota(
+            jnp.int32, (W, H), 0) < n_real
+        exact = jax.lax.Precision.HIGHEST \
+            if q_ref.dtype == jnp.float32 else None
+        for i in range(group):
+            s = jax.lax.dot_general(
+                q_ref[:, i * H:(i + 1) * H], kk, (((1,), (1,)), ((), ())),
+                precision=exact,
+                preferred_element_type=jnp.float32) * scale   # [W, 2W]
+            s = jnp.where(seen, s, -1e30)
+            p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+            o = jax.lax.dot_general(
+                p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+                precision=exact,
+                preferred_element_type=jnp.float32)           # [W, H]
+            # a query sees its own key: no denominator is 0; the rows
+            # past the last real position come out 0, as from a dead tile
+            o = o / jnp.sum(p, axis=1, keepdims=True)
+            out_ref[:, i * H:(i + 1) * H] = jnp.where(
+                real, o, 0.0).astype(out_ref.dtype)
+
+
+def window_prefill_supported(chunk, window, head_dim, itemsize=2):
+    """Gate for the fused window prefill-chunk kernel: a query tile is
+    `window` rows, so the chunk is a whole number of windows; a tile's
+    scores are [window, 2 window] and a head a lane column, so both are
+    whole 128-lane tiles."""
+    return chunk % window == 0 and window % _COLS == 0 \
+        and head_dim % _COLS == 0 and window % _packed_rows(itemsize) == 0
+
+
+def _window_example(rng):
+    """A chunk that resumes in the middle of a window over a ring that a
+    longer request left full (kernel_lint KN504)."""
+    N, Nk, H, W, C = 4, 2, 128, 128, 256
+    f = lambda *shape: 0.3 * rng.standard_normal(shape).astype(np.float32)
+    p0 = np.int32(rng.integers(0, 3 * W))
+    n_real = np.int32(rng.integers(1, C + 1))
+    return (f(C, N * H), f(C, Nk * H), f(C, Nk * H), f(3, W, Nk * H),
+            f(3, W, Nk * H), np.int32(2), p0, N), \
+        {"n_real": n_real, "kv_heads": Nk, "use_kernel": True}
+
+
+def _window_fallback(q, k, v, ring_k, ring_v, row, p0, n_heads,
+                     use_kernel=None, **kw):
+    return window_prefill_chunk(q, k, v, ring_k, ring_v, row, p0, n_heads,
+                                use_kernel=False, **kw)
+
+
+@register_kernel(
+    "window_prefill_chunk", example=_window_example,
+    fallback=_window_fallback, tol=(2e-3, 2e-3),
+    notes="sliding-window attention of one chunk: grid (K/V head, query "
+          "tile of `window` rows), each tile against its own tile of the "
+          "chunk's keys and the one before it, the request's ring for "
+          "tile 0; rows past n_real come out 0 from either path")
+@functools.partial(jax.jit, static_argnames=("n_heads", "use_kernel",
+                                             "kv_heads", "scale"))
+def window_prefill_chunk(q, k, v, ring_k, ring_v, row, p0, n_heads,
+                         use_kernel=None, n_real=None, kv_heads=None,
+                         scale=None):
+    """Chunked-prefill attention of a layer that attends over its last
+    `window` positions, the query's own included.
+
+    q [C, N*H] — the chunk's queries at positions p0..p0+C-1; k, v
+    [C, Nk*H] — the chunk's own keys and values, from the activations
+    (keys already rotated, where the layer rotates); ring_k, ring_v
+    [rows + 1, window, Nk*H] — the rings a request each
+    (`kv_cache.window_kind`): row `row` holds this request's positions
+    before p0, position p in ring row p % window, and `window` is read
+    off their shape; which of its rows are valid follows from p0
+    (`window_ring_positions`), so what the row's last owner left is
+    never seen. Nk = `kv_heads` (default N); `scale` on the scores
+    (default H ** -0.5); n_real: how many of the C positions are real
+    (the rows past them come out 0). The rings are
+    read, not written: the caller puts the chunk's rows in afterwards
+    (`window_ring_write`). Returns [C, N*H] in q's dtype.
+
+    The fused kernel (TPU + `window_prefill_supported`) never forms the
+    [C, window + C] scores: a query tile of `window` rows meets two key
+    tiles. Everywhere else the same masked softmax is composed over the
+    ring and the chunk side by side."""
+    C, nh = q.shape
+    N = n_heads
+    H = nh // N
+    Nk = N if kv_heads is None else int(kv_heads)
+    if N % Nk:
+        raise ValueError(f"{N} query heads over {Nk} K/V heads")
+    G = N // Nk
+    W = ring_k.shape[1]
+    scale = 1.0 / float(np.sqrt(H)) if scale is None else float(scale)
+    p0 = jnp.asarray(p0, jnp.int32)
+    n_real = jnp.asarray(C if n_real is None else n_real, jnp.int32)
+    if use_kernel is None:
+        use_kernel = (jax.default_backend() == "tpu"
+                      and window_prefill_supported(C, W, H, q.dtype.itemsize))
+    if not use_kernel:
+        kk = jnp.concatenate([ring_k[row].astype(q.dtype), k], axis=0)
+        vv = jnp.concatenate([ring_v[row].astype(q.dtype), v], axis=0)
+        kpos = jnp.concatenate([window_ring_positions(p0, W),
+                                p0 + jnp.arange(C, dtype=jnp.int32)])
+        qpos = (p0 + jnp.arange(C, dtype=jnp.int32))[:, None]
+        seen = (kpos >= 0) & (kpos <= qpos) & (qpos - kpos < W)
+        exact = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+        s = jnp.einsum("tkgh,skh->kgts", q.reshape(C, Nk, G, H),
+                       kk.reshape(W + C, Nk, H), precision=exact,
+                       preferred_element_type=jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        o = jnp.einsum("kgts,skh->tkgh", probs.astype(q.dtype),
+                       vv.reshape(W + C, Nk, H), precision=exact,
+                       preferred_element_type=jnp.float32)
+        real = jnp.arange(C, dtype=jnp.int32)[:, None] < n_real
+        return jnp.where(real, o.reshape(C, nh), 0.0).astype(q.dtype)
+
+    if C % W:
+        raise ValueError(f"window_prefill_chunk kernel: a chunk of {C} is "
+                         f"no whole number of windows of {W}")
+    prev = lambda g, t, at: (jnp.maximum(t - 1, 0), g)
+    own = lambda g, t, at: (t, g)
+    ring = lambda g, t, at: (at[0], 0, g)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Nk, C // W),
+        in_specs=[
+            pl.BlockSpec((W, G * H), own),
+            pl.BlockSpec((W, H), prev), pl.BlockSpec((W, H), prev),
+            pl.BlockSpec((W, H), own), pl.BlockSpec((W, H), own),
+            pl.BlockSpec((1, W, H), ring), pl.BlockSpec((1, W, H), ring),
+        ],
+        out_specs=pl.BlockSpec((W, G * H), own),
+    )
+    return pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, window=W,
+                          head_dim=H, group=G),
+        name="window_prefill_chunk",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((C, nh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+    )(jnp.stack([jnp.asarray(row, jnp.int32), p0, n_real]),
+      q, k.astype(q.dtype), v.astype(q.dtype), k.astype(q.dtype),
+      v.astype(q.dtype), ring_k.astype(q.dtype), ring_v.astype(q.dtype))
 
 
 def _decode_example(rng):

@@ -1,4 +1,6 @@
-"""Rotary position embeddings with YaRN scaling, on raw values.
+"""Rotary position embeddings with YaRN scaling, on raw values: one
+rotary key shared by all heads (latent attention) or every head of
+grouped-query q and k (`cos[:, None]`, `sin[:, None]` over the heads).
 
 The tables are never stored: `rotary_cos_sin` computes cos and sin of
 the positions a step works on, in float32, from the inverse
@@ -61,13 +63,17 @@ def rotary_cos_sin(positions, inv_freq, scale=1.0):
     return jnp.cos(emb) * scale, jnp.sin(emb) * scale
 
 
-def apply_rotary(x, cos, sin):
-    """Rotate x [..., dim] by cos/sin broadcastable to it. The pairs
-    (x0, x1), (x2, x3), ... of the input are first moved to
-    (x0, x2, ... | x1, x3, ...), as the DeepSeek-V2 reference code does
-    before its rotate-half. Float32 arithmetic, the input's dtype out."""
+def apply_rotary(x, cos, sin, interleaved=True):
+    """Rotate x [..., dim] by cos/sin broadcastable to it. With
+    `interleaved` the pairs (x0, x1), (x2, x3), ... of the input are
+    first moved to (x0, x2, ... | x1, x3, ...), as the DeepSeek-V2
+    reference code does before its rotate-half; without it the input is
+    taken as the two halves already (the plain rotate-half of the
+    Llama/EXAONE code: dimension i turns with dimension i + dim/2).
+    Float32 arithmetic, the input's dtype out."""
     f = x.astype(jnp.float32)
-    f = jnp.concatenate([f[..., 0::2], f[..., 1::2]], axis=-1)
+    if interleaved:
+        f = jnp.concatenate([f[..., 0::2], f[..., 1::2]], axis=-1)
     half = f.shape[-1] // 2
     rotated = jnp.concatenate([-f[..., half:], f[..., :half]], axis=-1)
     return (f * cos + rotated * sin).astype(x.dtype)

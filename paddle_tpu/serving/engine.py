@@ -24,9 +24,9 @@ The engine knows no architecture: a model hands it the per-layer
 protocol of `serving/served.py` (embed, layers that each declare a
 cache kind and bring a `decode` and a `prefill` over it, the head), and
 the arenas, the fork and the sizing follow the layers' cache kinds.
-Where a layer keeps rows by REQUEST (a recurrent state) a request holds
-one row of `[max_slots + 1, ...]` arenas from its admission to its
-release, the steps carry those arenas like the paged ones, and no prefix
+Where a layer keeps rows by REQUEST (a recurrent state, a window
+layer's ring of its last keys and values) a request holds one row of
+`[max_slots + 1, ...]` arenas from its admission to its release, the steps carry those arenas like the paged ones, and no prefix
 index is built: a block of K/V without the state at its boundary cannot
 resume a request.
 
@@ -205,7 +205,8 @@ class ServingEngine:
 
     `model.served()` must return the per-layer protocol of
     `serving/served.py`: GPTForPretraining (quantized or not),
-    DeepseekV2ForCausalLM and GraniteHybridForCausalLM implement it.
+    DeepseekV2ForCausalLM, GraniteHybridForCausalLM and
+    ExaoneMoeForCausalLM implement it.
     """
 
     def __init__(self, model, config=None, sink=None, **overrides):
@@ -243,8 +244,12 @@ class ServingEngine:
         # some layer keeps rows by request: one row a request beside its
         # blocks, as many rows as slots (admission bounds running +
         # prefilling by max_slots)
-        self.rows = RowPool(cfg.max_slots) if any(   # guarded by: _mu
-            k.by_request for k in self.cache_kinds) else None
+        row_names = sorted({k.name for k in self.cache_kinds
+                            if k.by_request})
+        self.rows = RowPool(cfg.max_slots, row_names) \
+            if row_names else None      # guarded by: _mu
+        # positions a window layer's ring holds (0: the model has none)
+        self._window = max(k.window for k in self.cache_kinds)
         with self._on_device():
             self.cache = PagedKVCache(   # guarded by: _mu
                 self.cache_kinds, num_blocks, self.block_size,
@@ -835,9 +840,11 @@ class ServingEngine:
         self._in_flight = None
         self._stats_pending.clear()
         if self.rows:
-            # a position computed twice moves a recurrent state twice:
-            # such a model's requests give their rows back and replay
-            # from position 0 (oldest first at the waiting front).
+            # a position computed twice moves a recurrent state twice,
+            # and a ring that took in a dropped step's position has lost
+            # the oldest key of the position to compute again: such a
+            # model's requests give their rows back and replay from
+            # position 0 (oldest first at the waiting front).
             # `_on_step_error` still counts them among the step's
             # requests: a permanent fault fails them
             self._voided = list(self.sched.admit_order)
@@ -1075,7 +1082,8 @@ class ServingEngine:
         self.pool = BlockPool(self.pool.num_blocks)
         self.sched.pool = self.pool
         if self.rows:
-            self.rows = self.sched.row_pool = RowPool(self.cfg.max_slots)
+            self.rows = self.sched.row_pool = RowPool(self.cfg.max_slots,
+                                                      self.rows.names)
         if self.prefix_index is not None:
             self.prefix_index.bind(self.pool)
         with self._on_device():
@@ -1221,6 +1229,20 @@ class ServingEngine:
             req.trace.note_cow_fork(time.monotonic())
         return True
 
+    def _row_attrs(self, live, behind):
+        """What a dispatch span says of the rows by request: how many
+        of the batch's requests hold one, under each kind the model
+        keeps (`state_rows`, `window_rows`), and `window_kv_rows`, the
+        ring rows a window layer attends over for this batch: of the
+        `behind` positions each request has cached, at most `window`,
+        whatever its length."""
+        names = self.rows.names if self.rows else ()
+        window = self._window
+        return dict(state_rows=live if "state" in names else 0,
+                    window_rows=live if window else 0,
+                    window_kv_rows=int(np.minimum(behind, window).sum())
+                    if window else 0)
+
     def _prefill_one(self):     # requires: _mu
         """Dispatch at most one chunk of one request. Returns (did,
         first): `first` is (request, token, logp) when the chunk was the
@@ -1283,7 +1305,8 @@ class ServingEngine:
                        rid=req.rid, p0=p0, n_real=c_real,
                        kv_rows=flash_prefill_kv_rows(
                            p0, c_real, self.block_size),
-                       state_rows=int(req.row is not None),
+                       **self._row_attrs(int(req.row is not None),
+                                         min(p0, self._window - 1)),
                        cache_kind=self._cache_kind_names,
                        in_flight=int(self._in_flight is not None)):
                 tok, logp, new_k, new_v, stats = self._dispatch(
@@ -1423,7 +1446,9 @@ class ServingEngine:
                 else "serving_decode"
         with _span("serving_dispatch", family=family, slots=slots,
                    ctx_tokens=ctx_tokens, kv_rows=kv_rows,
-                   state_rows=int(np.count_nonzero(rows)),
+                   # a slot attends over its own position too
+                   **self._row_attrs(int(np.count_nonzero(rows)),
+                                     ctx[rows > 0] + 1),
                    cache_kind=self._cache_kind_names,
                    in_flight=int(prev is not None)):
             tok, logp, new_k, new_v, stats = self._dispatch(
@@ -1597,7 +1622,9 @@ class ServingEngine:
         monitor.set_gauge("serving.prefilling", len(self.sched.prefilling))
         monitor.set_gauge("serving.kv_blocks_used", self.pool.num_used)
         if self.rows:
-            monitor.set_gauge("serving.state_rows_live", self.rows.num_live)
+            for kind in self.rows.names:
+                monitor.set_gauge(f"serving.{kind}_rows_live",
+                                  self.rows.num_live)
         ps = self._prefix_stats
         offered = ps["tokens_offered"]
         monitor.set_gauge("serving.prefix_hit_rate",

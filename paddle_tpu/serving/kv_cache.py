@@ -49,7 +49,8 @@ Three layers, split host/device:
   ops/pallas_decode.py). Latent (`latent_kind`): ONE arena of
   `[num_blocks, block_size, width]` whose row is key and value at once
   (multi-head latent attention). A layer whose memory is by REQUEST
-  and not by token (`state_kind`: a recurrent state) keeps arenas of
+  and not by token (`state_kind`: a recurrent state; `window_kind`: the
+  K and V of the last `window` positions, a ring) keeps arenas of
   `[rows + 1, ...]` instead, one row a request handed out by the
   `RowPool`; the prefix index cannot share such a layer's memory, so
   the engine builds none for a model that has one. The arrays are
@@ -71,7 +72,8 @@ import jax.numpy as jnp
 
 __all__ = ["BlockPool", "BlockLeakError", "CacheKind", "PagedKVCache",
            "NULL_BLOCK", "NULL_ROW", "PrefixIndex", "RowPool",
-           "StaleIndexError", "kv_kind", "latent_kind", "state_kind"]
+           "StaleIndexError", "kv_kind", "latent_kind", "state_kind",
+           "window_kind"]
 
 
 class BlockLeakError(AssertionError):
@@ -529,8 +531,9 @@ class CacheKind:
     The engine sizes, forks and swaps arenas by this alone; what the
     numbers mean is the layer's business."""
 
-    def __init__(self, name, widths=(), request_rows=()):
+    def __init__(self, name, widths=(), request_rows=(), window=0):
         self.name = str(name)
+        self.window = int(window)   # positions a ring holds; 0: no ring
         self.widths = tuple(int(w) for w in widths)
         self.request_rows = tuple(
             (tuple(int(n) for n in shape), jnp.dtype(dtype))
@@ -578,14 +581,29 @@ def state_kind(*request_rows):
     return CacheKind("state", request_rows=request_rows)
 
 
+def window_kind(width, window, dtype="bfloat16"):
+    """A layer that attends over its last `window` positions only: a
+    ring of `window` K rows and one of V rows a request, whatever the
+    request's length. Position p lives in row `p % window`; which rows
+    are valid follows from the positions a step works on (a chunk that
+    starts at position 0 starts from an empty ring whatever the row
+    held), so a ring is never cleared."""
+    ring = ((int(window), int(width)), dtype)
+    return CacheKind("window", request_rows=(ring, ring), window=window)
+
+
 class RowPool:    # guarded by: ServingEngine._mu
     """The rows 1..n of the request-row arenas (row 0 is the null row).
     A request takes one when it is admitted to prefill and gives it back
     when it is released or preempted; admission bounds running +
-    prefilling by `max_slots`, which is `n`, so a row is always free."""
+    prefilling by `max_slots`, which is `n`, so a row is always free.
+    `names` are the by-request kinds the model's layers keep ("state",
+    "window"): the `serving.<name>_rows_*` counters are written under
+    each."""
 
-    def __init__(self, n):
+    def __init__(self, n, names=("state",)):
         self.capacity = int(n)
+        self.names = tuple(names)
         self._free = list(range(self.capacity, NULL_ROW, -1))   # LIFO
         self._owner = {}          # row -> owner tag
 

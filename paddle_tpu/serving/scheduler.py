@@ -406,7 +406,8 @@ class Scheduler:    # guarded by: ServingEngine._mu
                     else time.monotonic()
             if self.row_pool is not None:
                 req.row = self.row_pool.take(owner=req.rid)
-                monitor.incr("serving.state_rows_taken")
+                for kind in self.row_pool.names:
+                    monitor.incr(f"serving.{kind}_rows_taken")
             self.prefilling.append(req)
             self.admit_order.append(req)
             cls = req.priority_class
@@ -530,7 +531,8 @@ class Scheduler:    # guarded by: ServingEngine._mu
             # from zeros in a program dispatched after this one
             self.row_pool.give(req.row)
             req.row = None
-            monitor.incr("serving.state_rows_released")
+            for kind in self.row_pool.names:
+                monitor.incr(f"serving.{kind}_rows_released")
         if req in self.prefilling:
             self.prefilling.remove(req)
         if req in self.admit_order:
@@ -546,7 +548,9 @@ class Scheduler:    # guarded by: ServingEngine._mu
         if req in self.waiting:
             return
         if req.row is not None and req.n_prefilled:
-            monitor.incr("serving.state_replays")   # a state thrown away
+            # a state or a ring thrown away: whatever a request keeps
+            # by row is computed again from position 0
+            monitor.incr("serving.state_replays")
         self._release(req)
         req.n_prefilled = 0
         req.state = WAITING

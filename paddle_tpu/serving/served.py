@@ -26,14 +26,21 @@ over them through the block table; and rows by REQUEST, where a layer
 reads what its request kept at `view.rows` / `view.row` of
 `[rows + 1, ...]` arenas and writes it back there (a recurrent state: a
 chunk that starts at position 0 starts from zeros, whatever the row
-held; padding positions leave it alone). `stats` is None or a dict of
-float32 scalars the engine sums over the layers and fetches with the
-step's tokens (`serving.<name>` counters).
+held; padding positions leave it alone. A window layer's ring,
+`kv_cache.window_kind`: position p lives in ring row p % window, a step
+sees the rows whose position, worked out from the step's own, is >= 0
+and inside the window, so a chunk at position 0 starts from an empty
+ring whatever the row held and nothing is ever cleared; a chunk reads
+the ring before it writes its last real rows into it). `stats` is None
+or a dict of float32 scalars the engine sums over the layers and
+fetches with the step's tokens (`serving.<name>` counters).
 
 The implementers are `models.gpt.GPTForPretraining` (full K/V),
-`models.deepseek_v2.DeepseekV2ForCausalLM` (latent) and
+`models.deepseek_v2.DeepseekV2ForCausalLM` (latent),
 `models.granite_hybrid.GraniteHybridForCausalLM` (grouped-query K/V in
-its attention layers, request rows in its Mamba-2 layers).
+its attention layers, request rows in its Mamba-2 layers) and
+`models.exaone_moe.ExaoneMoeForCausalLM` (grouped-query K/V paged by
+token in its full layers, rings by request in its window layers).
 """
 import collections
 

@@ -33,7 +33,8 @@ def mosaic(monkeypatch):
     """The kernels decide between Mosaic and the interpreter by the
     default backend, which is the CPU here."""
     jitted = (pallas_decode.paged_decode_attention,
-              pallas_decode.flash_prefill_chunk)
+              pallas_decode.flash_prefill_chunk,
+              pallas_decode.window_prefill_chunk)
     monkeypatch.setattr(pallas_decode, "_interpret", lambda: False)
     # the entries are jitted: no trace made under the other answer may
     # be found again, by these tests or after them
@@ -291,3 +292,92 @@ def test_ssm_registry_example_compiles_for_v5e(one_chip, mosaic_ssm, name):
         for a in args]).lower(lowering_platforms=("tpu",)).compile() \
         .as_text()
     assert "tpu_custom_call" in text and name in text
+
+
+# -- k-exaone-236b-a23b.serve-mixed -------------------------------------
+
+@pytest.mark.parametrize("step", ["decode", "chunk", "ring_decode",
+                                  "ring_chunk", "ring_chunk_example"])
+def test_window_and_full_attention_compile_for_v5e(one_chip, mosaic, step):
+    """64 query heads over 8 K/V heads of 128: queries 8,192 lanes wide,
+    arenas 1,024. A full layer's tables hold 832 blocks of 16 (13,312
+    positions); a window layer's ring is one 128-row page a request, 65
+    of them; 64 slots, chunks of 512."""
+    N, Nk, H, W, bs, mb, nb = 64, 8, 128, 128, 16, 832, 16384
+    S, C, bf16, i32 = 64, 512, jnp.bfloat16, jnp.int32
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kw = dict(use_kernel=True, kv_heads=Nk, scale=H ** -0.5)
+    pages, rings = sds((nb, bs, Nk * H), bf16), sds((S + 1, W, Nk * H), bf16)
+    name = {"decode": "paged_decode", "chunk": "flash_prefill_chunk",
+            "ring_decode": "paged_decode_window"}.get(
+                step, "window_prefill_chunk")
+    if step == "decode":
+        assert pallas_decode.paged_decode_supported(bs, Nk * H, Nk, 2, mb,
+                                                    N // Nk)
+        assert pallas_decode.paged_decode_tile_rows(
+            bs, Nk * H, Nk, 2, mb, N // Nk) == 512
+        fn = lambda q, k, v, t, c: pallas_decode.paged_decode_attention(
+            q, k, v, t, c, N, **kw)
+        args = (sds((S, 1, N * H), bf16), pages, pages, sds((S, mb), i32),
+                sds((S,), i32))
+    elif step == "chunk":
+        assert pallas_decode.flash_prefill_supported(bs, C, Nk * H, Nk, 2,
+                                                     mb)
+        fn = lambda q, k, v, t, p0, n: pallas_decode.flash_prefill_chunk(
+            q, k, v, t, p0, N, n_real=n, **kw)
+        args = (sds((1, C, N * H), bf16), pages, pages, sds((mb,), i32),
+                sds((), i32), sds((), i32))
+    elif step == "ring_decode":
+        assert pallas_decode.paged_decode_tile_rows(
+            W, Nk * H, Nk, 2, 1, N // Nk) == W
+        fn = lambda q, k, v, rows, c: pallas_decode.paged_decode_attention(
+            q, k, v, rows[:, None], jnp.minimum(c, W - 1), N,
+            name="paged_decode_window", **kw)
+        args = (sds((S, 1, N * H), bf16), rings, rings, sds((S,), i32),
+                sds((S,), i32))
+    elif step == "ring_chunk":
+        assert pallas_decode.window_prefill_supported(C, W, H, 2)
+        fn = lambda q, k, v, rk, rv, row, p0, n: \
+            pallas_decode.window_prefill_chunk(q, k, v, rk, rv, row, p0, N,
+                                               n_real=n, **kw)
+        args = (sds((C, N * H), bf16), sds((C, Nk * H), bf16),
+                sds((C, Nk * H), bf16), rings, rings, sds((), i32),
+                sds((), i32), sds((), i32))
+    else:   # the registry's own example, float32, as chip_smoke.py runs it
+        import numpy as np
+        from paddle_tpu.ops.kernel_registry import registered_kernels
+        reg = next(r for r in registered_kernels() if r.name == name)
+        ex, exkw = reg.example(np.random.default_rng(0))
+        fn = lambda *xs: reg.fn(*xs, ex[7], **exkw)
+        args = tuple(sds(np.shape(a), np.asarray(a).dtype) for a in ex[:7])
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("tokens", [64, 512])
+def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens):
+    """The held experts' products at K-EXAONE's widths (6,144 x 2,048,
+    weight blocks of 3.1 MB; 4 of the 16 experts, to keep the
+    description small), for a decode batch and for a chunk."""
+    _, moe_serving = mosaic_mla
+    d, f, E, k = 6144, 2048, 4, 8
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, live, w, e, wg, wu, wd):
+        return moe_serving.held_expert_ffn(x, live, w, e, (0, E), wg, wu,
+                                           wd, use_kernel=True)[0]
+
+    text = jax.jit(fn).trace(
+        sds((tokens, d), bf16), sds((tokens,), jnp.bool_),
+        sds((tokens, k), jnp.float32), sds((tokens, k), jnp.int32),
+        sds((E, d, f), bf16), sds((E, d, f), bf16),
+        sds((E, f, d), bf16)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
