@@ -495,23 +495,39 @@ def _production_cases(sz):
 
     # the grouped expert products at the cell's widths, 8 held experts:
     # a decode batch's tokens and a chunk's, routed unevenly (one expert
-    # idle, one with several tiles), padding tokens routed nowhere
+    # idle), padding tokens routed nowhere, at the tiles the cell runs
+    # (the router scores 160); then the chunk under a skewed load: one
+    # expert with 200 of its rows, more than the largest tile
     d_e, f_e, E = (128, 128, 4) if TINY else (5120, 1536, 8)
     wg, wu = rand((E, d_e, f_e), scale=0.02), rand((E, d_e, f_e), scale=0.02)
     wd = rand((E, f_e, d_e), scale=0.02)
-    for tokens in (32, 64 if TINY else 512):
+
+    def skewed(ex, busy, rows):
+        """`rows` of the tokens choose expert `busy` (once each)."""
+        take = rs.permutation(ex.shape[0])[:rows]
+        ex[ex == busy] = -1
+        ex[take, 0] = busy
+        return ex
+
+    C_e = 64 if TINY else 512
+    for tokens, skew in ((32, 0), (C_e, 0), (C_e, 40 if TINY else 200)):
         xt = rand((tokens, d_e), scale=1.0)
         ex = rs.randint(-2, E + 3, (tokens, 6)).astype(np.int32)
         ex[ex == 1] = 3                             # expert 1 idle
+        if skew:
+            ex = skewed(ex, 2, skew)
         wts = jnp.asarray(rs.rand(tokens, 6), jnp.float32)
         live = jnp.arange(tokens) < tokens - 3
         cases.append((
             ("moe_grouped_ffn",),
-            f"moe_grouped_ffn {tokens} tokens d={d_e} f={f_e} E={E}",
+            f"moe_grouped_ffn {tokens} tokens d={d_e} f={f_e} E={E}"
+            + (f" one expert with {skew} rows" if skew else ""),
             lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
-                x, l, w, e, (0, E), a, b, c, use_kernel=True)[0],
+                x, l, w, e, (0, E), a, b, c, use_kernel=True,
+                n_experts=160)[0],
             lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
-                x, l, w, e, (0, E), a, b, c, use_kernel=False)[0],
+                x, l, w, e, (0, E), a, b, c, use_kernel=False,
+                n_experts=160)[0],
             (xt, live, wts, jnp.asarray(ex), wg, wu, wd)))
 
     # the Granite-4.0-H cell's shapes: grouped-query heads (32 over 8 of
@@ -589,7 +605,8 @@ def _production_cases(sz):
     # middle of a window, deep in a request; one real position, a part,
     # all) and decode over 64 rings as one 128-row page a request, under
     # its own name. A full layer: both paged kernels with contexts to
-    # 13k. The expert products at 6,144 x 2,048 over 16 held experts
+    # 13k. The expert products at 6,144 x 2,048 over 16 held experts,
+    # the chunk also under the skewed load
     def exaone_cases():
         # a scope of its own: the lambdas of the blocks above read
         # their N, H, gq ... when they are called
@@ -667,19 +684,25 @@ def _production_cases(sz):
         wg, wu = rand((E_x, d_x, f_x), scale=0.02), \
             rand((E_x, d_x, f_x), scale=0.02)
         wd = rand((E_x, f_x, d_x), scale=0.02)
-        for tokens in (64, 128 if TINY else 512):
+        C_x = 128 if TINY else 512
+        for tokens, skew in ((64, 0), (C_x, 0), (C_x, 100 if TINY else 200)):
             xt = rand((tokens, d_x), scale=1.0)
             ex = rs.randint(-4, E_x + 5, (tokens, 8)).astype(np.int32)
             ex[ex == 1] = 3                             # expert 1 idle
+            if skew:
+                ex = skewed(ex, 2, skew)
             wts = jnp.asarray(rs.rand(tokens, 8), jnp.float32)
             live = jnp.arange(tokens) < tokens - 3
             out.append((
                 ("moe_grouped_ffn",),
-                f"moe_grouped_ffn {tokens} tokens d={d_x} f={f_x} E={E_x}",
+                f"moe_grouped_ffn {tokens} tokens d={d_x} f={f_x} E={E_x}"
+                + (f" one expert with {skew} rows" if skew else ""),
                 lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
-                    x, l, w, e, (0, E_x), a, b, c, use_kernel=True)[0],
+                    x, l, w, e, (0, E_x), a, b, c, use_kernel=True,
+                    n_experts=128)[0],
                 lambda x, l, w, e, a, b, c: moes.held_expert_ffn(
-                    x, l, w, e, (0, E_x), a, b, c, use_kernel=False)[0],
+                    x, l, w, e, (0, E_x), a, b, c, use_kernel=False,
+                    n_experts=128)[0],
                 (xt, live, wts, jnp.asarray(ex), wg, wu, wd)))
         return out
 

@@ -53,8 +53,9 @@ class HeldExperts(Weights):
     """The shared experts (one gated MLP of their summed width) plus
     this model's share of the routed experts, `self.c.held = (first,
     count)` of its config. A subclass sets `self.c`, makes the router's
-    parameters, then `build_experts`, and brings `route(x)` -> (weights
-    [T, k], experts [T, k])."""
+    parameters (`self.router` [d, E]: a column an expert it scores),
+    then `build_experts`, and brings `route(x)` -> (weights [T, k],
+    experts [T, k])."""
 
     def build_experts(self, d, f, n_shared):
         count = self.c.held[1]
@@ -73,7 +74,7 @@ class HeldExperts(Weights):
         routed, stats = held_expert_ffn(
             x, live, weights, experts, self.c.held, self.experts_gate._value,
             self.experts_up._value, self.experts_down._value,
-            use_kernel=use_kernel)
+            use_kernel=use_kernel, n_experts=self.router._value.shape[1])
         return self.shared.run(x) + routed, stats
 
 

@@ -25,11 +25,20 @@ here every token's chosen experts are computed, whatever the load.
 The expert products are grouped by expert: the (token, expert) pairs
 held here are ordered by expert, each expert's rows padded to whole
 tiles of `tm` rows, and `moe_grouped_ffn` walks the tiles with each
-tile's expert looked up through a scalar-prefetched map, so an
-expert's weights are read once for its tile and a dead tile reads
-nothing. At serving sizes (1-20 tokens an expert) the layer is bound
-by reading the held experts' weights. Off the TPU the same rows go
-through `jax.lax.ragged_dot`.
+tile's expert looked up through a scalar-prefetched map. A live tile
+is ONE READ of its expert's weights (the width axis is the inner one,
+so two tiles of one expert walk its matrices twice) and a dead tile
+fetches nothing, so `expert_tile_rows` sizes the tile to hold the rows
+of the busiest held expert, four times the mean, up to 128: a decode
+batch gives a held expert 1 (DeepSeek-V2: 32 slots x top 6 of 160) to
+4 (K-EXAONE: 64 x top 8 of 128) rows on average, a chunk of 512 tokens
+19 and 32, and the busiest 1.8 and 3.8 times that. A larger tile
+than that only pads: every held expert's rows end on a whole tile, and
+the padding is gathered and written like any row. Up to ~240 rows of
+bf16 the chip takes longer to read an expert's weights than to multiply
+by them (197e12 FLOP/s over 819e9 B/s), so the layer is bound by
+reading the held experts' weights, once each. Off the TPU the same
+rows go through `jax.lax.ragged_dot`.
 """
 import functools
 
@@ -38,13 +47,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.kernel_registry import register_kernel
+from ..ops.kernel_registry import register_kernel, vmem_footprint
 
 __all__ = ["route_group_limited", "route_sigmoid_topk", "held_expert_ffn",
-           "moe_grouped_ffn", "group_by_expert"]
+           "moe_grouped_ffn", "group_by_expert", "expert_tile_rows"]
 
 _F_TILE = 256               # columns of an expert's width a grid step takes
 _VMEM_LIMIT = 40 * 2 ** 20  # three weight blocks in two buffers each
+# the most rows of a tile, and the rows of the busiest held expert over
+# the mean that a tile allows for: the cells' routers give their busiest
+# 1.8 (deepseek-v2.serve-docs) and 3.8 times (k-exaone-236b-a23b.serve-
+# mixed) the mean (see expert_tile_rows)
+_TILE_ROWS = 128
+_LOAD = 4
 
 
 def _interpret():
@@ -116,8 +131,8 @@ def group_by_expert(local, held, n_experts, tm, n_tiles):
         tile_live.astype(jnp.int32), counts
 
 
-def _ffn_kernel(te_ref, tl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-                acc_ref, *, nj):
+def _ffn_kernel(te_ref, tl_ref, tr_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                o_ref, acc_ref, *, nj):
     """Grid (row tile i, width tile j): the tile's rows through columns
     j of its expert's gate and up matrices and rows j of its down
     matrix, summed over j in float32."""
@@ -193,26 +208,30 @@ def moe_grouped_ffn(xs, wg, wu, wd, tile_expert, tile_live, group_sizes,
         # leaves there differs by platform
         grouped = jnp.arange(M) < jnp.sum(group_sizes)
         return jnp.where(grouped[:, None], out, 0)
-    tf = next((t for t in (_F_TILE, 128) if f % t == 0), f)
+    tf = _width_tile(f)
     nj = f // tf
+    # a dead tile keeps the blocks the last live tile ended on, its rows
+    # (`tile_row`: the last live tile at or before each tile) and its
+    # expert's last columns, so it fetches nothing; it writes zeros
+    tile_row = jax.lax.cummax(jnp.where(
+        tile_live != 0, jnp.arange(M // tm, dtype=jnp.int32), 0))
 
     def col(i, j, tl):
-        # a dead tile keeps the block the last live tile ended on
         return jnp.where(tl[i] != 0, j, nj - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(M // tm, nj),
         in_specs=[
-            pl.BlockSpec((tm, d), lambda i, j, te, tl: (i, 0)),
+            pl.BlockSpec((tm, d), lambda i, j, te, tl, tr: (tr[i], 0)),
             pl.BlockSpec((1, d, tf),
-                         lambda i, j, te, tl: (te[i], 0, col(i, j, tl))),
+                         lambda i, j, te, tl, tr: (te[i], 0, col(i, j, tl))),
             pl.BlockSpec((1, d, tf),
-                         lambda i, j, te, tl: (te[i], 0, col(i, j, tl))),
+                         lambda i, j, te, tl, tr: (te[i], 0, col(i, j, tl))),
             pl.BlockSpec((1, tf, d),
-                         lambda i, j, te, tl: (te[i], col(i, j, tl), 0)),
+                         lambda i, j, te, tl, tr: (te[i], col(i, j, tl), 0)),
         ],
-        out_specs=pl.BlockSpec((tm, d), lambda i, j, te, tl: (i, 0)),
+        out_specs=pl.BlockSpec((tm, d), lambda i, j, te, tl, tr: (i, 0)),
         scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
     )
     return pl.pallas_call(
@@ -224,33 +243,79 @@ def moe_grouped_ffn(xs, wg, wu, wd, tile_expert, tile_live, group_sizes,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-    )(tile_expert, tile_live, xs, wg, wu, wd)
+    )(tile_expert, tile_live, tile_row, xs, wg, wu, wd)
 
 
-def _tile_rows(tokens):
-    # a decode batch gives an expert a token or two: the smallest tile
-    # a packed bf16 row block allows; a chunk gives it some twenty
-    return 16 if tokens <= 64 else 32
+def _width_tile(f):
+    return next((t for t in (_F_TILE, 128) if f % t == 0), f)
+
+
+def _ffn_footprint(tm, d, tf, itemsize):
+    """VMEM of `moe_grouped_ffn` at tiles of `tm` rows: the three weight
+    blocks, the rows and the output block in two buffers each, the
+    float32 accumulator, and the [tm, tf] float32 gate, up and hidden
+    products as temps."""
+    return vmem_footprint(
+        moving=[((d, tf), itemsize)] * 3 + [((tm, d), itemsize)] * 2,
+        scratch=[((tm, d), 4)],
+        temp_bytes=3 * tm * tf * 4)
+
+
+def expert_tile_rows(tokens, k, n_experts, d, f, itemsize,
+                     vmem_limit=_VMEM_LIMIT):
+    """Rows of one tile of `moe_grouped_ffn`: the tile policy, a pure
+    function of what the arguments' shapes show. `tokens` of the
+    program each choose `k` of the `n_experts` the router scores.
+
+    A tile is one read of its expert's weights, so it should hold all
+    the rows the busiest held expert gets, and no more: every held
+    expert's rows are padded to whole tiles. It is a whole number of
+    packed bf16 row blocks (16 rows), and among those the smallest that
+    holds `_LOAD` times the mean rows an expert (`tokens * k /
+    n_experts`), unless that (a) is more rows than one expert can get
+    in the program (a token chooses an expert once: `tokens`, rounded
+    up to one unit), (b) has more than `_TILE_ROWS` rows: on the v5e a
+    tile's products take as long as its weights' read at ~240 rows of
+    bf16, and 128 is the largest power of two that leaves the kernel
+    bound by the read, or (c) does not fit `vmem_limit` with every
+    moving block in two buffers."""
+    unit = 16
+
+    def whole(rows):
+        return -(-rows // unit) * unit
+
+    rows = min(whole(-(-_LOAD * tokens * k // n_experts)), whole(tokens),
+               _TILE_ROWS)
+    tf = _width_tile(f)
+    while rows > unit and _ffn_footprint(rows, d, tf, itemsize) > vmem_limit:
+        rows -= unit
+    return rows
 
 
 def held_expert_ffn(x, live, weights, experts, held, wg, wu, wd,
-                    use_kernel=None):
+                    use_kernel=None, n_experts=None):
     """The routed experts' weighted sum over the experts held here.
 
     x [T, d]; live [T] bool (padding tokens are routed nowhere);
     weights/experts [T, k] from the router; held=(first, count): wg, wu
-    [count, d, f] and wd [count, f, d] are experts first..first+count-1.
+    [count, d, f] and wd [count, f, d] are experts first..first+count-1;
+    `n_experts` the experts the router chose among (the held ones where
+    nobody says: it sizes the tiles, `expert_tile_rows`, and no result).
     Returns (y [T, d], stats): y the sum over a token's chosen experts
     that are held here, stats the step's counts as float32 scalars —
     `moe_tokens_routed` (live tokens), `moe_pairs_held` (token-expert
     pairs computed here), `moe_pairs_chosen` (k a live token),
     `moe_load_max` and `moe_load_mean` (the busiest held expert's rows
     and the mean over the held experts), `moe_experts_reached` (held
-    experts with at least one row: those whose weights the step reads)."""
+    experts with at least one row: those whose weights the step reads)
+    and `moe_weight_reads` (live tiles, each of which reads its expert's
+    weights: equal to the experts reached where every expert's rows fit
+    one tile)."""
     T, d = x.shape
     k = experts.shape[1]
     first, count = held
-    tm = _tile_rows(T)
+    tm = expert_tile_rows(T, k, n_experts or count, d, wg.shape[2],
+                          x.dtype.itemsize)
     n_tiles = -(-T * k // tm) + count
     local = (experts - first).reshape(T * k)
     here = jnp.logical_and(
@@ -273,5 +338,6 @@ def held_expert_ffn(x, live, weights, experts, held, wg, wu, wd,
              "moe_pairs_held": pairs,
              "moe_load_max": counts.max().astype(jnp.float32),
              "moe_load_mean": pairs / count,
-             "moe_experts_reached": (counts > 0).sum().astype(jnp.float32)}
+             "moe_experts_reached": (counts > 0).sum().astype(jnp.float32),
+             "moe_weight_reads": tile_live.sum().astype(jnp.float32)}
     return y, stats

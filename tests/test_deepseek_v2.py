@@ -196,6 +196,22 @@ def test_prefill_positions_real_and_padded_add_up():
         == grew("prefill_chunks") * eng.cfg.prefill_chunk
 
 
+def test_weight_reads_arrive_with_the_steps_counts():
+    """`moe_weight_reads` rides the packed stats array to
+    `serving.moe_weight_reads`; no program here has more tokens than a
+    tile has rows, so every reached expert is read once."""
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(1, TINY["vocab_size"], n) for n in (40, 17)]
+    eng = engine(enable_prefix_cache=False)
+    before = eng.metrics_snapshot()
+    _streams(eng, prompts, n_new=3)
+    after = eng.metrics_snapshot()
+    reads, reached = (after.get("serving." + n, 0) - before.get(
+        "serving." + n, 0) for n in ("moe_weight_reads",
+                                     "moe_experts_reached"))
+    assert reads == reached > 0
+
+
 def _streams(eng, prompts, n_new=6):
     hs = [eng.submit(p.astype(np.int32), SamplingParams(max_new_tokens=n_new))
           for p in prompts]

@@ -150,21 +150,26 @@ def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
             else "mla_prefill_chunk") in text
 
 
-@pytest.mark.parametrize("tokens", [32, 512])
-def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens):
+@pytest.mark.parametrize("tokens,rows", [(32, 16), (512, 80)])
+def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
+                                             rows):
     """The held experts' products at DeepSeek-V2's widths (8 of the 40
-    experts, to keep the description small), for a decode batch and for
-    a chunk."""
+    experts held of the router's 160, to keep the description small),
+    for a decode batch and for a chunk, at the tile `expert_tile_rows`
+    picks for each: Mosaic holds the kernel to its 40 MB VMEM limit
+    here, not first on the chip."""
     _, moe_serving = mosaic_mla
     d, f, E = 5120, 1536, 8
     bf16 = jnp.bfloat16
+    assert moe_serving.expert_tile_rows(tokens, 6, 160, d, f, 2) == rows
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     def fn(x, live, w, e, wg, wu, wd):
         return moe_serving.held_expert_ffn(x, live, w, e, (0, E), wg, wu,
-                                           wd, use_kernel=True)[0]
+                                           wd, use_kernel=True,
+                                           n_experts=160)[0]
 
     text = jax.jit(fn).trace(
         sds((tokens, d), bf16), sds((tokens,), jnp.bool_),
@@ -358,21 +363,26 @@ def test_window_and_full_attention_compile_for_v5e(one_chip, mosaic, step):
     assert "tpu_custom_call" in text and name in text
 
 
-@pytest.mark.parametrize("tokens", [64, 512])
-def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens):
+@pytest.mark.parametrize("tokens,rows", [(64, 16), (512, 128)])
+def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
+                                            rows):
     """The held experts' products at K-EXAONE's widths (6,144 x 2,048,
-    weight blocks of 3.1 MB; 4 of the 16 experts, to keep the
-    description small), for a decode batch and for a chunk."""
+    weight blocks of 3.1 MB; 4 of the 16 experts held of the router's
+    128, to keep the description small), for a decode batch and for a
+    chunk, at the tile `expert_tile_rows` picks for each (28.7 MB of
+    the 40 MB VMEM limit at 128 rows)."""
     _, moe_serving = mosaic_mla
     d, f, E, k = 6144, 2048, 4, 8
     bf16 = jnp.bfloat16
+    assert moe_serving.expert_tile_rows(tokens, k, 128, d, f, 2) == rows
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     def fn(x, live, w, e, wg, wu, wd):
         return moe_serving.held_expert_ffn(x, live, w, e, (0, E), wg, wu,
-                                           wd, use_kernel=True)[0]
+                                           wd, use_kernel=True,
+                                           n_experts=128)[0]
 
     text = jax.jit(fn).trace(
         sds((tokens, d), bf16), sds((tokens,), jnp.bool_),
