@@ -9,6 +9,17 @@ The directory is part of the cache key, so it must not move between
 runs: either the operator places it with `JAX_COMPILATION_CACHE_DIR`
 (JAX reads that itself; nothing is set here), or it is
 `<checkout>/.jax_cache`, derived from this file's location.
+
+What the key leaves out: JAX strips an op's debug info, its name stack
+among it, from the key (`jax._src.cache_key`, unless
+`jax_compilation_cache_include_metadata_in_key`), and `telemetry.scope`
+IS a name in that stack. A cache written by a tree with other scopes,
+or none, serves executables whose ops carry that tree's names, and a
+device trace then reads the old layers (`benchmark/readers/
+device_scope.py` reads None for every layer after a scope-less tree).
+Two checkouts never share `<checkout>/.jax_cache`; an operator who
+places one directory for several trees with `JAX_COMPILATION_CACHE_DIR`
+clears it when the scopes change.
 """
 import os
 

@@ -20,6 +20,8 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.dtypes import float0
+# JAX's name stack (what `jax.named_scope` extends) has no public reader
+from jax._src import source_info_util as _names
 
 
 class _AutogradState(threading.local):
@@ -74,10 +76,17 @@ class Node:
     key, so cotangents for the pre- and post-mutation values route to the
     right producers (the reference tracks the same hazard with
     `TensorInplaceVersion`, `framework/tensor.h:77`).
+
+    A node also keeps the name stack its forward was recorded under
+    (`telemetry.scope`'s `pt.<layer>` among it): `jax.vjp` runs under
+    the forward's scope, but the pull-back is called later, outside it,
+    and its ops, two thirds of a train step's matmuls, would bear no
+    layer's name. `reopened` puts the walk back under that stack.
     """
 
     __slots__ = ("inputs", "outputs", "vjp_fn", "multi_output",
-                 "in_keys", "out_keys", "in_had_producer", "out_avals")
+                 "in_keys", "out_keys", "in_had_producer", "out_avals",
+                 "scope")
 
     def __init__(self, inputs, outputs, vjp_fn, multi_output):
         self.inputs = inputs          # tuple[Tensor]
@@ -92,6 +101,20 @@ class Node:
         # shape this node actually produced
         self.out_avals = tuple((o._value.shape, o._value.dtype)
                                for o in outputs)
+        self.scope = _names.current_name_stack()
+
+
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def reopened(node):
+    """The name stack `node` was recorded under, around its share of a
+    reverse walk: the pull-back (a `custom_vjp`'s backward rule is
+    traced by it) and the sums of its cotangents. Outside any scope,
+    as all of eager mode is unless the caller opened one, nothing is
+    entered."""
+    return _names.set_name_stack(node.scope) if node.scope.stack \
+        else _NO_SCOPE
 
 
 def record(node):
@@ -129,6 +152,23 @@ def clear_tape():
     _state.nodes.clear()
 
 
+def _cotangent(node, pending):
+    """The cotangent of `node`'s outputs as its pull-back takes it,
+    popped from `pending`: zeros for an output nobody consumed."""
+    cots = []
+    for (shape, dtype), k in zip(node.out_avals, node.out_keys):
+        c = pending.pop(k, None)
+        if c is None:
+            c = jnp.zeros(shape, dtype)
+        elif c.dtype != dtype:
+            # accumulation across mixed-dtype consumers promotes
+            # (bf16 + f32 -> f32); jax.vjp requires the cotangent in
+            # the output's own dtype
+            c = c.astype(dtype)
+        cots.append(c)
+    return tuple(cots) if node.multi_output else cots[0]
+
+
 def backward(tensor, grad=None, retain_graph=False):
     """Reverse-mode over the tape. Analog of BasicEngine::Execute
     (`imperative/basic_engine.cc:379`) + GradientAccumulator summation
@@ -164,32 +204,23 @@ def backward_multi(tensors, grads=None, retain_graph=False):
     for node in reversed(_state.nodes):
         if not any(k in pending for k in node.out_keys):
             continue
-        cots = []
-        for (shape, dtype), k in zip(node.out_avals, node.out_keys):
-            c = pending.pop(k, None)
-            if c is None:
-                c = jnp.zeros(shape, dtype)
-            elif c.dtype != dtype:
-                # accumulation across mixed-dtype consumers promotes
-                # (bf16 + f32 -> f32); jax.vjp requires the cotangent in
-                # the output's own dtype
-                c = c.astype(dtype)
-            cots.append(c)
-        cot = tuple(cots) if node.multi_output else cots[0]
-        in_grads = node.vjp_fn(cot)
-        for inp, key, had_producer, g in zip(
-                node.inputs, node.in_keys, node.in_had_producer, in_grads):
-            if inp.stop_gradient or g.dtype == float0:
-                continue
-            if had_producer:
-                prev = pending.get(key)
-                pending[key] = g if prev is None else prev + g
-                if inp._retain_grad:
+        with reopened(node):
+            in_grads = node.vjp_fn(_cotangent(node, pending))
+            for inp, key, had_producer, g in zip(
+                    node.inputs, node.in_keys, node.in_had_producer,
+                    in_grads):
+                if inp.stop_gradient or g.dtype == float0:
+                    continue
+                if had_producer:
+                    prev = pending.get(key)
+                    pending[key] = g if prev is None else prev + g
+                    if inp._retain_grad:
+                        inp._accumulate_grad(g)
+                else:
+                    # leaf: accumulate into .grad (paddle accumulates
+                    # across backward() calls until clear_grad,
+                    # varbase_patch_methods.py)
                     inp._accumulate_grad(g)
-            else:
-                # leaf: accumulate into .grad (paddle accumulates across
-                # backward() calls until clear_grad, varbase_patch_methods.py)
-                inp._accumulate_grad(g)
 
     if not retain_graph:
         clear_tape()
@@ -246,28 +277,19 @@ def grad(outputs, inputs, grad_outputs=None, retain_graph=False,
     for node in reversed(_state.nodes):
         if not any(k in pending for k in node.out_keys):
             continue
-        cots = []
-        for (shape, dtype), k in zip(node.out_avals, node.out_keys):
-            c = pending.pop(k, None)
-            if c is None:
-                c = jnp.zeros(shape, dtype)
-            elif c.dtype != dtype:
-                # mixed-dtype consumer accumulation promotes; jax.vjp
-                # requires the output's own dtype (same as backward())
-                c = c.astype(dtype)
-            cots.append(c)
-        cot = tuple(cots) if node.multi_output else cots[0]
-        in_grads = node.vjp_fn(cot)
-        for inp, key, had_producer, g in zip(
-                node.inputs, node.in_keys, node.in_had_producer, in_grads):
-            if inp.stop_gradient or g.dtype == float0:
-                continue
-            if id(inp) in blocked:
-                continue  # no_grad_vars: gradient does not flow through
-            if had_producer:
-                prev = pending.get(key)
-                pending[key] = g if prev is None else prev + g
-            _stash(id(inp), g)
+        with reopened(node):
+            in_grads = node.vjp_fn(_cotangent(node, pending))
+            for inp, key, had_producer, g in zip(
+                    node.inputs, node.in_keys, node.in_had_producer,
+                    in_grads):
+                if inp.stop_gradient or g.dtype == float0:
+                    continue
+                if id(inp) in blocked:
+                    continue  # no_grad_vars: gradient does not flow through
+                if had_producer:
+                    prev = pending.get(key)
+                    pending[key] = g if prev is None else prev + g
+                _stash(id(inp), g)
 
     if not retain_graph:
         clear_tape()

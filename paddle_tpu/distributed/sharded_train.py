@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.tensor import Tensor
 from ..core import autograd
+from ..core.scope import scope
 from ..core.random import rng_guard, default_generator
 from ..jit import bind_tensors
 from . import env
@@ -259,7 +260,7 @@ class ShardedTrainStep:
                               if grads else jnp.ones((0,), jnp.bool_))
                 # health taps see the raw (pre-clip) grads
                 raw_grads = grads if health_taps else None
-                with autograd.no_grad():
+                with autograd.no_grad(), scope("optimizer"):
                     if opt._grad_clip is not None:
                         pg = opt._grad_clip(
                             [(p, Tensor(g)) for p, g in zip(params, grads)])

@@ -24,6 +24,7 @@ from ..core.tensor import Tensor, Parameter
 from ..core import autograd
 from ..core.random import rng_guard, default_generator
 from ..core.dtype import convert_dtype
+from ..core.scope import scope
 
 
 class InputSpec:
@@ -314,7 +315,7 @@ class TrainStep:
                 # health taps judge the RAW grads (an explosion the clip
                 # would mask is exactly what the detector must see)
                 raw_grads = grads if health_taps else None
-                with autograd.no_grad():
+                with autograd.no_grad(), scope("optimizer"):
                     if opt._grad_clip is not None:
                         pg = opt._grad_clip(
                             [(p, Tensor(g)) for p, g in zip(params, grads)])

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from ..core.tensor import Parameter
 from ..moe.serving import held_expert_ffn
 from ..nn import Layer
+from ..core.scope import scope
 
 __all__ = ["GatedMLP", "HeldExperts", "ServedDecoder", "Weights",
            "default_make", "matmul"]
@@ -44,9 +45,10 @@ class GatedMLP(Weights):
         self.down = self.param("down", (width, d))
 
     def run(self, x):
-        g = matmul(x, self.gate._value)
-        return matmul(jax.nn.silu(g) * matmul(x, self.up._value),
-                      self.down._value)
+        with scope("mlp"):
+            g = matmul(x, self.gate._value)
+            return matmul(jax.nn.silu(g) * matmul(x, self.up._value),
+                          self.down._value)
 
 
 class HeldExperts(Weights):
@@ -70,12 +72,15 @@ class HeldExperts(Weights):
         routing counts)."""
         if live is None:
             live = jnp.ones((x.shape[0],), bool)
-        weights, experts = self.route(x)
-        routed, stats = held_expert_ffn(
-            x, live, weights, experts, self.c.held, self.experts_gate._value,
-            self.experts_up._value, self.experts_down._value,
-            use_kernel=use_kernel, n_experts=self.router._value.shape[1])
-        return self.shared.run(x) + routed, stats
+        with scope("experts"):
+            weights, experts = self.route(x)
+            routed, stats = held_expert_ffn(
+                x, live, weights, experts, self.c.held,
+                self.experts_gate._value, self.experts_up._value,
+                self.experts_down._value, use_kernel=use_kernel,
+                n_experts=self.router._value.shape[1])
+            # the shared experts open `mlp` inside: the innermost owns
+            return self.shared.run(x) + routed, stats
 
 
 class ServedDecoder:

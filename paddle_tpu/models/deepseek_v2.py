@@ -36,6 +36,7 @@ from ..moe.serving import route_group_limited
 from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
 from ..ops.pallas_mla import mla_paged_decode, mla_prefill_chunk
+from ..core.scope import scope
 from .blocks import (GatedMLP, HeldExperts, ServedDecoder, Weights,
                      default_make, matmul)
 from ..ops.rotary import (apply_rotary, rotary_cos_sin, yarn_inv_freq,
@@ -252,11 +253,13 @@ class DeepseekV2Block(Weights):
     def feed_forward(self, h, live=None, use_kernel=None):
         """h + FFN(norm2(h)) and the expert layer's counts (None for
         the dense layers)."""
-        y = rms_norm_values(h, self.ffn_norm._value, self.eps)
-        if hasattr(self, "moe"):
-            out, stats = self.moe.run(y, live, use_kernel)
-            return h + out, stats
-        return h + self.mlp.run(y), None
+        sparse = hasattr(self, "moe")
+        with scope("experts" if sparse else "mlp"):
+            y = rms_norm_values(h, self.ffn_norm._value, self.eps)
+            if sparse:
+                out, stats = self.moe.run(y, live, use_kernel)
+                return h + out, stats
+            return h + self.mlp.run(y), None
 
 
 class _ServedBlock:
@@ -271,9 +274,11 @@ class _ServedBlock:
 
     def _step(self, h, pages, view, positions, attend):
         block = self.block
-        q, row = block.attn.absorbed(block.norm1(h), positions)
-        lat = pages[0].at[view.blk, view.off].set(row.astype(pages[0].dtype))
-        h = h + block.attn.output(attend(q, lat).astype(h.dtype))
+        with scope("attn"):
+            q, row = block.attn.absorbed(block.norm1(h), positions)
+            lat = pages[0].at[view.blk, view.off].set(
+                row.astype(pages[0].dtype))
+            h = h + block.attn.output(attend(q, lat).astype(h.dtype))
         h, stats = block.feed_forward(h, view.live, view.use_kernel)
         return h, (lat, None), stats
 

@@ -56,6 +56,7 @@ from ..ops.pallas_decode import (flash_prefill_chunk,
                                  paged_decode_attention,
                                  window_prefill_chunk, window_ring_write)
 from ..ops.rotary import apply_rotary, rotary_cos_sin, yarn_inv_freq
+from ..core.scope import scope
 from .blocks import (GatedMLP, HeldExperts, ServedDecoder, Weights,
                      default_make, matmul)
 
@@ -268,14 +269,17 @@ class ExaoneMoeBlock(Weights):
         """The block with `attend(h)` for the attention, which returns
         its output and whatever else: (h, that, the expert layer's
         counts or None)."""
-        out, rest = attend(h)
-        h = h + rms_norm_values(out, self.attn_norm._value, self.eps)
-        if hasattr(self, "moe"):
-            y, stats = self.moe.run(h, live, use_kernel)
-        else:
-            y, stats = self.mlp.run(h), None
-        return h + rms_norm_values(y, self.ffn_norm._value, self.eps), \
-            rest, stats
+        with scope("attn"):
+            out, rest = attend(h)
+            h = h + rms_norm_values(out, self.attn_norm._value, self.eps)
+        sparse = hasattr(self, "moe")
+        with scope("experts" if sparse else "mlp"):
+            if sparse:
+                y, stats = self.moe.run(h, live, use_kernel)
+            else:
+                y, stats = self.mlp.run(h), None
+            return h + rms_norm_values(y, self.ffn_norm._value, self.eps), \
+                rest, stats
 
 
 class _ServedBlock:

@@ -27,6 +27,7 @@ from ..nn import functional as F
 from ..nn.initializer import Normal, Constant
 from ..tensor.manipulation import reshape, transpose
 from ..ops.attention import flash_attention
+from ..core.scope import scope
 
 
 class GPTConfig:
@@ -243,6 +244,10 @@ class GPTBlock(Layer):
     # swaps the dense MLP for the routed MoEFFN here instead of
     # re-stating the ln/attn/dropout plumbing
     mlp_cls = GPTMLP
+    # the layer the second half of the block is in a device trace
+    # (`telemetry.scope`); a norm and a residual add belong to the half
+    # they feed
+    ffn_scope = "mlp"
 
     def __init__(self, config):
         super().__init__()
@@ -254,13 +259,20 @@ class GPTBlock(Layer):
 
     def forward(self, x, cache=None, offset=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), cache=cache, offset=offset)
+            with scope("attn"):
+                a, new_cache = self.attn(self.ln1(x), cache=cache,
+                                         offset=offset)
+            return self.ffn_half(x, a), new_cache
+        with scope("attn"):
+            a = self.attn(self.ln1(x))
+        return self.ffn_half(x, a)
+
+    def ffn_half(self, x, a):
+        """The block from its attention's output `a` on: x + a, the
+        MLP of ln2 of that, and its residual."""
+        with scope(self.ffn_scope):
             y, h = self._add_ln2(x, self.dropout(a))
-            x = h + self.dropout(self.mlp(y))
-            return x, new_cache
-        y, h = self._add_ln2(x, self.dropout(self.attn(self.ln1(x))))
-        x = h + self.dropout(self.mlp(y))
-        return x
+            return h + self.dropout(self.mlp(y))
 
     def _add_ln2(self, x, delta):
         """The residual-add + ln2 site in one op: (ln2(x+delta), x+delta).
@@ -326,15 +338,16 @@ class GPTModel(Layer):
                     off)
             else:
                 position_ids = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
-        h = self.wte(input_ids) + self.wpe(position_ids)
-        h = self.drop(h)
+        with scope("embed"):
+            h = self.wte(input_ids) + self.wpe(position_ids)
+            h = self.drop(h)
         h = _shard_activation(h)
         if caches is not None:
             new_caches = []
             for block, cache in zip(self.blocks, caches):
                 h, nc = block(h, cache=cache, offset=offset)
                 new_caches.append(nc)
-            return self.ln_f(h), new_caches
+            return self.final_norm(h), new_caches
         if self.config.remat:
             # jax.checkpoint per block: the backward recomputes the
             # block from its stored input — O(L) activation memory
@@ -344,11 +357,15 @@ class GPTModel(Layer):
             for block in self.blocks:
                 h = recompute(block, h)
                 h = _shard_activation(h)
-            return self.ln_f(h)
+            return self.final_norm(h)
         for block in self.blocks:
             h = block(h)
             h = _shard_activation(h)
-        return self.ln_f(h)
+        return self.final_norm(h)
+
+    def final_norm(self, h):
+        with scope("head"):
+            return self.ln_f(h)
 
 
 def _shard_activation(h):
@@ -431,7 +448,8 @@ class GPTForPretraining(Layer):
                 out = out * ws.astype(jnp.float32)[None, None, :]
                 out = out[..., :V]
                 return out.astype(cdt) if amp_state().enabled else out
-            return apply(head_q, h, wte.wq, wte.w_scale)
+            with scope("head"):
+                return apply(head_q, h, wte.wq, wte.w_scale)
         w = wte.weight
         from ..amp import maybe_cast_to_compute as _amp
 
@@ -452,7 +470,8 @@ class GPTForPretraining(Layer):
             # accumulator output so a hand-bf16 model still gets f32 CE
             from ..amp import amp_state
             return out.astype(hh.dtype) if amp_state().enabled else out
-        return apply(head, h, w)
+        with scope("head"):
+            return apply(head, h, w)
 
     def served(self):
         """This model behind the serving engine's per-layer protocol."""
@@ -505,18 +524,24 @@ class GPTForPretraining(Layer):
                 return fused_linear_cross_entropy(
                     hh.reshape(-1, d), ww, flat_lbl)
 
-            losses = apply(fn, h, w)
-        else:
-            logits = self(input_ids)
+            # the head's product is inside the fused op: all `loss`
+            with scope("loss"):
+                return _reduce_loss(apply(fn, h, w), loss_mask)
+        logits = self(input_ids)
+        with scope("loss"):
             vocab = logits.shape[-1]
             flat_logits = reshape(logits, [-1, vocab])
             flat_labels = reshape(labels, [-1])
-            losses = F.cross_entropy(flat_logits, flat_labels,
-                                     reduction="none")
-        if loss_mask is not None:
-            m = reshape(loss_mask, [-1])
-            return (losses * m).sum() / m.sum()
-        return losses.mean()
+            return _reduce_loss(
+                F.cross_entropy(flat_logits, flat_labels, reduction="none"),
+                loss_mask)
+
+
+def _reduce_loss(losses, loss_mask):
+    if loss_mask is not None:
+        m = reshape(loss_mask, [-1])
+        return (losses * m).sum() / m.sum()
+    return losses.mean()
 
 
 class _ServedGPTBlock:
@@ -532,18 +557,17 @@ class _ServedGPTBlock:
 
     def _step(self, h, pages, view, attend):
         block, nh = self.block, self.hidden
-        y = block.ln1(h)
-        q, k, v = block.attn.project_qkv(y)
-        rows = view.blk.shape[0]
-        kp = pages[0].at[view.blk, view.off].set(
-            k._value.reshape(rows, nh).astype(pages[0].dtype))
-        vp = pages[1].at[view.blk, view.off].set(
-            v._value.reshape(rows, nh).astype(pages[1].dtype))
-        out = attend(q._value, kp, vp)
-        a = block.attn.out_proj(Tensor(out))
-        y2, h2 = block._add_ln2(h, block.dropout(a))
-        h = h2 + block.dropout(block.mlp(y2))
-        return h, (kp, vp), None
+        with scope("attn"):
+            y = block.ln1(h)
+            q, k, v = block.attn.project_qkv(y)
+            rows = view.blk.shape[0]
+            kp = pages[0].at[view.blk, view.off].set(
+                k._value.reshape(rows, nh).astype(pages[0].dtype))
+            vp = pages[1].at[view.blk, view.off].set(
+                v._value.reshape(rows, nh).astype(pages[1].dtype))
+            out = attend(q._value, kp, vp)
+            a = block.attn.out_proj(Tensor(out))
+        return block.ffn_half(h, a), (kp, vp), None
 
     def decode(self, h, pages, view):
         from ..ops.pallas_decode import paged_decode_attention

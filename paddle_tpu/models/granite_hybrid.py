@@ -45,6 +45,7 @@ from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
 from ..ops.pallas_decode import flash_prefill_chunk, paged_decode_attention
 from ..ops.pallas_ssm import mamba2_chunk_scan, mamba2_state_step
+from ..core.scope import scope
 from .blocks import GatedMLP, ServedDecoder, Weights, default_make, matmul
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM"]
@@ -109,6 +110,8 @@ class Attention(Weights):
     """Grouped-query attention over the paged K/V arenas, whose rows
     are `num_key_value_heads * head_dim` wide."""
 
+    layer = "attn"      # its half of a block in a device trace
+
     def __init__(self, make, prefix, c):
         super().__init__(make, prefix)
         d, H = c.hidden_size, c.head_dim
@@ -172,6 +175,8 @@ class Mamba2(Weights):
     """The Mamba-2 mixer over a request's row: the convolution's tail
     `[d_conv - 1, conv_dim]` in the model's dtype and the state
     `[d_state, d_inner]` in float32."""
+
+    layer = "ssm"       # its half of a block in a device trace
 
     def __init__(self, make, prefix, c):
         super().__init__(make, prefix)
@@ -315,12 +320,14 @@ class GraniteHybridBlock(Weights):
         """The block with `mix(x)` for the mixer: `mix` returns the
         mixer's output and whatever else, which is handed back."""
         c = self.c
-        out, rest = mix(rms_norm_values(h, self.norm1._value,
-                                        c.rms_norm_eps))
-        h = h + (c.residual_multiplier * out).astype(h.dtype)
-        y = self.mlp.run(rms_norm_values(h, self.norm2._value,
-                                         c.rms_norm_eps))
-        return h + (c.residual_multiplier * y).astype(h.dtype), rest
+        with scope(self.mixer.layer):
+            out, rest = mix(rms_norm_values(h, self.norm1._value,
+                                            c.rms_norm_eps))
+            h = h + (c.residual_multiplier * out).astype(h.dtype)
+        with scope("mlp"):
+            y = self.mlp.run(rms_norm_values(h, self.norm2._value,
+                                             c.rms_norm_eps))
+            return h + (c.residual_multiplier * y).astype(h.dtype), rest
 
 
 class _ServedBlock:

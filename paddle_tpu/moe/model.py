@@ -46,6 +46,7 @@ class GPTMoEBlock(GPTBlock):
     drift from the dense family."""
 
     mlp_cls = MoEFFN
+    ffn_scope = "experts"
 
 
 class GPTMoEModel(GPTModel):

@@ -74,6 +74,7 @@ from ..ops.pallas_decode import (flash_prefill_kv_rows,
 from ..resilience.retry import classify_failure
 from ..telemetry.mem_obs import (MemoryObservatory, is_oom,
                                  register_provider)
+from ..telemetry.recorder import scope as _scope
 from ..telemetry.recorder import span as _telemetry_span
 from ..telemetry.reqtrace import RequestTracer
 from .kv_cache import (NULL_BLOCK, NULL_ROW, BlockPool, PagedKVCache,
@@ -432,17 +433,20 @@ class ServingEngine:
             gate); only chip_smoke.py and the tests pass it, to hold
             the fused kernel against the gather+dense path on the same
             step."""
-            param_vals = _cast_params(param_vals, dtype)
+            with _scope("cast"):
+                param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
-                h = served.embed(tokens[:, None], ctx[:, None])
+                with _scope("embed"):
+                    h = served.embed(tokens[:, None], ctx[:, None])
                 blk = jnp.take_along_axis(
                     tables, (ctx // bs_blk)[:, None], axis=1)[:, 0]
                 view = DecodeView(blk, ctx % bs_blk, tables, ctx, ctx > 0,
                                   use_kernel, rows)
                 h, new_k, new_v, stats = run_layers(
                     h, k_pages, v_pages, "decode", view)
-                last = served.head(h)[:, -1]
+                with _scope("head"):
+                    last = served.head(h)[:, -1]
             return last, new_k, new_v, stats
 
         def decode_logits(*args, **kw):
@@ -453,10 +457,11 @@ class ServingEngine:
                       sampling=True):
             last, new_k, new_v, stats = decode_step(
                 param_vals, k_pages, v_pages, tokens, ctx, tables, rows)
-            rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
-                if sampling else keys
-            tok, logp = select(last, rngs, temp, top_k, top_p,
-                               greedy, sampling=sampling)
+            with _scope("sample"):
+                rngs = jax.vmap(jax.random.fold_in)(keys, counts) \
+                    if sampling else keys
+                tok, logp = select(last, rngs, temp, top_k, top_p,
+                                   greedy, sampling=sampling)
             return tok, logp, new_k, new_v, stats
 
         def prefill_step(param_vals, k_pages, v_pages, ids, p0, n_real,
@@ -467,11 +472,13 @@ class ServingEngine:
             null-block writes), positions p0..p0+C-1; row: the request's
             row, where the model keeps any. use_kernel as in
             decode_step."""
-            param_vals = _cast_params(param_vals, dtype)
+            with _scope("cast"):
+                param_vals = _cast_params(param_vals, dtype)
             with autograd.fresh_tape(), autograd.no_grad(), \
                     bind_tensors(bound, param_vals):
                 positions = p0 + jnp.arange(C, dtype=jnp.int32)
-                h = served.embed(ids, positions[None])
+                with _scope("embed"):
+                    h = served.embed(ids, positions[None])
                 tmask = jnp.arange(C, dtype=jnp.int32) < n_real
                 blk = jnp.where(
                     tmask,
@@ -481,7 +488,8 @@ class ServingEngine:
                                  n_real, positions, tmask, use_kernel, row)
                 h, new_k, new_v, stats = run_layers(
                     h, k_pages, v_pages, "prefill", view)
-                last = served.head(h, at=n_real - 1)[:, -1]
+                with _scope("head"):
+                    last = served.head(h, at=n_real - 1)[:, -1]
             return last, new_k, new_v, stats
 
         def prefill_logits(*args, **kw):
@@ -496,9 +504,10 @@ class ServingEngine:
             last, new_k, new_v, stats = prefill_step(
                 param_vals, k_pages, v_pages, ids, p0, n_real, table_row,
                 row)
-            rngs = jax.random.fold_in(key, count)[None]
-            tok, logp = select(last, rngs, temp[None], top_k[None],
-                               top_p[None], greedy[None])
+            with _scope("sample"):
+                rngs = jax.random.fold_in(key, count)[None]
+                tok, logp = select(last, rngs, temp[None], top_k[None],
+                                   top_p[None], greedy[None])
             return tok[0], logp[0], new_k, new_v, stats
 
         def fork_fn(k_pages, v_pages, src, dst):
@@ -532,20 +541,25 @@ class ServingEngine:
             # stands in for the output of a step when none is in flight
             self._no_tokens = jnp.zeros((self.cfg.max_slots,), jnp.int32)
         donate = (1, 2) if jax.default_backend() == "tpu" else ()
-        self._decode_jit = jax.jit(
-            functools.partial(decode_fn, sampling=True),
-            donate_argnums=donate)
-        self._decode_greedy_jit = jax.jit(
-            functools.partial(decode_fn, sampling=False),
-            donate_argnums=donate)
+        def decode_greedy_fn(*args):
+            return decode_fn(*args, sampling=False)
+
+        # named functions, not partials: a device trace's `XLA Modules`
+        # line reads jit_decode_fn / jit_decode_greedy_fn /
+        # jit_prefill_fn
+        self._decode_jit = jax.jit(decode_fn, donate_argnums=donate)
+        self._decode_greedy_jit = jax.jit(decode_greedy_fn,
+                                          donate_argnums=donate)
         self._prefill_jit = jax.jit(prefill_fn, donate_argnums=donate)
         self._fork_jit = jax.jit(
             fork_fn,
             donate_argnums=(0, 1) if jax.default_backend() == "tpu"
             else ())
         # one request's row of every request-row arena (`request_rows`)
-        self._rows_jit = jax.jit(
-            lambda arenas, row: [a[row] for a in arenas])
+        def rows_fn(arenas, row):
+            return [a[row] for a in arenas]
+
+        self._rows_jit = jax.jit(rows_fn)
 
     def _on_device(self):
         """Allocate and compile for the configured device, where one

@@ -16,6 +16,12 @@ step ledger: wall time, compile vs. execute split, tokens/sec, MFU,
 memory, per-collective time.
 
 Entry points:
+- span / scope — the two naming primitives: `span(name)` times the host
+  while the program runs (recorder, chrome trace, and the profiler's
+  own trace); `scope(name)` names the device work traced inside one
+  model layer (`jax.named_scope("pt." + name)`, `SCOPES` the whole
+  vocabulary; defined in core/scope.py) and costs a compiled program
+  nothing.
 - TelemetryRecorder — per-step JSONL records; context-activate it and
   `jit.TrainStep` / `distributed.ShardedTrainStep` record themselves.
 - StepTimer — explicit jax.stages AOT compile-cache wrapper.
@@ -58,8 +64,8 @@ from .metrics_http import MetricsServer  # noqa: F401
 from .mfu import (  # noqa: F401
     device_peak_flops, model_flops_per_token, train_step_flops)
 from .recorder import (  # noqa: F401
-    StepTimer, TelemetryRecorder, auto_step, current_recorder, open_spans,
-    span)
+    SCOPES, StepTimer, TelemetryRecorder, auto_step, current_recorder,
+    open_spans, scope, span)
 from .reqtrace import (  # noqa: F401
     RequestTrace, RequestTracer, decompose, dominant_cause,
     trace_chrome_spans)
@@ -71,7 +77,7 @@ from .sink import (  # noqa: F401
 from .watchdog import HangWatchdog, dump_black_box  # noqa: F401
 
 __all__ = [
-    "TelemetryRecorder", "StepTimer", "span", "auto_step",
+    "TelemetryRecorder", "StepTimer", "span", "scope", "SCOPES", "auto_step",
     "current_recorder", "open_spans", "JsonlSink", "read_jsonl",
     "make_step_record", "make_phase_record", "make_ckpt_record",
     "make_serving_record", "make_reqtrace_record",

@@ -37,6 +37,10 @@ import jax
 
 from .. import monitor
 from .. import profiler as _profiler
+# `telemetry.scope`, the device's counterpart of `span`: it lives in
+# core/ because the model packages, which may not import this one
+# (tests/test_layering.py), are where it is opened
+from ..core.scope import SCOPES, scope  # noqa: F401
 from . import mfu as _mfu
 from .sink import JsonlSink, make_step_record
 
