@@ -29,17 +29,66 @@ DEFAULT_BLOCK_K = None
 
 
 def _resolve_blocks(sq, block_q, block_k, for_bwd=False):
-    """Block policy, a function of the shape alone (measured on an
-    earlier revision (jax 0.4.37), not since): bk=1024 at every shape
-    (512..16384, D 64/128). The backward's whole-slice dq VMEM
-    accumulator caps bq at 512 beyond sq=8192 (the constraint is
-    governed by sq, not sk); the forward has no such working set and
+    """Block policy, a function of the shape alone: bk=1024 at every
+    shape (512..16384, D 64/128). Measured on the v5e under jax 0.9.0
+    (PR 37, kernels alone, bf16, diagonal tiles walked by
+    sub_block_rows; PERF.md section 6): at 144 x 2,048 x 64 tiles of
+    1,024 read 2.30 ms forward / 3.69 backward, and tiles of 512, under
+    a first form of the walk that read 2.66 / 4.04 at 1,024, 3.90 /
+    4.72 (10 tiles a head instead of 3: their grid steps cost more than
+    the masked scores they save); at D=128 tiles of 1,024 read 0.76 /
+    1.11 ms at 64 x 2,048 and 2.29 / 3.99 at 16 x 8,192. The backward's
+    whole-slice dq VMEM accumulator caps bq at 512 beyond sq=8192 (the
+    constraint is governed by sq, not sk; that cap dates from jax
+    0.4.37 and was not searched again: bq=512 under bk=1,024 reads 17.7
+    ms at 16 x 16,384 x 64); the forward has no such working set and
     keeps bq=1024 everywhere. Explicit block args override."""
     if block_k is None:
         block_k = 1024
     if block_q is None:
         block_q = 512 if (for_bwd and sq > 8192) else 1024
     return block_q, block_k
+
+
+def sub_block_rows(bq, h, itemsize):
+    """Rows `c` of a row sub-block of a DIAGONAL tile of the triangle
+    grids, a function of the shape alone: the tile's row sub-block i
+    meets only the (i+1)*c key columns at or left of the diagonal, so
+    the masked upper half of the tile is never formed. Tiles of 256
+    rows or fewer keep one masked body (c == bq); larger ones walk
+    sub-blocks of 128 rows — 8 at bq=1024, 36/64 of the tile's scores.
+    Measured on the v5e (jax 0.9.0, bf16, PR 37; PERF.md section 6) at
+    144 x 2,048 x 64 and at H=128 with S=2,048 and 8,192: 128 rows read
+    faster than 256 and 512 at both widths, forward and backward, with
+    the same compile time; H and the operand size did not change the
+    answer, so they are taken and not used."""
+    del h, itemsize
+    c = 128
+    if bq <= 256 or bq % c:
+        return bq
+    return c
+
+
+def _tri_score_elems(sq, bq, bk, c):
+    """Score elements a head's triangle grid computes over [sq, sq]
+    (bk % bq == 0): whole [bq, bk] tiles below the diagonal, and in
+    each of the sq/bq diagonal tiles the row sub-blocks of `c` rows
+    against their live columns only."""
+    nq, nk, r = sq // bq, sq // bk, bk // bq
+    tiles = nk * nq - r * nk * (nk - 1) // 2
+    diag = sum(c * (j * bq + (i + 1) * c)
+               for j in range(r) for i in range(bq // c))
+    return (tiles - nq) * bq * bk + nk * diag
+
+
+def causal_work_ratio(sq, bq, bk, c):
+    """Score elements the triangle-grid kernels compute over the
+    sq*sq/2 the causal mask needs: how far the diagonal tiles' walk
+    engages, from the tiling alone (at sq=2048, bq=bk=1024: 1.5 with
+    c=1024, whole diagonal tiles; 1.25 at c=512; 1.125 at 256; 1.0625
+    at 128). benchmark/work.py's flash_flops counts the needed half
+    whatever this reads, so flash_attn_roofline moves against it."""
+    return _tri_score_elems(sq, bq, bk, c) / (sq * sq / 2)
 
 
 _LANES = 128  # stats buffers padded to a full lane register
@@ -313,11 +362,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse[None, :], (_SUB, lse.shape[0]))
 
 
+def _mask_square(x, fill):
+    """Causal mask of a [c, c] square of scores (or probabilities) on
+    the diagonal: local iotas, no tile offsets to add."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(rows >= cols, x, fill)
+
+
+def _mask_crossing(x, fill):
+    """Causal mask of a [c, w] block whose LAST c columns are the square
+    the diagonal crosses: columns left of it are live and are passed
+    through without an iota or a select."""
+    c, w = x.shape
+    if w == c:
+        return _mask_square(x, fill)
+    return jnp.concatenate(
+        [x[:, :w - c], _mask_square(x[:, w - c:], fill)], axis=1)
+
+
+def _diagonal_walk(bq, c, off=0):
+    """Row sub-blocks of a diagonal tile of bq rows that start `off`
+    columns into its k tile: (rows, live) for each sub-block of c rows
+    — sub-block i meets the first off + (i+1)*c key columns, the last
+    c of which the diagonal crosses."""
+    return [(slice(r0, r0 + c), off + r0 + c) for r0 in range(0, bq, c)]
+
+
 def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                    acc_sc, m_sc, l_sc, *, bq, bk):
+                    acc_sc, m_sc, l_sc, *, bq, bk, c):
     """Triangle-grid causal forward (offset == 0, bq == bk): grid step t
     enumerates live tiles only; the diagonal tile (ki == qi) is the only
-    one needing the mask, and it is also the row's finalize step."""
+    one needing the mask, and it is also the row's finalize step. It
+    walks its row sub-blocks of c rows (sub_block_rows), each against
+    the key columns at or left of the diagonal alone."""
     t = pl.program_id(1)
     qi, ki = _tri_fwd_decode(t)
 
@@ -327,37 +405,60 @@ def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    def compute(masked):
-        q = q_ref[0]                               # [bq, H] input dtype
-        k = k_ref[0]                               # [bk, H]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk] f32
-        if masked:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + qi * bq
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ki * bk
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_prev = m_sc[:, :1]                       # [bq, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                     # [bq, bk] f32
-        l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0]                               # [bk, H]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bq, H]
-        acc_sc[:] = acc_sc[:] * alpha + pv
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+    def compute(subs):
+        """One online-softmax step of each (rows, blocks) of `subs`:
+        `rows` a static slice of the q tile, `blocks` the (key columns,
+        crossing) pieces its scores are formed in, a crossing piece
+        being a square on the diagonal. Written phase by phase ACROSS
+        the sub-blocks — every score product, then every maximum, every
+        exponential, every value product, the stores — because Mosaic
+        schedules near source order: sub-block by sub-block each short
+        product waits for the reduction before it, and a diagonal tile
+        walked so costs more than one computed whole (v5e, PR 37)."""
+        scores = []
+        for rows, blocks in subs:
+            q = q_ref[0, rows, :]                  # [c, H] input dtype
+            # bf16 inputs feed the MXU directly; accumulation stays f32
+            ss = [jax.lax.dot_general(
+                q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) for cols, _ in blocks]
+            scores.append([_mask_square(s_, _NEG_INF) if crossing else s_
+                           for s_, (_, crossing) in zip(ss, blocks)])
+        m_prev = [m_sc[rows, :1] for rows, _ in subs]          # [c, 1]
+        m_new = [jnp.maximum(mp, functools.reduce(jnp.maximum, [
+            jnp.max(s_, axis=-1, keepdims=True) for s_ in ss]))
+            for mp, ss in zip(m_prev, scores)]
+        alpha = [jnp.exp(mp - mn) for mp, mn in zip(m_prev, m_new)]
+        probs = [[jnp.exp(s_ - mn) for s_ in ss]               # f32
+                 for mn, ss in zip(m_new, scores)]
+        l_new = [a * l_sc[rows, :1] + sum(
+            jnp.sum(p, axis=-1, keepdims=True) for p in ps)
+            for a, (rows, _), ps in zip(alpha, subs, probs)]
+        pv = [sum(jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, cols, :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            for p, (cols, _) in zip(ps, blocks))               # [c, H]
+            for ps, (_, blocks) in zip(probs, subs)]
+        acc = [acc_sc[rows, :] * a + x
+               for (rows, _), a, x in zip(subs, alpha, pv)]
+        for (rows, _), acc_, mn, ln in zip(subs, acc, m_new, l_new):
+            acc_sc[rows, :] = acc_
+            m_sc[rows, :] = jnp.broadcast_to(mn, (mn.shape[0], _LANES))
+            l_sc[rows, :] = jnp.broadcast_to(ln, (ln.shape[0], _LANES))
 
     @pl.when(ki == qi)
     def _():
-        compute(True)
+        # a sub-block's live columns as the rectangle left of the
+        # diagonal and the square on it: no score right of it is formed
+        subs = []
+        for rows, live in _diagonal_walk(bq, c):
+            left = [(slice(0, live - c), False)] if live > c else []
+            subs.append((rows, left + [(rows, True)]))
+        compute(subs)
 
     @pl.when(ki < qi)
     def _():
-        compute(False)
+        compute([(slice(0, bq), [(slice(0, bk), False)])])
 
     @pl.when(ki == qi)
     def _finalize():
@@ -376,10 +477,12 @@ def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _flash_fwd_tri(qr, kr, vr, bq, bk, nq):
     bn, sq, h = qr.shape
     T = nq * (nq + 1) // 2
-    # exact live-tile fraction of the full nq x nq square: the cost
-    # estimate below quotes full-square costs scaled by this, so the
-    # scheduler sees the causal work the grid actually runs (~half),
-    # not the ~2x-overstated dense cost
+    c = sub_block_rows(bq, h, qr.dtype.itemsize)
+    # the cost estimate below quotes what the grid runs, not the ~2x-
+    # overstated dense square: the score elements the tiles compute
+    # (diagonal tiles their live sub-blocks only) and the blocks the
+    # live tiles fetch (frac of the full nq x nq square)
+    elems = bn * _tri_score_elems(sq, bq, bk, c)
     frac = (nq + 1) / (2 * nq)
 
     def qmap(bn_, t):
@@ -394,7 +497,7 @@ def _flash_fwd_tri(qr, kr, vr, bq, bk, nq):
     def lmap(bn_, t):
         return (bn_, 0, _tri_fwd_decode(t)[0])
 
-    kernel = functools.partial(_fwd_kernel_tri, bq=bq, bk=bk)
+    kernel = functools.partial(_fwd_kernel_tri, bq=bq, bk=bk, c=c)
     # SEQUENTIAL-GRID INVARIANT: the flat-index dimension (T) enumerates
     # live tiles in row-major order and the kernel's running softmax
     # state (acc/m/l scratch) carries across its steps; this dimension
@@ -427,12 +530,12 @@ def _flash_fwd_tri(qr, kr, vr, bq, bk, nq):
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            # full-square costs (4 flops/elem over sq x sq scores + pv,
-            # 1 exp/elem, dense q/k/v/o traffic) x the live-tile fraction
-            flops=int(4 * bn * sq * sq * h * frac),
+            # 4 flops/elem over the computed scores + pv, 1 exp/elem;
+            # dense q/k/v/o traffic x the live-tile fraction
+            flops=4 * elems * h,
             bytes_accessed=int((qr.size * 2 + kr.size + vr.size)
                                * qr.dtype.itemsize * frac),
-            transcendentals=int(bn * sq * sq * frac)),
+            transcendentals=elems),
         interpret=_interpret(),
     )(qr, kr, vr)
     return out, lse
@@ -681,11 +784,13 @@ def _bwd_merged_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_merged_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref,
                            delta_ref, dq_ref, dk_ref, dv_ref,
                            dk_sc, dv_sc, dq_sc,
-                           *, bq, bk, nq, r):
+                           *, bq, bk, nq, r, c):
     """Triangle-grid causal merged backward (offset == 0, bk % bq == 0):
     column-major over live tiles only. Same 5-dot body and whole-slice dq
     accumulator as _bwd_merged_kernel; the mask is applied only on the r
-    diagonal-crossing tiles per column (qj // r == ki)."""
+    diagonal-crossing tiles per column (qj // r == ki), which walk their
+    row sub-blocks of c rows (sub_block_rows), each against the key
+    columns at or left of the diagonal alone."""
     t = pl.program_id(1)
     ki, qj = _tri_bwd_decode(t, nq, r)
 
@@ -698,43 +803,61 @@ def _bwd_merged_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init_dq():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    def compute(masked):
-        q = q_ref[0]                               # [bq, H]
-        k = k_ref[0]                               # [bk, H]
-        v = v_ref[0]
-        do = do_ref[0]                             # [bq, H]
-        lse = lse_ref[0][0][:, None]               # [bq, 1]
-        delta = delta_ref[0][0][:, None]           # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        p = jnp.exp(s - lse)
+    def compute(subs, masked):
+        """The five products of each (rows, live) of `subs`: `rows` a
+        static slice of the q tile, against the k tile's first `live`
+        rows; `masked` says the last len(rows) of those cross the
+        diagonal. Phase by phase ACROSS the sub-blocks, as in
+        _fwd_kernel_tri.compute (sub-block by sub-block reads 8% slower
+        on the v5e), but one product a sub-block with the crossing
+        square cut out and put back: in the forward's two pieces the
+        backward read 2% slower. The phases stand in the order the
+        whole tile (one sub-block) reads fastest in: both score-shaped
+        products first read 0.7% slower there."""
+        q = [q_ref[0, rows, :] for rows, _ in subs]            # [c, H]
+        do = [do_ref[0, rows, :] for rows, _ in subs]          # [c, H]
+        p = []
+        for q_, (rows, live) in zip(q, subs):
+            lse = lse_ref[0, 0, rows][:, None]                 # [c, 1]
+            s = jax.lax.dot_general(
+                q_, k_ref[0, :live, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [c, live]
+            p.append(jnp.exp(s - lse))
         if masked:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + qj * bq
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ki * bk
-            p = jnp.where(rows >= cols, p, 0.0)
-        dv_sc[:] = dv_sc[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p = [_mask_crossing(p_, 0.0) for p_ in p]
+        dv = [jax.lax.dot_general(
+            p_.astype(do_.dtype), do_, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for p_, do_ in zip(p, do)]
+        ds = []
+        for p_, q_, do_, (rows, live) in zip(p, q, do, subs):
+            delta = delta_ref[0, 0, rows][:, None]             # [c, 1]
+            dp = jax.lax.dot_general(
+                do_, v_ref[0, :live, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [c, live]
+            ds.append((p_ * (dp - delta)).astype(q_.dtype))
+        dk = [jax.lax.dot_general(
+            ds_, q_, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for ds_, q_ in zip(ds, q)]
+        dq = [jax.lax.dot_general(
+            ds_, k_ref[0, :live, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bq, bk]
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_sc[:] = dk_sc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        rows_sl = pl.ds(qj * bq, bq)
-        dq_sc[rows_sl, :] = dq_sc[rows_sl, :] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            for ds_, (_, live) in zip(ds, subs)]
+        for (rows, live), dv_, dk_, dq_ in zip(subs, dv, dk, dq):
+            dv_sc[:live, :] = dv_sc[:live, :] + dv_
+            dk_sc[:live, :] = dk_sc[:live, :] + dk_
+            rows_sl = pl.ds(qj * bq + rows.start, rows.stop - rows.start)
+            dq_sc[rows_sl, :] = dq_sc[rows_sl, :] + dq_
 
-    @pl.when(qj // r == ki)
-    def _():
-        compute(True)
+    # a diagonal tile's q rows start j * bq columns into its k tile
+    # (j = qj - r * ki < r, static under its own branch)
+    for j in range(r):
+        @pl.when(qj == r * ki + j)
+        def _(j=j):
+            compute(_diagonal_walk(bq, c, j * bq), True)
 
     @pl.when(qj // r > ki)
     def _():
-        compute(False)
+        compute([(slice(0, bq), bk)], False)
 
     @pl.when(qj == nq - 1)
     def _finalize_kv():
@@ -761,9 +884,11 @@ def _flash_bwd_merged_tri(qr, kr, vr, gr, lse, delta, bq, bk, nq):
     r = bk // bq
     nk = sq // bk
     T = nk * nq - r * nk * (nk - 1) // 2
-    # exact live-tile fraction of the full nk x nq tile square (~(nq+1)/
-    # (2*nq) at r=1): scales the full-square cost estimate below so the
-    # scheduler no longer sees ~2x-overstated causal backward cost
+    c = sub_block_rows(bq, h, qr.dtype.itemsize)
+    # as in _flash_fwd_tri: the score elements the tiles compute, and
+    # the live-tile fraction of the full nk x nq tile square (~(nq+1)/
+    # (2*nq) at r=1) for the blocks they fetch
+    elems = bn * _tri_score_elems(sq, bq, bk, c)
     frac = T / (nk * nq)
 
     def qmap(bn_, t):
@@ -776,7 +901,7 @@ def _flash_bwd_merged_tri(qr, kr, vr, gr, lse, delta, bq, bk, nq):
         return (bn_, 0, _tri_bwd_decode(t, nq, r)[1])
 
     kernel = functools.partial(
-        _bwd_merged_kernel_tri, bq=bq, bk=bk, nq=nq, r=r)
+        _bwd_merged_kernel_tri, bq=bq, bk=bk, nq=nq, r=r, c=c)
     # SEQUENTIAL-GRID INVARIANT: the flat-index dimension (T) walks live
     # tiles column-major and the kernel relies on Mosaic's sequential
     # grid order twice — (a) dk/dv scratch accumulates down each column,
@@ -816,12 +941,12 @@ def _flash_bwd_merged_tri(qr, kr, vr, gr, lse, delta, bq, bk, nq):
             pltpu.VMEM((sq, h), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            # full-square costs (5 MXU dots/tile = 10 flops/elem, 1 exp/
-            # elem, q/do/lse/delta + k/v + dq/dk/dv traffic) x live frac
-            flops=int(10 * bn * sq * sq * h * frac),
+            # 5 MXU dots = 10 flops/elem over the computed scores, 1
+            # exp/elem; q/do/lse/delta + k/v + dq/dk/dv traffic x frac
+            flops=10 * elems * h,
             bytes_accessed=int((qr.size * 4 + kr.size * 4)
                                * qr.dtype.itemsize * frac),
-            transcendentals=int(bn * sq * sq * frac)),
+            transcendentals=elems),
         interpret=_interpret(),
     )(qr, kr, vr, gr, lse, delta)
     return dq, dk, dv

@@ -391,3 +391,45 @@ def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
         sds((E, f, d), bf16)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+
+
+@pytest.fixture
+def mosaic_flash(monkeypatch):
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    return pallas_attention
+
+
+@pytest.mark.parametrize("bn,S,H,bq,bk,dtype", [
+    (144, 2048, 64, 1024, 1024, jnp.bfloat16),   # gpt3-125m.train
+    (64, 2048, 128, 1024, 1024, jnp.bfloat16),   # GPT-3 1.3B's heads
+    (8, 2048, 64, 1024, 1024, jnp.float32),
+    (8, 16384, 64, 512, 1024, jnp.bfloat16),     # r = 2: beyond 8,192
+    (8, 16384, 128, 256, 1024, jnp.bfloat16),    # r = 4: the small-bq clamp
+])
+def test_causal_flash_tri_kernels_compile_for_v5e(one_chip, mosaic_flash,
+                                                  bn, S, H, bq, bk, dtype):
+    """The training kernels' triangle grids with the diagonal tiles
+    walked by row sub-blocks: static slices of the tile's refs at
+    multiples of sub_block_rows, the crossing square cut out of the
+    scores and put back, at the tiles _resolve_blocks and _vjp_bwd
+    choose for these lengths."""
+    pa = mosaic_flash
+    nq = S // bq
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, stat = sds((bn, S, H), dtype), sds((bn, 8, S), jnp.float32)
+    if bq == bk:
+        text = jax.jit(
+            lambda q, k, v: pa._flash_fwd_tri(q, k, v, bq, bk, nq)).trace(
+                x, x, x).lower(lowering_platforms=("tpu",)).compile(
+                ).as_text()
+        assert "tpu_custom_call" in text and "flash_fwd_tri" in text
+    text = jax.jit(
+        lambda q, k, v, g, l, d: pa._flash_bwd_merged_tri(
+            q, k, v, g, l, d, bq, bk, nq)).trace(
+                x, x, x, x, stat, stat).lower(
+                    lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and "flash_bwd_merged_tri" in text

@@ -216,6 +216,149 @@ def test_add_layer_norm_dispatcher_cpu_path():
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
 
+def _flat_case(seed, bn, s, h, dtype):
+    """q, k, v and an upstream gradient in the kernels' flat [BN, S, H]
+    layout, q pre-scaled as the callers hand it over."""
+    rng = np.random.default_rng(seed)
+    def mk(scale=0.3):
+        return jnp.asarray(scale * rng.standard_normal((bn, s, h)), dtype)
+    return mk(0.3 / math.sqrt(h) * 8), mk(), mk(), mk()
+
+
+def _gap(a, b):
+    return float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+
+
+# (S, bq, bk, H, dtype): tiles of 512 and 1,024 reach the sub-blocked
+# diagonal (c = 128: 4 and 8 row sub-blocks), a tile of 256 keeps the one
+# masked body; bk > bq (r = 2, 4) is the backward's shape beyond 8,192
+# positions, where a diagonal tile's rows start j * bq columns into its
+# k tile (ADVICE.md r5: no test reached r > 1 at these tiles)
+_TRI_CASES = [
+    (2048, 1024, 1024, 64, jnp.float32),
+    (2048, 1024, 1024, 64, jnp.bfloat16),      # gpt3-125m.train's tiles
+    (2048, 1024, 1024, 128, jnp.float32),
+    (2048, 1024, 1024, 128, jnp.bfloat16),     # GPT-3 1.3B's heads
+    (2048, 512, 512, 64, jnp.float32),
+    (2048, 512, 512, 128, jnp.bfloat16),
+    (1024, 256, 256, 64, jnp.float32),         # the single masked body
+    (2048, 512, 1024, 64, jnp.float32),        # r = 2
+    (2048, 512, 1024, 128, jnp.bfloat16),
+    (2048, 256, 1024, 64, jnp.float32),        # r = 4
+    (3072, 512, 1024, 64, jnp.float32),        # r = 2, three columns
+    (1152, 384, 384, 64, jnp.float32),         # three sub-blocks a tile
+]
+_TRI_PARAMS = [
+    pytest.param(s, bq, bk, h, dt,
+                 id=f"s{s}-bq{bq}-bk{bk}-h{h}-{jnp.dtype(dt).name}")
+    for s, bq, bk, h, dt in _TRI_CASES]
+
+
+# the forward's triangle grid takes square tiles only
+@pytest.mark.parametrize(
+    "s,bq,bk,h,dtype",
+    [p for p in _TRI_PARAMS if p.values[1] == p.values[2]])
+def test_tri_forward_matches_reference(s, bq, bk, h, dtype):
+    """(out, lse) of the triangle-grid forward against the naive
+    attention it tiles, diagonal tiles walked by sub-blocks."""
+    from paddle_tpu.ops import pallas_attention as pa
+    qr, kr, vr, _ = _flat_case(21, 2, s, h, dtype)
+    out, lse = pa._flash_fwd_tri(qr, kr, vr, bq, bk, s // bq)
+    ref_out, ref_lse = pa._ref_fwd_flat(qr, kr, vr, causal=True)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert out.dtype == qr.dtype and lse.shape == ref_lse.shape
+    assert _gap(out, ref_out) < tol
+    assert _gap(lse, ref_lse) < (2e-5 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("s,bq,bk,h,dtype", _TRI_PARAMS)
+def test_tri_backward_matches_reference(s, bq, bk, h, dtype):
+    """(dq, dk, dv) of the triangle-grid merged backward from the saved
+    lse / delta against the naive backward, r > 1 included."""
+    from paddle_tpu.ops import pallas_attention as pa
+    qr, kr, vr, gr = _flat_case(22, 2, s, h, dtype)
+    out, lse = pa._ref_fwd_flat(qr, kr, vr, causal=True)
+    delta = jnp.sum(gr.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    delta = jnp.broadcast_to(delta[:, None, :], (2, pa._SUB, s))
+    got = pa._flash_bwd_merged_tri(qr, kr, vr, gr, lse, delta,
+                                   bq, bk, s // bq)
+    ref = pa._ref_bwd_flat(qr, kr, vr, gr, lse, delta, causal=True)
+    for name, a, b_ in zip(("dq", "dk", "dv"), got, ref):
+        scale = float(jnp.abs(b_.astype(jnp.float32)).max())
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        assert _gap(a, b_) < tol * max(scale, 1.0), (name, _gap(a, b_))
+
+
+@pytest.mark.parametrize("c", [256, 512])
+def test_tri_kernels_at_other_sub_block_sizes(monkeypatch, c):
+    """The walk is right at any sub-block size the policy may come to
+    choose, not only at the one it chooses today."""
+    from paddle_tpu.ops import pallas_attention as pa
+    monkeypatch.setattr(pa, "sub_block_rows", lambda bq, h, itemsize: c)
+    s, bq, h = 2048, 1024, 64
+    qr, kr, vr, gr = _flat_case(23, 1, s, h, jnp.float32)
+    out, lse = pa._flash_fwd_tri(qr, kr, vr, bq, bq, s // bq)
+    ref_out, ref_lse = pa._ref_fwd_flat(qr, kr, vr, causal=True)
+    assert _gap(out, ref_out) < 2e-5 and _gap(lse, ref_lse) < 2e-5
+    delta = jnp.sum(gr * ref_out, -1)
+    delta = jnp.broadcast_to(delta[:, None, :], (1, pa._SUB, s))
+    for bq_bwd in (1024, 512):            # r = 1 and r = 2
+        got = pa._flash_bwd_merged_tri(qr, kr, vr, gr, ref_lse, delta,
+                                       bq_bwd, 1024, s // bq_bwd)
+        ref = pa._ref_bwd_flat(qr, kr, vr, gr, ref_lse, delta, causal=True)
+        for name, a, b_ in zip(("dq", "dk", "dv"), got, ref):
+            assert _gap(a, b_) < 2e-5, (bq_bwd, name, _gap(a, b_))
+
+
+@pytest.mark.parametrize("sq,bq,bk,c,want", [
+    (2048, 1024, 1024, 1024, 1.5),      # whole diagonal tiles (before PR 37)
+    (2048, 1024, 1024, 512, 1.25),
+    (2048, 1024, 1024, 256, 1.125),
+    (2048, 1024, 1024, 128, 1.0625),    # gpt3-125m.train
+    (8192, 1024, 1024, 256, 1.03125),   # the waste shrinks with the length
+    (2048, 512, 512, 256, 1.125),
+    (2048, 128, 128, 128, 1.0625),
+    (2048, 512, 1024, 512, 1.25),       # r = 2: dead columns right of a
+    (2048, 512, 1024, 256, 1.125),      # diagonal tile are not formed
+])
+def test_causal_work_ratio(sq, bq, bk, c, want):
+    from paddle_tpu.ops.pallas_attention import causal_work_ratio
+    assert causal_work_ratio(sq, bq, bk, c) == want
+
+
+def test_causal_work_ratio_counts_what_the_kernels_compute():
+    """The ratio's numerator, by brute force over the tiles: a row
+    sub-block of a diagonal tile meets the columns up to its own last
+    row's, whole tiles below the diagonal all of theirs."""
+    from paddle_tpu.ops.pallas_attention import causal_work_ratio
+    for sq, bq, bk, c in [(2048, 1024, 1024, 256), (4096, 512, 1024, 256),
+                          (3072, 256, 1024, 256), (2048, 512, 512, 128)]:
+        elems = 0
+        for k0 in range(0, sq, bk):
+            for q0 in range(k0, sq, bq):
+                if q0 >= k0 + bk:
+                    elems += bq * bk
+                    continue
+                for r0 in range(q0, q0 + bq, c):
+                    elems += c * (r0 + c - k0)
+        assert causal_work_ratio(sq, bq, bk, c) == elems / (sq * sq / 2)
+
+
+@pytest.mark.parametrize("bq,h,itemsize,want", [
+    (1024, 64, 2, 128),      # gpt3-125m.train: 8 row sub-blocks
+    (1024, 128, 2, 128),     # GPT-3 1.3B's heads
+    (1024, 64, 4, 128),
+    (512, 64, 2, 128),       # the backward's tile beyond 8,192 positions
+    (256, 128, 2, 256),      # 256 rows or fewer: the single masked body
+    (128, 64, 4, 128),
+    (384, 64, 2, 128),       # an explicit tile: 3 sub-blocks
+])
+def test_sub_block_rows_is_a_table_of_the_shape(bq, h, itemsize, want):
+    from paddle_tpu.ops.pallas_attention import sub_block_rows
+    c = sub_block_rows(bq, h, itemsize)
+    assert c == want and bq % c == 0 and c % 128 == 0
+
+
 @pytest.mark.parametrize("sq,block_q,block_k,for_bwd,want", [
     (512, None, None, False, (1024, 1024)),
     (8192, None, None, True, (1024, 1024)),     # the backward's last full bq
