@@ -32,6 +32,8 @@ SCOPES = frozenset((
     "mlp",          # dense and gated MLPs, the shared experts
     "experts",      # router, gather, moe_grouped_ffn, scatter
     "ssm",          # a Mamba-2 layer: projections, convolution, scan
+    "linear",       # a delta-rule layer: projections, convolution, the
+                    # kernel call, the gated norm
     "head",         # the final norm and the vocabulary projection
     "loss",         # cross-entropy and its reduction
     "optimizer",    # clip, the AdamW update, the casts back

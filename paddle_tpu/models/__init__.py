@@ -20,3 +20,6 @@ from .granite_hybrid import (  # noqa: F401
 from .exaone_moe import (  # noqa: F401
     ExaoneMoeConfig, ExaoneMoeForCausalLM,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig, Qwen3NextForCausalLM,
+)

@@ -1,8 +1,9 @@
 """What the served decoder models share (`deepseek_v2`, `granite_hybrid`,
-`exaone_moe`): parameters handed over leaf by leaf, the matmul with a
-float32 sum, the gated MLP, the expert layer around a model's own router,
-and the head behind the serving engine's protocol. RMSNorm is
-`nn.functional.norm.rms_norm_values`.
+`exaone_moe`, `qwen3_next`): parameters handed over leaf by leaf, the
+matmul with a float32 sum, the gated MLP, the expert layer around a
+model's own router, and the head behind the serving engine's protocol.
+RMSNorm is `nn.functional.norm.rms_norm_values`; Qwen3-Next's two others
+are here.
 """
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,8 @@ from ..nn import Layer
 from ..core.scope import scope
 
 __all__ = ["GatedMLP", "HeldExperts", "ServedDecoder", "Weights",
-           "default_make", "matmul"]
+           "default_make", "gated_rms_norm", "matmul",
+           "zero_centred_rms_norm"]
 
 
 class Weights(Layer):
@@ -33,6 +35,26 @@ class Weights(Layer):
 def matmul(x, w):
     return jnp.dot(x, w.astype(x.dtype), preferred_element_type=jnp.float32) \
         .astype(x.dtype)
+
+
+def zero_centred_rms_norm(x, w, eps):
+    """RMSNorm whose gain is 1 + w (Qwen3-Next's: w starts at 0), all of
+    it in float32, the input's dtype out."""
+    f = x.astype(jnp.float32)
+    f = f * jax.lax.rsqrt(jnp.mean(f * f, axis=-1, keepdims=True) + eps)
+    return (f * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def gated_rms_norm(x, gate, w, eps):
+    """w * RMSNorm(x), THEN times silu(gate) (Qwen3-Next's gated norm;
+    Mamba-2's gates first and norms after): the norm's sums in float32
+    and its result in the input's dtype before the gain, the gate in
+    float32."""
+    f = x.astype(jnp.float32)
+    normed = (f * jax.lax.rsqrt(jnp.mean(f * f, axis=-1, keepdims=True)
+                                + eps)).astype(x.dtype) * w.astype(x.dtype)
+    return (normed.astype(jnp.float32)
+            * jax.nn.silu(gate.astype(jnp.float32))).astype(x.dtype)
 
 
 class GatedMLP(Weights):

@@ -1,6 +1,8 @@
 """Rotary position embeddings with YaRN scaling, on raw values: one
 rotary key shared by all heads (latent attention) or every head of
-grouped-query q and k (`cos[:, None]`, `sin[:, None]` over the heads).
+grouped-query q and k (`cos[:, None]`, `sin[:, None]` over the heads),
+over a head's whole width or as many of its first dimensions as the
+tables are wide.
 
 The tables are never stored: `rotary_cos_sin` computes cos and sin of
 the positions a step works on, in float32, from the inverse
@@ -70,7 +72,14 @@ def apply_rotary(x, cos, sin, interleaved=True):
     reference code does before its rotate-half; without it the input is
     taken as the two halves already (the plain rotate-half of the
     Llama/EXAONE code: dimension i turns with dimension i + dim/2).
-    Float32 arithmetic, the input's dtype out."""
+    Tables narrower than x, cos/sin [..., r], turn the first r
+    dimensions alone and pass the rest as they are (Qwen3-Next's partial
+    rotary). Float32 arithmetic, the input's dtype out."""
+    r = cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([
+            apply_rotary(x[..., :r], cos, sin, interleaved), x[..., r:]],
+            axis=-1)
     f = x.astype(jnp.float32)
     if interleaved:
         f = jnp.concatenate([f[..., 0::2], f[..., 1::2]], axis=-1)

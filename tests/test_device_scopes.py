@@ -97,6 +97,20 @@ def _exaone():
         held=(0, 4)))
 
 
+def _qwen3_next():
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextForCausalLM)
+    return Qwen3NextForCausalLM(Qwen3NextConfig(
+        vocab_size=96, hidden_size=32,
+        layer_types=("linear_attention", "full_attention"),
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        num_experts=8, num_experts_per_tok=2, max_seq_len=128,
+        dtype="float32", held=(0, 4)))
+
+
 # model: (builder, engine options, the scopes its programs should hold
 # beside embed / attn / mlp / head / sample)
 SERVED = {
@@ -105,6 +119,7 @@ SERVED = {
     "deepseek-v2": (_deepseek, {"dtype": None}, {"experts"}),
     "granite-hybrid": (_granite, {"dtype": None}, {"ssm"}),
     "exaone-moe": (_exaone, {"dtype": None}, {"experts"}),
+    "qwen3-next": (_qwen3_next, {"dtype": None}, {"experts", "linear"}),
 }
 PROGRAMS = ("decode", "decode_greedy", "prefill")
 EVERYWHERE = {"embed", "attn", "mlp", "head", "sample"}
@@ -315,7 +330,7 @@ def test_a_custom_vjps_backward_rule_is_traced_under_the_forwards_scope():
 
 def test_scope_is_a_named_scope_and_nothing_else():
     assert telemetry.scope is scope_mod.scope
-    assert len(telemetry.SCOPES) == 10
+    assert len(telemetry.SCOPES) == 11
     with telemetry.scope("mlp"):
         assert str(jax._src.source_info_util.current_name_stack()) == \
             "pt.mlp"

@@ -433,3 +433,128 @@ def test_causal_flash_tri_kernels_compile_for_v5e(one_chip, mosaic_flash,
                 x, x, x, x, stat, stat).lower(
                     lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text and "flash_bwd_merged_tri" in text
+
+
+# -- qwen3-next-80b-a3b.serve-longgen -------------------------------------
+
+@pytest.fixture
+def mosaic_gdn(monkeypatch):
+    from paddle_tpu.ops import pallas_gdn
+    jitted = (pallas_gdn.gdn_state_step, pallas_gdn.gdn_chunk)
+    monkeypatch.setattr(pallas_gdn, "_interpret", lambda: False)
+    for fn in jitted:
+        fn.clear_cache()
+    yield pallas_gdn
+    for fn in jitted:
+        fn.clear_cache()
+
+
+@pytest.mark.parametrize("kernel", ["gdn_state_step", "gdn_chunk"])
+def test_gdn_kernels_compile_for_v5e(one_chip, mosaic_gdn, kernel):
+    """32 value heads over 16 key heads of 128 x 128: 129 rows of
+    [32, 128, 128] float32 (2 MiB a row), 128 slots, all 32 heads a
+    grid step; a chunk of 512 in sub-chunks of 64."""
+    gdn = mosaic_gdn
+    S, H, Hk, K, V, C = 128, 32, 16, 128, 128, 512
+    f32, i32 = jnp.float32, jnp.int32
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    if kernel == "gdn_state_step":
+        assert gdn.state_step_heads(H, Hk, K, V) == 32
+        fn = lambda *a: gdn.gdn_state_step(*a, use_kernel=True)
+        args = (sds((S + 1, H, K, V), f32), sds((S,), i32),
+                sds((S,), jnp.bool_), sds((S, Hk, K), f32),
+                sds((S, Hk, K), f32), sds((S, H, V), f32), sds((S, H), f32),
+                sds((S, H), f32))
+    else:
+        assert gdn.chunk_supported(C, 64, K, V)
+        fn = lambda *a: gdn.gdn_chunk(*a, sub=64, use_kernel=True)
+        args = (sds((C, Hk, K), f32), sds((C, Hk, K), f32),
+                sds((C, H, V), f32), sds((C, H), f32), sds((C, H), f32),
+                sds((H, K, V), f32), sds((), i32))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text
+
+
+@pytest.mark.parametrize("name", ["gdn_state_step", "gdn_chunk"])
+def test_gdn_registry_example_compiles_for_v5e(one_chip, mosaic_gdn, name):
+    import numpy as np
+    from paddle_tpu.ops.kernel_registry import registered_kernels
+    reg = next(r for r in registered_kernels() if r.name == name)
+    args, kwargs = reg.example(np.random.default_rng(0))
+
+    def fn(*xs):
+        return reg.fn(*xs, **kwargs)
+
+    text = jax.jit(fn).trace(*[
+        jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                             sharding=one_chip)
+        for a in args]).lower(lowering_platforms=("tpu",)).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_gated_attention_heads_compile_for_v5e(one_chip, mosaic, step):
+    """16 query heads over 2 K/V heads of 256 (group 8): queries 4,096
+    lanes wide, arenas 512; tables of 1,280 blocks of 16 (20,480
+    positions), 128 slots, chunks of 512."""
+    N, Nk, H, bs, mb, nb = 16, 2, 256, 16, 1280, 32768
+    S, C, bf16, i32 = 128, 512, jnp.bfloat16, jnp.int32
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kw = dict(use_kernel=True, kv_heads=Nk, scale=H ** -0.5)
+    pages = sds((nb, bs, Nk * H), bf16)
+    if step == "decode":
+        assert pallas_decode.paged_decode_supported(bs, Nk * H, Nk, 2, mb,
+                                                    N // Nk)
+        fn = lambda q, k, v, t, c: pallas_decode.paged_decode_attention(
+            q, k, v, t, c, N, **kw)
+        args = (sds((S, 1, N * H), bf16), pages, pages, sds((S, mb), i32),
+                sds((S,), i32))
+    else:
+        assert pallas_decode.flash_prefill_supported(bs, C, Nk * H, Nk, 2,
+                                                     mb)
+        fn = lambda q, k, v, t, p0, n: pallas_decode.flash_prefill_chunk(
+            q, k, v, t, p0, N, n_real=n, **kw)
+        args = (sds((1, C, N * H), bf16), pages, pages, sds((mb,), i32),
+                sds((), i32), sds((), i32))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_decode" if step == "decode"
+            else "flash_prefill_chunk") in text
+
+
+@pytest.mark.parametrize("tokens,rows", [(128, 16), (512, 48)])
+def test_qwen3next_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
+                                               rows):
+    """The held experts' products at Qwen3-Next's widths (2,048 x 512:
+    two width tiles of 256; 8 of the 64 experts held of the router's
+    512, to keep the description small), for a decode batch and for a
+    chunk, at the tile `expert_tile_rows` picks for each."""
+    _, moe_serving = mosaic_mla
+    d, f, E, k = 2048, 512, 8, 10
+    bf16 = jnp.bfloat16
+    assert moe_serving.expert_tile_rows(tokens, k, 512, d, f, 2) == rows
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, live, w, e, wg, wu, wd):
+        return moe_serving.held_expert_ffn(x, live, w, e, (0, E), wg, wu,
+                                           wd, use_kernel=True,
+                                           n_experts=512)[0]
+
+    text = jax.jit(fn).trace(
+        sds((tokens, d), bf16), sds((tokens,), jnp.bool_),
+        sds((tokens, k), jnp.float32), sds((tokens, k), jnp.int32),
+        sds((E, d, f), bf16), sds((E, d, f), bf16),
+        sds((E, f, d), bf16)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
