@@ -55,9 +55,10 @@ Both paged kernels take grouped-query heads (`kv_heads`: the arenas are
 `kv_heads * head_dim` wide and `n_heads // kv_heads` query heads read
 each K/V head). The query heads are regrouped outside the kernel into
 `group` rows over the arenas' lanes, member i of every K/V head side by
-side; `paged_decode` puts the members on further sublanes of the same
-two products, `flash_prefill_chunk` gives each member grid steps of its
-own. With `kv_heads == n_heads` both are what they were.
+side; `paged_decode` packs every member of every K/V head into one
+block of sublanes of the same two products (`paged_decode_head_rows`),
+`flash_prefill_chunk` gives each member grid steps of its own. With
+`kv_heads == n_heads` both are what they were.
 
 Both paged kernels have a gather+dense fallback that reproduces the
 composed einsum math of models/gpt._cached_attention bit for bit, so
@@ -237,9 +238,14 @@ def _packed_rows(itemsize):
     return _SUB * max(1, 4 // itemsize)
 
 
-def _head_rows(n_heads):
-    # one row a head, padded to the 16 sublanes a packed bf16 tile has
-    return -(-n_heads // 16) * 16
+def paged_decode_head_rows(kv_heads, group=1):
+    """Sublane rows of the paged decode kernel's two products and of its
+    softmax: one row for each of a slot's `kv_heads * group` query
+    heads, member i of K/V head n in row i * kv_heads + n, the block
+    padded once to the 16 sublanes a packed bf16 tile has. A pure
+    function of the shapes: rows past `kv_heads * group` are the padding
+    every tile computes."""
+    return -(-(kv_heads * group) // 16) * 16
 
 
 def _paged_footprint(rows, hidden, n_heads, itemsize, group=1):
@@ -248,7 +254,7 @@ def _paged_footprint(rows, hidden, n_heads, itemsize, group=1):
     scratch, so charged once a buffer), q and the output block moving
     with the slot, the per-head accumulator, and the [heads, rows] f32
     logits/probabilities plus the [heads, hidden] product as temps."""
-    R = group * _head_rows(n_heads)
+    R = paged_decode_head_rows(n_heads, group)
     return vmem_footprint(
         moving=[((group, hidden), itemsize), ((group, hidden), 4)],
         scratch=[((2, rows, hidden), itemsize)] * 2
@@ -338,11 +344,13 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
 
     `n_heads` are the K/V heads. With `group` query heads to each, q
     arrives as [group, N*H], row i holding member i of every K/V head on
-    that head's lanes, and the sublanes hold `group` blocks of heads:
-    row i * R/group + n is member i of K/V head n."""
+    that head's lanes, and the members are packed into one block of
+    sublanes (`paged_decode_head_rows`): row i * n_heads + n is member i
+    of K/V head n, and the rows past the last member own no lane."""
     b = pl.program_id(0)
     R = acc_sc.shape[0]
-    Rk = R // group
+    # the rows a member takes: its K/V heads, or all R where it is alone
+    Rk = n_heads if group > 1 else R
     nh = n_heads * head_dim
     ctx = ctx_ref[b]
 
@@ -362,14 +370,19 @@ def _paged_kernel(tab_ref, ctx_ref, q_ref, k_hbm, v_hbm, out_ref,
     m_sc[...] = jnp.full_like(m_sc, -1e30)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc_sc[...] = jnp.zeros_like(acc_sc)
-    head = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 0) % Rk
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 0)
+    head = row % Rk
     lane = jax.lax.broadcasted_iota(jnp.int32, (R, nh), 1)
     own = jnp.logical_and(lane >= head * head_dim,
                           lane < (head + 1) * head_dim)
+    if group * Rk < R:
+        own = jnp.logical_and(own, row < group * Rk)
     q = q_ref[0].astype(jnp.float32)                      # [group, NH]
     if group > 1:
-        q = jnp.concatenate([jnp.broadcast_to(q[i:i + 1], (Rk, nh))
-                             for i in range(group)], axis=0)
+        member = row // Rk
+        qg, q = q, jnp.broadcast_to(q[:1], (R, nh))
+        for i in range(1, group):
+            q = jnp.where(member == i, qg[i:i + 1], q)
     qh = jnp.where(own, q, 0.0).astype(k_buf.dtype)       # [R, NH]
 
     def compute(t, buf):
@@ -421,10 +434,12 @@ def paged_decode_supported(block_size, hidden, n_heads, itemsize=2,
 
 def _paged_example(rng):
     """Randomized in-support paged config (kernel_lint KN504): distinct
-    physical blocks per row, tails at the null block 0."""
-    N, H = 4, 32
-    nh = N * H * (1 if rng.integers(2) else 2)  # nh 128 or 256
-    N = nh // H
+    physical blocks per row, tails at the null block 0; a K/V head read
+    by 1, 2, 4 or 8 query heads, whose rows the kernel packs."""
+    H = 32
+    nh = 128 * (1 if rng.integers(2) else 2)    # arenas 128 or 256 lanes
+    Nk = nh // H
+    N = Nk * int(rng.choice([1, 2, 4, 8]))
     bs = 16
     S = int(rng.choice([2, 3]))
     mb = int(rng.integers(2, 4))
@@ -435,10 +450,10 @@ def _paged_example(rng):
         n_alloc = int(ctx[s]) // bs + 1
         for i in range(n_alloc):
             tables[s, i] = 1 + s * mb + i
-    q = 0.1 * rng.standard_normal((S, 1, nh)).astype(np.float32)
+    q = 0.1 * rng.standard_normal((S, 1, N * H)).astype(np.float32)
     kp = 0.1 * rng.standard_normal((num_blocks, bs, nh)).astype(np.float32)
     vp = 0.1 * rng.standard_normal((num_blocks, bs, nh)).astype(np.float32)
-    return (q, kp, vp, tables, ctx, N), {"use_kernel": True}
+    return (q, kp, vp, tables, ctx, N), {"use_kernel": True, "kv_heads": Nk}
 
 
 def _paged_fallback(q, k_pages, v_pages, block_tables, ctx_lens,
@@ -552,7 +567,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         raise ValueError(
             f"paged_decode kernel: no tile of {bs}-row pages at width "
             f"{wk} fits VMEM (see paged_decode_supported)")
-    R = G * _head_rows(Nk)
+    R = paged_decode_head_rows(Nk, G)
     # [S, G, wk]: as it is where every query head has its own K/V head
     qg = _regroup(q, Nk, G)[:, :, 0] if G > 1 else q
     grid_spec = pltpu.PrefetchScalarGridSpec(

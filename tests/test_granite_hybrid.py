@@ -172,6 +172,52 @@ def _paged_case(rng, N, Nk, H, S=3, bs=16, mb=10):
     return q, kp, vp, tables, ctx
 
 
+def _idle_and_boundary_case(rng, N, Nk, H, bs=16, mb=40):
+    """Five slots: two idle (context 0 on the null block 0, as the
+    engine leaves a free slot), one whose context ends on the last row
+    of the kernel's first tile, one that begins the second, one drawn."""
+    rows = pd.paged_decode_tile_rows(bs, Nk * H, Nk, 4, mb, N // Nk)
+    assert 0 < rows < mb * bs
+    ctx = np.array([0, rows - 1, rows, rng.integers(1, mb * bs), 0],
+                   np.int32)
+    q, kp, vp, tables, _ = _paged_case(rng, N, Nk, H, S=5, bs=bs, mb=mb)
+    tables[:] = 0
+    for s in (1, 2, 3):
+        tables[s, :int(ctx[s]) // bs + 1] = 1 + s * mb + np.arange(
+            int(ctx[s]) // bs + 1)
+    return q, kp, vp, tables, ctx
+
+
+def test_paged_decode_head_rows_pack_the_members():
+    """One row a query head, the block padded once to 16 sublanes: as
+    many rows as before where a K/V head has one query head, and the
+    grouped cells' 128 / 128 / 64 rows become 16 / 64 / 32."""
+    # the GPT widths: one row a head, padded to 16, as before the packing
+    for kv_heads, rows in ((12, 16), (16, 16), (32, 32), (40, 48),
+                           (96, 96)):
+        assert pd.paged_decode_head_rows(kv_heads) == rows
+        assert pd.paged_decode_head_rows(kv_heads, 1) == rows
+    assert pd.paged_decode_head_rows(2, 8) == 16       # longgen
+    assert pd.paged_decode_head_rows(8, 8) == 64       # mixed
+    assert pd.paged_decode_head_rows(8, 4) == 32       # many
+    assert pd.paged_decode_head_rows(8, 3) == 32       # 24 rows + 8 pad
+    assert pd.paged_decode_head_rows(1, 4) == 16
+
+
+def test_paged_footprint_charges_the_packed_rows(monkeypatch):
+    rows, hidden, it = 512, 512, 2
+    packed = pd._paged_footprint(rows, hidden, 2, it, 8)
+    asked = []
+    monkeypatch.setattr(pd, "paged_decode_head_rows",
+                        lambda k, g: asked.append((k, g)) or 128)
+    padded = pd._paged_footprint(rows, hidden, 2, it, 8)
+    assert asked == [(2, 8)]
+    # each head row: its accumulator and two statistics, its logits,
+    # probabilities and mask, its spread q and its product
+    per_row = (hidden + 2 * pd._COLS) * 4 + (3 * rows + 2 * hidden) * 4
+    assert padded - packed == (128 - 16) * per_row
+
+
 def _dense_gqa(q, k, v, n_heads, kv_heads, scale, causal_from=None):
     """q [T, N*H] against k, v [L, Nk*H] by repeated K/V heads."""
     T, L = q.shape[0], k.shape[0]
@@ -189,10 +235,21 @@ def _dense_gqa(q, k, v, n_heads, kv_heads, scale, causal_from=None):
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
-@pytest.mark.parametrize("heads", [(8, 2, 64), (16, 4, 32), (4, 1, 128)])
+@pytest.mark.parametrize("heads", [
+    (8, 2, 64), (16, 4, 32), (4, 1, 128),
+    # the grouped cells' layouts (longgen, mixed, many) and one whose
+    # 24 query heads leave 8 padding rows in the packed block; each with
+    # idle slots and contexts that end on a tile's last row or begin one
+    (16, 2, 256, "idle"), (64, 8, 128, "idle"), (32, 8, 64, "idle"),
+    (24, 8, 32, "idle")])
 def test_paged_decode_with_fewer_kv_heads(heads, use_kernel):
-    N, Nk, H = heads
-    q, kp, vp, tables, ctx = _paged_case(np.random.default_rng(4), N, Nk, H)
+    N, Nk, H = heads[:3]
+    if len(heads) == 3:
+        q, kp, vp, tables, ctx = _paged_case(np.random.default_rng(4), N,
+                                             Nk, H)
+    else:
+        q, kp, vp, tables, ctx = _idle_and_boundary_case(
+            np.random.default_rng(7), N, Nk, H)
     got = np.asarray(pd.paged_decode_attention(
         q, kp, vp, tables, ctx, N, use_kernel=use_kernel, kv_heads=Nk,
         scale=0.07))

@@ -284,21 +284,24 @@ def test_kn505_paged_kernel_prefetch_clean():
     projection but the kernel's two tile buffers each."""
     from paddle_tpu.ops.pallas_decode import paged_decode_tile_rows
 
-    caps, (args, _), _ = _capture("paged_decode")
+    caps, (args, kwargs), _ = _capture("paged_decode")
     (cap,) = caps
     assert cap.num_scalar_prefetch == 2
     assert all(np.asarray(v).dtype.kind in "iu"
                for v in cap.prefetch_values)
     assert check_gridspec(cap) == []
     q, kp, vp, tables, ctx, n_heads = args
-    S, _, nh = q.shape
+    S = q.shape[0]
+    # q regrouped: one row a member of each K/V head, the arenas' width
+    kv_heads, nh = kwargs["kv_heads"], kp.shape[2]
+    group = n_heads // kv_heads
     assert cap.grid == (S,)
     blocked = [s.block_shape for s in cap.in_specs]
-    assert blocked == [(1, 1, nh), None, None]
+    assert blocked == [(1, group, nh), None, None]
     assert [s.array_shape for s in cap.in_specs[1:]] == \
         [kp.shape, vp.shape]
-    rows = paged_decode_tile_rows(kp.shape[1], nh, n_heads,
-                                  kp.dtype.itemsize, tables.shape[1])
+    rows = paged_decode_tile_rows(kp.shape[1], nh, kv_heads,
+                                  kp.dtype.itemsize, tables.shape[1], group)
     bs = kp.shape[1]
     tile = ((2, rows // bs, bs, nh), np.dtype(kp.dtype))
     assert cap.scratch.count(tile) == 2

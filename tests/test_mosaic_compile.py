@@ -531,6 +531,28 @@ def test_gated_attention_heads_compile_for_v5e(one_chip, mosaic, step):
             else "flash_prefill_chunk") in text
 
 
+@pytest.mark.parametrize("N,Nk,H", [(24, 8, 32), (12, 4, 64), (8, 2, 64)])
+def test_packed_query_rows_compile_for_v5e(one_chip, mosaic, N, Nk, H):
+    """Grouped query heads packed into a block of sublanes that they do
+    not fill: 24, 12 and 8 live rows of 32, 16 and 16; in the last two
+    a member's rows are a slice that starts off the 8-row float32
+    tile."""
+    S, bs, mb, bf16, i32 = 8, 16, 64, jnp.bfloat16, jnp.int32
+    assert pallas_decode.paged_decode_head_rows(Nk, N // Nk) > N
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pages = sds((4 * mb, bs, Nk * H), bf16)
+    fn = lambda q, k, v, t, c: pallas_decode.paged_decode_attention(
+        q, k, v, t, c, N, use_kernel=True, kv_heads=Nk)
+    text = jax.jit(fn).trace(
+        sds((S, 1, N * H), bf16), pages, pages, sds((S, mb), i32),
+        sds((S,), i32)).lower(lowering_platforms=("tpu",)).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and "paged_decode" in text
+
+
 @pytest.mark.parametrize("tokens,rows", [(128, 16), (512, 48)])
 def test_qwen3next_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
                                                rows):
