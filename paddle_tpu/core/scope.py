@@ -30,7 +30,7 @@ SCOPES = frozenset((
     "embed",        # the embedding lookup (and learned positions)
     "attn",         # projections, rotary, cache writes, the kernel call
     "mlp",          # dense and gated MLPs, the shared experts
-    "experts",      # router, gather, moe_grouped_ffn, scatter
+    "experts",      # router, grouping, moe_grouped_ffn
     "ssm",          # a Mamba-2 layer: projections, convolution, scan
     "linear",       # a delta-rule layer: projections, convolution, the
                     # kernel call, the gated norm

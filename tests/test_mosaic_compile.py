@@ -150,18 +150,27 @@ def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
             else "mla_prefill_chunk") in text
 
 
+def _no_layout(text, n_tiles, rows, tokens, k, d):
+    """The compiled program holds no array of the layout's rows
+    [n_tiles * rows, d] nor of one row a pair [tokens * k, d]: the
+    kernel gathers and sums in VMEM."""
+    for n in (n_tiles * rows, tokens * k):
+        assert f"[{n},{d}]" not in text, n
+
+
 @pytest.mark.parametrize("tokens,rows", [(32, 16), (512, 80)])
 def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
                                              rows):
-    """The held experts' products at DeepSeek-V2's widths (8 of the 40
-    experts held of the router's 160, to keep the description small),
-    for a decode batch and for a chunk, at the tile `expert_tile_rows`
-    picks for each: Mosaic holds the kernel to its 40 MB VMEM limit
-    here, not first on the chip."""
+    """The held experts' products at DeepSeek-V2's widths (the cell's 40
+    experts held of the router's 160), for a decode batch and for a
+    chunk, at the tile `expert_tile_rows` picks for each: Mosaic holds
+    the kernel to its VMEM limit, x and y held whole, here and not
+    first on the chip."""
     _, moe_serving = mosaic_mla
-    d, f, E = 5120, 1536, 8
+    d, f, E = 5120, 1536, 40
     bf16 = jnp.bfloat16
     assert moe_serving.expert_tile_rows(tokens, 6, 160, d, f, 2) == rows
+    assert moe_serving._resident(rows, tokens, d, f, 2)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -178,6 +187,7 @@ def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
         sds((E, f, d), bf16)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+    _no_layout(text, -(-tokens * 6 // rows) + E, rows, tokens, 6, d)
 
 
 @pytest.mark.parametrize("name", ["mla_paged_decode", "mla_prefill_chunk",
@@ -367,14 +377,15 @@ def test_window_and_full_attention_compile_for_v5e(one_chip, mosaic, step):
 def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
                                             rows):
     """The held experts' products at K-EXAONE's widths (6,144 x 2,048,
-    weight blocks of 3.1 MB; 4 of the 16 experts held of the router's
-    128, to keep the description small), for a decode batch and for a
-    chunk, at the tile `expert_tile_rows` picks for each (28.7 MB of
-    the 40 MB VMEM limit at 128 rows)."""
+    weight blocks of 3.1 MB; the cell's 16 experts held of the router's
+    128), for a decode batch and for a chunk, at the tile
+    `expert_tile_rows` picks for each (52.7 MB at 128 rows beside the
+    chunk's x and y: the limit is the most of the v5e's 128 MiB)."""
     _, moe_serving = mosaic_mla
-    d, f, E, k = 6144, 2048, 4, 8
+    d, f, E, k = 6144, 2048, 16, 8
     bf16 = jnp.bfloat16
     assert moe_serving.expert_tile_rows(tokens, k, 128, d, f, 2) == rows
+    assert moe_serving._resident(rows, tokens, d, f, 2)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -391,6 +402,7 @@ def test_exaone_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
         sds((E, f, d), bf16)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+    _no_layout(text, -(-tokens * k // rows) + E, rows, tokens, k, d)
 
 
 @pytest.fixture
@@ -557,13 +569,15 @@ def test_packed_query_rows_compile_for_v5e(one_chip, mosaic, N, Nk, H):
 def test_qwen3next_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
                                                rows):
     """The held experts' products at Qwen3-Next's widths (2,048 x 512:
-    two width tiles of 256; 8 of the 64 experts held of the router's
-    512, to keep the description small), for a decode batch and for a
-    chunk, at the tile `expert_tile_rows` picks for each."""
+    two width tiles of 256; the cell's 64 experts held of the router's
+    512), for a decode batch and for a chunk, at the tile
+    `expert_tile_rows` picks for each: no bf16[8208,2048] at the
+    chunk's."""
     _, moe_serving = mosaic_mla
-    d, f, E, k = 2048, 512, 8, 10
+    d, f, E, k = 2048, 512, 64, 10
     bf16 = jnp.bfloat16
     assert moe_serving.expert_tile_rows(tokens, k, 512, d, f, 2) == rows
+    assert moe_serving._resident(rows, tokens, d, f, 2)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -580,3 +594,4 @@ def test_qwen3next_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
         sds((E, f, d), bf16)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_grouped_ffn" in text
+    _no_layout(text, -(-tokens * k // rows) + E, rows, tokens, k, d)
