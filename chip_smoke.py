@@ -15,7 +15,8 @@ out by the repo's own means:
   kernels   every registered Pallas kernel compiled by Mosaic against
             its declared fallback, then the production tile shapes the
             two legs above do not reach (the DeepSeek-V2, Granite and
-            K-EXAONE cells': latent attention, expert products, the
+            K-EXAONE cells': latent attention in the absorbed and the
+            expanded form, expert products, the
             Mamba-2 step and scan, a window layer's chunk and its
             decode over rings, 64/8 heads of 128 to 13k)
   four_chip dist.ShardedTrainStep on a dp=2 x mp=2 mesh when there are
@@ -492,6 +493,35 @@ def _production_cases(sz):
                     np.int32(p0), rank, 0.11, use_kernel=False,
                     n_real=np.int32(n)),
             (rand((C, N, W)), arena, row)))
+    # the expanded form of such chunks (k and v a head formed in VMEM):
+    # a whole chunk at the start and in the middle of a table, one of
+    # as many real positions as it takes to pay and one past the first
+    # query block, each resuming inside a block
+    nope, rope, v_dim = 128, 64, 128
+    W_x, rank_x = (256, 128) if TINY else (W, rank)
+    arena_x = rand((len(pages) + 1, pbs, W_x)) if TINY else arena
+    pays = next((n for n in range(1, C + 1) if pm.mla_expanded_pays(
+        n, N, rank_x, nope, rope, v_dim, C)), C)
+    w_uk, w_uv = rand((N, nope, rank_x)), rand((N, rank_x, v_dim))
+    for p0, n_real in ((0, C), (mid, C), (mid + 24, pays),
+                       (C + 8, C // 2 + 5)):
+        row = np.full((mb,), arena_x.shape[0] + 7, np.int32)
+        n_alloc = (p0 + n_real - 1) // pbs + 1
+        row[:n_alloc] = pages[:n_alloc]
+        cases.append((
+            ("mla_prefill_chunk_expanded",),
+            f"mla_prefill_chunk_expanded C={C} p0={p0} n_real={n_real}",
+            lambda qn, qp, a, t, p0=p0, n=n_real:
+                pm.mla_prefill_chunk_expanded(
+                    qn, qp, a, w_uk, w_uv, t, np.int32(p0), 0.11,
+                    use_kernel=True, n_real=np.int32(n)),
+            lambda qn, qp, a, t, p0=p0, n=n_real, live=n_alloc:
+                pm.mla_prefill_chunk_expanded(
+                    qn, qp, a, w_uk, w_uv,
+                    jnp.where(jnp.arange(t.shape[0]) < live, t, 0),
+                    np.int32(p0), 0.11, use_kernel=False,
+                    n_real=np.int32(n)),
+            (rand((C, N, nope)), rand((C, N, rope)), arena_x, row)))
 
     # the grouped expert products at the cell's widths, 8 held experts:
     # a decode batch's tokens and a chunk's, routed unevenly (one expert

@@ -15,6 +15,11 @@ norm is RMSNorm and nothing has a bias.
            ABSORBED: q_lat = q_nope W_uk, score = [q_lat | q_pe] . row,
            o = (softmax . c_kv) W_uv, then W_o. The parameters hold
            W_uk and W_uv, the two halves of the published kv_b_proj.
+           A prompt chunk with many real positions forms k and v a head
+           from the cached rows instead, in the kernel's VMEM
+           (`ops/pallas_mla.mla_prefill_chunk_expanded`): the
+           up-projection of a row is then shared by the chunk's
+           positions.
   experts  float32 softmax over all routed experts, group-limited
            top-k, weights not renormalised and scaled, plus the shared
            experts; no capacity and no drops (`moe/serving.py`). A
@@ -35,7 +40,8 @@ from ..core.tensor import Tensor
 from ..moe.serving import route_group_limited
 from ..nn import Layer, LayerList
 from ..nn.functional.norm import rms_norm_values
-from ..ops.pallas_mla import mla_paged_decode, mla_prefill_chunk
+from ..ops.pallas_mla import (mla_expanded_pays, mla_paged_decode,
+                              mla_prefill_chunk, mla_prefill_chunk_expanded)
 from ..core.scope import scope
 from .blocks import (GatedMLP, HeldExperts, ServedDecoder, Weights,
                      default_make, matmul)
@@ -200,21 +206,33 @@ class MLAttention(Weights):
 
     def absorbed(self, x, positions):
         """(queries [T, H, W], cache rows [T, W]) of the absorbed form,
-        W = `latent_width`: [q_nope W_uk | q_pe | 0] and
-        [c_kv | k_pe | 0]."""
-        c = self.c
+        W = `latent_width`: `absorb` and `latent_row` of what `project`
+        gives."""
         q_nope, q_pe, c_kv, k_pe = self.project(x, positions)
-        q_lat = _einsum("thn,hnc->thc", q_nope, self.w_uk._value)
+        return self.absorb(q_nope, q_pe), self.latent_row(c_kv, k_pe)
+
+    def _pad(self, a):
+        """Zeros that take a's last axis to `latent_width` lanes
+        beside the rank part."""
+        c = self.c
         pad = c.latent_width - c.kv_lora_rank - c.qk_rope_head_dim
-        q = jnp.concatenate(
-            [q_lat, q_pe, jnp.zeros(q_pe.shape[:2] + (pad,), x.dtype)], -1)
-        row = jnp.concatenate(
-            [c_kv, k_pe, jnp.zeros((k_pe.shape[0], pad), x.dtype)], -1)
-        return q, row
+        return jnp.zeros(a.shape[:-1] + (pad,), a.dtype)
+
+    def absorb(self, q_nope, q_pe):
+        """[q_nope W_uk | q_pe | 0] [T, H, W]: the absorbed queries."""
+        q_lat = _einsum("thn,hnc->thc", q_nope, self.w_uk._value)
+        return jnp.concatenate([q_lat, q_pe, self._pad(q_pe)], -1)
+
+    def latent_row(self, c_kv, k_pe):
+        """[c_kv | k_pe | 0] [T, W]: the tokens' rows of the cache."""
+        return jnp.concatenate([c_kv, k_pe, self._pad(k_pe)], -1)
 
     def output(self, o_lat):
         """o_lat [T, H, rank] (softmax-weighted c_kv) -> [T, d]."""
-        o = _einsum("thc,hcv->thv", o_lat, self.w_uv._value)
+        return self.out(_einsum("thc,hcv->thv", o_lat, self.w_uv._value))
+
+    def out(self, o):
+        """The heads' outputs o [T, H, v] -> [T, d]."""
         return matmul(o.reshape(o.shape[0], -1), self.o._value)
 
     def dense(self, x, positions):
@@ -229,8 +247,7 @@ class MLAttention(Weights):
             .astype(jnp.float32) * c.softmax_scale()
         causal = positions[None, :, None] >= positions[None, None, :]
         probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
-        o = _einsum("hts,shv->thv", probs.astype(x.dtype), v)
-        return matmul(o.reshape(o.shape[0], -1), self.o._value)
+        return self.out(_einsum("hts,shv->thv", probs.astype(x.dtype), v))
 
 
 class DeepseekV2Block(Weights):
@@ -265,11 +282,11 @@ class DeepseekV2Block(Weights):
 class _ServedBlock:
     """One block behind the engine's per-layer protocol, cache kind
     latent: the step's rows [c_kv | k_pe] go into the layer's one arena
-    and the absorbed attention reads them back from it."""
+    and the attention reads them back from it, absorbed in decode."""
 
-    def __init__(self, block, c):
+    def __init__(self, block, c, first=False):
         from ..serving.kv_cache import latent_kind
-        self.block, self.c = block, c
+        self.block, self.c, self.first = block, c, first
         self.cache_kind = latent_kind(c.latent_width)
 
     def _step(self, h, pages, view, positions, attend):
@@ -292,14 +309,44 @@ class _ServedBlock:
         return self._step(h, pages, view, view.ctx, attend)
 
     def prefill(self, h, pages, view):
-        c = self.c
+        """A chunk's rows go into the arena as decode's do. Its attention
+        takes the expanded form where `mla_expanded_pays` for its real
+        positions, else decode's absorbed one; one program serves every
+        chunk length, so the choice is a `lax.cond` on the device, both
+        branches compiled with the program. The first block counts the
+        positions of a chunk that took the expanded form
+        (`serving.mla_prefill_positions_expanded`)."""
+        block, c = self.block, self.c
+        attn = block.attn
+        with scope("attn"):
+            q_nope, q_pe, c_kv, k_pe = attn.project(block.norm1(h),
+                                                    view.positions)
+            lat = pages[0].at[view.blk, view.off].set(
+                attn.latent_row(c_kv, k_pe).astype(pages[0].dtype))
+            expanded = mla_expanded_pays(
+                view.n_real, c.num_attention_heads, c.kv_lora_rank,
+                c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+                chunk=h.shape[0])
 
-        def attend(q, lat):
-            return mla_prefill_chunk(
-                q, lat, view.table_row, view.p0, c.kv_lora_rank,
-                c.softmax_scale(), use_kernel=view.use_kernel,
-                n_real=view.n_real)
-        return self._step(h, pages, view, view.positions, attend)
+            def expanded_form():
+                o = mla_prefill_chunk_expanded(
+                    q_nope, q_pe, lat, attn.w_uk._value, attn.w_uv._value,
+                    view.table_row, view.p0, c.softmax_scale(),
+                    use_kernel=view.use_kernel, n_real=view.n_real)
+                return attn.out(o.astype(h.dtype))
+
+            def absorbed_form():
+                o_lat = mla_prefill_chunk(
+                    attn.absorb(q_nope, q_pe), lat, view.table_row, view.p0,
+                    c.kv_lora_rank, c.softmax_scale(),
+                    use_kernel=view.use_kernel, n_real=view.n_real)
+                return attn.output(o_lat.astype(h.dtype))
+            h = h + jax.lax.cond(expanded, expanded_form, absorbed_form)
+        h, stats = block.feed_forward(h, view.live, view.use_kernel)
+        if self.first:
+            stats = dict(stats or {}, mla_prefill_positions_expanded=(
+                jnp.where(expanded, view.n_real, 0)))
+        return h, (lat, None), stats
 
 
 class DeepseekV2ForCausalLM(Layer):
@@ -343,5 +390,5 @@ class DeepseekV2ForCausalLM(Layer):
 
     def served(self):
         """This model behind the serving engine's per-layer protocol."""
-        return ServedDecoder(self, [_ServedBlock(b, self.config)
-                                    for b in self.blocks])
+        return ServedDecoder(self, [_ServedBlock(b, self.config, i == 0)
+                                    for i, b in enumerate(self.blocks)])

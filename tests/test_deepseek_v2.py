@@ -176,6 +176,135 @@ def test_a_full_chunk_is_the_chunk_without_n_real():
     assert np.array_equal(np.asarray(full), np.asarray(plain))
 
 
+# the expanded form at TINY's widths: rows of 128 lanes, c_kv 32 of them
+X = dict(H=4, nope=16, rope=8, v=16, rank=32, W=128)
+
+
+def _expanded_case(seed, p0, n_real, C=512, bs=16, held=40):
+    """A chunk at p0 over a table that cycles through `held` pages of a
+    small arena, its entries past the page of the last real position
+    pointing at a block of NaN, and the same table with every entry real
+    (what the reference gathers)."""
+    rng = np.random.default_rng(seed)
+    H, nope, rope, v, rank, W = (X[k] for k in ("H", "nope", "rope", "v",
+                                                "rank", "W"))
+    mb = -(-(p0 + C) // bs)
+    pages = 0.3 * rng.standard_normal((held + 2, bs, W)).astype(np.float32)
+    pages[held + 1] = np.nan
+    whole = (1 + np.arange(mb) % held).astype(np.int32)
+    live = (p0 + n_real - 1) // bs + 1
+    row = np.where(np.arange(mb) < live, whole, held + 1).astype(np.int32)
+    q_nope = 0.3 * rng.standard_normal((C, H, nope)).astype(np.float32)
+    q_pe = 0.3 * rng.standard_normal((C, H, rope)).astype(np.float32)
+    w_uk = 0.3 * rng.standard_normal((H, nope, rank)).astype(np.float32)
+    w_uv = 0.3 * rng.standard_normal((H, rank, v)).astype(np.float32)
+    return q_nope, q_pe, pages, w_uk, w_uv, row, whole
+
+
+def _pays_from():
+    return next(n for n in range(1, 512) if pallas_mla.mla_expanded_pays(
+        n, X["H"], X["rank"], X["nope"], X["rope"], X["v"]))
+
+
+@pytest.mark.parametrize("p0", [0, 496, 8192],
+                         ids=["first", "across_a_tile", "far_on_few_pages"])
+@pytest.mark.parametrize("n_real", ["one", "below", "at", "all"])
+def test_expanded_kernel_works_on_its_real_positions_only(p0, n_real):
+    """The kernel forms k and v a head from the tiles it walks and
+    attends the chunk's query blocks of 256 rows (two at C 512): the
+    real positions are the gather+dense reference's, the padded ones
+    exactly zero, no page past the last real position's is read."""
+    n = {"one": 1, "below": _pays_from() - 1, "at": _pays_from(),
+         "all": 512}[n_real]
+    q_nope, q_pe, pages, w_uk, w_uv, row, whole = _expanded_case(
+        n + p0, p0, n)
+    scale = 0.2
+    got = np.asarray(pallas_mla.mla_prefill_chunk_expanded(
+        q_nope, q_pe, pages, w_uk, w_uv, row, np.int32(p0), scale,
+        use_kernel=True, n_real=np.int32(n)))
+    positions = p0 + jnp.arange(q_nope.shape[0], dtype=jnp.int32)
+    want = np.asarray(pallas_mla._expanded_dense(
+        q_nope, q_pe, pages[whole].reshape(-1, X["W"]), w_uk, w_uv,
+        positions, scale))
+    assert not np.isnan(got).any()
+    assert np.abs(got[:n] - want[:n]).max() < TOL
+    assert not got[n:].any()
+    # the gather+dense path keeps the same contract over a sound table
+    dense = np.asarray(pallas_mla.mla_prefill_chunk_expanded(
+        q_nope, q_pe, pages, w_uk, w_uv, whole, np.int32(p0), scale,
+        use_kernel=False, n_real=np.int32(n)))
+    assert np.abs(dense[:n] - want[:n]).max() < TOL and not dense[n:].any()
+
+
+def test_expanded_pays_at_its_crossover():
+    """The two forms' operations weighed by their measured cost: at
+    DeepSeek-V2's widths, in query blocks of 256, the expanded one pays
+    at 199-256 live positions (one block) and from 308 on (two), not at
+    the 158 the operations alone give; the choice is the same function
+    of a traced count."""
+    def pays(n, chunk=512):
+        return pallas_mla.mla_expanded_pays(n, 128, 512, 128, 64, 128,
+                                            chunk)
+    ns = (1, 157, 158, 198, 199, 256, 257, 307, 308, 512)
+    assert [n for n in ns if pays(n)] == [199, 256, 308, 512]
+    assert bool(jax.jit(pays)(jnp.int32(199))) \
+        and not bool(jax.jit(pays)(jnp.int32(198)))
+    # weighed alike, the operations cross at 158
+    one = dict(_FORM_COST=1.0, _ATTEND_COST=1.0)
+    with pytest.MonkeyPatch.context() as mp:
+        for k, v in one.items():
+            mp.setattr(pallas_mla, k, v)
+        assert [n for n in ns if pays(n, 1)] == [158, 198, 199, 256, 257,
+                                                 307, 308, 512]
+    # TINY's widths, in the blocks of the kernel tests and of an engine
+    # with chunks of 32
+    assert _pays_from() == 104 and next(
+        n for n in range(1, 33) if pallas_mla.mla_expanded_pays(
+            n, 4, 32, 16, 8, 16, 32)) == 18
+
+
+def test_expanded_kernel_registers_an_example_and_its_fallback():
+    from paddle_tpu.analysis.kernel_lint import lint_kernel
+    reg = next(r for r in registered_kernels()
+               if r.name == "mla_prefill_chunk_expanded")
+    assert reg.fallback is not None and reg.fn is \
+        pallas_mla.mla_prefill_chunk_expanded
+    findings, info = lint_kernel(reg, seeds=(0, 1))
+    assert not findings, [f.message for f in findings]
+    assert info["has_fallback"] and info["n_calls"] == 1
+
+
+@pytest.mark.parametrize("use_kernel", [None, True],
+                         ids=["fallback", "kernels"])
+@pytest.mark.parametrize("n", [6, 20, 45], ids=["absorbed", "expanded",
+                                                "both"])
+def test_served_prefill_through_each_form_is_the_published_model(
+        n, use_kernel):
+    """A prompt of one chunk of 6 real positions (under the crossover of
+    18 at TINY's widths and chunks of 32: absorbed), one of 20
+    (expanded), and one of 32 + 13 (expanded, then absorbed) gives the
+    logits of the whole-sequence forward with k and v a head as
+    published."""
+    eng = engine()
+    prompt = np.random.default_rng(11).integers(1, TINY["vocab_size"], n)
+    got, seq = served_logits(eng, prompt, 1, use_kernel)
+    dense = np.asarray(eng.model(np.asarray(seq)[None])._value[0])
+    assert np.abs(got[0] - dense[n - 1]).max() < TOL
+
+
+def test_expanded_positions_are_counted_once_a_chunk():
+    """The first layer counts the real positions of the chunks that took
+    the expanded form: 20, and the first 32 of 45, of the three prompts'
+    chunks; the chunks of 6 and 13 stayed absorbed."""
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(1, TINY["vocab_size"], n) for n in (6, 20, 45)]
+    eng = engine(enable_prefix_cache=False)
+    name = "serving.mla_prefill_positions_expanded"
+    before = eng.metrics_snapshot().get(name, 0)
+    _streams(eng, prompts, n_new=2)
+    assert eng.metrics_snapshot().get(name, 0) - before == 20 + 32
+
+
 def test_prefill_positions_real_and_padded_add_up():
     """Every chunk is dispatched at `prefill_chunk` positions; the two
     counters say how many of them were a prompt's."""

@@ -108,6 +108,7 @@ def mosaic_mla(monkeypatch):
     from paddle_tpu.moe import serving as moe_serving
     from paddle_tpu.ops import pallas_mla
     jitted = (pallas_mla.mla_paged_decode, pallas_mla.mla_prefill_chunk,
+              pallas_mla.mla_prefill_chunk_expanded,
               moe_serving.moe_grouped_ffn)
     monkeypatch.setattr(pallas_mla, "_interpret", lambda: False)
     monkeypatch.setattr(moe_serving, "_interpret", lambda: False)
@@ -148,6 +149,61 @@ def test_latent_attention_compiles_for_v5e(one_chip, mosaic_mla, step):
     assert "tpu_custom_call" in text
     assert ("mla_paged_decode" if step == "decode"
             else "mla_prefill_chunk") in text
+
+
+def test_expanded_latent_chunk_compiles_for_v5e(one_chip, mosaic_mla):
+    """deepseek-v2.serve-docs' chunk in the expanded form: bf16, 128
+    heads, rank 512 of rows of 640 lanes, 16-row pages, a chunk of 512
+    with its real positions traced; and both forms in one program
+    behind `mla_expanded_pays`, as the served block holds them. No [L, heads, 128] k or
+    v of a whole table reaches HBM."""
+    pallas_mla, _ = mosaic_mla
+    N, W, rank, nope, rope, v, bs, mb, nb, C = (128, 640, 512, 128, 64,
+                                                128, 16, 576, 2048, 512)
+    bf16 = jnp.bfloat16
+    assert pallas_mla._expanded_supported(bs, C, W, rank, nope, rope, v, N,
+                                          2, mb)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = (sds((C, N, nope), bf16), sds((C, N, rope), bf16),
+            sds((nb, bs, W), bf16), sds((N, nope, rank), bf16),
+            sds((N, rank, v), bf16), sds((mb,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32))
+    kernel = jax.jit(
+        lambda qn, qp, a, wk, wv, t, p0, n:
+            pallas_mla.mla_prefill_chunk_expanded(
+                qn, qp, a, wk, wv, t, p0, 0.11, use_kernel=True,
+                n_real=n)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in kernel
+    assert "mla_prefill_chunk_expanded" in kernel
+
+    def entry(qn, qp, a, wk, wv, t, p0, n):
+        # the served block's choice (models/deepseek_v2.py): the absorbed
+        # kernel between W_uk folded into the queries and W_uv after it
+        def absorbed():
+            q = jnp.concatenate(
+                [jnp.einsum("thn,hnc->thc", qn, wk), qp,
+                 jnp.zeros((C, N, W - rank - rope), bf16)], -1)
+            o = pallas_mla.mla_prefill_chunk(q, a, t, p0, rank, 0.11,
+                                             use_kernel=True, n_real=n)
+            return jnp.einsum("thc,hcv->thv", o, wv)
+        return jax.lax.cond(
+            pallas_mla.mla_expanded_pays(n, N, rank, nope, rope, v, C),
+            lambda: pallas_mla.mla_prefill_chunk_expanded(
+                qn, qp, a, wk, wv, t, p0, 0.11, use_kernel=True, n_real=n),
+            absorbed)
+    entry = jax.jit(entry).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert "mla_prefill_chunk_expanded" in entry
+    assert "mla_prefill_chunk/" in entry or '"mla_prefill_chunk"' in entry
+    # what the gather+dense form lays out in HBM: k^T and v of the table
+    for text in (kernel, entry):
+        for shape in ((N, nope, mb * bs), (N, mb * bs, v),
+                      (mb * bs, N, nope)):
+            assert "[" + ",".join(map(str, shape)) + "]" not in text
 
 
 def _no_layout(text, n_tiles, rows, tokens, k, d):
@@ -191,6 +247,7 @@ def test_grouped_expert_ffn_compiles_for_v5e(one_chip, mosaic_mla, tokens,
 
 
 @pytest.mark.parametrize("name", ["mla_paged_decode", "mla_prefill_chunk",
+                                  "mla_prefill_chunk_expanded",
                                   "moe_grouped_ffn"])
 def test_registry_example_compiles_for_v5e(one_chip, mosaic_mla, name):
     """chip_smoke.py's kernel leg runs every registered kernel's own
