@@ -52,7 +52,7 @@ BF16_TOL = 3e-2     # the repo's bf16 tolerance (.claude/skills/verify)
 
 Sizes = collections.namedtuple(
     "Sizes", "model batch seq parity_batch train_steps engine prompt_lens "
-             "tail_lens flash_seq decode_len rows")
+             "tail_lens flash_seq decode_len")
 
 
 def sizes():
@@ -68,7 +68,7 @@ def sizes():
             engine=dict(max_slots=16, block_size=16, prefill_chunk=128,
                         max_model_len=512, weights="wo8"),
             prompt_lens=(37, 150, 260, 16), tail_lens=(30, 20, 90),
-            flash_seq=2048, decode_len=1024, rows=4096)
+            flash_seq=2048, decode_len=1024)
     return Sizes(
         model=GPTConfig(vocab_size=512, hidden_size=128, num_layers=1,
                         num_heads=2, max_seq_len=256, dropout=0.0),
@@ -76,7 +76,7 @@ def sizes():
         engine=dict(max_slots=4, block_size=16, prefill_chunk=32,
                     max_model_len=128, weights="wo8"),
         prompt_lens=(21, 40, 70, 16), tail_lens=(10, 8, 30),
-        flash_seq=256, decode_len=32, rows=256)
+        flash_seq=256, decode_len=32)
 
 
 def check(ok, what):
@@ -324,7 +324,6 @@ def _production_cases(sz):
     from paddle_tpu.moe import kernels as moe
     from paddle_tpu.ops import pallas_attention as pa
     from paddle_tpu.ops import pallas_decode as pd
-    from paddle_tpu.ops import pallas_layernorm as pln
     from paddle_tpu.ops.attention import _composed_attention
 
     rs = np.random.RandomState(0)
@@ -746,18 +745,6 @@ def _production_cases(sz):
     cases.append((("int8_matvec",), f"int8_matvec 16x{nh} over V={vocab}",
                   p8.int8_matvec, p8._matvec_fallback, (hq, wq, ws)))
 
-    x, res = rand((sz.rows, nh), scale=1.0), rand((sz.rows, nh), scale=1.0)
-    wt, bs = rand((nh,), jnp.float32, 1.0), rand((nh,), jnp.float32, 1.0)
-    cases.append((("layernorm_fused",), f"layernorm_fused {sz.rows}x{nh}",
-                  lambda *a: pln.fused_add_layer_norm(*a, 1e-5),
-                  lambda *a: pln._ln_primal_fallback(*a, 1e-5),
-                  (x, res, wt, bs)))
-    cases.append((("layernorm_fwd_saved",),
-                  f"layernorm_fwd_saved {sz.rows}x{nh}",
-                  lambda *a: pln._fwd(*a, 1e-5),
-                  lambda *a: pln._ln_fwd_fallback(*a, 1e-5),
-                  (x, res, wt, bs)))
-
     # the largest source the MoE gate admits at this width, in bf16
     # (bisected over multiples of 8; the debug mode stops at 64 rows)
     lo, hi = 8, 64 if TINY else 1 << 20
@@ -796,8 +783,8 @@ def leg_kernels(sz):
 
     # every registered kernel at its registered example against its
     # declared fallback at its own tolerance (the debug mode takes the
-    # first three: tier-1's kernel doctor already sweeps the registry
-    # in the interpreter)
+    # first three: tier-1's tests/test_kernel_parity.py already checks
+    # every registration in the interpreter)
     for kern in list(registry)[:3] if TINY else registry:
         errs = []
         findings = check_fallback_parity(

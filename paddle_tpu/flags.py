@@ -79,17 +79,6 @@ _register(
     "materialized; backward recomputes chunk logits (flash-style). "
     "Off falls back to logits + F.cross_entropy.")
 _register(
-    "use_pallas_layernorm", False, bool,
-    "Use the Pallas fused residual+LayerNorm kernel "
-    "(ops/pallas_layernorm.py) at the transformer residual+ln2 site "
-    "where shapes divide (rows%256==0, d%128==0, TPU backend). "
-    "ISOLATED 1.69x vs composed XLA at [16384,768] fwd+bwd but "
-    "NET-SLOWER end-to-end: GPT-1.3B-dims block MFU 0.611->0.387 (the "
-    "vjp's f32 residual-sum output doubles HBM writes at d=2048, and "
-    "XLA fuses the composed add+LN into neighboring ops); measured on "
-    "an earlier revision (jax 0.4.37), not since. Off (default) "
-    "composes add+LN in XLA.")
-_register(
     "use_pallas_attention", True, bool,
     "Master switch for the Pallas flash-attention kernel; off forces the "
     "composed XLA attention everywhere.")

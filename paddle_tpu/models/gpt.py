@@ -275,8 +275,7 @@ class GPTBlock(Layer):
             return h + self.dropout(self.mlp(y))
 
     def _add_ln2(self, x, delta):
-        """The residual-add + ln2 site in one op: (ln2(x+delta), x+delta).
-        Routes to the Pallas pair kernel under `use_pallas_layernorm`."""
+        """The residual-add + ln2 site in one op: (ln2(x+delta), x+delta)."""
         return F.fused_add_layer_norm(x, delta, self.ln2.weight,
                                       self.ln2.bias, self.ln2._epsilon)
 

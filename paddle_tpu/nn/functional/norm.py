@@ -244,34 +244,19 @@ def group_norm(x, num_groups, epsilon=1e-05, weight=None, bias=None,
 def fused_add_layer_norm(x, residual, weight, bias, epsilon=1e-05,
                          name=None):
     """(LayerNorm(x + residual), x + residual) — the pre-LN transformer
-    residual site in one op. Dispatches to the Pallas pair kernel
-    (`ops/pallas_layernorm.py`, measured 1.69x the composed XLA lowering
-    on v5e at GPT bench shapes) when `use_pallas_layernorm` is on and
-    shapes divide; composed XLA with identical f32-moment numerics
-    otherwise. Reference analog: the fused_bias_dropout_residual_
-    layer_norm op family / skip_layernorm_fuse_pass.cc."""
+    residual site in one op, composed in XLA (which fuses the add and
+    the norm into their neighbours) with f32 moments. Reference analog:
+    the fused_bias_dropout_residual_layer_norm op family /
+    skip_layernorm_fuse_pass.cc."""
     x = ensure_tensor(x)
     residual = ensure_tensor(residual)
     weight = ensure_tensor(weight)
     bias = ensure_tensor(bias)
 
     def fn(v, r, w, b):
-        from ...flags import get_flag
-        from ...ops.pallas_layernorm import (fused_add_layer_norm_pair,
-                                             _BLOCK_ROWS)
-        lead = v.shape[:-1]
-        d = v.shape[-1]
-        rows = 1
-        for n in lead:
-            rows *= int(n)
-        if (get_flag("use_pallas_layernorm") and rows % _BLOCK_ROWS == 0
-                and d % 128 == 0 and jax.default_backend() == "tpu"):
-            out2, carry2 = fused_add_layer_norm_pair(
-                v.reshape(-1, d), r.reshape(-1, d), w, b, epsilon)
-            return out2.reshape(*lead, d), carry2.reshape(*lead, d)
-        # composed path: same bandwidth discipline as layer_norm above —
-        # f32 moments, elementwise math and scale/shift in input dtype
-        # (no f32 copy of the [.., d] stream is materialized)
+        # same bandwidth discipline as layer_norm above — f32 moments,
+        # elementwise math and scale/shift in input dtype (no f32 copy
+        # of the [.., d] stream is materialized)
         h = v + r
         from ...amp import amp_op_dtype
         acc = amp_op_dtype("layer_norm", jnp.float32)

@@ -5,4 +5,3 @@ Analog of the reference's `operators/fused/` directory
 as Pallas TPU kernels + XLA-fused compositions.
 """
 from .attention import scaled_dot_product_attention, flash_attention  # noqa: F401
-from .pallas_layernorm import add_layer_norm, fused_add_layer_norm  # noqa: F401,E402

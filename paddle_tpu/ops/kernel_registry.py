@@ -212,8 +212,8 @@ def _load_inventory():
     # decorators run; lru_cache keeps this a one-time side effect
     from . import pallas_attention  # noqa: F401
     from . import pallas_decode  # noqa: F401
+    from . import pallas_gdn  # noqa: F401
     from . import pallas_int8  # noqa: F401
-    from . import pallas_layernorm  # noqa: F401
     from . import pallas_mla  # noqa: F401
     from . import pallas_ssm  # noqa: F401
     from ..moe import kernels  # noqa: F401

@@ -71,7 +71,7 @@ from .reqtrace import (  # noqa: F401
     trace_chrome_spans)
 from .sink import (  # noqa: F401
     JsonlSink, export_chrome_tracing, make_ckpt_record,
-    make_memsnap_record, make_phase_record, make_reqtrace_record,
+    make_memsnap_record, make_reqtrace_record,
     make_serving_record, make_step_record, read_jsonl,
     validate_step_record)
 from .watchdog import HangWatchdog, dump_black_box  # noqa: F401
@@ -79,7 +79,7 @@ from .watchdog import HangWatchdog, dump_black_box  # noqa: F401
 __all__ = [
     "TelemetryRecorder", "StepTimer", "span", "scope", "SCOPES", "auto_step",
     "current_recorder", "open_spans", "JsonlSink", "read_jsonl",
-    "make_step_record", "make_phase_record", "make_ckpt_record",
+    "make_step_record", "make_ckpt_record",
     "make_serving_record", "make_reqtrace_record",
     "make_memsnap_record",
     "MemoryObservatory", "is_oom", "register_provider", "snapshot_ledger",

@@ -1,18 +1,16 @@
 """Mesh observatory: measured collective latencies vs the planner's
 ICI/DCN peaks, a persistent comm DB, and per-step comm attribution.
 
-The communication sibling of the kernel observatory
-(telemetry/kernel_obs.py). Every comm number the planner prices today
-is analytic: `cost_model.estimate_layout_cost` divides wire bytes by
-the static `ICI_BW_BY_CHIP` / `DCN_BW_BYTES` tables and nothing ever
-measures them. This module closes that loop:
+Every comm number the planner prices today is analytic:
+`cost_model.estimate_layout_cost` divides wire bytes by the static
+`ICI_BW_BY_CHIP` / `DCN_BW_BYTES` tables and nothing ever measures
+them. This module closes that loop:
 
 - **measure_collective / sweep_mesh** — run each mesh collective
   (psum / all_gather / reduce_scatter / all_to_all / ppermute, per mesh
-  axis, payloads swept log2 from 256 KiB to 256 MiB) under the
-  kernel_obs discipline: AOT ``lower().compile()`` timed separately,
-  warmup, then median-of-k ``block_until_ready`` samples against an
-  injectable clock.
+  axis, payloads swept log2 from 256 KiB to 256 MiB): AOT
+  ``lower().compile()`` timed separately, warmup, then median-of-k
+  ``block_until_ready`` samples against an injectable clock.
 - **attribution** — place each measurement as an achieved-bandwidth
   fraction against the SAME peak tables the planner prices with
   (`cost_model.ICI_BW_BY_CHIP` / `DCN_BW_BYTES` — one source for
@@ -20,13 +18,12 @@ measures them. This module closes that loop:
   wire bytes from `analysis/comm_audit`'s fraction convention so the
   harness and the jaxpr auditor can never disagree about what a
   collective moves. CPU backends have no entry in the peak tables, so
-  bw_frac / predicted_ms are None there — no roofline, no drift to
-  judge (the kernel_obs exemption rule).
+  bw_frac / predicted_ms are None there — no roofline, no drift to judge.
 - **CommDB** — a JSON file at a path the caller names: best-known
   latency per (op, axis-size, payload, backend) key, rolled forward
-  only by ``commlab --db PATH --update-db`` with the KernelDB
-  keep-best / refuse-non-finite semantics. A measured collective
-  drifting a multiplicative band BELOW its DB row fires the
+  only by ``commlab --db PATH --update-db``, keeping the best row and
+  refusing non-finite ones. A measured collective drifting a
+  multiplicative band BELOW its DB row fires the
   `comm_bw_degraded` rule (telemetry/health.py); the DB reference rides
   ON the record (db_ms) so in-flight and offline replays judge
   identically.
@@ -94,8 +91,7 @@ def payload_sweep(min_bytes=PAYLOAD_MIN_BYTES, max_bytes=PAYLOAD_MAX_BYTES):
 
 def db_key(op, axis_size, payload_bytes, backend):
     """``op|ax<n>|<payload_bytes>|<backend>`` — the DB's primary key:
-    the (op, axis-size, payload, backend) identity of one measurement,
-    mirroring kernel_obs.db_key's kernel|sig|dtype|backend."""
+    the (op, axis-size, payload, backend) identity of one measurement."""
     return f"{op}|ax{int(axis_size)}|{int(payload_bytes)}|{backend}"
 
 
@@ -227,7 +223,7 @@ def sweep_program(op, axis, mesh, payload_bytes, dtype=np.float32):
 
 
 # ---------------------------------------------------------------------------
-# measurement harness (the kernel_obs timing discipline)
+# measurement harness
 # ---------------------------------------------------------------------------
 
 class CommMeasureResult:
@@ -395,7 +391,7 @@ def rank_step_skew(records):
 
 
 # ---------------------------------------------------------------------------
-# persistent measurement DB (the KernelDB contract)
+# persistent measurement DB
 # ---------------------------------------------------------------------------
 
 def _finite(v):

@@ -21,8 +21,8 @@ Three pieces:
 
 - **Anomaly detector** (`AnomalyDetector`) — rolling-window z-score
   rules over the fetched stats and/or recorded step JSONL: hard NaN/Inf
-  (`nan`), `loss_spike`, `grad_explosion`, `step_time_regression`,
-  plus `phase_error` for failed bench phases. The same rules run
+  (`nan`), `loss_spike`, `grad_explosion`, `step_time_regression`
+  and the record-kind rules listed on the class. The same rules run
   in-flight (HealthMonitor) and offline (tools/healthwatch.py replays a
   metrics JSONL), so what pages you in production is exactly what CI
   gates on.
@@ -92,16 +92,6 @@ class HealthConfig:
     flops_drift_tol   relative drift between a compile record's
                       cost.flops and its analytic_flops (the peak-FLOPs
                       table MFU claims ride on) that fires `flops_drift`
-    kernel_drift_tol  multiplicative tolerance between a kernelbench
-                      record's measured kernel_ms and its roofline-
-                      predicted predicted_ms (telemetry/kernel_obs):
-                      `kernel_time_drift` fires when the ratio leaves
-                      [1/(1+tol), 1+tol] — symmetric in log space so
-                      BOTH directions are reachable (slower: the
-                      kernel lost its roofline position; faster than
-                      the roofline floor: the KN503 counts the
-                      prediction rides on are inflated). Latched per
-                      kernel.
     comm_bw_tol       multiplicative tolerance between a commbench
                       record's measured time_ms and its best-known DB
                       latency db_ms (telemetry/comm_obs.CommDB):
@@ -167,7 +157,7 @@ class HealthConfig:
                  z_loss=8.0, z_grad=8.0, z_step_time=8.0,
                  rel_step_time=1.5, storm_compiles=5, storm_window_steps=32,
                  hbm_drift_tol=0.15, flops_drift_tol=0.25,
-                 kernel_drift_tol=3.0, comm_bw_tol=1.0,
+                 comm_bw_tol=1.0,
                  straggler_rel=0.5, straggler_abs_ms=10.0,
                  ckpt_stall_s=300.0, tail_cause_frac=0.6,
                  tail_cause_count=4, hbm_pressure_frac=0.92,
@@ -191,7 +181,6 @@ class HealthConfig:
         self.storm_window_steps = int(storm_window_steps)
         self.hbm_drift_tol = float(hbm_drift_tol)
         self.flops_drift_tol = float(flops_drift_tol)
-        self.kernel_drift_tol = float(kernel_drift_tol)
         self.comm_bw_tol = float(comm_bw_tol)
         self.straggler_rel = float(straggler_rel)
         self.straggler_abs_ms = float(straggler_abs_ms)
@@ -290,8 +279,6 @@ class AnomalyDetector:
                            rel_step_time x window median; records with
                            compile_ms > 0 are exempt (recompiles are
                            legitimately slow) and never enter the window
-    - phase_error          a bench phase record carrying an 'error' key
-                           or a non-finite metric value
     - recompile_storm      compile records (kind='compile',
                            telemetry.compile_obs): storm_compiles
                            RECOMPILES (n_compiles > 1 — first compiles
@@ -404,10 +391,6 @@ class AnomalyDetector:
         """Judge one record; returns [Anomaly, ...] ([] == healthy)."""
         self._n += 1
         rec = record or {}
-        if rec.get("kind") == "phase":
-            found = self._observe_phase(rec)
-            self.anomalies.extend(found)
-            return found
         if rec.get("kind") == "compile":
             found = self._observe_compile(rec)
             self.anomalies.extend(found)
@@ -418,10 +401,6 @@ class AnomalyDetector:
             return found
         if rec.get("kind") == "reqtrace":
             found = self._observe_reqtrace(rec)
-            self.anomalies.extend(found)
-            return found
-        if rec.get("kind") == "kernelbench":
-            found = self._observe_kernelbench(rec)
             self.anomalies.extend(found)
             return found
         if rec.get("kind") == "commbench":
@@ -487,23 +466,6 @@ class AnomalyDetector:
             if a:
                 found.append(a)
         self.anomalies.extend(found)
-        return found
-
-    def _observe_phase(self, rec):
-        name = rec.get("phase", "?")
-        found = []
-        metrics = rec.get("metrics") or {}
-        if "error" in metrics or "error" in rec:
-            found.append(Anomaly(
-                "phase_error", name, None,
-                f"phase {name!r} recorded an error: "
-                f"{metrics.get('error') or rec.get('error')}"))
-        bad = [k for k, v in metrics.items()
-               if isinstance(v, float) and not math.isfinite(v)]
-        if bad:
-            found.append(Anomaly(
-                "phase_error", name, None,
-                f"phase {name!r} carries non-finite metric(s): {bad}"))
         return found
 
     def _observe_compile(self, rec):
@@ -580,52 +542,6 @@ class AnomalyDetector:
                     f"{float(analytic):.3e} the MFU accounting assumes "
                     f"(tolerance {c.flops_drift_tol * 100:.0f}%)",
                     expected=analytic, z=round(drift, 3)))
-        return found
-
-    def _observe_kernelbench(self, rec):
-        """The kernel_time_drift rule over one kernel-observatory
-        measurement record (telemetry/kernel_obs via tools/kernellab):
-        measured kernel_ms vs the roofline-predicted predicted_ms,
-        latched per kernel like the compile drift rules — a drifting
-        kernel fires once (a sweep measures it at many shapes — one
-        page, not N) and re-arms only after a measurement comes back
-        inside tolerance. Records without predicted_ms (CPU backends,
-        where the peak tables answer None) are exempt: no roofline, no
-        drift to judge. Same records in flight and offline
-        (tools/healthwatch.py, kernellab --selfcheck), so replays
-        agree."""
-        c = self.config
-        found = []
-        kernel = rec.get("kernel", "?")
-        measured = rec.get("kernel_ms")
-        predicted = rec.get("predicted_ms")
-        if not isinstance(measured, (int, float)) or measured <= 0 \
-                or not isinstance(predicted, (int, float)) \
-                or predicted <= 0:
-            return found
-        # Multiplicative band: relative drift is bounded below by -1,
-        # so a subtractive |drift| > tol test with tol >= 1 could NEVER
-        # fire in the too-fast direction. The ratio test is symmetric
-        # in log space and both sides stay reachable at any tolerance.
-        ratio = float(measured) / float(predicted)
-        band = 1.0 + c.kernel_drift_tol
-        if 1.0 / band <= ratio <= band:
-            self._drift_latched.discard(("kernel_time_drift", kernel))
-        elif ("kernel_time_drift", kernel) not in self._drift_latched:
-            self._drift_latched.add(("kernel_time_drift", kernel))
-            if ratio > band:
-                side = (f"{ratio:.1f}x slower than")
-            else:
-                side = (f"{1.0 / ratio:.1f}x faster than")
-            found.append(Anomaly(
-                "kernel_time_drift", rec.get("step", self._n - 1),
-                float(measured),
-                f"{kernel}: measured {float(measured):.3f} ms is "
-                f"{side} the roofline-predicted "
-                f"{float(predicted):.3f} ms (band {1.0 / band:.2f}x"
-                f"–{band:.2f}x) — the KN503 counts or the peak tables "
-                "no longer describe this kernel",
-                expected=predicted, z=round(ratio, 3)))
         return found
 
     def _observe_commbench(self, rec):

@@ -1,13 +1,12 @@
 """Memory observatory: live HBM ledger, KV occupancy telemetry, OOM
 forensics, and the admission-headroom gauge.
 
-The memory sibling of the compile (compile_obs), kernel (kernel_obs)
-and mesh (comm_obs) observatories. Those three close the loop on what
-the chip COMPILES, COMPUTES and MOVES; until now nothing closed it on
-what the chip HOLDS: `compile_obs` captures only the static
-``memory_analysis()`` projection, the serving BlockPool's occupancy
-never reaches /metrics, and an allocation failure kills the process
-with no forensic record. This module is the live side:
+The memory sibling of the compile (compile_obs) and mesh (comm_obs)
+observatories. Those close the loop on what the chip COMPILES and
+MOVES; until now nothing closed it on what the chip HOLDS:
+`compile_obs` captures only the static ``memory_analysis()``
+projection, the serving BlockPool's occupancy never reaches /metrics,
+and an allocation failure kills the process with no forensic record. This module is the live side:
 
 - **ledger** — `snapshot_ledger` walks ``jax.live_arrays()`` and
   attributes every live byte into exactly one bucket (params /

@@ -21,18 +21,6 @@ PEAK_FLOPS_BY_KIND = {
     "v6 lite": 918e12, "v6e": 918e12,
 }
 
-# peak HBM bandwidth (bytes/s) per chip by device kind substring — the
-# second axis of the roofline the kernel observatory
-# (telemetry/kernel_obs.py) places measured kernels on; same
-# longest-substring keying as the FLOPs table so the two can never
-# disagree about which chip they describe
-PEAK_HBM_BW_BY_KIND = {
-    "v2": 700e9, "v3": 900e9, "v4": 1228e9,
-    "v5 lite": 819e9, "v5e": 819e9, "v5p": 2765e9,
-    "v6 lite": 1638e9, "v6e": 1638e9,
-}
-
-
 def _match_kind(table, kind):
     """Longest-substring lookup of a device-kind string. kind=None
     reads the live default device, and there an accelerator with no
@@ -61,13 +49,6 @@ def device_peak_flops(kind=None):
     device and raises for an accelerator the table does not list.
     Returns None on CPU backends — 'MFU not computable'."""
     return _match_kind(PEAK_FLOPS_BY_KIND, kind)
-
-
-def device_peak_hbm_bw(kind=None):
-    """Peak HBM bandwidth (bytes/s) for a device-kind string, same
-    matching rules as device_peak_flops. None when unknown (CPU) —
-    the roofline's bandwidth fraction is then not computable."""
-    return _match_kind(PEAK_HBM_BW_BY_KIND, kind)
 
 
 def model_flops_per_token(n_params, num_layers=0, hidden_size=0, seq_len=0):

@@ -653,90 +653,6 @@ def make_thread_lint_record(source, findings=(), edges=(), rank=0,
     return rec
 
 
-# required keys of a kernel-observatory measurement record
-# (telemetry/kernel_obs via tools/kernellab.py); optional: dtype,
-# fallback_ms, speedup, compile_ms, flops, bytes_accessed, flops_frac,
-# bw_frac, predicted_ms, bound, config, db_key, n_samples, warmup,
-# event, seed
-KERNELBENCH_RECORD_KEYS = ("schema", "kind", "rank", "kernel", "sig",
-                           "backend", "kernel_ms")
-
-# what one kernelbench record may claim to be (cross-checked by
-# tools/trace_check.py: a db_update must reference a measured row)
-KERNELBENCH_EVENTS = ("measure", "tune", "db_update")
-
-
-def make_kernelbench_record(kernel, sig, backend, kernel_ms, rank=0,
-                            dtype=None, fallback_ms=None, speedup=None,
-                            compile_ms=None, flops=None,
-                            bytes_accessed=None, flops_frac=None,
-                            bw_frac=None, predicted_ms=None, bound=None,
-                            config=None, db_key=None, n_samples=None,
-                            warmup=None, event=None, seed=None, **extra):
-    """One measured kernel data point as a first-class typed record
-    (kind='kernelbench') — the dynamic sibling of kind='kernel_lint':
-    the Kernel Doctor records what a kernel IS, the observatory records
-    how fast it RAN. `sig` + `dtype` + `backend` reproduce the DB key
-    (telemetry/kernel_obs.db_key); `kernel_ms` is the compile-excluded
-    execute median (compile_ms rides separately, the PR-4 split);
-    roofline fractions are achieved/peak in [0, 1]; `predicted_ms` is
-    the roofline floor the kernel_time_drift rule judges against.
-    Non-finite timings become None + an error note — the validators
-    fail them loudly rather than letting a NaN ride the ledger."""
-    def _clean(v):
-        if v is None:
-            return None, False
-        bad = isinstance(v, float) and (v != v or v in (float("inf"),
-                                                        float("-inf")))
-        return (None if bad else float(v)), bad
-
-    kernel_ms, bad = _clean(kernel_ms)
-    rec = {
-        "schema": SCHEMA_VERSION,
-        "kind": "kernelbench",
-        "rank": int(rank),
-        "kernel": str(kernel),
-        "sig": str(sig),
-        "backend": str(backend),
-        "kernel_ms": kernel_ms,
-    }
-    if bad:
-        rec["error"] = "non-finite kernel_ms"
-    if dtype is not None:
-        rec["dtype"] = str(dtype)
-    for key, v in (("fallback_ms", fallback_ms), ("speedup", speedup),
-                   ("compile_ms", compile_ms),
-                   ("flops_frac", flops_frac), ("bw_frac", bw_frac),
-                   ("predicted_ms", predicted_ms)):
-        v, bad = _clean(v)
-        if v is not None:
-            rec[key] = v
-        elif bad:
-            rec["error"] = f"non-finite {key}"
-    if flops is not None:
-        rec["flops"] = int(flops)
-    if bytes_accessed is not None:
-        rec["bytes_accessed"] = int(bytes_accessed)
-    if bound is not None:
-        rec["bound"] = str(bound)
-    if config is not None:
-        rec["config"] = dict(config)
-    if db_key is not None:
-        rec["db_key"] = str(db_key)
-    if n_samples is not None:
-        rec["n_samples"] = int(n_samples)
-    if warmup is not None:
-        rec["warmup"] = int(warmup)
-    if event is not None:
-        rec["event"] = str(event)
-    if seed is not None:
-        rec["seed"] = int(seed)
-    for k, v in extra.items():
-        if v is not None:
-            rec[k] = v
-    return rec
-
-
 # required keys of a mesh-observatory measurement record
 # (telemetry/comm_obs via tools/commlab.py); optional: compile_ms,
 # wire_bytes, achieved_bw, peak_bw, bw_frac, predicted_ms, db_ms,
@@ -762,9 +678,7 @@ def make_commbench_record(op, axis, axis_size, payload_bytes, backend,
                           db_key=None, medium=None, n_samples=None,
                           warmup=None, event=None, seed=None, **extra):
     """One measured collective data point as a first-class typed record
-    (kind='commbench') — the communication sibling of kind='kernelbench':
-    the kernel observatory measures what one chip computes, the mesh
-    observatory measures what the mesh moves. `op` + `axis_size` +
+    (kind='commbench'): what the mesh moves. `op` + `axis_size` +
     `payload_bytes` + `backend` reproduce the DB key
     (telemetry/comm_obs.db_key); `time_ms` is the compile-excluded
     execute median (compile_ms rides separately); `achieved_bw` /
@@ -773,8 +687,8 @@ def make_commbench_record(op, axis, axis_size, payload_bytes, backend,
     `calibration_from_comm_records` ratios against; `db_ms` is the
     best-known DB latency the comm_bw_degraded rule judges against
     (absent when the measurement was given no DB — no reference, no
-    jurisdiction). Non-finite timings become None + an error note, like
-    make_kernelbench_record — a NaN never rides the ledger silently."""
+    jurisdiction). Non-finite timings become None + an error note — a
+    NaN never rides the ledger silently."""
     def _clean(v):
         if v is None:
             return None, False
@@ -997,22 +911,6 @@ def make_plan_record(model, chosen, candidates_considered,
     return rec
 
 
-def make_phase_record(phase, metrics, rank=0):
-    """A phase record: same envelope, kind='phase', the phase's metric
-    dict under 'metrics'. Non-finite floats become None —
-    json.dumps would otherwise emit bare NaN/Infinity tokens, which are
-    invalid for strict JSON consumers (jq, Chrome)."""
-    clean = {}
-    for k, v in (metrics or {}).items():
-        if isinstance(v, float) and (v != v or v in (float("inf"),
-                                                     float("-inf"))):
-            clean[k] = None
-        elif isinstance(v, (int, float)) or v is None or isinstance(v, str):
-            clean[k] = v
-    return {"schema": SCHEMA_VERSION, "kind": "phase", "rank": int(rank),
-            "phase": str(phase), "metrics": clean}
-
-
 class JsonlSink:
     """Append-only JSONL metrics file, one record per line. Thread-safe.
 
@@ -1106,11 +1004,6 @@ def validate_step_record(rec):
     if not isinstance(rec, dict):
         return [f"record is {type(rec).__name__}, not dict"]
     kind = rec.get("kind")
-    if kind == "phase":
-        for key in ("schema", "phase", "metrics"):
-            if key not in rec:
-                problems.append(f"phase record missing '{key}'")
-        return problems
     if kind == "compile":
         for key in COMPILE_RECORD_KEYS:
             if key not in rec:
@@ -1231,46 +1124,6 @@ def validate_step_record(rec):
                         problems.append(
                             f"lock row {j} ({row.get('name')}) carries "
                             "no integer 'acquires' count")
-        return problems
-    if kind == "kernelbench":
-        for key in KERNELBENCH_RECORD_KEYS:
-            if key not in rec:
-                problems.append(f"kernelbench record missing '{key}'")
-        if not str(rec.get("kernel", "")).strip():
-            problems.append("kernelbench record names no kernel")
-        for key in ("kernel_ms", "fallback_ms", "compile_ms",
-                    "predicted_ms"):
-            v = rec.get(key)
-            if v is not None and (not isinstance(v, (int, float))
-                                  or v != v or v < 0):
-                problems.append(
-                    f"'{key}' not a non-negative number: {v!r}")
-        if rec.get("kernel_ms") is None and "error" not in rec:
-            problems.append("kernelbench record with null kernel_ms "
-                            "carries no 'error' note")
-        for key in ("flops_frac", "bw_frac"):
-            v = rec.get(key)
-            if v is not None and (not isinstance(v, (int, float))
-                                  or v != v or not 0.0 <= v <= 1.0):
-                problems.append(
-                    f"'{key}' not a roofline fraction in [0, 1]: {v!r}")
-        v = rec.get("speedup")
-        if v is not None and (not isinstance(v, (int, float))
-                              or v != v or v <= 0):
-            problems.append(f"'speedup' not a positive number: {v!r}")
-        for key in ("flops", "bytes_accessed", "n_samples", "warmup"):
-            v = rec.get(key)
-            if v is not None and (not isinstance(v, int) or v < 0):
-                problems.append(
-                    f"'{key}' not a non-negative int: {v!r}")
-        b = rec.get("bound")
-        if b is not None and b not in ("compute", "memory"):
-            problems.append(f"'bound' not 'compute'/'memory': {b!r}")
-        ev = rec.get("event")
-        if ev is not None and ev not in KERNELBENCH_EVENTS:
-            problems.append(f"unknown kernelbench event {ev!r} "
-                            f"(expected one of "
-                            f"{list(KERNELBENCH_EVENTS)})")
         return problems
     if kind == "commbench":
         for key in COMMBENCH_RECORD_KEYS:

@@ -440,7 +440,8 @@ def test_straggler_exemptions():
 def test_rank_step_skew_offline():
     recs = [_step_rec(0, 0, 100.0), _step_rec(0, 1, 160.0),
             _step_rec(1, 0, 90.0),                       # single rank
-            {"kind": "phase", "phase": "x", "metrics": {}}]
+            {"kind": "compile", "step": 0, "rank": 2, "fn": "x",
+             "compile_ms": 500.0}]
     skew = comm_obs.rank_step_skew(recs)
     assert skew == {0: {0: 0.0, 1: 60.0}}
 

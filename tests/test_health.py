@@ -225,19 +225,6 @@ def test_detector_nan_hard_rule_and_window_isolation():
     assert len(det._loss) == 4
 
 
-def test_detector_phase_records():
-    det = AnomalyDetector(HealthConfig(action="record"))
-    det.observe({"kind": "phase", "phase": "ok",
-                 "metrics": {"tokens_per_sec": 100.0}})
-    assert det.anomalies == []
-    det.observe({"kind": "phase", "phase": "broken",
-                 "metrics": {"error": "boom", "mfu": 0.0}})
-    det.observe({"kind": "phase", "phase": "nonfinite",
-                 "metrics": {"mfu": float("nan")}})
-    assert [a.kind for a in det.anomalies] == ["phase_error",
-                                               "phase_error"]
-
-
 def test_anomaly_to_dict_json_safe():
     a = Anomaly("nan", 3, float("nan"), "boom")
     json.dumps(a.to_dict())   # non-finite value must not break dumps
@@ -625,7 +612,8 @@ def test_healthwatch_specimen_selfcheck(capsys):
                  "step_time_regression"):
         assert f"[{kind}]" in text
     # a family the specimen can't produce fails the selfcheck
-    rc, _ = _healthwatch_main([spec, "--expect", "phase_error"], capsys)
+    rc, _ = _healthwatch_main([spec, "--expect", "comm_bw_degraded"],
+                              capsys)
     assert rc == 9
 
 

@@ -415,7 +415,7 @@ def test_registry_covers_every_pallas_site():
     assert len(regs) >= 12
     assert {"flash_fwd_tri", "flash_bwd_merged_tri", "paged_decode",
             "decode_fused", "int8_matvec", "moe_gather", "moe_combine",
-            "layernorm_fused"} <= set(regs.names())
+            "gdn_chunk"} <= set(regs.names())
     swept = kernel_lint.pallas_site_functions(root)
     registered_fns = {r.fn_name for r in regs}
     assert set(swept) == registered_fns, (

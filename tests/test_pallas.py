@@ -139,83 +139,6 @@ def test_triangle_grid_backward_long_context_default_blocks():
             (name, np.abs(np.asarray(a) - np.asarray(b_)).max())
 
 
-def test_fused_add_layer_norm_matches_composed():
-    """Pallas fused residual+LN (interpret on CPU via the composed-path
-    equivalence + direct kernel run) matches LN(x+res) fwd and grads."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops import pallas_layernorm as pln
-
-    rs = np.random.RandomState(0)
-    rows, d = 256, 128
-    x = jnp.asarray(rs.randn(rows, d), jnp.float32)
-    res = jnp.asarray(rs.randn(rows, d), jnp.float32)
-    w = jnp.asarray(rs.rand(d) + 0.5, jnp.float32)
-    b = jnp.asarray(rs.randn(d), jnp.float32)
-
-    def composed(xx, rr, ww, bb):
-        s = xx + rr
-        mean = jnp.mean(s, -1, keepdims=True)
-        var = jnp.mean((s - mean) ** 2, -1, keepdims=True)
-        return (s - mean) * jax.lax.rsqrt(var + 1e-5) * ww + bb
-
-    # interpret-mode run of the actual kernel
-    from jax.experimental import pallas as pl
-    import functools as ft
-    out, ssum, rstd = pl.pallas_call(
-        ft.partial(pln._fwd_kernel, eps=1e-5),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, d), lambda i: (i, 0)),
-                  pl.BlockSpec((d,), lambda i: (0,)),
-                  pl.BlockSpec((d,), lambda i: (0,))],
-        out_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0)),
-                   pl.BlockSpec((rows, d), lambda i: (i, 0)),
-                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, d), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, d), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
-        interpret=True,
-    )(x, res, w, b)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(composed(x, res, w, b)),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(ssum), np.asarray(x + res),
-                               rtol=1e-6)
-
-    # custom-vjp backward vs jax.grad of the composed fn (the vjp reuses
-    # the saved sum, so run it against the composed loss directly)
-    def loss_c(xx, rr, ww, bb):
-        return jnp.sum(composed(xx, rr, ww, bb) ** 2)
-
-    gc = jax.grad(loss_c, argnums=(0, 1, 2, 3))(x, res, w, b)
-    out_c = composed(x, res, w, b)
-    gd = 2 * out_c
-    dx, dres, dw, db = pln._vjp_bwd(1e-5, (x + res, (1.0 / jnp.sqrt(
-        jnp.var(x + res, -1, keepdims=True) + 1e-5)), w), gd)
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(gc[0]),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(gc[2]),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(db), np.asarray(gc[3]),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_add_layer_norm_dispatcher_cpu_path():
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas_layernorm import add_layer_norm
-    rs = np.random.RandomState(1)
-    x = jnp.asarray(rs.randn(8, 16), jnp.float32)
-    r = jnp.asarray(rs.randn(8, 16), jnp.float32)
-    w = jnp.ones((16,), jnp.float32)
-    b = jnp.zeros((16,), jnp.float32)
-    out = add_layer_norm(x, r, w, b)        # CPU: composed path
-    s = np.asarray(x + r)
-    ref = (s - s.mean(-1, keepdims=True)) / np.sqrt(
-        s.var(-1, keepdims=True) + 1e-5)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
-
-
 def _flat_case(seed, bn, s, h, dtype):
     """q, k, v and an upstream gradient in the kernels' flat [BN, S, H]
     layout, q pre-scaled as the callers hand it over."""

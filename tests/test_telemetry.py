@@ -215,19 +215,6 @@ def test_telemetry_callback_model_fit(tmp_path):
     json.load(open(tpath))
 
 
-def test_phase_record_schema():
-    """Phase records validate under the same schema; non-finite
-    metric values must not leak bare NaN/Infinity into the JSONL."""
-    rec = telemetry.make_phase_record(
-        "gpt3_125m_train", {"tokens_per_sec": 1000.0, "mfu": 0.5})
-    assert telemetry.validate_step_record(rec) == []
-    assert rec["kind"] == "phase" and rec["schema"] == 1
-    bad = telemetry.make_phase_record(
-        "x", {"mfu": float("nan"), "tflops": float("inf"), "ok": 1.0})
-    assert bad["metrics"] == {"mfu": None, "tflops": None, "ok": 1.0}
-    json.loads(json.dumps(bad, allow_nan=False))   # strict-JSON clean
-
-
 def test_unlisted_accelerator_kind_is_an_error(monkeypatch):
     """The live device's peak: None on the CPU, an error (not a silent
     MFU of 0) for an accelerator the table does not list."""

@@ -98,14 +98,6 @@ JAX_PLATFORMS=cpu python tools/autoshard.py --selfcheck
 # standalone astlint run above), and the emitted kind=kernel_lint
 # records must validate under tools/trace_check.py
 JAX_PLATFORMS=cpu python tools/kerneldoctor.py --selfcheck
-# kernel lab gate (tools/kernellab.py over telemetry/kernel_obs.py),
-# the doctor's MEASURED sibling, same two-sided pattern: the drift
-# specimen (tools/specimens/kernelbench_drift.jsonl) must trip the
-# kernel_time_drift anomaly BY NAME in both directions through the
-# real AnomalyDetector, a clean measurement run over every registered
-# kernel must validate under trace_check and stay quiet, and the
-# timing DB must refuse non-finite rows and round-trip losslessly
-JAX_PLATFORMS=cpu python tools/kernellab.py --selfcheck
 # concurrency doctor gate (tools/threaddoctor.py over paddle_tpu/
 # analysis/threadlint.py + lockwatch.py), the doctor pattern applied
 # to the host-side threaded runtime: the checked-in broken specimens
@@ -119,12 +111,12 @@ JAX_PLATFORMS=cpu python tools/kernellab.py --selfcheck
 # kind=thread_lint records must validate under tools/trace_check.py
 # including the observed-subset-of-static cross-rule
 JAX_PLATFORMS=cpu python tools/threaddoctor.py --selfcheck
-# comm lab gate (tools/commlab.py over telemetry/comm_obs.py), the
-# kernel-lab pattern applied to the mesh: the checked-in degraded
-# specimen (tools/specimens/commbench_degraded.jsonl) must trip the
-# comm_bw_degraded anomaly BY NAME through the real AnomalyDetector
-# while its in-band and reference-free rows stay silent, a clean sweep
-# over every (op, size>1 axis) of the dp=2,mp=4 mesh must validate
+# comm lab gate (tools/commlab.py over telemetry/comm_obs.py): the
+# checked-in degraded specimen (tools/specimens/commbench_degraded.jsonl)
+# must trip the comm_bw_degraded anomaly BY NAME through the real
+# AnomalyDetector while its in-band and reference-free rows stay
+# silent, a clean sweep over every (op, size>1 axis) of the dp=2,mp=4
+# mesh must validate
 # under trace_check AND pass the comm_audit wire-byte honesty leg
 # (claimed bytes vs a re-trace of the same sweep program), and the
 # comm DB must refuse non-finite rows and round-trip losslessly
@@ -141,11 +133,6 @@ JAX_PLATFORMS=cpu python tools/commlab.py --selfcheck
 JAX_PLATFORMS=cpu python tools/memwatch.py --selfcheck
 
 stage "[4/10] observatory smokes + health/compile specimens"
-# kernel-lab smoke (tools/kernellab.py --smoke): every registered
-# Pallas kernel run once against its declared fallback on the SAME
-# inputs, the kind=kernelbench records gated through trace_check and
-# the kernel_time_drift rule inside the tool (exit 13 on any finding)
-JAX_PLATFORMS=cpu python tools/kernellab.py --smoke
 # comm-lab smoke (tools/commlab.py --smoke): every shard_map collective
 # over every size>1 axis of the dp=2,mp=4 mesh, the kind=commbench
 # records gated through trace_check AND the comm_audit wire-byte leg

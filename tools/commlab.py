@@ -2,12 +2,10 @@
 """Comm Lab CLI: measured collective latencies + the persistent comm
 database over the live mesh (paddle_tpu/telemetry/comm_obs).
 
-The MESH sibling of tools/kernellab.py: the kernel lab measures what
-one chip computes, the comm lab measures what the mesh moves. Every
-sweep point runs one shard_map collective (psum / all_gather /
-reduce_scatter / all_to_all / ppermute) over one size>1 mesh axis at
-one payload rung under the kernel-observatory timing discipline —
-AOT lower/compile timed separately, warmup, median-of-k
+The comm lab measures what the mesh moves. Every sweep point runs one
+shard_map collective (psum / all_gather / reduce_scatter / all_to_all /
+ppermute) over one size>1 mesh axis at one payload rung — AOT
+lower/compile timed separately, warmup, median-of-k
 ``block_until_ready`` — then lands as a typed kind=commbench record
 attributed against the planner's `ICI_BW_BY_CHIP` / `DCN_BW_BYTES`
 peaks (achieved-bandwidth fraction; None on CPU where no peak exists).
@@ -197,7 +195,7 @@ def run_smoke(args, trace_check):
 
 
 def run_selfcheck():
-    """Proof the lab works (the kernellab --selfcheck pattern): the
+    """Proof the lab works: the
     degraded specimen must trip the rule by name while its in-band and
     reference-free rows stay silent, the clean sweep must validate +
     audit + stay quiet, and the DB must hold its refuse-non-finite

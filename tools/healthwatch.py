@@ -18,8 +18,7 @@ exactly what CI gates on. Two modes:
 Step records (kind=step) run the rolling-window rules (NaN/Inf, loss
 spike, grad explosion, step-time regression — compile steps exempt)
 plus the per-rank straggler rule (step-boundary skew across ranks of
-the same step); phase records (kind=phase) are checked
-for recorded errors and non-finite metrics; checkpoint records
+the same step); checkpoint records
 (kind=ckpt, paddle_tpu.resilience) run the checkpoint_failed /
 checkpoint_stall rules; mesh-observatory records (kind=commbench,
 telemetry/comm_obs) run the comm_bw_degraded rule against the DB
@@ -53,7 +52,7 @@ sys.path.insert(0, REPO)
 
 def analyze_file(path, config):
     """Replay one JSONL through a fresh detector. Returns (anomalies,
-    n_step, n_phase, problems)."""
+    n_step, problems)."""
     from paddle_tpu.telemetry.health import AnomalyDetector
     from paddle_tpu.telemetry.sink import read_jsonl
 
@@ -61,18 +60,16 @@ def analyze_file(path, config):
     try:
         records = read_jsonl(path)
     except (OSError, json.JSONDecodeError) as e:
-        return [], 0, 0, [f"{path}: unreadable: {e}"]
+        return [], 0, [f"{path}: unreadable: {e}"]
     if not records:
         # same stance as trace_check: a file nothing ever wrote must
         # not green-light the run it claims to describe
-        return [], 0, 0, [f"{path}: no records — telemetry never wrote"]
+        return [], 0, [f"{path}: no records — telemetry never wrote"]
     det = AnomalyDetector(config)
-    n_step = n_phase = 0
+    n_step = 0
     for rec in records:
         kind = rec.get("kind") if isinstance(rec, dict) else None
-        if kind == "phase":
-            n_phase += 1
-        elif kind == "step":
+        if kind == "step":
             n_step += 1
         elif kind == "ckpt":
             # checkpoint-lifecycle records (paddle_tpu.resilience):
@@ -104,7 +101,7 @@ def analyze_file(path, config):
         else:
             continue
         det.observe(rec)
-    return det.anomalies, n_step, n_phase, problems
+    return det.anomalies, n_step, problems
 
 
 def main(argv=None):
@@ -149,19 +146,18 @@ def main(argv=None):
     all_anoms, all_problems = [], []
     per_file = {}
     for path in args.paths:
-        anoms, n_step, n_phase, problems = analyze_file(path, config)
+        anoms, n_step, problems = analyze_file(path, config)
         if keep is not None:
             anoms = [a for a in anoms if a.kind in keep]
         all_anoms += anoms
         all_problems += problems
         per_file[path] = {
-            "n_step_records": n_step, "n_phase_records": n_phase,
+            "n_step_records": n_step,
             "anomalies": [a.to_dict() for a in anoms],
             "problems": problems,
         }
         tag = f"{len(anoms)} finding(s)" if anoms else "clean"
-        print(f"healthwatch: {path}: {n_step} step + {n_phase} phase "
-              f"record(s), {tag}")
+        print(f"healthwatch: {path}: {n_step} step record(s), {tag}")
         for a in anoms:
             print(f"  [{a.kind}] {a.message}")
         for p in problems:

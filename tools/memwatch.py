@@ -2,8 +2,7 @@
 """Memory watch CLI: render, gate and replay the memory observatory's
 HBM ledger (paddle_tpu/telemetry/mem_obs, kind=memsnap records).
 
-The memory sibling of tools/compile_report.py / kernellab.py /
-commlab.py: the compile observatory projects what a program SHOULD
+The memory sibling of tools/compile_report.py / commlab.py: the compile observatory projects what a program SHOULD
 hold (static ``memory_analysis()``), this tool reads what the process
 ACTUALLY held — the live-array ledger bucketed into params / opt_state
 / kv / workspace / other, the KV-pool block census, and the OOM

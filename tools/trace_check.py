@@ -2,8 +2,8 @@
 """Validate a telemetry metrics JSONL + Chrome trace pair.
 
 CI gate for the flight-recorder schema (paddle_tpu/telemetry): checks
-that every JSONL record parses and carries the required step/phase
-fields with finite values, that the Chrome trace is valid trace JSON
+that every JSONL record parses and carries the required fields of its
+kind with finite values, that the Chrome trace is valid trace JSON
 (traceEvents with ph/ts/dur/pid), and — when both are given — that the
 trace's step spans are consistent with the JSONL step count. Used by
 tests/test_telemetry.py and runnable standalone:
@@ -24,8 +24,8 @@ def check_metrics_jsonl(path):
     """Returns (n_records, n_step_records, n_compile_records,
     n_ckpt_records, n_plan_records, n_elastic_records,
     n_serving_records, n_kernel_records, n_reqtrace_records,
-    n_kernelbench_records, n_thread_lint_records, n_commbench_records,
-    n_memsnap_records, n_fleet_records, problems). Positional
+    n_thread_lint_records, n_commbench_records, n_memsnap_records,
+    n_fleet_records, problems). Positional
     consumers should
     prefer check_pair's named stats dict — this tuple GROWS when a new
     record kind lands (kerneldoctor's selfcheck was silently broken by
@@ -40,7 +40,7 @@ def check_metrics_jsonl(path):
     records = []
     try:
         if os.path.getsize(path) == 0:
-            return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
+            return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
                 f"{path}: empty metrics file (0 bytes): no step was "
                 "ever recorded"]
         with open(path) as f:
@@ -53,7 +53,7 @@ def check_metrics_jsonl(path):
                 except json.JSONDecodeError as e:
                     problems.append(f"{path}:{i + 1}: not JSON: {e}")
     except OSError as e:
-        return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
+        return 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, [
             f"{path}: unreadable: {e}"]
     if not records:
         problems.append(f"{path}: no records")
@@ -68,7 +68,6 @@ def check_metrics_jsonl(path):
     problems += check_serving_records(records, path)
     problems += check_kernel_records(records, path)
     problems += check_reqtrace_records(records, path)
-    problems += check_kernelbench_records(records, path)
     problems += check_thread_lint_records(records, path)
     problems += check_commbench_records(records, path)
     problems += check_memsnap_records(records, path)
@@ -91,9 +90,6 @@ def check_metrics_jsonl(path):
     n_reqtrace = sum(1 for r in records
                      if isinstance(r, dict)
                      and r.get("kind") == "reqtrace")
-    n_kernelbench = sum(1 for r in records
-                        if isinstance(r, dict)
-                        and r.get("kind") == "kernelbench")
     n_thread_lint = sum(1 for r in records
                         if isinstance(r, dict)
                         and r.get("kind") == "thread_lint")
@@ -106,8 +102,8 @@ def check_metrics_jsonl(path):
     n_fleet = sum(1 for r in records
                   if isinstance(r, dict) and r.get("kind") == "fleet")
     return (len(records), n_steps, n_compiles, n_ckpt, n_plan,
-            n_elastic, n_serving, n_kernel, n_reqtrace, n_kernelbench,
-            n_thread_lint, n_commbench, n_memsnap, n_fleet, problems)
+            n_elastic, n_serving, n_kernel, n_reqtrace, n_thread_lint,
+            n_commbench, n_memsnap, n_fleet, problems)
 
 
 def check_compile_records(records, path):
@@ -689,72 +685,6 @@ def check_reqtrace_records(records, path):
     return problems
 
 
-# speedup must agree with the two timings it claims to summarize
-KERNELBENCH_SPEEDUP_TOL = 0.05
-
-
-def check_kernelbench_records(records, path):
-    """Cross-rules over kernel-observatory measurement records
-    (kind='kernelbench', telemetry/kernel_obs via tools/kernellab.py).
-    The schema basics (non-negative ms, roofline fractions in [0, 1])
-    live in sink.validate_step_record; here the claims that span
-    fields or records:
-
-    - a speedup claim requires BOTH timings (kernel_ms and
-      fallback_ms) and must equal fallback_ms / kernel_ms within 5% —
-      a ratio the ledger cannot reproduce is a doctored row;
-    - a db_update event must reference, by db_key, a measured row
-      (event measure/tune) present in the SAME file — the DB may only
-      roll forward from measurements the ledger shows.
-    """
-    problems = []
-    measured_keys = set()
-    for r in records:
-        if isinstance(r, dict) and r.get("kind") == "kernelbench" \
-                and r.get("event") in (None, "measure", "tune") \
-                and r.get("db_key"):
-            measured_keys.add(r["db_key"])
-    for i, rec in enumerate(records):
-        if not isinstance(rec, dict) or rec.get("kind") != "kernelbench":
-            continue
-        sp = rec.get("speedup")
-        km = rec.get("kernel_ms")
-        fm = rec.get("fallback_ms")
-        if sp is not None:
-            if not isinstance(km, (int, float)) \
-                    or not isinstance(fm, (int, float)):
-                problems.append(
-                    f"{path}:{i + 1}: kernelbench {rec.get('kernel')} "
-                    f"claims speedup {sp} without both timings "
-                    "(kernel_ms and fallback_ms) — a ratio with no "
-                    "numerator or denominator on the ledger")
-            elif km > 0 and isinstance(sp, (int, float)) and sp == sp:
-                want = fm / km
-                if abs(sp - want) > KERNELBENCH_SPEEDUP_TOL \
-                        * max(abs(want), 1e-9):
-                    problems.append(
-                        f"{path}:{i + 1}: kernelbench "
-                        f"{rec.get('kernel')} speedup {sp:.4f} does "
-                        f"not match fallback_ms/kernel_ms = "
-                        f"{want:.4f} — the ratio and its inputs "
-                        "disagree")
-        if rec.get("event") == "db_update":
-            key = rec.get("db_key")
-            if not key:
-                problems.append(
-                    f"{path}:{i + 1}: kernelbench db_update for "
-                    f"{rec.get('kernel')} carries no db_key — an "
-                    "update that references nothing")
-            elif key not in measured_keys:
-                problems.append(
-                    f"{path}:{i + 1}: kernelbench db_update "
-                    f"references db_key {key!r} but no measured "
-                    "(measure/tune) record in this file carries it — "
-                    "the DB may only roll forward from measurements "
-                    "the ledger shows")
-    return problems
-
-
 # how far achieved_bw / bw_frac / predicted_ms may drift from the
 # values recomputable from their own inputs on the same record
 COMMBENCH_DERIVED_TOL = 0.05
@@ -779,7 +709,7 @@ def check_commbench_records(records, path):
       more than twice the operand;
     - a db_update event must reference, by db_key, a measured row in
       the SAME file — the DB may only roll forward from measurements
-      the ledger shows (the kernelbench rule).
+      the ledger shows.
     """
     problems = []
     measured_keys = set()
@@ -1170,15 +1100,14 @@ def check_pair(jsonl_path, trace_path=None):
     valid; stats carries the already-computed counts so callers don't
     re-parse the files."""
     (n_rec, n_steps, n_compiles, n_ckpt, n_plan, n_elastic,
-     n_serving, n_kernel, n_reqtrace, n_kernelbench, n_thread_lint,
-     n_commbench, n_memsnap, n_fleet, problems) = \
+     n_serving, n_kernel, n_reqtrace, n_thread_lint, n_commbench,
+     n_memsnap, n_fleet, problems) = \
         check_metrics_jsonl(jsonl_path)
     stats = {"n_records": n_rec, "n_steps": n_steps,
              "n_compiles": n_compiles, "n_ckpt": n_ckpt,
              "n_plan": n_plan,
              "n_elastic": n_elastic, "n_serving": n_serving,
              "n_kernel": n_kernel, "n_reqtrace": n_reqtrace,
-             "n_kernelbench": n_kernelbench,
              "n_thread_lint": n_thread_lint,
              "n_commbench": n_commbench,
              "n_memsnap": n_memsnap,
@@ -1195,9 +1124,8 @@ def check_pair(jsonl_path, trace_path=None):
                 if isinstance(trace, dict) else trace
             steps = [e for e in events if isinstance(e, dict)
                      and e.get("cat") == "step" and e.get("ph") == "X"]
-            # cross-check against STEP records only: phase-only JSONL
-            # next to a stepped trace used to vacuously pass (the phase
-            # lines inflated the record count)
+            # cross-check against STEP records only: records of other
+            # kinds next to a stepped trace must not inflate the count
             if steps and n_steps == 0:
                 problems.append(
                     f"{trace_path}: {len(steps)} step spans but "
@@ -1235,8 +1163,6 @@ def main(argv):
         msg += f" ({stats['n_kernel']} kernel-lint records)"
     if stats.get("n_reqtrace"):
         msg += f" ({stats['n_reqtrace']} request traces)"
-    if stats.get("n_kernelbench"):
-        msg += f" ({stats['n_kernelbench']} kernel measurements)"
     if stats.get("n_thread_lint"):
         msg += f" ({stats['n_thread_lint']} thread-lint records)"
     if stats.get("n_commbench"):
